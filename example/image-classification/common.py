@@ -41,7 +41,7 @@ def add_common_args(parser):
 
 def parse_devices(spec):
     if not spec:
-        return [mx.tpu()] if mx.num_tpus() > 0 else [mx.cpu()]
+        return [mx.context.default_device_context()]
     devs = []
     for tok in spec.split(","):
         tok = tok.strip()

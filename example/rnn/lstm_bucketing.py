@@ -61,7 +61,7 @@ def main():
 
     mod = mx.mod.BucketingModule(
         sym_gen, default_bucket_key=train.default_bucket_key,
-        context=[mx.tpu()] if mx.num_tpus() > 0 else [mx.cpu()])
+        context=[mx.context.default_device_context()])
     mod.fit(train, eval_metric=mx.metric.Perplexity(ignore_label=None),
             kvstore=args.kvstore,
             optimizer="sgd",

@@ -5,7 +5,7 @@ memoized static pricing, and replay-manifest construction
 Chip windows are scarce, so config selection happens off-chip: every
 model the search needs already exists in this package and prices a
 graph without lowering anything — MXL-R (roofline MFU ceiling,
-calibrated against the compiled AOT table in AOT_r05.json), MXL-M
+calibrated against the compiled AOT table in docs/mfu_gap.md), MXL-M
 (peak-HBM fit), MXL-K (Mosaic tile legality), MXL-E (pipeline/MoE
 schedule lint — infeasible stage splits and expert counts are pruned,
 a feasible pipeline config's ceiling is scaled by its simulated 1F1B
@@ -32,8 +32,8 @@ arrive.
 
 HBM feasibility is a *predictor*, not the MXL-M lint: the analytic
 peak keeps every residual live, while the compiled step re-materializes
-and dies long before that bound (AOT_r05.json: 11.2 GB compiled temp
-at b512 vs 70 GB analytic).  The predictor credits activations with
+and dies long before that bound (docs/mfu_gap.md's table: 11.2 GB
+compiled temp at b512 vs 70 GB analytic).  The predictor credits activations with
 ``MXTPU_AUTOTUNE_ACT_CREDIT`` (default 0.2, calibrated against the
 same AOT rows) and shards state across the config's mesh; MXL-M's own
 lint semantics are untouched.
@@ -309,7 +309,7 @@ def predicted_peak_hbm(config, mem):
     The analytic ``peak_hbm_report`` keeps every residual live;
     compiled programs re-materialize and stage, so activations get an
     AOT-calibrated credit (``MXTPU_AUTOTUNE_ACT_CREDIT``, default 0.2
-    — AOT_r05.json b512: 11.2 GB compiled temp vs 70 GB analytic).
+    — docs/mfu_gap.md b512: 11.2 GB compiled temp vs 70 GB analytic).
     dp·tp shard the batch/hidden activation axes; params/grads/opt
     state shard over tp, and over dp too when the rule is fsdp
     (ZeRO-3)."""

@@ -15,7 +15,7 @@ TPU); MXU ops pay 3 passes in training, others 2, plus 24 bytes per
 trained parameter scalar (f32 grad write + optimizer state + master
 weight round-trip).  The raw per-op sum is fusion-blind, so training
 traffic is **calibrated against the compiled AOT rows in
-AOT_r05.json** with two terms: a fusion factor (XLA elides ~23% of
+docs/mfu_gap.md's table** with two terms: a fusion factor (XLA elides ~23% of
 naive per-op traffic once producers fuse into consumers) and a
 batch-independent staging term per trained parameter (the
 copy-start/copy-done alternate-memory traffic visible in the AOT
@@ -64,8 +64,8 @@ _TRAIN_PASSES_MXU = 3
 _TRAIN_PASSES_OTHER = 2
 # f32 grad write + optimizer state read/write + master weight round-trip
 _PARAM_UPDATE_BYTES = 24
-# training-traffic calibration vs the compiled AOT table (AOT_r05.json,
-# docs/mfu_gap.md): fraction of naive per-op bytes that survive XLA
+# training-traffic calibration vs the compiled AOT table
+# (docs/mfu_gap.md): fraction of naive per-op bytes that survive XLA
 # fusion, and alternate-memory staging bytes per trained parameter
 # (batch-independent: the entry computation's copy-start/done pairs
 # move weights, not activations)
@@ -224,7 +224,7 @@ def roofline_report(ctx):
     op_bytes = sum(r["bytes"] for r in facts["rows"])
     calibration = None
     if facts["training"] and ctx.target == "tpu":
-        # the AOT_r05.json fit (see module docstring): fused traffic +
+        # the docs/mfu_gap.md fit (see module docstring): fused traffic +
         # param-update round-trip + batch-independent staging
         calibration = {
             "fusion_factor": _env_float(

@@ -97,7 +97,7 @@ def _ensure_builtin_specs():
     except Exception:
         pass
     try:
-        from .. import kernels  # noqa: F401  (quantize/flash_decode/fused_opt)
+        from .. import kernels  # noqa: F401  (quantize/fused_opt)
     except Exception:
         pass
 

@@ -14,6 +14,11 @@ Mapping rules:
   ``mx.gpu(0)`` run unchanged on a TPU chip (north-star "context-string
   change only").
 - ``cpu_pinned(i)`` -> cpu (pinned memory is meaningless under XLA host).
+
+A context is a placement, not a label: ``tpu()``/``gpu()`` raise when the
+process has no accelerator (the reference's ``gpu(0)`` fails the same way
+on a CPU-only build), and every array created or written under a context
+lives on that context's device (``ndarray.NDArray._set_data``).
 """
 from __future__ import annotations
 
@@ -22,7 +27,7 @@ import threading
 from .base import MXNetError
 
 __all__ = ["Context", "cpu", "gpu", "tpu", "cpu_pinned", "current_context",
-           "num_gpus", "num_tpus"]
+           "num_gpus", "num_tpus", "default_device_context"]
 
 
 class Context:
@@ -77,7 +82,15 @@ class Context:
         import jax
 
         if self.device_type in ("cpu", "cpu_pinned"):
-            devs = jax.local_devices(backend="cpu")
+            try:
+                devs = jax.local_devices(backend="cpu")
+            except RuntimeError as exc:
+                # JAX_PLATFORMS names the accelerator alone: the host
+                # backend does not exist beside it
+                raise MXNetError(
+                    "%s: jax has no cpu backend in this process (%s); "
+                    "list it beside the accelerator, e.g. "
+                    "JAX_PLATFORMS=tpu,cpu" % (self, exc)) from exc
         else:
             # gpu and tpu both mean "the accelerator platform".
             devs = _accelerator_devices()
@@ -89,8 +102,10 @@ class Context:
                     "(cluster has %d remote devices); use the host-local "
                     "device ids of this worker" % (self, len(devs)))
             devs = local
-            if not devs:  # CPU-only test environment: fall back gracefully
-                devs = jax.local_devices(backend="cpu")
+            if not devs:
+                raise MXNetError(
+                    "%s: this process has no accelerator (jax.devices() is "
+                    "%s); use mx.cpu()" % (self, jax.devices()))
         if self.device_id >= len(devs):
             raise MXNetError(
                 "%s: device_id %d out of range (%d %s devices visible)"
@@ -112,11 +127,7 @@ class Context:
 def _accelerator_devices():
     import jax
 
-    try:
-        devs = jax.devices()
-    except RuntimeError:
-        return []
-    return [d for d in devs if d.platform != "cpu"]
+    return [d for d in jax.devices() if d.platform != "cpu"]
 
 
 def cpu(device_id=0) -> Context:
@@ -142,6 +153,13 @@ def num_gpus() -> int:
 
 def num_tpus() -> int:
     return len(_accelerator_devices())
+
+
+def default_device_context() -> Context:
+    """The accelerator when this process has one, else the host: what the
+    example scripts and the serving tools bind to when the user names no
+    device."""
+    return tpu() if num_tpus() > 0 else cpu()
 
 
 def current_context() -> Context:

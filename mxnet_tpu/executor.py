@@ -44,12 +44,8 @@ def trace_residual_bytes(trace, arg_values, aux_values, wrt_names):
     differentiating wrt ``wrt_names`` — the backend-independent
     activation-memory number (what mirroring shrinks).  Shared by
     Executor.backward_residual_bytes, the multichip dryrun, and the
-    mirror tests.  Returns None when the saved-residuals introspection
-    (a private jax API) is unavailable."""
-    try:
-        from jax._src.ad_checkpoint import saved_residuals
-    except ImportError:
-        return None
+    mirror tests."""
+    from jax._src.ad_checkpoint import saved_residuals
     wrt = {n: arg_values[n] for n in wrt_names}
 
     def f(wrt_values):
@@ -254,10 +250,7 @@ def _build_program(symbol, group2ctx):
     for node in topo:
         group = node.attrs.get("ctx_group")
         if group and group in group2ctx:
-            try:
-                node_device[id(node)] = group2ctx[group].jax_device
-            except Exception:
-                pass
+            node_device[id(node)] = group2ctx[group].jax_device
 
     variables = [n for n in topo if n.is_variable]
     segments = _mirror_segments([n for n in topo if not n.is_variable])
@@ -399,6 +392,8 @@ class Executor:
         self._ctx = ctx if isinstance(ctx, Context) else Context(ctx)
         self._group2ctx = group2ctx or {}
         self._monitor_callback = None
+        from .parallel import overlap as _overlap
+        _overlap.enable_persistent_cache()   # on-disk XLA cache, idempotent
 
         # bind-time graph validation knob: "warn" (default) surfaces lint
         # findings as GraphLintWarning, "error" refuses to bind a graph
@@ -459,6 +454,7 @@ class Executor:
                         for a, s in zip(aux_list, aux_shapes)]
         self.aux_arrays = aux_list
         self.aux_dict = dict(zip(self._aux_names, aux_list))
+        self._check_placement()
 
         # static graph lint BEFORE tracing: a bad graph fails here with
         # positioned findings instead of an opaque XLA trace error
@@ -492,9 +488,15 @@ class Executor:
         # read by _mirror_segments) plus the validation-rules fingerprint,
         # so a flag flip between binds cannot reuse a stale program.
         # Caching bound methods here would pin the first executor's buffers.
+        # ... and the mesh context the bind happens under (the Module
+        # mesh group's): attention traces differently per mesh, so a
+        # one-device program must not be reused for a mesh executor.
+        from .parallel.ring_attention import current_sequence_parallel
+        scope = current_sequence_parallel()
         cache_key = (tuple(sorted((k, str(v))
                                   for k, v in self._group2ctx.items())),
-                     _bind_env_fingerprint(self._validate_mode))
+                     _bind_env_fingerprint(self._validate_mode),
+                     scope.fingerprint() if scope is not None else None)
         cache = getattr(symbol, "_jit_cache", None)
         if cache is None:
             cache = symbol._jit_cache = {}
@@ -512,6 +514,27 @@ class Executor:
         self._n_fused_step = 0
         self._n_monitored_compiled = 0
         self._fused_cache = None  # (optimizer fingerprint, jitted step)
+
+    def _check_placement(self):
+        """Refuse arrays that do not live on this executor's context (or,
+        for ``ctx_group`` graphs, on one of the ``group2ctx`` contexts) —
+        the reference's bind contract (graph_executor.cc:391
+        AssignContext checks every arg against its node's device).  jit
+        would otherwise run wherever the committed arrays happen to sit,
+        whatever ``ctx`` says."""
+        devices = {c.jax_device
+                   for c in [self._ctx] + list(self._group2ctx.values())}
+        for kind, arrays in (("argument", self.arg_dict),
+                             ("gradient", self.grad_dict),
+                             ("aux state", self.aux_dict)):
+            for name, arr in arrays.items():
+                if devices.isdisjoint(arr.data.devices()):
+                    raise MXNetError(
+                        "bind: %s %r lives on %s, not on the executor's "
+                        "context %s; move it with as_in_context()"
+                        % (kind, name, sorted(str(d) for d in
+                                              arr.data.devices()),
+                           self._ctx))
 
     def _validate_bind(self, args, args_grad, grad_req, aux_states):
         """Run the static analyzer with full bind context and apply the
@@ -850,8 +873,7 @@ class Executor:
         (``force_mirroring``/MXNET_BACKWARD_DO_MIRROR ->
         ``jax.checkpoint``) exists to shrink.  Backend-independent: read
         from the partial-eval trace, not the compiled executable (XLA:CPU
-        does not attribute temp buffers).  Returns None when jax's
-        saved-residuals introspection is unavailable."""
+        does not attribute temp buffers)."""
         arg_values = {n: a.data for n, a in self.arg_dict.items()}
         aux_values = {n: a.data for n, a in self.aux_dict.items()}
         wrt_names = tuple(n for n in self._arg_names
@@ -868,7 +890,9 @@ class Executor:
             a = self.arg_dict[n]
             s = optimizer.create_state_arrays(a.shape, a.dtype)
             if s is not None:
-                states[n] = s
+                # beside the weight it updates (a mesh-replicated weight
+                # gives a mesh-replicated state)
+                states[n] = jax.device_put(s, a.data.sharding)
         return states
 
     # -- monitor (MXExecutorSetMonitorCallback parity) ------------------

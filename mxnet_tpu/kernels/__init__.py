@@ -1,32 +1,31 @@
 """Quantized + fused kernel tier (docs/perf.md "Quantization & fused
 kernels").
 
-Three legs, each a Pallas kernel with an exact jnp reference fallback
-and an MXL-K spec registered through ``analysis.tiling.KERNEL_SPECS``:
+Two legs, each a Pallas kernel with an exact jnp reference and an MXL-K
+spec registered through ``analysis.tiling.KERNEL_SPECS``; which of the
+two a call runs is decided by where the computation is placed
+(:func:`.common.dispatch`):
 
 - :mod:`.quantize` — per-channel int8/fp8 weight-only quantization
   (params + symbol rewrite) and the dequant-in-registers matmul behind
   the ``QuantizedDense`` op;
-- :mod:`.flash_decode` — fused single-query attention over the paged
-  KV cache's block table (``MXTPU_FLASH_DECODE``);
 - :mod:`.fused_opt` — the bucketed flatten/update/unflatten optimizer
   sweep replacing the per-leaf tree-map (``MXTPU_FUSED_OPT``).
 
-Importing this package registers all three kernel specs, so
-``mxlint`` / ``Symbol.validate()`` statically tile-check every block
-layout the kernels use (``analysis.tiling._ensure_builtin_specs``
-imports it for the same reason).
+(The third Pallas kernel in the tree is the flash-attention forward in
+``parallel/ring_attention.py``.)
+
+Importing this package registers both kernel specs, so ``mxlint`` /
+``Symbol.validate()`` statically tile-check every block layout the
+kernels use (``analysis.tiling._ensure_builtin_specs`` imports it for
+the same reason).
 """
-from . import quantize, flash_decode, fused_opt               # noqa: F401
+from . import quantize, fused_opt                              # noqa: F401
 from .quantize import (quantize_params, quantize_symbol,       # noqa: F401
                        quantizable_weights, quantized_matmul)
-from .flash_decode import (flash_decode_attention,             # noqa: F401
-                           decode_attention_reference,
-                           flash_decode_enabled)
 from .fused_opt import fused_apply, fused_opt_mode, supports_fused  # noqa: F401,E501
 
-__all__ = ["quantize", "flash_decode", "fused_opt",
+__all__ = ["quantize", "fused_opt",
            "quantize_params", "quantize_symbol", "quantizable_weights",
-           "quantized_matmul", "flash_decode_attention",
-           "decode_attention_reference", "flash_decode_enabled",
+           "quantized_matmul",
            "fused_apply", "fused_opt_mode", "supports_fused"]

@@ -12,9 +12,10 @@ with one sweep per size-targeted bucket:
 2. each bucket's weights/grads/state leaves are flattened and
    concatenated into single vectors INSIDE the traced step;
 3. the optimizer's pure ``update_fn`` runs once on the concatenated
-   vectors — on the Pallas elementwise sweep kernel below when
-   ``MXTPU_FUSED_OPT=kernel`` (TPU), as a plain fused XLA computation
-   when ``MXTPU_FUSED_OPT=1``;
+   vectors — as a plain fused XLA computation when
+   ``MXTPU_FUSED_OPT=1``; on the Pallas elementwise sweep kernel below
+   when ``MXTPU_FUSED_OPT=kernel`` and the step is placed on a TPU
+   (the same XLA computation anywhere else, ``common.dispatch``);
 4. results are sliced back to the original leaf shapes.
 
 Bit-identity: this is only legal for optimizers whose update is purely
@@ -25,9 +26,10 @@ tree-map path (asserted on a multi-device mesh by
 tests/test_kernels.py).  LAMB (per-tensor trust ratios) and SGLD
 (per-leaf noise draws) refuse the fused path and fall back.
 
-The sweep kernel views each bucket as a (rows, 128) lane-major sheet
-(tail-padded with zeros, dropped on unflatten) and tiles rows in
-granule-aligned blocks; scalars (lr, wd, t) ride as (1, 1) blocks.
+The sweep kernel views each bucket as a (rows, 128) lane-major sheet,
+tail-padded with zeros to a whole number of row blocks (dropped on
+unflatten), and tiles rows in granule-aligned blocks; scalars (lr, wd,
+t) ride as (1, 1) blocks.
 """
 from __future__ import annotations
 
@@ -37,7 +39,7 @@ import numpy as _np
 
 from ..base import MXNetError
 from ..analysis.tiling import register_kernel_spec
-from .common import env_flag, pick_block, resolve_interpret
+from .common import cdiv, dispatch, env_flag, pick_block
 
 __all__ = ["fused_opt_mode", "supports_fused", "plan_buckets",
            "fused_apply", "fused_opt_kernel_spec"]
@@ -137,10 +139,11 @@ def _sweep_call(w, g, state_leaves, lr, wd, t, update, interpret,
     import jax.experimental.pallas as pl
 
     n = w.shape[0]
-    rows = -(-n // _LANES)
-    pad = rows * _LANES - n
     sub = {1: 32, 2: 16}.get(jnp.dtype(w.dtype).itemsize, 8)
-    br = pick_block(rows, sub, block_rows)
+    br = pick_block(cdiv(n, _LANES), sub, block_rows)
+    # the flat vector is padded anyway: pad to whole row BLOCKS
+    rows = cdiv(cdiv(n, _LANES), br) * br
+    pad = rows * _LANES - n
     n_state = len(state_leaves)
 
     def sheet(v):
@@ -171,9 +174,10 @@ def _sweep_call(w, g, state_leaves, lr, wd, t, update, interpret,
         out_specs=out_specs,
         out_shape=[jax.ShapeDtypeStruct(b[1], w.dtype)
                    for b in out_blocks],
+        name="fused_opt_sweep",
         interpret=interpret,
     )(sheet(w), sheet(g), *[sheet(s) for s in state_leaves],
-      scalar(lr), scalar(wd), jnp.asarray(t, jnp.float32).reshape(1, 1))
+      scalar(lr), scalar(wd), scalar(t))
 
     def unsheet(v):
         return v.reshape(rows * _LANES)[:n]
@@ -227,15 +231,20 @@ def fused_apply(optimizer, params, grads, opt_state, lr, wd, t,
         if preprocess is not None:
             g_flat = preprocess(g_flat)
         state_leaves = _concat_state(optimizer, opt_state, bucket)
-        if mode == "kernel":
-            itp = resolve_interpret(interpret, "MXTPU_FUSED_OPT")
-            if itp is None:
-                itp = True      # explicit kernel mode off-TPU: interpret
-            nw, ns = _sweep_call(w_flat, g_flat, state_leaves,
-                                 lr, wd, t, update, itp)
+        scalars = [jnp.asarray(v, jnp.float32) for v in (lr, wd, t)]
+
+        def pallas_sweep(w, g, leaves, lr_, wd_, t_, interpret=False):
+            return _sweep_call(w, g, leaves, lr_, wd_, t_, update,
+                               interpret)
+
+        if mode != "kernel":
+            nw, ns = update(w_flat, g_flat, state_leaves, *scalars)
+        elif interpret is not None:
+            nw, ns = pallas_sweep(w_flat, g_flat, state_leaves, *scalars,
+                                  interpret=bool(interpret))
         else:
-            t_f = jnp.asarray(t, jnp.float32)
-            nw, ns = update(w_flat, g_flat, state_leaves, lr, wd, t_f)
+            nw, ns = dispatch(pallas_sweep, update, w_flat, g_flat,
+                              state_leaves, *scalars)
         offset = 0
         for n, size in zip(bucket, sizes):
             shape = tuple(params[n].shape)
@@ -289,9 +298,9 @@ def fused_opt_kernel_spec(numel=1 << 20, block_rows=512, dtype="float32",
     """MXL-K spec for the sweep at one dtype (CI sweeps f32/bf16/int8;
     row blocks are granule multiples at all three) — same layout helper
     as the call."""
-    rows = -(-int(numel) // _LANES)
     sub = {1: 32, 2: 16}.get(_np.dtype(dtype).itemsize, 8)
-    br = pick_block(rows, sub, block_rows)
+    br = pick_block(cdiv(numel, _LANES), sub, block_rows)
+    rows = cdiv(cdiv(numel, _LANES), br) * br
     in_blocks, out_blocks = _sweep_block_layout(rows, br, dtype, n_state)
     names_in = (["weight", "grad"]
                 + ["state%d" % i for i in range(n_state)]

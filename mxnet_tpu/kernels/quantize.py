@@ -37,7 +37,7 @@ import numpy as _np
 
 from ..base import MXNetError
 from ..analysis.tiling import register_kernel_spec
-from .common import pick_block, resolve_interpret
+from .common import cdiv, dispatch, pick_block
 
 __all__ = ["QDTYPES", "storage_dtype", "quantize_array",
            "dequantize_array", "quantize_params", "quantizable_weights",
@@ -269,15 +269,25 @@ def quantized_matmul(x, w_q, scale, block_m=256, block_n=512, block_k=512,
                      interpret=None):
     """Weight-only quantized matmul ``x (M, K) @ w_q (N, K).T * scale``.
 
-    Pallas on TPU (or ``interpret=True``/``MXTPU_QUANTIZE_FORCE``), jnp
-    reference elsewhere — both produce float32 accumulation cast back
-    to x's dtype.  Block sizes adapt down to exact divisors of the
-    problem dims (``common.pick_block``) so no grid step computes
-    padding.
+    The Pallas kernel where the computation is placed on a TPU, the jnp
+    reference elsewhere (``common.dispatch``); an explicit ``interpret``
+    runs the kernel either way.  Both accumulate in float32 and cast
+    back to x's dtype.
     """
-    mode = resolve_interpret(interpret, "MXTPU_QUANTIZE_FORCE")
-    if mode is None:
-        return quantized_matmul_reference(x, w_q, scale)
+    def kernel(x, w_q, scale, interpret=False):
+        return _qmm_call(x, w_q, scale, block_m, block_n, block_k,
+                         interpret)
+
+    if interpret is not None:
+        return kernel(x, w_q, scale, interpret=bool(interpret))
+    return dispatch(kernel, quantized_matmul_reference, x, w_q, scale)
+
+
+def _qmm_call(x, w_q, scale, block_m, block_n, block_k, interpret):
+    """The pallas_call.  M and N only index outputs, so their trailing
+    blocks may be partial (an LM head of 50,257 rows needs no padded
+    copy of the weight); K is reduced over, so it is tiled exactly or
+    zero-padded."""
     import jax
     import jax.numpy as jnp
     import jax.experimental.pallas as pl
@@ -285,13 +295,16 @@ def quantized_matmul(x, w_q, scale, block_m=256, block_n=512, block_k=512,
     (m, k), (n, _k2) = x.shape, w_q.shape
     bm, bk, bn = _qmm_blocks(m, k, n, x.dtype, str(w_q.dtype), block_m,
                              block_n, block_k)
+    n_k_blocks = cdiv(k, bk)
+    if k % bk:
+        pad = ((0, 0), (0, n_k_blocks * bk - k))
+        x, w_q, k = jnp.pad(x, pad), jnp.pad(w_q, pad), n_k_blocks * bk
     in_blocks, out_blocks = _qmm_block_layout(m, k, n, bm, bk, bn,
                                               w_q.dtype, x.dtype)
-    n_k_blocks = k // bk
     kernel = functools.partial(_qmm_kernel, n_k_blocks=n_k_blocks)
     out = pl.pallas_call(
         kernel,
-        grid=(m // bm, n // bn, n_k_blocks),
+        grid=(cdiv(m, bm), cdiv(n, bn), n_k_blocks),
         in_specs=[
             pl.BlockSpec(in_blocks[0][0], lambda i, j, kk: (i, kk)),
             pl.BlockSpec(in_blocks[1][0], lambda i, j, kk: (j, kk)),
@@ -299,7 +312,8 @@ def quantized_matmul(x, w_q, scale, block_m=256, block_n=512, block_k=512,
         ],
         out_specs=pl.BlockSpec(out_blocks[0][0], lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct(out_blocks[0][1], jnp.float32),
-        interpret=mode,
+        name="quantized_matmul",
+        interpret=interpret,
     )(x, w_q, scale.reshape(1, n).astype(jnp.float32))
     return out.astype(x.dtype)
 
@@ -323,7 +337,7 @@ def qmm_kernel_spec(m=256, k=1024, n=1024, block_m=256, block_n=512,
                    "dtype": out_blocks[0][2]})
     return {"name": "quantized_matmul[%s,w:%s]" % (dtype, qd),
             "origin": "mxnet_tpu/kernels/quantize.py",
-            "grid": (m // bm, n // bn, k // bk),
+            "grid": (cdiv(m, bm), cdiv(n, bn), cdiv(k, bk)),
             "blocks": blocks}
 
 

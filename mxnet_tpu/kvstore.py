@@ -856,13 +856,7 @@ def _maybe_init_distributed():
     elastic.check_generation_fence()
     if getattr(_maybe_init_distributed, "_done", False):
         return
-    already = False
-    try:
-        already = jax.distributed.is_initialized()
-    except AttributeError:  # older jax
-        from jax._src import distributed as _dist
-        already = _dist.global_state.client is not None
-    if already:
+    if jax.distributed.is_initialized():
         _maybe_init_distributed._done = True
         return
     missing = [k for k in ("MXTPU_NUM_WORKERS", "MXTPU_WORKER_RANK")

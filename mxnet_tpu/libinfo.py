@@ -20,14 +20,33 @@ _ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 _LIB_PATH = os.path.join(_ROOT, "lib", "libmxtpu.so")
 
 
+#: why the last build attempt failed (the compiler's last lines), or None
+_BUILD_ERROR = None
+
+
 def _try_build():
-    """Best-effort `make` of the native lib (once per process)."""
+    """`make` the native lib from the tracked sources (lib/ is ignored
+    build output: a fresh checkout has none).  True when the library
+    exists afterwards; a failure keeps its reason in ``_BUILD_ERROR``."""
+    global _BUILD_ERROR
     try:
         subprocess.run(["make", "-s", "-C", _ROOT],
                        check=True, capture_output=True, timeout=120)
-        return os.path.exists(_LIB_PATH)
-    except Exception:
+    except (OSError, subprocess.SubprocessError) as exc:
+        tail = getattr(exc, "stderr", None) or b""
+        _BUILD_ERROR = "%r %s" % (exc, tail.decode(
+            "utf-8", "replace").strip()[-400:])
         return False
+    return os.path.exists(_LIB_PATH)
+
+
+def describe():
+    """One line saying which host runtime this process got — what
+    chip_smoke.py prints instead of warning and carrying on."""
+    if find_lib() is not None:
+        return "native engine/recordio (%s)" % _LIB_PATH
+    return "pure-python engine/recordio (no %s: %s)" % (
+        _LIB_PATH, _BUILD_ERROR or "MXTPU_NO_NATIVE set, or no make/g++")
 
 
 def find_lib(build=True):
@@ -57,7 +76,7 @@ def _find_lib_locked(build):
             import warnings
             warnings.warn("mxnet_tpu: native library build failed; "
                           "falling back to pure-python engine/recordio "
-                          "(run `make` in %s for details)" % _ROOT)
+                          "(%s)" % _BUILD_ERROR)
     if not os.path.exists(_LIB_PATH):
         return None
     try:

@@ -132,12 +132,13 @@ class DataParallelExecutorGroup(object):
             shared_exec = None if shared_group is None else \
                 shared_group.execs[i]
             need_grad = {n for n, r in grad_req_dict.items() if r != "null"}
-            exec_ = _bind_exec(self.symbol, ctx, input_shapes,
-                               self.param_names,
-                               need_grad=need_grad if for_training else False,
-                               base_exec=shared_exec,
-                               shared_data_arrays=self.shared_data_arrays[i],
-                               grad_req=grad_req_dict)
+            with self._mesh_scope():    # the program is keyed on it
+                exec_ = _bind_exec(
+                    self.symbol, ctx, input_shapes, self.param_names,
+                    need_grad=need_grad if for_training else False,
+                    base_exec=shared_exec,
+                    shared_data_arrays=self.shared_data_arrays[i],
+                    grad_req=grad_req_dict)
             self.execs.append(exec_)
 
         self.data_arrays = [[(self.slices[i], e.arg_dict[name])
@@ -169,10 +170,7 @@ class DataParallelExecutorGroup(object):
         log = logger or logging
         if len({c.device_type for c in contexts}) != 1:
             return
-        try:
-            devices = [c.jax_device for c in contexts]
-        except Exception:
-            return
+        devices = [c.jax_device for c in contexts]
         if len(set(devices)) != len(devices):
             return
         n_proc = jax.process_count()
@@ -196,6 +194,14 @@ class DataParallelExecutorGroup(object):
     @property
     def _num_proc(self):
         return getattr(self, "_n_proc", 1)
+
+    def _mesh_scope(self):
+        """The mesh context attention ops need while the mesh executor
+        is bound, traced and run (``ring_attention.sequence_parallel``):
+        per-device flash attention — GSPMD cannot partition a Mosaic
+        kernel.  A no-op for per-device slicing groups (no mesh)."""
+        from ..parallel.ring_attention import attention_scope
+        return attention_scope(self._mesh)
 
     def _put_sharded(self, value, sharding):
         """numpy/NDArray -> global jax array with the given sharding; the
@@ -254,8 +260,9 @@ class DataParallelExecutorGroup(object):
         if is_train is None:
             is_train = self.for_training
         self._ensure_on_mesh()
-        for exec_ in self.execs:
-            exec_.forward(is_train=is_train)
+        with self._mesh_scope():
+            for exec_ in self.execs:
+                exec_.forward(is_train=is_train)
 
     def forward_backward(self, data_batch):
         """Fused fwd+bwd: ONE XLA dispatch per executor instead of the
@@ -264,8 +271,9 @@ class DataParallelExecutorGroup(object):
             raise MXNetError("re-bind with for_training=True to run backward")
         self.load_data_batch(data_batch)
         self._ensure_on_mesh()
-        for exec_ in self.execs:
-            exec_.forward_backward()
+        with self._mesh_scope():
+            for exec_ in self.execs:
+                exec_.forward_backward()
 
     def fused_step(self, data_batch, optimizer, states, num_update):
         """Whole train step (fwd+bwd+optimizer update) as one dispatch.
@@ -278,7 +286,8 @@ class DataParallelExecutorGroup(object):
         self.load_data_batch(data_batch)
         if self.sharded:
             states = self._ensure_on_mesh((states,))[0]
-        return self.execs[0].fused_step(optimizer, states, num_update)
+        with self._mesh_scope():
+            return self.execs[0].fused_step(optimizer, states, num_update)
 
     def fused_step_hlo(self, optimizer):
         """Lowered HLO text of the fused step (introspection/tests: the
@@ -302,19 +311,21 @@ class DataParallelExecutorGroup(object):
                     raise MXNetError("fused_step_hlo under multi-process "
                                      "needs a batch loaded first "
                                      "(load_data_batch)")
-        return exec_.lower_fused_step(optimizer, states)
+        with self._mesh_scope():
+            return exec_.lower_fused_step(optimizer, states)
 
     def backward(self, out_grads=None):
         if not self.for_training:
             raise MXNetError("re-bind with for_training=True to run backward")
-        for i, exec_ in enumerate(self.execs):
-            if out_grads is not None:
-                islice = self.slices[i]
-                sliced = [g[islice] if g.shape[0] == self.batch_size else g
-                          for g in out_grads]
-                exec_.backward(sliced)
-            else:
-                exec_.backward()
+        with self._mesh_scope():
+            for i, exec_ in enumerate(self.execs):
+                if out_grads is not None:
+                    islice = self.slices[i]
+                    sliced = [g[islice] if g.shape[0] == self.batch_size
+                              else g for g in out_grads]
+                    exec_.backward(sliced)
+                else:
+                    exec_.backward()
 
     # ------------------------------------------------------------------
     def get_outputs(self, merge_multi_context=True):
@@ -345,6 +356,9 @@ class DataParallelExecutorGroup(object):
     def set_params(self, arg_params, aux_params):
         for exec_ in self.execs:
             exec_.copy_params_from(arg_params, aux_params)
+        # a mesh group's parameters live on EVERY device of the mesh, not
+        # on the first context until the next step happens to move them
+        self._ensure_on_mesh()
 
     def update_metric(self, eval_metric, labels):
         if self.sharded and self._num_proc > 1:

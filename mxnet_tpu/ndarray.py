@@ -34,6 +34,7 @@ from .context import Context, current_context
 __all__ = [
     "NDArray", "zeros", "ones", "empty", "full", "array", "arange",
     "concatenate", "load", "save", "waitall", "onehot_encode", "imdecode",
+    "on_context",
 ]
 
 import jax
@@ -48,13 +49,12 @@ _LIVE = weakref.WeakSet()
 _LIVE_LOCK = threading.Lock()
 
 
-def _ctx_device(ctx):
-    try:
-        return ctx.jax_device
-    except MXNetError:
-        raise  # out-of-range device id is a real user error
-    except Exception:
-        return None  # backend not initialisable (e.g. no accelerator): stay on default
+def _on_device(data, dev):
+    """``data`` as a jax array whose storage includes ``dev``.  Arrays
+    already there — mesh-sharded ones included — pass through untouched."""
+    if isinstance(data, jax.Array) and dev in data.devices():
+        return data
+    return jax.device_put(data, dev)
 
 
 class NDArray:
@@ -82,13 +82,10 @@ class NDArray:
             return
         if isinstance(data, NDArray):
             data = data.data
-        if not isinstance(data, jax.Array):
+        if not isinstance(data, (jax.Array, _np.ndarray)):
             data = jnp.asarray(data)
         ctx = ctx if ctx is not None else current_context()
-        dev = _ctx_device(ctx)
-        if dev is not None and (not hasattr(data, "devices") or dev not in data.devices()):
-            data = jax.device_put(data, dev)
-        self._storage = data
+        self._storage = _on_device(data, ctx.jax_device)
         self._ctx = ctx
 
     # ------------------------------------------------------------------
@@ -107,16 +104,22 @@ class NDArray:
         This is the moral equivalent of an engine write-dependency push
         (threaded_engine.cc:53-79): in XLA, rebinding to a new buffer whose
         computation depends on the old one gives the same serialization.
+        The new buffer stays on this array's context: a value computed
+        elsewhere (another context, jax's default device) is moved here.
         """
         if not self._writable:
             raise MXNetError("trying to write to a read-only NDArray")
-        value = jnp.asarray(value, dtype=self.dtype)
+        # a host value bound for storage is shaped on the host, so it
+        # reaches the context's device in one transfer
+        on_host = self._parent is None and not isinstance(value, jax.Array)
+        xp = _np if on_host else jnp
+        value = xp.asarray(value, dtype=self.dtype)
         if value.shape != self.shape:
-            value = jnp.broadcast_to(value, self.shape)
+            value = xp.broadcast_to(value, self.shape)
         if self._parent is not None:
             self._parent._set_data(self._setter(self._parent.data, value))
         else:
-            self._storage = value
+            self._storage = _on_device(value, self._ctx.jax_device)
 
     # ------------------------------------------------------------------
     # basic properties
@@ -387,11 +390,21 @@ def full(shape, val, ctx=None, dtype=mx_real_t):
 def array(source_array, ctx=None, dtype=None):
     if isinstance(source_array, NDArray):
         src = source_array.data
-        dtype = dtype or src.dtype
-    else:
-        src = _np.asarray(source_array)
-        dtype = dtype or (src.dtype if src.dtype != _np.float64 else mx_real_t)
-    return NDArray(jnp.asarray(src, dtype=dtype), ctx=ctx)
+        return NDArray(src.astype(dtype or src.dtype), ctx=ctx)
+    # host data goes straight to the context's device (one transfer, not a
+    # detour through jax's default device)
+    src = _np.asarray(source_array)
+    dtype = dtype or (src.dtype if src.dtype != _np.float64 else mx_real_t)
+    return NDArray(src.astype(dtype, copy=False), ctx=ctx)
+
+
+def on_context(value, ctx):
+    """``value`` (an NDArray of any context, or host data) as an NDArray
+    on ``ctx``; an NDArray already there is returned itself, so callers
+    that bind the same weights many times share one copy."""
+    if isinstance(value, NDArray):
+        return value.as_in_context(ctx)
+    return array(value, ctx=ctx)
 
 
 def arange(start, stop=None, step=1.0, repeat=1, ctx=None, dtype=mx_real_t):

@@ -185,6 +185,27 @@ class MultiHeadAttention(OperatorProperty):
         return out
 
 
+def paged_decode_attention(q, k_pool, v_pool, table, pos):
+    """Single-query attention over a block-paged KV cache: gather the
+    blocks the table names, mask positions past ``pos``, softmax.
+    ``q (B, H, D)``, pools ``(NB, BS, H, D)``, ``table (B, MB) int32``,
+    ``pos (B,) int32`` (the newest token's index).  Returns ``(B, H,
+    D)`` in q's dtype."""
+    import jax
+    B, H, D = q.shape
+    BS = k_pool.shape[1]
+    MB = table.shape[1]
+    scale = 1.0 / float(_np.sqrt(D))
+    kk = k_pool[table].reshape(B, MB * BS, H, D).astype(q.dtype)
+    vv = v_pool[table].reshape(B, MB * BS, H, D).astype(q.dtype)
+    s = jnp.einsum("bhd,bthd->bht", q, kk) * scale
+    t_idx = jnp.arange(MB * BS, dtype=jnp.int32)
+    s = jnp.where(t_idx[None, None, :] <= pos[:, None, None], s, -1e30)
+    p = jax.nn.softmax(s, axis=-1)
+    o = jnp.einsum("bht,bthd->bhd", p, vv.astype(p.dtype))
+    return o.astype(q.dtype)
+
+
 class _CachedMHAParam(ParamStruct):
     num_heads = Field(int, required=True, lower=1)
     mode = Field(str, default="decode", doc="prefill | decode")
@@ -336,18 +357,8 @@ class CachedMultiHeadAttention(OperatorProperty):
             slot = pos % BS
             kc = kc.at[blk, slot].set(kh[:, 0].astype(kc.dtype))
             vc = vc.at[blk, slot].set(vh[:, 0].astype(vc.dtype))
-            scale = 1.0 / float(_np.sqrt(D))
-            qh = q.reshape(B, H, D)
-            from ..kernels import flash_decode as _fd
-            if _fd.flash_decode_enabled():
-                # MXTPU_FLASH_DECODE: block-parallel partial-softmax
-                # kernel over the block table (Pallas on TPU; the env
-                # resolver falls back to the exact reference elsewhere)
-                o = _fd.flash_decode_attention(qh, kc, vc, table, pos,
-                                               scale=scale)
-            else:
-                o = _fd.decode_attention_reference(qh, kc, vc, table, pos,
-                                                   scale=scale)
+            o = paged_decode_attention(q.reshape(B, H, D), kc, vc, table,
+                                       pos)
             o = o.astype(q.dtype).reshape(B, 1, E)
         return [o @ wo.T + bo, kc, vc], None
 

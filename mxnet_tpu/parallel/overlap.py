@@ -32,14 +32,14 @@ loops never hid:
    jax version) so a second ``ShardedTrainer`` bind, a bucketing-module
    rebind, or an elastic re-mesh resume at a previously-seen world size
    reuses the traced/lowered artifact instead of re-paying lowering.
-   :func:`enable_persistent_cache` additionally points JAX's on-disk
-   compilation cache at ``MXTPU_COMPILE_CACHE_DIR`` so even a fresh
-   process skips XLA compilation proper.
+   :func:`enable_persistent_cache` additionally keeps JAX's on-disk
+   compilation cache on (where ``JAX_COMPILATION_CACHE_DIR`` says, else
+   ``<checkout>/.jax_cache``) so even a fresh process skips XLA
+   compilation proper.
 
 Knobs: ``MXTPU_PREFETCH`` / ``prefetch=`` (off by default),
 ``MXTPU_PREFETCH_DEPTH`` (default 2, double buffering),
-``MXTPU_BUCKET_MB`` (default 25; ``0`` disables bucketing),
-``MXTPU_COMPILE_CACHE_DIR`` (unset disables the on-disk cache).
+``MXTPU_BUCKET_MB`` (default 25; ``0`` disables bucketing).
 """
 from __future__ import annotations
 
@@ -657,32 +657,27 @@ def compile_cache_clear():
             _STATS[k] = 0
 
 
-_PERSISTENT_ENABLED = [None]
+#: <checkout>/.jax_cache — computed from this file, never from the cwd:
+#: the directory is part of JAX's cache key, so one that moves never hits
+_CHECKOUT_CACHE = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".jax_cache")
 
 
-def enable_persistent_cache(path=None):
-    """Point JAX's on-disk compilation cache at ``path`` (default
-    ``MXTPU_COMPILE_CACHE_DIR``).  Idempotent; returns the active
-    directory or None when disabled/unavailable.  The on-disk layer
-    means a FRESH process skips XLA compilation; the in-process
+def enable_persistent_cache():
+    """Make sure XLA compilations persist on disk, and return where.
+
+    The ONE place the package decides the cache directory (Executor,
+    ShardedTrainer and the tools all call it; idempotent).  Whoever runs
+    the program steers it from outside: with ``JAX_COMPILATION_CACHE_DIR``
+    set JAX already uses that directory and nothing is touched here.
+    Unset, the cache lives in ``<checkout>/.jax_cache`` (gitignored).
+    A fresh process then skips XLA compilation proper; the in-process
     registry above additionally skips tracing/lowering."""
-    path = path or os.environ.get("MXTPU_COMPILE_CACHE_DIR")
-    if not path:
-        return _PERSISTENT_ENABLED[0]
-    if _PERSISTENT_ENABLED[0] == path:
-        return path
-    try:
-        import jax
-        os.makedirs(path, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", path)
-        try:
-            # cache even sub-second compiles: the unit suite's toy
-            # graphs are exactly what warms CI
-            jax.config.update(
-                "jax_persistent_cache_min_compile_time_secs", 0.0)
-        except Exception:
-            pass
-        _PERSISTENT_ENABLED[0] = path
-        return path
-    except Exception:
-        return None
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    import jax
+    if jax.config.jax_compilation_cache_dir != _CHECKOUT_CACHE:
+        jax.config.update("jax_compilation_cache_dir", _CHECKOUT_CACHE)
+    return _CHECKOUT_CACHE

@@ -29,16 +29,12 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-try:
-    from jax import shard_map as _shard_map
+from jax import shard_map as _shard_map
 
-    def shard_map(f, **kw):
-        return _shard_map(f, check_vma=False, **kw)
-except ImportError:  # older jax: kwarg is check_rep, not check_vma
-    from jax.experimental.shard_map import shard_map as _shard_map
 
-    def shard_map(f, **kw):
-        return _shard_map(f, check_rep=False, **kw)
+def shard_map(f, **kw):
+    return _shard_map(f, check_vma=False, **kw)
+
 
 from .mesh import make_mesh  # noqa: F401  (re-exported convenience)
 
@@ -48,18 +44,6 @@ __all__ = ["pipeline_apply", "GPipeTrainer", "build_1f1b_tables",
 
 def _identity_perm(k):
     return [(i, (i + 1) % k) for i in range(k)]
-
-
-def _axis_size(axis):
-    """Static size of a named mesh axis from inside shard_map.
-    ``lax.axis_size`` only exists in newer jax; older versions expose
-    the bound axis env through ``jax.core.axis_frame`` (which returns
-    either the size itself or a frame carrying it)."""
-    if hasattr(lax, "axis_size"):
-        return lax.axis_size(axis)
-    import jax.core as _core
-    frame = _core.axis_frame(axis)
-    return frame if isinstance(frame, int) else frame.size
 
 
 def _reverse_perm(k):
@@ -168,7 +152,7 @@ def pipeline_apply(block_fn, local_params, microbatches, *, axis="pp"):
     the caller's psum/where; here we simply return what each member
     drained — the caller masks by axis_index == K-1).
     """
-    k = _axis_size(axis)
+    k = lax.axis_size(axis)
     idx = lax.axis_index(axis)
     m = microbatches.shape[0]
     ticks = m + k - 1
@@ -232,7 +216,7 @@ def _pipeline_1f1b(block_fn, layers_p, stream, batch_mbs, head_loss_fn,
     on member 0, ``g_layers`` on every member for its own layers.  All
     unscaled: the caller divides by M for the microbatch mean.
     """
-    k = _axis_size(axis)
+    k = lax.axis_size(axis)
     idx = lax.axis_index(axis)
     m = stream.shape[0]
     depth = min(m, k + 1)  # stash ring: <= k in flight, +1 for the
@@ -488,7 +472,7 @@ class GPipeTrainer:
 
         def loss_and_grads(params, batch):
             def inner(embed_p, layers_p, head_p, local_batch):
-                k = _axis_size("pp")
+                k = lax.axis_size("pp")
                 idx = lax.axis_index("pp")
                 h = embed_fn(embed_p, local_batch)
                 mb = h.shape[0] // m

@@ -6,9 +6,9 @@ LSTM) — it is the TPU-native capability that replaces those workarounds
 for long sequences:
 
 - ``flash_attention``: fused online-softmax attention as a Pallas TPU
-  kernel (MXU matmuls, no (seq, seq) materialization in HBM).  Falls back
-  to the jnp reference implementation off-TPU so tests/CPU paths stay
-  exact.
+  kernel (MXU matmuls, no (seq, seq) materialization in HBM) where the
+  computation is placed on a TPU; the jnp reference implementation
+  anywhere else, so tests/CPU paths stay exact.
 - ``ring_attention``: blockwise attention over a ``Mesh`` axis ("sp"):
   each device holds a sequence chunk of q/k/v; k/v chunks rotate around
   the ring via ``lax.ppermute`` while the online-softmax state (o, m, l)
@@ -23,10 +23,8 @@ final output o / l — associative across blocks, so ring order is free.
 """
 from __future__ import annotations
 
-import contextlib
 import functools
 import math
-import os as _os
 
 import jax
 import jax.numpy as jnp
@@ -34,30 +32,7 @@ from jax import lax
 
 __all__ = ["attention_reference", "flash_attention", "ring_attention",
            "blockwise_combine", "sequence_parallel",
-           "current_sequence_parallel", "aot_lowering_scope"]
-
-# >0 while inside aot_lowering_scope(): compile-only lowering against a
-# TPU topology, where the ambient backend is the cpu host — the only
-# context where MXTPU_FLASH_FORCE may force the Mosaic kernel path off
-# a real TPU (executing that path on cpu/gpu would just abort)
-_AOT_LOWERING_DEPTH = 0
-
-
-@contextlib.contextmanager
-def aot_lowering_scope():
-    """Mark a compile-only/AOT lowering region (tools/aot_*.py).
-
-    Inside the scope ``flash_attention`` honors ``MXTPU_FLASH_FORCE=1``
-    even though ``jax.devices()`` reports the cpu host backend, so the
-    fused step lowers the SAME Mosaic kernel graph the chip runs.
-    Outside it a leaked MXTPU_FLASH_FORCE on a non-TPU backend is
-    ignored (reference fallback) instead of crashing execution."""
-    global _AOT_LOWERING_DEPTH
-    _AOT_LOWERING_DEPTH += 1
-    try:
-        yield
-    finally:
-        _AOT_LOWERING_DEPTH -= 1
+           "current_sequence_parallel", "attention_scope"]
 
 _NEG_INF = -1e30
 # TPU lane width: logsumexp stats are stored broadcast across one lane
@@ -221,6 +196,7 @@ def _flash_forward_kernel_call(q, k, v, causal, scale, block_q, block_k,
             jax.ShapeDtypeStruct(ob[1], q.dtype),
             jax.ShapeDtypeStruct(lseb[1], jnp.float32),
         ],
+        name="flash_forward",
         interpret=interpret,
     )(q3, k3, v3)
     return out.reshape(B, H, Sq, D), lse[..., 0].reshape(B, H, Sq)
@@ -274,7 +250,13 @@ def _flash_backward_blockwise(q, k, v, o, lse, do, causal, scale, block_k):
 
 def flash_attention(q, k, v, causal=False, scale=None, block_q=128,
                     block_k=128, interpret=None):
-    """Fused attention; q/k/v (B, H, S, D).  Pallas on TPU, jnp elsewhere.
+    """Fused attention; q/k/v (B, H, S, D).  The Pallas kernel where the
+    computation is placed on a TPU, the jnp reference elsewhere
+    (``kernels.common.dispatch``: decided when the enclosing step is
+    lowered, so a compile-only lowering against a TPU topology carries
+    the Mosaic call and a cpu-placed step on a chip host does not).  An
+    explicit ``interpret`` runs the kernel either way: ``True`` through
+    the Pallas interpreter (tests), ``False`` through Mosaic.
 
     Differentiable: the forward runs the fused kernel and saves the
     logsumexp stats; the backward is the blockwise flash backward
@@ -288,47 +270,37 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=128,
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[-1])
     sq, sk = q.shape[-2], k.shape[-2]
-    if sq % block_q or sk % block_k:   # hard kernel constraint
+
+    def reference(q, k, v):
         return attention_reference(q, k, v, causal=causal, scale=scale)
-    if interpret is None:
-        # default: real kernel on TPU, fast jnp reference elsewhere.
-        # An EXPLICIT interpret skips this ambient probe entirely:
-        # True exercises the kernel off-TPU (tests), False forces the
-        # Mosaic path.  MXTPU_FLASH_FORCE=1 does the same for callers
-        # that can't plumb the argument (MultiHeadAttention inside a
-        # traced step) — but ONLY inside aot_lowering_scope(), i.e.
-        # compile-only lowering against a TPU topology where
-        # jax.devices() reports the cpu host backend
-        # (tools/aot_longcontext_check.py).  A leaked MXTPU_FLASH_FORCE
-        # outside that scope must not force Mosaic onto a cpu/gpu
-        # backend, where it would abort execution.
-        on_tpu = any(d.platform == "tpu" for d in jax.devices())
-        if _os.environ.get("MXTPU_FLASH_FORCE") and (
-                on_tpu or _AOT_LOWERING_DEPTH > 0):
-            interpret = False
-        elif not on_tpu:
-            return attention_reference(q, k, v, causal=causal, scale=scale)
-        else:
-            interpret = False
 
-    @jax.custom_vjp
-    def _fa(q, k, v):
-        out, _ = _flash_forward_kernel_call(q, k, v, causal, scale,
-                                            block_q, block_k, interpret)
-        return out
+    if sq % block_q or sk % block_k:   # hard kernel constraint
+        return reference(q, k, v)
 
-    def _fa_fwd(q, k, v):
-        out, lse = _flash_forward_kernel_call(q, k, v, causal, scale,
-                                              block_q, block_k, interpret)
-        return out, (q, k, v, out, lse)
+    def kernel(q, k, v, interpret=False):
+        @jax.custom_vjp
+        def _fa(q, k, v):
+            out, _ = _flash_forward_kernel_call(
+                q, k, v, causal, scale, block_q, block_k, interpret)
+            return out
 
-    def _fa_bwd(res, ct):
-        q, k, v, out, lse = res
-        return _flash_backward_blockwise(q, k, v, out, lse, ct, causal,
-                                         scale, block_k)
+        def _fa_fwd(q, k, v):
+            out, lse = _flash_forward_kernel_call(
+                q, k, v, causal, scale, block_q, block_k, interpret)
+            return out, (q, k, v, out, lse)
 
-    _fa.defvjp(_fa_fwd, _fa_bwd)
-    return _fa(q, k, v)
+        def _fa_bwd(res, ct):
+            q, k, v, out, lse = res
+            return _flash_backward_blockwise(q, k, v, out, lse, ct, causal,
+                                             scale, block_k)
+
+        _fa.defvjp(_fa_fwd, _fa_bwd)
+        return _fa(q, k, v)
+
+    if interpret is not None:
+        return kernel(q, k, v, interpret=bool(interpret))
+    from ..kernels.common import dispatch
+    return dispatch(kernel, reference, q, k, v)
 
 
 # ----------------------------------------------------------------------
@@ -380,7 +352,7 @@ def ring_attention(q, k, v, axis_name="sp", causal=False, scale=None):
 
 
 # ----------------------------------------------------------------------
-# Sequence-parallel context: routes symbolic MultiHeadAttention to the ring
+# Mesh context: tells symbolic MultiHeadAttention what its step is sharded over
 # ----------------------------------------------------------------------
 import contextlib as _contextlib
 import threading as _threading
@@ -396,12 +368,23 @@ class _SPContext(object):
         self.seq_axis = seq_axis
         self.batch_axis = batch_axis
 
+    def fingerprint(self):
+        """What a program traced under this context bakes in."""
+        return (tuple(self.mesh.shape.items()),
+                tuple(d.id for d in self.mesh.devices.flat),
+                self.seq_axis, self.batch_axis)
+
 
 @_contextlib.contextmanager
 def sequence_parallel(mesh, seq_axis="sp", batch_axis="dp"):
-    """While active, MultiHeadAttention lowers to ring_attention over
-    ``seq_axis`` of ``mesh`` (must be active when the step is traced —
-    ShardedTrainer(seq_axis=...) does this automatically)."""
+    """While active, MultiHeadAttention knows the mesh its step is
+    sharded over (must be active when the step is TRACED —
+    ShardedTrainer and the Module mesh group do this through
+    :func:`attention_scope`).
+    With ``seq_axis`` an axis of ``mesh`` it lowers to ring_attention
+    over that axis; otherwise (``seq_axis=None``: data/tensor parallel
+    only) each device runs the flash path on its own block of the batch
+    under shard_map — GSPMD cannot partition a Mosaic kernel."""
     prev = getattr(_SP_STATE, "ctx", None)
     _SP_STATE.ctx = _SPContext(
         mesh, seq_axis,
@@ -416,26 +399,53 @@ def current_sequence_parallel():
     return getattr(_SP_STATE, "ctx", None)
 
 
+def attention_scope(mesh, seq_axis=None):
+    """The context to trace and run a step over ``mesh`` under (None or
+    one device: nothing to tell): :func:`sequence_parallel` with the
+    ring over 'sp' when the step shards a sequence axis and the mesh has
+    one, per-device flash attention otherwise."""
+    if mesh is None or mesh.size == 1:
+        return _contextlib.nullcontext()
+    ring = seq_axis is not None and "sp" in mesh.axis_names
+    return sequence_parallel(mesh, seq_axis="sp" if ring else None)
+
+
 def sharded_self_attention(q, k, v, causal=False):
-    """Attention dispatch for (B, H, S, D): ring attention when a
-    sequence_parallel context is active, flash/reference otherwise."""
+    """Attention dispatch for (B, H, S, D): flash/reference on one
+    device; under a :func:`sequence_parallel` mesh context, ring
+    attention over the sequence axis, or per-device flash."""
     ctx = current_sequence_parallel()
-    if ctx is None or ctx.seq_axis not in ctx.mesh.axis_names:
+    if ctx is None:
         return flash_attention(q, k, v, causal=causal)
-    try:
-        from jax import shard_map
-    except ImportError:
-        from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
+    mesh = ctx.mesh
 
-    spec = P(ctx.batch_axis, None, ctx.seq_axis, None)
+    if ctx.seq_axis in mesh.axis_names:
+        spec = P(ctx.batch_axis, None, ctx.seq_axis, None)
+        check_vma = True
 
-    def att(q, k, v):
-        return ring_attention(q, k, v, axis_name=ctx.seq_axis,
-                              causal=causal)
+        def att(q, k, v):
+            return ring_attention(q, k, v, axis_name=ctx.seq_axis,
+                                  causal=causal)
+    else:
+        # "Mosaic kernels cannot be automatically partitioned. Please
+        # wrap the call in a shard_map" (the four-chip host, PR 21): the
+        # batch splits over dp, the heads over tp where those divide,
+        # and every device attends over its own block
+        def split(axis, dim):
+            size = mesh.shape.get(axis, 1) if axis else 1
+            return axis if size > 1 and dim % size == 0 else None
 
-    return shard_map(att, mesh=ctx.mesh, in_specs=(spec,) * 3,
-                     out_specs=spec)(q, k, v)
+        spec = P(split(ctx.batch_axis, q.shape[0]),
+                 split("tp", q.shape[1]), None, None)
+        check_vma = False       # pallas_call outputs declare no vma
+
+        def att(q, k, v):
+            return flash_attention(q, k, v, causal=causal)
+
+    return shard_map(att, mesh=mesh, in_specs=(spec,) * 3, out_specs=spec,
+                     check_vma=check_vma)(q, k, v)
 
 
 def flash_kernel_spec(batch_heads=8, seq_q=512, seq_k=512, head_dim=64,

@@ -340,8 +340,9 @@ class ShardedTrainer(object):
                                      donate_argnums=donate_argnums)
         self._abstract_args = None   # ShapeDtypeStructs of the step args
         self._lowered = None         # cached jax.stages.Lowered
+        self._compiled_step = None   # cached jax.stages.Compiled of it
         self._cache_entry = None     # overlap compile-cache slot
-        # on-disk XLA cache (MXTPU_COMPILE_CACHE_DIR), idempotent
+        # on-disk XLA cache, idempotent
         _overlap.enable_persistent_cache()
 
         def eval_step(params, aux, batch, rng):
@@ -830,24 +831,32 @@ class ShardedTrainer(object):
                 self._cache_entry["lowered"] = self._lowered
         return self._lowered
 
+    def _compiled(self):
+        """The step as an AOT-compiled executable (for introspection;
+        ``step()`` dispatches through jit's own cache), or None before
+        the first step.  Compiled once."""
+        if self._compiled_step is None:
+            lowered = self._lower()
+            if lowered is None:
+                return None
+            self._compiled_step = lowered.compile()
+        return self._compiled_step
+
     def compiled_step_cost_analysis(self):
-        """XLA cost analysis of the whole train step (dict with 'flops'),
-        or None before the first step."""
-        lowered = self._lower()
-        if lowered is None:
-            return None
-        cost = lowered.cost_analysis()
-        if isinstance(cost, (list, tuple)):  # older jax returns [dict]
-            cost = cost[0] if cost else None
-        return cost
+        """XLA cost analysis of the whole COMPILED train step (dict with
+        'flops'), or None before the first step.  (The lowered-only
+        analysis returns None on the TPU backend — found on the chip in
+        PR 21: bench.py's MFU had been on its analytic fallback.)"""
+        compiled = self._compiled()
+        return None if compiled is None else compiled.cost_analysis()
 
     def donation_verified(self):
         """True iff XLA actually aliased donated inputs to outputs (the
         in-place-update guarantee), from the executable's memory analysis."""
-        lowered = self._lower()
-        if lowered is None:
+        compiled = self._compiled()
+        if compiled is None:
             return None
-        mem = lowered.compile().memory_analysis()
+        mem = compiled.memory_analysis()
         if mem is None:
             return None
         alias = getattr(mem, "alias_size_in_bytes", None)
@@ -856,13 +865,10 @@ class ShardedTrainer(object):
         return alias > 0
 
     def _sp_scope(self):
-        """Active sequence-parallel context while tracing/running the step:
-        MultiHeadAttention nodes lower to ring attention over 'sp'."""
-        import contextlib
-        if self.seq_axis is not None and "sp" in self.mesh.axis_names:
-            from .ring_attention import sequence_parallel
-            return sequence_parallel(self.mesh)
-        return contextlib.nullcontext()
+        """The mesh context MultiHeadAttention needs while the step is
+        traced or run (ring or per-device flash attention)."""
+        from .ring_attention import attention_scope
+        return attention_scope(self.mesh, self.seq_axis)
 
 
 class ShardedPredictor(object):
@@ -974,7 +980,9 @@ class ShardedPredictor(object):
         GLOBAL batch on every process)."""
         placed = _place_batch(batch, self.batch_sharding)
         rng = jax.random.PRNGKey(0)
-        outs = self._jit_forward(self.params, self.aux, placed, rng)
+        from .ring_attention import attention_scope
+        with attention_scope(self.mesh, self.seq_axis):
+            outs = self._jit_forward(self.params, self.aux, placed, rng)
         if jax.process_count() > 1:
             # outputs stay dp-sharded across hosts: gather before the
             # host copy (device_get cannot read non-addressable shards)

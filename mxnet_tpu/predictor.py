@@ -57,7 +57,8 @@ class Predictor(object):
     param_file : str | bytes | dict — params file/bytes ('arg:'/'aux:'
         prefixed names, the save_checkpoint format) or a plain dict
     input_shapes : dict name -> shape
-    ctx : Context (default cpu; pass mx.tpu() for the chip)
+    ctx : Context every input, weight and output lives on (default
+        cpu(), like the reference's MXPredCreate dev_type)
     quantize : None | "int8" | "fp8_e4m3" — weight-only quantization:
         rewrite matched FullyConnected nodes to QuantizedDense
         (kernels/quantize.py) and quantize the corresponding params.
@@ -72,11 +73,8 @@ class Predictor(object):
         # compilation rides the PR-8 caches: the cross-symbol program
         # registry (executor._PROGRAM_REGISTRY, graph-hash keyed) makes
         # a SECOND Predictor over the same symbol/ctx reuse the traced
-        # program with zero new lowerings, and the persistent on-disk
-        # cache (MXTPU_COMPILE_CACHE_DIR, when set) lets even a fresh
-        # process skip XLA compilation proper
-        from .parallel import overlap as _overlap
-        _overlap.enable_persistent_cache()
+        # program with zero new lowerings; the on-disk XLA cache the
+        # Executor enables lets even a fresh process skip compilation
         if isinstance(symbol_json, os.PathLike):
             symbol_json = os.fspath(symbol_json)
         if isinstance(symbol_json, str) and symbol_json.endswith(".json"):
@@ -125,25 +123,28 @@ class Predictor(object):
                 inferred = dict(zip(arg_names, arg_shapes))
         except Exception:
             pass
+        # plain-numpy dicts are allowed: nd.on_context wraps them so the
+        # executor's .data access yields a jax array (np.ndarray.data is
+        # a memoryview), preserving dtype (int8/fp8 for quantized); a
+        # param already on ctx is shared, not copied — every bucket
+        # Predictor of one server binds the same weights
         args = {}
         for name in arg_names:
             if name in input_shapes:
-                args[name] = nd.zeros(input_shapes[name])
+                args[name] = nd.zeros(input_shapes[name], ctx=ctx)
             elif name in arg_params:
-                v = arg_params[name]
-                # plain-numpy dicts are allowed: wrap so the executor's
-                # .data access yields a jax array (np.ndarray.data is a
-                # memoryview), preserving dtype (int8/fp8 for quantized)
-                args[name] = v if isinstance(v, nd.NDArray) else nd.array(v)
+                args[name] = arg_params[name] = nd.on_context(
+                    arg_params[name], ctx)
             elif inferred.get(name) is not None:
-                args[name] = nd.zeros(inferred[name])
+                args[name] = nd.zeros(inferred[name], ctx=ctx)
             else:
                 raise MXNetError("Predictor: missing parameter %r" % name)
         aux = {}
         for name in self.symbol.list_auxiliary_states():
             if name not in aux_params:
                 raise MXNetError("Predictor: missing aux state %r" % name)
-            aux[name] = aux_params[name]
+            aux[name] = aux_params[name] = nd.on_context(
+                aux_params[name], ctx)
         self._exec = self.symbol.bind(ctx, args, aux_states=aux,
                                       grad_req="null")
         self._ctx = ctx

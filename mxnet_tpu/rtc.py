@@ -76,7 +76,7 @@ class Rtc(object):
         self._interpret = interpret
         self._compiled = {}
 
-    def _build(self, in_shapes, in_dtypes):
+    def _build(self, in_shapes, in_dtypes, interpret):
         if not self._pallas:
             fn = self._fn
 
@@ -90,10 +90,6 @@ class Rtc(object):
 
         out_shapes = self._out_shapes or [in_shapes[0]] * self._n_out
         out_dtypes = self._out_dtypes or [in_dtypes[0]] * self._n_out
-        interpret = self._interpret
-        if interpret is None:
-            interpret = not any(d.platform == "tpu"
-                                for d in jax.devices())
         out_spec = tuple(jax.ShapeDtypeStruct(tuple(s), d)
                          for s, d in zip(out_shapes, out_dtypes))
 
@@ -117,9 +113,20 @@ class Rtc(object):
         if not ins:
             raise MXNetError("Rtc.push needs at least one input")
         xs = [i.data if isinstance(i, NDArray) else i for i in ins]
-        key = tuple((tuple(x.shape), str(x.dtype)) for x in xs)
+        interpret = self._interpret
+        if interpret is None:
+            # Mosaic where the inputs (hence the computation) live on a
+            # TPU, the Pallas interpreter anywhere else
+            interpret = not any(d.platform == "tpu"
+                                for x in xs if isinstance(x, jax.Array)
+                                for d in x.devices())
+        interpret = bool(interpret)
+        key = (interpret,) + tuple(
+            (tuple(x.shape), str(x.dtype)) for x in xs)
         if key not in self._compiled:
             self._compiled[key] = self._build(
-                [tuple(x.shape) for x in xs], [x.dtype for x in xs])
+                [tuple(x.shape) for x in xs], [x.dtype for x in xs],
+                interpret)
         outs = self._compiled[key](*xs)
-        return tuple(NDArray(o) for o in outs)
+        ctx = next((i.context for i in ins if isinstance(i, NDArray)), None)
+        return tuple(NDArray(o, ctx=ctx) for o in outs)

@@ -1438,9 +1438,12 @@ def _build_replica_server(spec):
     dtypes?}], "version"?, "max_delay_ms"?, "max_queue"?}``.  ``symbol``
     is JSON text or a path; ``params`` a path (the checkpoint the
     replica loads)."""
+    from ..context import default_device_context
     from .server import ModelServer
     srv = ModelServer(max_delay_ms=spec.get("max_delay_ms"),
                       max_queue=spec.get("max_queue"))
+    # the accelerator when this replica process has one, else the host
+    ctx = default_device_context()
     for m in spec.get("models", ()):
         srv.add_model(
             m["name"], m["symbol"], m["params"],
@@ -1449,7 +1452,7 @@ def _build_replica_server(spec):
             histogram=m.get("histogram"),
             buckets=m.get("buckets"),
             priority=int(m.get("priority", 0)),
-            dtypes=m.get("dtypes"))
+            dtypes=m.get("dtypes"), ctx=ctx)
     if spec.get("version"):
         srv.param_version = str(spec["version"])
     return srv

@@ -225,12 +225,16 @@ class GenerationEngine(object):
                 compute_dtype=compute_dtype)
         self.decode_buckets = self.decode_plan.buckets
 
+        from ..context import Context, cpu
+        ctx = Context(ctx) if ctx is not None else cpu()
+        #: the one device weights, pools, inputs and outputs live on
+        self.ctx = ctx
         total_len = self.prompt_buckets[-1] + self.max_new
         self.cache = PagedKVCache(KVCacheConfig(
             num_layers=num_layers, num_heads=num_heads,
             head_dim=self.dim // self.num_heads, max_seq_len=total_len,
             num_blocks=kv_blocks, block_size=kv_block_size,
-            dtype=cache_dtype))
+            dtype=cache_dtype), ctx=ctx)
         if mesh is not None:
             self.cache.shard_pools(mesh, tp_axis=tp_axis)
         mb = self.cache.config.blocks_per_seq
@@ -253,6 +257,13 @@ class GenerationEngine(object):
             from ..kernels import quantize as _q
             qnames = _q.quantizable_weights(dec_json)
             params = _q.quantize_params(params, qnames, qdtype=quantize)
+        # weights go to the engine's device ONCE; every bucket Predictor
+        # below then shares those arrays instead of copying them
+        from .. import ndarray as _nd
+        from ..predictor import load_ndarray_file
+        if not isinstance(params, dict):
+            params = load_ndarray_file(params)
+        params = {k: _nd.on_context(v, ctx) for k, v in params.items()}
         self._prefill = {}
         for S in self.prompt_buckets:
             shapes = dict({"data": (1, S), "pos_ids": (1, S),
@@ -442,15 +453,14 @@ class GenerationEngine(object):
     def run_async(self, pred, host_inputs):
         """Dispatch one prefill/decode forward without blocking.
 
-        Host inputs go through ``jnp.asarray`` (one h2d copy); the
+        Host inputs go to the engine's device (one h2d copy each); the
         cache pools are injected device-side as-is — the functional
         update round-trips between steps with zero host copies.
         Returns caller-owned raw device arrays ``[logits, k0, v0, …]``.
         """
-        import jax.numpy as jnp
         ex = pred._exec
         for k, v in host_inputs.items():
-            ex.arg_dict[k]._set_data(jnp.asarray(v))
+            ex.arg_dict[k]._set_data(v)
         for i in range(self.num_layers):
             ex.arg_dict["layer%d_att_k_cache" % i]._set_data(
                 self.cache.k_pools[i])
@@ -512,10 +522,10 @@ class GenerationEngine(object):
     # -- introspection -----------------------------------------------------
 
     def kernel_path(self):
-        """Which decode-attention path steps take right now (env-driven,
-        so evaluated per call): ``flash_decode`` or ``gather``."""
-        from ..kernels.flash_decode import flash_decode_enabled
-        return "flash_decode" if flash_decode_enabled() else "gather"
+        """Which decode-attention path steps take: ``gather`` (the
+        block-table gather of ``ops.attention.paged_decode_attention``)
+        is the only one the tree ships."""
+        return "gather"
 
     def stats(self):
         s = self.cache.stats()

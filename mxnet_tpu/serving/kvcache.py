@@ -230,7 +230,10 @@ class PagedKVCache(object):
     """
 
     def __init__(self, config, ctx=None, init_pools=True):
+        from ..context import cpu
         self.config = config
+        #: where the pools live — the device of the executors they feed
+        self.ctx = ctx if ctx is not None else cpu()
         self._lock = threading.Lock()
         self._free = list(range(config.num_blocks - 1, TRASH_BLOCK, -1))
         self._seqs = {}
@@ -239,11 +242,12 @@ class PagedKVCache(object):
         self.v_pools = []
         if init_pools:
             import jax.numpy as jnp
+            dev = self.ctx.jax_device
             shape = config.pool_shape
             dt = config.dtype
             for _ in range(config.num_layers):
-                self.k_pools.append(jnp.zeros(shape, dtype=dt))
-                self.v_pools.append(jnp.zeros(shape, dtype=dt))
+                self.k_pools.append(jnp.zeros(shape, dtype=dt, device=dev))
+                self.v_pools.append(jnp.zeros(shape, dtype=dt, device=dev))
 
     # -- allocation --------------------------------------------------------
 

@@ -17,7 +17,7 @@ from .ndarray import NDArray, array, zeros
 __all__ = ["reldiff", "same", "assert_almost_equal", "numeric_grad",
            "check_numeric_gradient", "check_symbolic_forward",
            "check_symbolic_backward", "default_context", "rand_ndarray",
-           "check_consistency"]
+           "check_consistency", "tpu_lowering_text"]
 
 _DEFAULT_RTOL = 1e-4
 _DEFAULT_ATOL = 1e-6
@@ -223,3 +223,14 @@ def check_consistency(sym, location, ctx_list=None, aux_states=None,
                                                "grad(%s)@%s" % (n, b_tag)))
             results.append((tag, got))
     return results
+
+
+def tpu_lowering_text(jitted, *args):
+    """StableHLO text of ``jitted`` lowered FOR a TPU from any host (no
+    chip, no libtpu): what a test greps for ``tpu_custom_call`` to see
+    that a step placed on a TPU carries its Mosaic kernels.  Traced with
+    x64 off — the suite enables it, and Mosaic has no 64-bit types."""
+    import jax
+    with jax.enable_x64(False):
+        return jitted.trace(*args).lower(
+            lowering_platforms=("tpu",)).as_text()

@@ -127,22 +127,19 @@ for dt in ("float32", "bfloat16", "int8"):
 print("paged_kv_cache MXL-K sweep OK (f32/bf16/int8)")
 '
     # the quantized + fused kernel tier (docs/perf.md "Quantization &
-    # fused kernels"): all three Pallas specs — dequant matmul, flash
-    # decode, fused optimizer sweep — must stay Mosaic tile-legal at
-    # every compute dtype they serve
+    # fused kernels"): both Pallas specs — dequant matmul, fused
+    # optimizer sweep — must stay Mosaic tile-legal at every compute
+    # dtype they serve
     JAX_PLATFORMS=cpu python -c '
 from mxnet_tpu.analysis.tiling import spec_findings
-from mxnet_tpu.kernels.flash_decode import flash_decode_kernel_spec
 from mxnet_tpu.kernels.fused_opt import fused_opt_kernel_spec
 from mxnet_tpu.kernels.quantize import qmm_kernel_spec
-for mk in (qmm_kernel_spec, flash_decode_kernel_spec,
-           fused_opt_kernel_spec):
+for mk in (qmm_kernel_spec, fused_opt_kernel_spec):
     for dt in ("float32", "bfloat16", "int8"):
         spec = mk(dtype=dt)
         bad = [f for f in spec_findings(spec) if f[1] == "error"]
         assert not bad, (spec["name"], bad)
-print("kernel-tier MXL-K sweep OK "
-      "(qmm/flash_decode/fused_opt x f32/bf16/int8)")
+print("kernel-tier MXL-K sweep OK (qmm/fused_opt x f32/bf16/int8)")
 '
     # ...and the kernel tier itself (env-gated dispatch, bucket plans)
     # must stay divergence-clean under the MXL-D self-lint

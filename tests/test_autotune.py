@@ -23,11 +23,11 @@ def _resnet50():
 
 
 # ----------------------------------------------------------------------
-# the pinned v5e table (docs/mfu_gap.md / AOT_r05.json): the calibrated
+# the pinned v5e table (docs/mfu_gap.md): the calibrated
 # MXL-R model must keep reproducing the compiled AOT ceilings
 # ----------------------------------------------------------------------
 V5E_TABLE = [
-    # batch, compiled mfu ceiling, compiled TF/step (AOT_r05.json)
+    # batch, compiled mfu ceiling, compiled TF/step (docs/mfu_gap.md)
     (64, 0.193, 1.572),
     (256, 0.293, 6.282),
     (512, 0.331, 12.564),

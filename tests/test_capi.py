@@ -39,10 +39,6 @@ def test_capi_smoke(tmp_path):
     env["MXTPU_PARAMS_FILE"] = str(tmp_path / "mlp-0000.params")
     env["PYTHONPATH"] = _ROOT + os.pathsep + env.get("PYTHONPATH", "")
     env["JAX_PLATFORMS"] = "cpu"
-    # the embedded interpreter must skip the hanging accelerator plugin
-    env["PYTHONPATH"] = os.pathsep.join(
-        p for p in env["PYTHONPATH"].split(os.pathsep)
-        if p and not os.path.isfile(os.path.join(p, "sitecustomize.py")))
     proc = subprocess.run([os.path.join(_ROOT, "lib", "capi_smoke")],
                           env=env, capture_output=True, text=True,
                           timeout=300)
@@ -67,9 +63,6 @@ def test_capi_threads():
     env = dict(os.environ)
     env["PYTHONPATH"] = _ROOT + os.pathsep + env.get("PYTHONPATH", "")
     env["JAX_PLATFORMS"] = "cpu"
-    env["PYTHONPATH"] = os.pathsep.join(
-        p for p in env["PYTHONPATH"].split(os.pathsep)
-        if p and not os.path.isfile(os.path.join(p, "sitecustomize.py")))
     proc = subprocess.run([os.path.join(_ROOT, "lib", "capi_threads")],
                           env=env, capture_output=True, text=True,
                           timeout=300)
@@ -108,9 +101,6 @@ def test_capi_parity(tmp_path):
     env["MXTPU_SCRATCH"] = str(tmp_path)
     env["PYTHONPATH"] = _ROOT + os.pathsep + env.get("PYTHONPATH", "")
     env["JAX_PLATFORMS"] = "cpu"
-    env["PYTHONPATH"] = os.pathsep.join(
-        p for p in env["PYTHONPATH"].split(os.pathsep)
-        if p and not os.path.isfile(os.path.join(p, "sitecustomize.py")))
     proc = subprocess.run([os.path.join(_ROOT, "lib", "capi_parity")],
                           env=env, capture_output=True, text=True,
                           timeout=600)

@@ -1,9 +1,10 @@
 """Quantized + fused kernel tier (docs/perf.md "Quantization & fused
 kernels"): weight-only int8 quantization end-to-end (array -> symbol
-rewrite -> Predictor -> GenerationEngine), flash-decode equivalence
-over the paged KV cache, bit-identity of the fused optimizer sweep on
-the 8-device mesh, MXL-K lint coverage of all three kernel specs, and
-the benchdiff gate catching a simulated decode-throughput regression.
+rewrite -> Predictor -> GenerationEngine), decode attention over the
+paged KV cache, kernel-or-reference dispatch by placement, bit-identity
+of the fused optimizer sweep on the 8-device mesh, MXL-K lint coverage
+of the kernel specs, and the benchdiff gate catching a simulated
+decode-throughput regression.
 
 Pallas kernels run in interpret mode on the CPU test mesh — the same
 trace Mosaic compiles on TPU, so everything but the hardware lowering
@@ -25,12 +26,12 @@ from mxnet_tpu import ndarray as nd
 from mxnet_tpu import parallel
 from mxnet_tpu.base import MXNetError
 from mxnet_tpu.executor import program_registry_stats
-from mxnet_tpu.kernels import flash_decode as fd
 from mxnet_tpu.kernels import fused_opt as fo
 from mxnet_tpu.kernels import quantize as qz
 from mxnet_tpu.models import transformer as tf
 from mxnet_tpu.predictor import Predictor
 from mxnet_tpu.serving import GenerationEngine
+from mxnet_tpu.test_utils import tpu_lowering_text
 
 _ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -210,68 +211,103 @@ def test_engine_quantize_env_and_optout(monkeypatch, lm_params):
 
 
 # ---------------------------------------------------------------------------
-# flash decode over the paged KV cache
+# decode attention over the paged KV cache
 # ---------------------------------------------------------------------------
 
-def _decode_case(seed=11, b=4, h=4, d=32, nb=16, bs=8, mb=4):
-    rng = np.random.RandomState(seed)
-    q = jnp.asarray(rng.randn(b, h, d).astype(np.float32))
-    k_pool = jnp.asarray(rng.randn(nb, bs, h, d).astype(np.float32))
-    v_pool = jnp.asarray(rng.randn(nb, bs, h, d).astype(np.float32))
-    table = jnp.asarray(
-        rng.choice(nb, size=(b, mb), replace=False).astype(np.int32))
-    # positions hit block boundaries, a single token, and a full table
-    pos = jnp.asarray(np.array([1, bs, bs + 1, mb * bs], np.int32)[:b])
-    return q, k_pool, v_pool, table, pos
+def test_paged_decode_attention_matches_dense():
+    """The block-table gather against plain per-sequence attention over
+    the same keys laid out contiguously: positions on block boundaries,
+    a single token, and a full table."""
+    from mxnet_tpu.ops.attention import paged_decode_attention
+    b, h, d, nb, bs, mb = 4, 4, 32, 16, 8, 4
+    rng = np.random.RandomState(11)
+    q = rng.randn(b, h, d).astype(np.float32)
+    k_pool = rng.randn(nb, bs, h, d).astype(np.float32)
+    v_pool = rng.randn(nb, bs, h, d).astype(np.float32)
+    table = rng.choice(nb, size=(b, mb), replace=False).astype(np.int32)
+    pos = np.array([1, bs, bs + 1, mb * bs - 1], np.int32)
+    got = np.asarray(paged_decode_attention(
+        jnp.asarray(q), jnp.asarray(k_pool), jnp.asarray(v_pool),
+        jnp.asarray(table), jnp.asarray(pos)))
+    for i in range(b):
+        n = pos[i] + 1
+        keys = k_pool[table[i]].reshape(mb * bs, h, d)[:n]
+        vals = v_pool[table[i]].reshape(mb * bs, h, d)[:n]
+        s = np.einsum("hd,thd->ht", q[i], keys) / np.sqrt(d)
+        p = np.exp(s - s.max(axis=-1, keepdims=True))
+        p /= p.sum(axis=-1, keepdims=True)
+        want = np.einsum("ht,thd->hd", p, vals)
+        np.testing.assert_allclose(got[i], want, rtol=2e-5, atol=2e-5)
 
 
-def test_flash_decode_matches_reference():
-    q, k_pool, v_pool, table, pos = _decode_case()
-    want = fd.decode_attention_reference(q, k_pool, v_pool, table, pos)
-    got = fd.flash_decode_attention(q, k_pool, v_pool, table, pos,
-                                    interpret=True)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                               rtol=2e-5, atol=2e-5)
-
-
-def test_flash_decode_explicit_scale_and_dtype():
-    q, k_pool, v_pool, table, pos = _decode_case(seed=12)
-    q = q.astype(jnp.bfloat16)
-    k_pool = k_pool.astype(jnp.bfloat16)
-    v_pool = v_pool.astype(jnp.bfloat16)
-    want = fd.decode_attention_reference(q, k_pool, v_pool, table, pos,
-                                         scale=0.25)
-    got = fd.flash_decode_attention(q, k_pool, v_pool, table, pos,
-                                    scale=0.25, interpret=True)
-    assert got.dtype == q.dtype
-    np.testing.assert_allclose(
-        np.asarray(got, np.float32), np.asarray(want, np.float32),
-        rtol=2e-2, atol=2e-2)
-
-
-def test_flash_decode_env_flag(monkeypatch):
-    monkeypatch.delenv("MXTPU_FLASH_DECODE", raising=False)
-    assert not fd.flash_decode_enabled()
-    monkeypatch.setenv("MXTPU_FLASH_DECODE", "1")
-    assert fd.flash_decode_enabled()
-    monkeypatch.setenv("MXTPU_FLASH_DECODE", "0")
-    assert not fd.flash_decode_enabled()
-
-
-def test_engine_kernel_path_reports_flag(monkeypatch, lm_params):
+def test_engine_kernel_path(lm_params):
     kw = dict(vocab_size=V, num_layers=L, num_heads=H, dim=E,
               max_seq_len=S, max_new_tokens=3, prompt_buckets=(8,),
               decode_buckets=(1, 2), kv_blocks=16, kv_block_size=8)
     eng = GenerationEngine(params=dict(lm_params), **kw)
-    monkeypatch.delenv("MXTPU_FLASH_DECODE", raising=False)
     assert eng.kernel_path() == "gather"
-    base = eng.generate([[3, 5, 7], [2, 4]])
-    monkeypatch.setenv("MXTPU_FLASH_DECODE", "1")
-    assert eng.kernel_path() == "flash_decode"
-    assert eng.stats()["kernel_path"] == "flash_decode"
-    # off-TPU the flag routes through the exact reference: identical
-    eng2 = GenerationEngine(params=dict(lm_params), **kw)
-    assert eng2.generate([[3, 5, 7], [2, 4]]) == base
+    assert eng.stats()["kernel_path"] == "gather"
+
+
+# ---------------------------------------------------------------------------
+# block picking and kernel-or-reference dispatch
+# ---------------------------------------------------------------------------
+
+def test_pick_block_never_falls_back_to_whole_dim():
+    """A dim with no aligned divisor used to become ONE block (38 MB of
+    VMEM for an LM head of 50,257 rows): now it is an aligned block with
+    a trailing partial step."""
+    from mxnet_tpu.kernels.common import pick_block
+    assert pick_block(50257, 128, 512) == 512        # no divisor: partial
+    assert pick_block(199665, 8, 512) == 512         # resnet50 bucket rows
+    assert pick_block(768, 128, 512) == 384          # exact divisor wins
+    assert pick_block(3072, 128, 512) == 512
+    assert pick_block(100, 128, 512) == 100          # fits: whole dim
+
+
+def test_quantized_matmul_ragged_edges():
+    """M and N run a trailing partial block, K is zero-padded — none of
+    M=300, N=1000, K=600 divides its block."""
+    rng = np.random.RandomState(5)
+    x = jnp.asarray(rng.randn(300, 600).astype(np.float32))
+    w_q, scale = qz.quantize_array(rng.randn(1000, 600).astype(np.float32))
+    want = qz.quantized_matmul_reference(x, jnp.asarray(w_q),
+                                         jnp.asarray(scale))
+    got = qz.quantized_matmul(x, jnp.asarray(w_q), jnp.asarray(scale),
+                              interpret=True)
+    assert got.shape == (300, 1000)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-4, atol=1e-3)
+
+
+def test_kernel_dispatch_follows_placement():
+    """Each default-path kernel is chosen by the platform its step is
+    lowered FOR: Mosaic in the TPU lowering, none in the cpu lowering —
+    which runs, and equals the reference."""
+    rng = np.random.RandomState(6)
+    x = jnp.asarray(rng.randn(8, 256).astype(np.float32))
+    w_q, scale = qz.quantize_array(rng.randn(384, 256).astype(np.float32))
+    w_q, scale = jnp.asarray(w_q), jnp.asarray(scale)
+    qmm = jax.jit(qz.quantized_matmul)
+    opt, params, grads, state = _leaf_case("sgd")
+
+    def sweep(params, grads, state):
+        return fo.fused_apply(opt, params, grads, state, 0.05, 0.0, 2.0,
+                              mode="kernel")
+
+    for fn, args in ((qmm, (x, w_q, scale)),
+                     (jax.jit(sweep), (params, grads, state))):
+        assert "tpu_custom_call" in tpu_lowering_text(fn, *args)
+        assert "tpu_custom_call" not in fn.lower(*args).as_text()
+    np.testing.assert_array_equal(
+        np.asarray(qmm(x, w_q, scale)),
+        np.asarray(qz.quantized_matmul_reference(x, w_q, scale)))
+    got_w, _ = jax.jit(sweep)(params, grads, state)
+    want_w, _ = jax.jit(lambda p, g, s: fo.fused_apply(
+        opt, p, g, s, 0.05, 0.0, 2.0, mode="1"))(params, grads, state)
+    for n in params:
+        np.testing.assert_array_equal(np.asarray(got_w[n]),
+                                      np.asarray(want_w[n]))
 
 
 # ---------------------------------------------------------------------------
@@ -429,7 +465,7 @@ def test_kernel_specs_registered_and_lint_clean():
                                            kernel_spec_issues)
     _ensure_builtin_specs()
     for name in ("kernels.quantize.quantized_matmul",
-                 "kernels.flash_decode", "kernels.fused_opt.sweep"):
+                 "kernels.fused_opt.sweep"):
         assert name in KERNEL_SPECS, name
     assert kernel_spec_issues() == []
 

@@ -184,9 +184,30 @@ def test_context():
     b = a.as_in_context(mx.cpu(1))
     assert b.context == mx.cpu(1)
     assert np.allclose(a.asnumpy(), b.asnumpy())
-    # gpu() aliases to accelerator; on cpu-only test env falls back to cpu
-    c = nd.zeros((2, 2), ctx=mx.gpu(0))
-    assert c.shape == (2, 2)
+    assert b.data.devices() == {mx.cpu(1).jax_device}
+    # a context is a placement: with no accelerator in the process the
+    # accelerator contexts raise instead of landing on the host
+    for ctx in (mx.gpu(0), mx.tpu(0)):
+        with pytest.raises(mx.base.MXNetError, match="no accelerator"):
+            nd.zeros((2, 2), ctx=ctx)
+    assert mx.num_tpus() == 0
+    assert mx.context.default_device_context() == mx.cpu(0)
+
+
+def test_write_keeps_device():
+    """Every way of writing into an array leaves it on its context's
+    device (assignment, views, in-place arithmetic, copyto)."""
+    dev = mx.cpu(3).jax_device
+    a = nd.zeros((2, 2), ctx=mx.cpu(3))
+    a[:] = 1
+    assert a.data.devices() == {dev}
+    a[0][:] = nd.ones((2,)) * 5          # value computed on cpu(0)
+    assert a.data.devices() == {dev}
+    a += nd.ones((2, 2))
+    assert a.data.devices() == {dev}
+    nd.array(np.arange(4.0).reshape(2, 2)).copyto(a)
+    assert a.data.devices() == {dev} and a.context == mx.cpu(3)
+    assert np.allclose(a.asnumpy(), [[0, 1], [2, 3]])
 
 
 def test_waitall():

@@ -9,16 +9,14 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-try:
-    from jax import shard_map
-except ImportError:
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 import mxnet_tpu as mx
 from mxnet_tpu import symbol as sym
 from mxnet_tpu.parallel.ring_attention import (
     attention_reference, blockwise_combine, flash_attention, ring_attention)
-from mxnet_tpu.test_utils import assert_almost_equal, check_numeric_gradient
+from mxnet_tpu.test_utils import (assert_almost_equal,
+                                  check_numeric_gradient, tpu_lowering_text)
 
 rng = np.random.RandomState(11)
 
@@ -50,27 +48,19 @@ def test_flash_kernel_interpret_matches_reference(causal):
                         rtol=1e-4, atol=1e-5)
 
 
-def test_flash_force_ignored_outside_aot_scope(monkeypatch):
-    """A leaked MXTPU_FLASH_FORCE on a cpu backend must fall back to the
-    reference path (forcing Mosaic there aborts execution); inside
-    aot_lowering_scope() the override is honored for compile-only
-    lowering."""
-    from mxnet_tpu.parallel import ring_attention as ra
-    monkeypatch.setenv("MXTPU_FLASH_FORCE", "1")
+def test_flash_dispatch_follows_placement():
+    """Kernel or reference is decided by the platform the step is
+    lowered FOR, not by what the process can see: the same jitted
+    function carries the Mosaic custom call in its TPU lowering and
+    none in its cpu lowering, which runs and matches the reference."""
     q, k, v = _qkv(B=1, H=2, S=256, D=8)   # multiple of the 128 blocks
-    want = attention_reference(q, k, v)
-    got = flash_attention(q, k, v)   # env leaked, no scope: reference
-    assert_almost_equal(np.asarray(got), np.asarray(want),
-                        rtol=1e-4, atol=1e-5)
-    # inside the scope the override IS honored: flash_attention takes
-    # the Mosaic kernel path, which the cpu backend cannot lower — the
-    # error (instead of a silent reference fallback) proves the branch
-    with ra.aot_lowering_scope():
-        assert ra._AOT_LOWERING_DEPTH == 1
-        with pytest.raises(Exception):
-            jax.jit(lambda a, b, c: flash_attention(a, b, c)
-                    ).lower(q, k, v)
-    assert ra._AOT_LOWERING_DEPTH == 0
+    step = jax.jit(lambda a, b, c: flash_attention(a, b, c, causal=True))
+    assert "tpu_custom_call" in tpu_lowering_text(step, q, k, v)
+    assert "tpu_custom_call" not in step.lower(q, k, v).as_text()
+    want = attention_reference(q, k, v, causal=True)
+    for got in (step(q, k, v), flash_attention(q, k, v, causal=True)):
+        assert_almost_equal(np.asarray(got), np.asarray(want),
+                            rtol=1e-4, atol=1e-5)
 
 
 @pytest.mark.parametrize("causal", [False, True])
@@ -215,6 +205,63 @@ def test_transformer_sharded_trainer_sp():
                                               batch)
         outs[tag] = np.asarray(out[0])
     assert_almost_equal(outs["single"], outs["sp"], rtol=1e-3, atol=1e-4)
+
+
+def test_mesh_steps_carry_the_flash_kernel_per_device():
+    """GSPMD cannot partition a Mosaic kernel ("wrap the call in a
+    shard_map" — what the four-chip host said in PR 21), so a step
+    sharded over a mesh WITHOUT a sequence axis must run the flash path
+    per device: the dp=4 ShardedTrainer step and the Module mesh
+    group's fused step both lower for a TPU with one Mosaic call per
+    layer, and on the cpu mesh they compute what one device computes."""
+    from mxnet_tpu.parallel import make_mesh
+    from mxnet_tpu.parallel.trainer import ShardedTrainer
+    from mxnet_tpu import optimizer as opt_mod
+
+    V, S, B, L = 32, 128, 8, 2          # S a multiple of the 128 blocks
+    net = mx.models.transformer.get_symbol(vocab_size=V, num_layers=L,
+                                           num_heads=2, dim=16, seq_len=S)
+    r = np.random.RandomState(0)
+    data = r.randint(0, V, (B, S)).astype(np.float32)
+    label = r.randint(0, V, (B, S)).astype(np.float32)
+
+    outs = {}
+    for dp in (1, 4):
+        mx.random.seed(42)
+        tr = ShardedTrainer(net, opt_mod.create("sgd", learning_rate=0.1),
+                            make_mesh(jax.devices()[:dp], dp=dp))
+        params, opt_state, aux = tr.init_params(
+            {"data": (B, S)}, label_shapes={"softmax_label": (B, S)},
+            initializer=mx.init.Xavier(rnd_type="gaussian"))
+        batch = tr.shard_batch({"data": data, "softmax_label": label})
+        _p, _o, _a, out = tr.step(params, opt_state, aux, batch)
+        outs[dp] = np.asarray(out[0])
+        with tr._sp_scope():
+            text = tpu_lowering_text(tr._jit_step, *tr._abstract_args)
+        assert text.count("tpu_custom_call") == L, dp
+    assert_almost_equal(outs[1], outs[4], rtol=1e-3, atol=1e-4)
+
+    mod = mx.mod.Module(net, context=[mx.cpu(i) for i in range(4)])
+    mod.fit(mx.io.NDArrayIter(data, label, batch_size=B), num_epoch=1,
+            kvstore="device", optimizer="sgd", eval_metric="ce",
+            optimizer_params={"learning_rate": 0.1},
+            initializer=mx.init.Xavier())
+    group = mod._exec_group
+    exe = group.execs[0]
+    assert group.sharded and exe._n_fused_step == 1
+    with group._mesh_scope():
+        _wrt, step = exe._get_fused(mod._optimizer)
+        text = tpu_lowering_text(
+            step, {n: a.data for n, a in exe.arg_dict.items()},
+            {n: a.data for n, a in exe.aux_dict.items()},
+            jax.random.PRNGKey(0), mod._fused_holder["states"],
+            jnp.float32(0.1), jnp.float32(0.0), jnp.int32(1))
+    assert text.count("tpu_custom_call") == L
+    # the same graph bound on one device afterwards gets its own program
+    one = mx.mod.Module(net, context=mx.cpu(5))
+    one.bind(data_shapes=[("data", (B, S))],
+             label_shapes=[("softmax_label", (B, S))])
+    assert one._exec_group.execs[0]._program is not exe._program
 
 
 @pytest.mark.parametrize("causal", [False, True])

@@ -23,10 +23,7 @@ def measure_psum(sizes, repeat):
     import jax
     import jax.numpy as jnp
     from jax.sharding import Mesh, PartitionSpec as P
-    try:
-        from jax import shard_map
-    except ImportError:  # older jax
-        from jax.experimental.shard_map import shard_map
+    from jax import shard_map
 
     devs = jax.devices()
     n = len(devs)

@@ -88,7 +88,11 @@ def parse_shapes(spec):
 
 def build_server(args):
     import numpy as np  # noqa: F401  (models need it transitively)
+    from mxnet_tpu.context import default_device_context
     from mxnet_tpu.serving import ModelServer, checkpoint_files
+
+    # the accelerator when this process has one, else the host
+    ctx = default_device_context()
 
     srv = ModelServer(max_delay_ms=args.max_delay_ms,
                       max_queue=args.max_queue)
@@ -109,11 +113,11 @@ def build_server(args):
             prompt_histogram=args.histogram,
             decode_buckets=args.decode_buckets,
             kv_blocks=args.kv_blocks, kv_block_size=args.kv_block_size,
-            priority=args.priority)
+            priority=args.priority, ctx=ctx)
         sys.stderr.write(
-            "mxserve: generative model %r prompt buckets %s decode "
-            "buckets %s, %d KV blocks x %d\n"
-            % (args.name, list(engine.prompt_buckets),
+            "mxserve: generative model %r on %s, prompt buckets %s "
+            "decode buckets %s, %d KV blocks x %d\n"
+            % (args.name, ctx, list(engine.prompt_buckets),
                list(engine.decode_buckets),
                engine.cache.stats()["blocks_total"],
                engine.cache.config.block_size))
@@ -129,10 +133,11 @@ def build_server(args):
         args.name, symbol, params, shapes,
         histogram=args.histogram, buckets=args.buckets,
         priority=args.priority,
-        max_buckets=args.max_buckets)
-    sys.stderr.write("mxserve: model %r buckets %s (planned waste %.3f, "
-                     "pow2 %.3f)\n" % (args.name, list(plan.buckets),
-                                       plan.waste, plan.pow2_waste))
+        max_buckets=args.max_buckets, ctx=ctx)
+    sys.stderr.write("mxserve: model %r on %s, buckets %s (planned waste "
+                     "%.3f, pow2 %.3f)\n"
+                     % (args.name, ctx, list(plan.buckets), plan.waste,
+                        plan.pow2_waste))
     return srv
 
 

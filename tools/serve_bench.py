@@ -102,6 +102,13 @@ def _stamp_retrace(out):
         pass
 
 
+def _ctx():
+    """Where the bench serves from: the accelerator when the process has
+    one, else the host (the example scripts' rule)."""
+    from mxnet_tpu.context import default_device_context
+    return default_device_context()
+
+
 def build_model(args):
     """(symbol_json, params dict, per-sample input shapes, input name)."""
     import mxnet_tpu as mx
@@ -323,7 +330,7 @@ def check_logits(args, params):
     per_engine = []
     for quantize in ("", args.quantize):   # "" forces f32 even with env
         eng = GenerationEngine(params=dict(params), quantize=quantize,
-                               **kw)
+                               ctx=_ctx(), **kw)
         eng.collect_logits = True
         eng.generate(prompts)
         per_engine.append(eng.last_logits)
@@ -360,7 +367,8 @@ def run_generate(args):
         prompt_buckets=args.prompt_buckets,
         prompt_histogram=None if args.prompt_buckets else args.prompt_sizes,
         decode_buckets=args.decode_buckets,
-        kv_blocks=args.kv_blocks, kv_block_size=args.kv_block_size)
+        kv_blocks=args.kv_blocks, kv_block_size=args.kv_block_size,
+        ctx=_ctx())
     from mxnet_tpu.executor import program_registry_stats
     lowerings_at_warmup = program_registry_stats()["lowerings"]
 
@@ -696,7 +704,8 @@ def main(argv=None):
     srv = ModelServer(max_delay_ms=args.max_delay_ms,
                       max_queue=args.max_queue)
     plan = srv.add_model("bench", symbol, params, shapes,
-                         histogram=args.sizes, buckets=args.buckets)
+                         histogram=args.sizes, buckets=args.buckets,
+                         ctx=_ctx())
 
     rng = np.random.RandomState(args.seed)
     # pre-generate request payloads outside the timed window
