@@ -25,6 +25,7 @@ from .base import MXNetError
 from .context import Context
 from .ndarray import NDArray, zeros
 from . import random as _random
+from .observability import spans as _spans
 
 _ZERO_KEY = None
 
@@ -629,8 +630,9 @@ class Executor:
                     prog.monitor_sink = None
                 self._n_monitored_compiled += 1
         else:
-            outs, aux_out = self._jit_forward(arg_values, aux_values, rng,
-                                              is_train=bool(is_train))
+            with _spans.span("step_dispatch"):
+                outs, aux_out = self._jit_forward(
+                    arg_values, aux_values, rng, is_train=bool(is_train))
         for i, o in enumerate(outs):
             self._publish_output(i, o)
         if is_train:
@@ -689,8 +691,9 @@ class Executor:
                       for g in out_grads]
         wrt = {n: arg_values[n] for n in wrt_names}
         self._n_fwd_bwd += 1
-        outs, aux_out, grads = self._jit_fwd_bwd(arg_values, aux_values, rng,
-                                                 ograds, wrt)
+        with _spans.span("step_dispatch"):
+            outs, aux_out, grads = self._jit_fwd_bwd(
+                arg_values, aux_values, rng, ograds, wrt)
         for i, o in enumerate(outs):
             self._publish_output(i, o)
         for n, a in self.aux_dict.items():
@@ -832,10 +835,11 @@ class Executor:
         else:
             lr = optimizer.lr
         self._n_fused_step += 1
-        outs, aux_out, grads, new_w, new_s = jit_step(
-            arg_values, aux_values, rng, states,
-            jnp.float32(lr), jnp.float32(optimizer.wd),
-            jnp.int32(num_update))
+        with _spans.span("step_dispatch", step=num_update):
+            outs, aux_out, grads, new_w, new_s = jit_step(
+                arg_values, aux_values, rng, states,
+                jnp.float32(lr), jnp.float32(optimizer.wd),
+                jnp.int32(num_update))
         for i, o in enumerate(outs):
             self._publish_output(i, o)
         for n, a in self.aux_dict.items():

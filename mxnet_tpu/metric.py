@@ -16,6 +16,7 @@ import math
 import numpy
 
 from .base import MXNetError
+from .observability import spans as _spans
 
 __all__ = ["EvalMetric", "CompositeEvalMetric", "Accuracy", "TopKAccuracy",
            "F1", "Perplexity", "MAE", "MSE", "RMSE", "CrossEntropy",
@@ -35,7 +36,10 @@ def check_label_shapes(labels, preds, shape=0):
 
 def _asnumpy(x):
     if hasattr(x, "asnumpy"):
-        return x.asnumpy()
+        # the blocking read every metric goes through: the wait for the
+        # step that produces x, then the read-back
+        with _spans.span("metric_sync"):
+            return x.asnumpy()
     return numpy.asarray(x)
 
 

@@ -12,6 +12,7 @@ import numpy as _np
 
 from ..base import MXNetError
 from .. import metric as _metric
+from .. import observability as _obs
 from ..callback import BatchEndParam as _BatchEndParam
 
 __all__ = ["BaseModule"]
@@ -267,19 +268,13 @@ class BaseModule(object):
         # numeric sentinel (MXTPU_SENTINEL): a NaN/Inf/spiking grad-norm
         # skips the update instead of poisoning the parameters
         from ..resilience import Sentinel
-        from ..resilience import sentinel as _sentinel_mod
-        from .. import observability as _obs
-        from ..observability import timed_iter
         sentinel = Sentinel.from_env(logger=self.logger)
-        num_step = 0
-        telemetry = _obs.enabled()
 
         try:
             self._fit_epochs(
                 train_data, eval_data, eval_metric, validation_metric,
                 epoch_end_callback, batch_end_callback, eval_end_callback,
-                eval_batch_end_callback, monitor, sentinel, _sentinel_mod,
-                _obs, timed_iter, telemetry, num_step, begin_epoch,
+                eval_batch_end_callback, monitor, sentinel, begin_epoch,
                 num_epoch)
         finally:
             if own_prefetch is not None:
@@ -289,52 +284,75 @@ class BaseModule(object):
                     validation_metric, epoch_end_callback,
                     batch_end_callback, eval_end_callback,
                     eval_batch_end_callback, monitor, sentinel,
-                    _sentinel_mod, _obs, timed_iter, telemetry, num_step,
                     begin_epoch, num_epoch):
         """The epoch loop body of :meth:`fit` (split out so the async
-        feed can be closed in exactly one ``finally``)."""
+        feed can be closed in exactly one ``finally``).
+
+        Every iteration is one ``fit_step`` span from the fetch to the
+        last callback, so that all a step costs hangs from one root:
+        ``data_wait``, then ``h2d`` and ``step_dispatch`` (opened where
+        the executor group copies and calls), ``update`` unless the step
+        was fused, ``metric`` with its ``metric_sync``, ``batch_end``.
+        The fetch that finds the epoch's end leaves a ``fit_step`` with
+        no ``step_dispatch``; ``epoch_end`` then covers the parameters'
+        round trip through the host and the epoch-end callbacks."""
+        from ..resilience import sentinel as _sentinel_mod
+        span = _obs.span
+        num_step = 0
         for epoch in range(begin_epoch, num_epoch):
             tic = time.time()
             eval_metric.reset()
-            batches = timed_iter(train_data, name="data_wait",
-                                 step_from=lambda: num_step)
-            for nbatch, data_batch in enumerate(batches):
-                t0 = time.perf_counter() if telemetry else None
-                if monitor is not None:
-                    monitor.tic()
-                self.forward_backward(data_batch)
-                num_step += 1
-                skip = False
-                if sentinel is not None:
-                    grads = getattr(self, "_exec_group", None)
-                    grads = getattr(grads, "grad_arrays", None)
-                    gnorm = Sentinel.grad_norm(grads) if grads else None
-                    skip = sentinel.check(
-                        num_step, grad_norm=gnorm) != _sentinel_mod.OK
-                if not skip:
-                    self.update()
-                if t0 is not None:
-                    _obs.record_step(
-                        num_step, time.perf_counter() - t0, epoch=epoch,
-                        batch_size=_batch_num_samples(data_batch),
-                        skipped=skip or None)
-                self.update_metric(eval_metric, data_batch.label)
-                if monitor is not None:
-                    monitor.toc_print()
-                if batch_end_callback is not None:
-                    _call(batch_end_callback, _BatchEndParam(
-                        epoch=epoch, nbatch=nbatch, eval_metric=eval_metric,
-                        locals=locals()))
+            batches = iter(train_data)
+            nbatch = -1
+            while True:
+                with span("fit_step", step=num_step + 1) as fit_step:
+                    with span("data_wait", step=num_step + 1) as wait:
+                        data_batch = next(batches, None)
+                        # the fetch that found the end is no event
+                        wait.log = fit_step.log = data_batch is not None
+                    if data_batch is None:
+                        break
+                    nbatch += 1
+                    if monitor is not None:
+                        monitor.tic()
+                    self.forward_backward(data_batch)
+                    num_step += 1
+                    skip = False
+                    if sentinel is not None:
+                        grads = getattr(self, "_exec_group", None)
+                        grads = getattr(grads, "grad_arrays", None)
+                        gnorm = sentinel.grad_norm(grads) if grads else None
+                        skip = sentinel.check(
+                            num_step, grad_norm=gnorm) != _sentinel_mod.OK
+                    if not skip:
+                        self.update()
+                    self.update_metric(eval_metric, data_batch.label)
+                    if monitor is not None:
+                        monitor.toc_print()
+                    if batch_end_callback is not None:
+                        with span("batch_end", step=num_step):
+                            _call(batch_end_callback, _BatchEndParam(
+                                epoch=epoch, nbatch=nbatch,
+                                eval_metric=eval_metric, locals=locals()))
+                # the whole iteration, fetch included: what a step costs
+                _obs.record_step(
+                    num_step, fit_step.dur_s, epoch=epoch,
+                    batch_size=_batch_num_samples(data_batch),
+                    skipped=skip or None, timing="iteration")
 
             for name, val in eval_metric.get_name_value():
                 self.logger.info("Epoch[%d] Train-%s=%f", epoch, name, val)
             toc = time.time()
             self.logger.info("Epoch[%d] Time cost=%.3f", epoch, (toc - tic))
 
-            arg_p, aux_p = self.get_params()
-            self.set_params(arg_p, aux_p)
-            if epoch_end_callback is not None:
-                _call(epoch_end_callback, epoch, self.symbol, arg_p, aux_p)
+            # once an epoch the parameters go to the host and back (the
+            # callbacks checkpoint the host copy): time the chip idles
+            with span("epoch_end", step=num_step):
+                arg_p, aux_p = self.get_params()
+                self.set_params(arg_p, aux_p)
+                if epoch_end_callback is not None:
+                    _call(epoch_end_callback, epoch, self.symbol, arg_p,
+                          aux_p)
 
             if eval_data is not None:
                 res = self.score(eval_data, validation_metric,

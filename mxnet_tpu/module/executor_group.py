@@ -28,6 +28,7 @@ from ..ndarray import NDArray, zeros, concatenate
 from ..executor_manager import (_split_input_slice, _check_arguments,
                                 _bind_exec, _load_data, _load_label)
 from ..io import DataDesc
+from ..observability import spans as _spans
 
 __all__ = ["DataParallelExecutorGroup"]
 
@@ -240,19 +241,24 @@ class DataParallelExecutorGroup(object):
 
     # ------------------------------------------------------------------
     def load_data_batch(self, data_batch):
-        if self.sharded:
-            exec_ = self.execs[0]
-            for name, src in zip(self.data_names, data_batch.data):
-                exec_.arg_dict[name]._set_data(
-                    self._put_sharded(src, self._data_sharding))
-            if self.label_arrays and data_batch.label:
-                for name, src in zip(self.label_names, data_batch.label):
+        # ``h2d`` is the ISSUE of the copies: ``device_put`` may return
+        # before the bytes have moved, so what is still in flight shows up
+        # as device idle time after the dispatch, not in this span
+        with _spans.span("h2d"):
+            if self.sharded:
+                exec_ = self.execs[0]
+                for name, src in zip(self.data_names, data_batch.data):
                     exec_.arg_dict[name]._set_data(
                         self._put_sharded(src, self._data_sharding))
-            return
-        _load_data(data_batch, self.data_arrays)
-        if self.label_arrays and data_batch.label:
-            _load_label(data_batch, self.label_arrays)
+                if self.label_arrays and data_batch.label:
+                    for name, src in zip(self.label_names,
+                                         data_batch.label):
+                        exec_.arg_dict[name]._set_data(
+                            self._put_sharded(src, self._data_sharding))
+                return
+            _load_data(data_batch, self.data_arrays)
+            if self.label_arrays and data_batch.label:
+                _load_label(data_batch, self.label_arrays)
 
     def forward(self, data_batch=None, is_train=None):
         if data_batch is not None:
@@ -361,21 +367,24 @@ class DataParallelExecutorGroup(object):
         self._ensure_on_mesh()
 
     def update_metric(self, eval_metric, labels):
-        if self.sharded and self._num_proc > 1:
-            # outputs are global (batch x hosts); this process owns the
-            # local batch — evaluate on our addressable output shards
-            exec_ = self.execs[0]
-            local_outs = []
-            for out in exec_.outputs:
-                shards = sorted(out.data.addressable_shards,
-                                key=lambda s: s.index[0].start or 0)
-                local_outs.append(NDArray(
-                    _np.concatenate([_np.asarray(s.data) for s in shards])))
-            eval_metric.update(list(labels), local_outs)
-            return
-        for texec, islice in zip(self.execs, self.slices):
-            labels_slice = [label[islice] for label in labels]
-            eval_metric.update(labels_slice, texec.outputs)
+        # the metric's own arithmetic is this span's self time; its
+        # blocking reads are the ``metric_sync`` children
+        with _spans.span("metric"):
+            if self.sharded and self._num_proc > 1:
+                # outputs are global (batch x hosts); this process owns the
+                # local batch — evaluate on our addressable output shards
+                exec_ = self.execs[0]
+                local_outs = []
+                for out in exec_.outputs:
+                    shards = sorted(out.data.addressable_shards,
+                                    key=lambda s: s.index[0].start or 0)
+                    local_outs.append(NDArray(_np.concatenate(
+                        [_np.asarray(s.data) for s in shards])))
+                eval_metric.update(list(labels), local_outs)
+                return
+            for texec, islice in zip(self.execs, self.slices):
+                labels_slice = [label[islice] for label in labels]
+                eval_metric.update(labels_slice, texec.outputs)
 
     def install_monitor(self, mon):
         for exec_ in self.execs:

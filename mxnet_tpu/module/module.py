@@ -17,6 +17,7 @@ from .. import context as ctx_mod
 from .. import optimizer as opt_mod
 from ..initializer import Uniform
 from ..ndarray import NDArray, zeros
+from ..observability import spans as _spans
 from .base_module import BaseModule, _check_input_names
 from .executor_group import DataParallelExecutorGroup
 
@@ -354,20 +355,22 @@ class Module(BaseModule):
             self._fused_update_done = False
             return
         from ..model import _update_params_on_kvstore, _update_params
-        if self._update_on_kvstore:
-            _update_params_on_kvstore(self._exec_group.param_arrays,
-                                      self._exec_group.grad_arrays,
-                                      self._kvstore)
-        else:
-            # inline-allreduce groups already hold globally-reduced grads
-            # (XLA all-reduce in backward) — routing them through the
-            # kvstore again would double-count across workers
-            kv = None if getattr(self, "_kv_inline", False) else self._kvstore
-            _update_params(self._exec_group.param_arrays,
-                           self._exec_group.grad_arrays,
-                           updater=self._updater,
-                           num_device=len(self._exec_group.execs),
-                           kvstore=kv)
+        with _spans.span("update"):
+            if self._update_on_kvstore:
+                _update_params_on_kvstore(self._exec_group.param_arrays,
+                                          self._exec_group.grad_arrays,
+                                          self._kvstore)
+            else:
+                # inline-allreduce groups already hold globally-reduced
+                # grads (XLA all-reduce in backward) — routing them through
+                # the kvstore again would double-count across workers
+                kv = None if getattr(self, "_kv_inline", False) \
+                    else self._kvstore
+                _update_params(self._exec_group.param_arrays,
+                               self._exec_group.grad_arrays,
+                               updater=self._updater,
+                               num_device=len(self._exec_group.execs),
+                               kvstore=kv)
 
     def get_outputs(self, merge_multi_context=True):
         self._assert_binded()

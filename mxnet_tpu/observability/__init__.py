@@ -1,7 +1,7 @@
 """Pod-wide telemetry: structured event log, phase spans, counters,
 cross-rank aggregation.
 
-Off by default.  Set ``MXTPU_TELEMETRY=1`` (and optionally
+The event log is off by default.  Set ``MXTPU_TELEMETRY=1`` (and optionally
 ``MXTPU_TELEMETRY_DIR=/some/scratch``) and every rank appends typed
 JSONL records — step timings, phase spans, derived counters, faults,
 checkpoint lifecycle, collective traffic — to its own
@@ -10,8 +10,10 @@ report; :mod:`.aggregate` publishes live per-rank summaries over the
 coordination-service KV.  Schema and usage: docs/observability.md.
 
 The fit loops / trainer / kvstore / resilience seams call
-:func:`record_step` and :func:`spans.span`; both are cheap no-ops when
-telemetry is off, so the default path pays one cached boolean check.
+:func:`record_step` and :func:`spans.span`.  A span is always a trace
+annotation (``mx.<name>``) and a record in :mod:`.spans`' in-memory ring,
+about 2 us; with telemetry off neither call writes to the log, and
+``record_step`` only notes the step in the flight recorder's ring.
 """
 from __future__ import annotations
 

@@ -249,9 +249,12 @@ def _env_key():
             os.environ.get("MXTPU_RUN_ID"))
 
 
-def get():
-    """The process EventLog, or None when telemetry is off."""
-    now = time.monotonic()
+def get(now=None):
+    """The process EventLog, or None when telemetry is off.  ``now``: a
+    ``time.perf_counter()`` reading the caller has just taken (a span
+    has), which saves this call its own clock read."""
+    if now is None:
+        now = time.perf_counter()
     if 0.0 <= now - _STATE["checked"] < _RECHECK_S:
         return _STATE["log"]
     _STATE["checked"] = now

@@ -156,6 +156,11 @@ class FlightRecorder(object):
                    dict(e, age_ms=int(now * 1000.0) - e["launch_wall_ms"])
                    for e in pend],
                "events": recs}
+        try:        # the last phases too: the program's closed spans
+            from . import spans
+            doc["spans"] = spans.snapshot()
+        except Exception:
+            doc["spans"] = None
         if self._probe is not None:
             try:
                 doc["absent_ranks"] = sorted(self._probe())

@@ -25,12 +25,20 @@ from __future__ import annotations
 __all__ = ["TRAIN_PHASES", "SERVE_PHASES", "PHASES", "is_canonical",
            "DATA_WAIT", "H2D", "STEP", "ALLREDUCE", "KV_BARRIER",
            "CKPT_SAVE", "EVAL", "HOTSTATE_SNAPSHOT", "WARM_RESUME",
-           "QUEUE_WAIT", "PACK", "DEVICE", "UNPACK"]
+           "FIT_STEP", "STEP_DISPATCH", "UPDATE", "METRIC", "METRIC_SYNC",
+           "BATCH_END", "EPOCH_END", "QUEUE_WAIT", "PACK", "DEVICE", "UNPACK"]
 
 #: phases the training wiring emits (fit loops, ShardedTrainer, kvstore,
-#: and the warm-elasticity transition: host offload + warm assembly)
+#: and the warm-elasticity transition: host offload + warm assembly).
+#: From ``fit_step`` on: one iteration of ``Module.fit`` and what it is
+#: made of — the jitted call's dispatch (also ``ShardedTrainer.step``'s),
+#: a non-fused ``update``, ``metric`` with the blocking read ``metric_sync``
+#: inside it, and the batch-end callbacks — then ``epoch_end``, the
+#: parameters' round trip through the host and the epoch-end callbacks.
 TRAIN_PHASES = ("data_wait", "h2d", "step", "allreduce", "kv_barrier",
-                "ckpt_save", "eval", "hotstate_snapshot", "warm_resume")
+                "ckpt_save", "eval", "hotstate_snapshot", "warm_resume",
+                "fit_step", "step_dispatch", "update", "metric",
+                "metric_sync", "batch_end", "epoch_end")
 
 #: request-visible serving phases, in pipeline order (docs/serving.md)
 SERVE_PHASES = ("queue_wait", "pack", "device", "unpack")
@@ -39,7 +47,8 @@ SERVE_PHASES = ("queue_wait", "pack", "device", "unpack")
 PHASES = TRAIN_PHASES + SERVE_PHASES
 
 (DATA_WAIT, H2D, STEP, ALLREDUCE, KV_BARRIER, CKPT_SAVE, EVAL,
- HOTSTATE_SNAPSHOT, WARM_RESUME) = TRAIN_PHASES
+ HOTSTATE_SNAPSHOT, WARM_RESUME, FIT_STEP, STEP_DISPATCH, UPDATE, METRIC,
+ METRIC_SYNC, BATCH_END, EPOCH_END) = TRAIN_PHASES
 (QUEUE_WAIT, PACK, DEVICE, UNPACK) = SERVE_PHASES
 
 _CANON = frozenset(PHASES)

@@ -1,24 +1,38 @@
-"""Phase spans: one name shared by the event log and the xprof trace.
+"""Phase spans: one name, three sinks.
 
-``span("data_wait")`` / ``span("h2d")`` / ``span("step")`` /
-``span("allreduce")`` / ``span("ckpt_save")`` time a phase on the host
-and (a) append a ``span`` record to the event log, (b) forward the same
-name to :class:`mxnet_tpu.profiler.annotate` so a captured xprof trace
-carries identical region names — the operator reads "allreduce is the
-slow phase" off either surface without a translation table.
+``span("data_wait")`` / ``span("h2d")`` / ``span("step_dispatch")`` /
+``span("allreduce")`` / ``span("ckpt_save")`` time a phase on the host.
+Every span, with no switch,
 
-When telemetry is off and no profiler trace is running, ``span()``
-returns a shared null context: zero allocation, zero timing.
+(a) opens a ``jax.profiler.TraceAnnotation("mx." + name)``, so any
+    profiler session (``mx.profiler.profiler_set_state("run")``, a
+    benchmark's traced run) holds the program's spans in the same
+    xplane as the device's ``XLA Ops``, on the trace's clock;
+(b) appends a closed-span record ``(id, parent_id, name, step, t0_ns,
+    t1_ns, thread)`` to a bounded in-memory ring (``perf_counter_ns``
+    times; the parent is the enclosing span on the same thread; the
+    oldest record is dropped first).  :func:`steps`, :func:`self_ns`
+    and :func:`snapshot` read it.
+
+With ``MXTPU_TELEMETRY=1`` it also (c) emits a ``span`` record to the
+event log (plus trace/span ids under ``MXTPU_TRACE=1``).  The variable
+adds the log and nothing else.
 """
 from __future__ import annotations
 
+import collections
+import itertools
+import threading
 import time
+
+from jax.profiler import TraceAnnotation as _Annotation
 
 from . import events
 from . import trace as _trace
-from .phases import TRAIN_PHASES
+from .phases import STEP_DISPATCH, TRAIN_PHASES
 
-__all__ = ["span", "SPAN_NAMES", "timed_iter", "overlap_report"]
+__all__ = ["span", "SPAN_NAMES", "timed_iter", "overlap_report",
+           "SpanRecord", "steps", "self_ns", "snapshot", "reset"]
 
 #: canonical phase names (free-form names are allowed; these are the
 #: ones the built-in wiring emits and mxtop groups by).  Compat alias
@@ -27,96 +41,181 @@ __all__ = ["span", "SPAN_NAMES", "timed_iter", "overlap_report"]
 #: can't drift.
 SPAN_NAMES = TRAIN_PHASES
 
+#: prefix of the program's spans in a profiler trace (a benchmark's own
+#: annotations carry another)
+TRACE_PREFIX = "mx."
 
-class _NullSpan(object):
-    __slots__ = ()
+#: closed spans kept.  A fit step leaves about ten, so this holds some
+#: 400 steps: more than any traced window, at well under a megabyte.
+RING_CAPACITY = 4096
 
-    def __enter__(self):
-        return self
+#: what a ring record holds, in order
+_FIELDS = ("id", "parent_id", "name", "step", "t0_ns", "t1_ns", "thread")
 
-    def __exit__(self, *exc):
-        return False
-
-
-_NULL = _NullSpan()
+_ring = collections.deque(maxlen=RING_CAPACITY)
+_next_id = itertools.count(1).__next__
+_local = threading.local()
 
 
 class _Span(object):
-    __slots__ = ("name", "step", "fields", "_t0", "_ann", "_ids")
+    """An open span; ``t0_ns`` / ``t1_ns`` / ``dur_s`` can be read from it
+    once the ``with`` block has closed."""
+    __slots__ = ("name", "step", "fields", "log", "id", "parent_id",
+                 "t0_ns", "t1_ns", "_stack", "_ann", "_ids")
 
     def __init__(self, name, step, fields):
         self.name = name
         self.step = step
         self.fields = fields
-        self._t0 = None
-        self._ann = None
-        self._ids = None
+        self.log = True         # False: keep this span out of the event log
 
     def __enter__(self):
         try:
-            from ..profiler import annotate
-            self._ann = annotate(self.name)
-            self._ann.__enter__()
-        except Exception:               # no jax / exotic backend: host
-            self._ann = None            # timing still works
-        # MXTPU_TRACE=1: push a trace frame so this span carries
-        # trace/span/parent ids and emits inside it bind to it
-        self._ids = _trace.begin_span(self.name) or None
-        self._t0 = time.perf_counter()
+            stack = _local.stack
+        except AttributeError:
+            stack = _local.stack = []
+        self._stack = stack
+        self.parent_id = stack[-1] if stack else None
+        self.id = _next_id()
+        stack.append(self.id)
+        self._ann = _Annotation(TRACE_PREFIX + self.name)
+        self._ann.__enter__()
+        self.t0_ns = t0 = time.perf_counter_ns()
+        # the log's own ids (MXTPU_TRACE=1): push a trace frame so this
+        # span's record carries trace/span/parent ids and emits inside
+        # it bind to it.  The log is asked with the clock reading just
+        # taken: two reads a span, however slow the host's clock is.
+        self._ids = (_trace.begin_span(self.name) or None) \
+            if events.get(t0 * 1e-9) is not None else None
         return self
 
     def __exit__(self, *exc):
-        dur_ms = (time.perf_counter() - self._t0) * 1e3
+        self.t1_ns = t1 = time.perf_counter_ns()
+        self._ann.__exit__(*exc)
         if self._ids is not None:
             _trace.end_span()
-        if self._ann is not None:
-            try:
-                self._ann.__exit__(*exc)
-            except Exception:
-                pass
-        events.emit("span", step=self.step, name=self.name,
-                    dur_ms=round(dur_ms, 3), **(self._ids or {}),
-                    **self.fields)
+        self._stack.pop()
+        _ring.append((self.id, self.parent_id, self.name, self.step,
+                      self.t0_ns, t1, threading.get_ident()))
+        if self.log and events.get(t1 * 1e-9) is not None:
+            events.emit("span", step=self.step, name=self.name,
+                        dur_ms=round((t1 - self.t0_ns) * 1e-6, 3),
+                        **(self._ids or {}), **self.fields)
         return False
+
+    @property
+    def dur_s(self):
+        return (self.t1_ns - self.t0_ns) * 1e-9
 
 
 def span(name, step=None, **fields):
-    """Context manager timing one phase.  Null (free) when telemetry is
-    off; otherwise records a ``span`` event and annotates the trace."""
-    if events.get() is None:
-        return _NULL
+    """Context manager timing one phase: a trace annotation and a ring
+    record always, a ``span`` event when telemetry is on."""
     return _Span(name, step, fields)
 
 
 def timed_iter(iterable, name="data_wait", step_from=None):
     """Pass-through generator that times each ``next()`` under ``span``
-    — the input-pipeline wait the fit loops can't see otherwise.  Plain
-    iteration (no timing) when telemetry is off.
+    — the input-pipeline wait the fit loops can't see otherwise.
 
     ``step_from``: optional zero-arg callable giving the step to tag
     each span with (called per batch, AFTER the fetch).
     """
-    if events.get() is None:
-        for item in iterable:
-            yield item
-        return
     it = iter(iterable)
     while True:
-        ids = _trace.begin_span(name)
-        t0 = time.perf_counter()
-        try:
-            item = next(it)
-        except StopIteration:
-            if ids:
-                _trace.end_span()
-            return
-        dur_ms = (time.perf_counter() - t0) * 1e3
-        if ids:
-            _trace.end_span()
-        events.emit("span", name=name,
-                    step=step_from() if step_from is not None else None,
-                    dur_ms=round(dur_ms, 3), **ids)
+        with span(name) as sp:
+            try:
+                item = next(it)
+            except StopIteration:
+                sp.log = False      # the fetch that found the end
+                return
+            if step_from is not None:
+                sp.step = step_from()
         yield item
+
+
+# ----------------------------------------------------------------------
+# reading the ring
+# ----------------------------------------------------------------------
+class SpanRecord(object):
+    """One closed span as the readers see it, with its ``children`` (by
+    start time) where it came from :func:`steps`."""
+    __slots__ = _FIELDS + ("children",)
+
+    def __init__(self, *values):
+        for field, value in zip(_FIELDS, values):
+            setattr(self, field, value)
+        self.children = []
+
+    @property
+    def dur_ns(self):
+        return self.t1_ns - self.t0_ns
+
+    def walk(self):
+        """This span, then every descendant, parents before children and
+        siblings by start time."""
+        yield self
+        for child in self.children:
+            for rec in child.walk():
+                yield rec
+
+    def named(self, *names):
+        """The spans of :meth:`walk` called one of ``names``."""
+        return [rec for rec in self.walk() if rec.name in names]
+
+
+def snapshot():
+    """Everything in the ring, oldest first, as plain dicts (what a
+    flight dump carries: the last phases before a crash)."""
+    return [dict(zip(_FIELDS, rec)) for rec in list(_ring)]
+
+
+def reset():
+    """Empty the ring (tests)."""
+    _ring.clear()
+
+
+def steps(n):
+    """The last ``n`` step roots, newest last, each a :class:`SpanRecord`
+    with its descendants linked under ``children``.  A step root is a
+    parentless span that is, or contains, a ``step_dispatch``: a
+    ``fit_step`` of ``Module.fit``, or the bare ``step_dispatch`` of a
+    ``ShardedTrainer.step``.  Chosen by parent links and ring order,
+    never by ``step`` numbers, which start again with every ``fit``.
+    Fewer than ``n`` come back where the ring holds fewer."""
+    raw = list(_ring)
+    if not raw or n <= 0:
+        return []
+    children = {}
+    for rec in raw:
+        children.setdefault(rec[1], []).append(rec)
+    # children close before their parents, so a full ring may have
+    # dropped some of an old root's: such a root is not whole
+    whole_from = raw[0][5] if len(raw) == _ring.maxlen else 0
+
+    def build(rec):
+        node = SpanRecord(*rec)
+        node.children = [build(c) for c in sorted(
+            children.get(node.id, ()), key=lambda c: c[4])]
+        return node
+
+    out = []
+    for rec in reversed(children.get(None, ())):
+        if rec[4] < whole_from:
+            break
+        root = build(rec)
+        if root.named(STEP_DISPATCH):
+            out.append(root)
+            if len(out) == n:
+                break
+    out.reverse()
+    return out
+
+
+def self_ns(span):
+    """A span's duration minus the part its children cover (they ran on
+    its thread, one after another, inside it)."""
+    return span.dur_ns - sum(c.dur_ns for c in span.children)
 
 
 def overlap_report(records, phases=("data_wait", "h2d")):
@@ -139,7 +238,10 @@ def overlap_report(records, phases=("data_wait", "h2d")):
     two phases, and the ratio rises above 1 — "wall < Σ phases" is the
     proof the dead time went under the step.  ``phases`` deliberately
     excludes ``allreduce``/``kv_barrier``: those spans nest inside the
-    ``step`` record's window and would double-count serially.
+    ``step`` record's window and would double-count serially.  So do the
+    loop's own ``data_wait``/``h2d`` where the step records cover whole
+    iterations (``Module.fit``: ``timing="iteration"``); there only the
+    producer thread's spans (tagged ``async``) are added.
 
     Returns ``{"overlap_ratio", "wall_ms", "serial_ms", "steps",
     "phase_ms": {phase: total}, "phase_p50_ms": {phase: p50},
@@ -182,9 +284,12 @@ def overlap_report(records, phases=("data_wait", "h2d")):
         if wall <= 0:
             continue
         serial = sum(float(r["dur_ms"]) for r in steps[1:])
+        whole = any(r.get("timing") == "iteration" for r in steps)
         phase_durs = {}
         for r in recs:
             if r.get("kind") != "span" or r.get("name") not in phases:
+                continue
+            if whole and not r.get("async"):
                 continue
             w = r.get("wall_ms")
             if w is None or not (t0 < w <= t1):

@@ -679,12 +679,19 @@ class ShardedTrainer(object):
                 _abstractify, step_args)
             self._adopt_cached_step()
 
+        from .. import observability as _obs
+        # host dispatch wall only: XLA execution is async, so this
+        # understates device time unless the caller syncs (the Module
+        # path does via update(); docs/observability.md)
+        dispatched = _obs.span("step_dispatch", step=self.num_update)
+
         def dispatch():
             # inside the guarded region so injected hangs are caught
             # exactly like a wedged collective would be
-            _resilience.maybe_fault("step", step=self.num_update)
-            with self._sp_scope():
-                out = self._jit_step(*step_args)
+            with dispatched:
+                _resilience.maybe_fault("step", step=self.num_update)
+                with self._sp_scope():
+                    out = self._jit_step(*step_args)
             if self.sentinel:
                 self._sentinel_state = out[4]
                 return out[:4]
@@ -694,7 +701,6 @@ class ShardedTrainer(object):
         if timeout is None:
             timeout = _resilience.step_timeout_s()
 
-        from .. import observability as _obs
         # the fused step is a pod-wide rendezvous (the in-step psum means
         # every rank must enter for any to leave), so ledger it like a
         # collective: a step that never completes stays pending and the
@@ -702,30 +708,13 @@ class ShardedTrainer(object):
         _obs.flight.collective_begin(
             "train_step", self.num_update,
             participants=list(range(jax.process_count())))
-        if _obs.events.get() is not None:
-            # host dispatch wall only: XLA execution is async, so this
-            # understates device time unless the caller syncs (the
-            # Module path does via update(); docs/observability.md)
-            import time as _time
-            t0 = _time.perf_counter()
-            try:
-                if timeout:
-                    out = _resilience.run_with_timeout(
-                        dispatch, timeout, phase="train_step",
-                        step=self.num_update)
-                else:
-                    out = dispatch()
-            finally:
-                _obs.record_step(self.num_update,
-                                 _time.perf_counter() - t0,
-                                 batch_size=self._batch_samples(batch),
-                                 timing="dispatch")
-        elif timeout:
-            out = _resilience.run_with_timeout(
-                dispatch, timeout, phase="train_step",
-                step=self.num_update)
-        else:
-            out = dispatch()
+        # without a timeout this is dispatch() itself
+        out = _resilience.run_with_timeout(
+            dispatch, timeout or None, phase="train_step",
+            step=self.num_update)
+        _obs.record_step(self.num_update, dispatched.dur_s,
+                         batch_size=self._batch_samples(batch),
+                         timing="dispatch")
         _obs.flight.collective_end("train_step", self.num_update)
         return out
 

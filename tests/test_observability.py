@@ -110,11 +110,16 @@ def test_env_rebuild_swaps_log(monkeypatch, tmp_path):
 # ----------------------------------------------------------------------
 # spans.py
 # ----------------------------------------------------------------------
-def test_span_null_when_disabled():
-    s1, s2 = spans.span("step"), spans.span("h2d")
-    assert s1 is s2                          # shared null object
-    with s1:
+def test_span_without_telemetry_keeps_ring_and_skips_log():
+    # telemetry off: the span still lands in the in-memory ring (and in
+    # any profiler trace); only the event log is skipped
+    assert events.get() is None
+    spans.reset()
+    with spans.span("step", step=3):
         pass
+    (rec,) = spans.snapshot()
+    assert rec["name"] == "step" and rec["step"] == 3
+    assert rec["parent_id"] is None and rec["t1_ns"] >= rec["t0_ns"]
 
 
 def test_span_records_duration(monkeypatch, tmp_path):
