@@ -8,7 +8,14 @@ for long sequences:
 - ``flash_attention``: fused online-softmax attention as a Pallas TPU
   kernel (MXU matmuls, no (seq, seq) materialization in HBM) where the
   computation is placed on a TPU; the jnp reference implementation
-  anywhere else, so tests/CPU paths stay exact.
+  anywhere else, so tests/CPU paths stay exact.  The kernel multiplies
+  q, k, v in the dtype they arrive in (bfloat16 operands are not
+  widened; float32 operands get the product they always got, at
+  Mosaic's default precision), sums every product in float32, rounds p
+  to v's dtype for p·v only, and keeps the softmax state and the saved
+  logsumexp in float32.
+  Under ``causal`` a query block reads the key blocks up to the
+  diagonal and no further: masked blocks are skipped, not computed.
 - ``ring_attention``: blockwise attention over a ``Mesh`` axis ("sp"):
   each device holds a sequence chunk of q/k/v; k/v chunks rotate around
   the ring via ``lax.ppermute`` while the online-softmax state (o, m, l)
@@ -35,9 +42,9 @@ __all__ = ["attention_reference", "flash_attention", "ring_attention",
            "current_sequence_parallel", "attention_scope"]
 
 _NEG_INF = -1e30
-# TPU lane width: logsumexp stats are stored broadcast across one lane
-# row so the pallas output block is a legal Mosaic (8,128) tile
-_LSE_LANES = 128
+# sublanes of a float32 tile: the logsumexp row is stored once per
+# sublane so the pallas output block is a legal Mosaic (8,128) tile
+_LSE_ROWS = 8
 
 
 def attention_reference(q, k, v, causal=False, scale=None,
@@ -100,52 +107,120 @@ def blockwise_combine(q, kv_blocks, causal=False, scale=None, q_offset=0,
 # ----------------------------------------------------------------------
 # Pallas flash attention (TPU)
 # ----------------------------------------------------------------------
+def _causal_k_blocks(q_block, block_q, block_k, n_k_blocks):
+    """``(unmasked, visited)`` for query block ``q_block`` of a causal
+    call with both offsets 0: key blocks ``[0, unmasked)`` lie wholly at
+    or below the diagonal (every key visible to every row of the block),
+    ``[unmasked, visited)`` are crossed by it and need the mask, and
+    ``[visited, n_k_blocks)`` hold no visible key and are never read.
+    Python ints in, ints out; the kernel hands it ``pl.program_id``."""
+    first_row = q_block * block_q
+    clamp = min if isinstance(q_block, int) else jnp.minimum
+    unmasked = clamp((first_row + 1) // block_k, n_k_blocks)
+    visited = clamp((first_row + block_q + block_k - 1) // block_k,
+                    n_k_blocks)
+    return unmasked, visited
+
+
+def _scale_folds_into(scale, dtype):
+    """Whether ``q * scale`` in ``dtype`` loses nothing the scores would
+    keep: float32 rounds it where the product rounds anyway; a narrower
+    dtype only when ``scale`` is a power of two (1/8 at 64-wide heads)."""
+    return dtype == jnp.float32 or math.frexp(scale)[0] == 0.5
+
+
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_k, causal,
                   scale, seq_k):
-    """Grid: (batch*heads, q_blocks).  One q block vs all k blocks.
-    Outputs the normalized o block and the logsumexp stats (saved for the
-    blockwise backward)."""
-    q = q_ref[...].astype(jnp.float32)  # (block_q, d)
-    block_q = q.shape[0]
+    """Grid: (batch*heads, q_blocks).  One q block against the key
+    blocks it can see: all of them, or under ``causal`` those up to the
+    diagonal (``_causal_k_blocks``), of which only the ones the diagonal
+    crosses are masked.
+
+    Both products take q, k, v as they come (bfloat16 operands are not
+    widened) and sum in float32; p is rounded to v's dtype for p·v; the
+    online-softmax state and lse are float32.
+
+    The scores are held keys-by-queries, s = k·qᵀ (block_k, block_q):
+    the per-query statistics m, l are then (1, block_q) rows, a vector
+    register per 128 queries where a (block_q, 1) column takes one per
+    8, and o accumulates as (d, block_q) with no lane left empty at
+    d = 64.  Outputs the normalized o block and the logsumexp stats
+    (saved for the blockwise backward)."""
     import jax.experimental.pallas as pl
 
-    q_block_idx = pl.program_id(1)
-    q_offset = q_block_idx * block_q
-
-    m = jnp.full((block_q,), _NEG_INF, jnp.float32)
-    l = jnp.zeros((block_q,), jnp.float32)
-    o = jnp.zeros(q.shape, jnp.float32)
-
+    block_q, d = q_ref.shape
     n_k_blocks = seq_k // block_k
+    q_offset = pl.program_id(1) * block_q
+    q = q_ref[...]
+    fold = _scale_folds_into(scale, q.dtype)
+    if fold:
+        q = q * scale
 
-    def body(i, carry):
-        m, l, o = carry
-        k = k_ref[pl.dslice(i * block_k, block_k), :].astype(jnp.float32)
-        v = v_ref[pl.dslice(i * block_k, block_k), :].astype(jnp.float32)
-        s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
-        if causal:
-            qpos = q_offset + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            kpos = i * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(qpos >= kpos, s, _NEG_INF)
-        m_new = jnp.maximum(m, jnp.max(s, axis=-1))
-        p = jnp.exp(s - m_new[:, None])
+    def step(masked, i, carry):
+        m, l, o = carry             # (1, block_q) twice, (d, block_q)
+        start = pl.multiple_of(i * block_k, block_k)
+        k = k_ref[pl.ds(start, block_k), :]
+        v = v_ref[pl.ds(start, block_k), :]
+        s = lax.dot_general(k, q, (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32)
+        if not fold:
+            s = s * scale
+        if masked:
+            kpos = lax.broadcasted_iota(jnp.int32, s.shape, 0)
+            qpos = lax.broadcasted_iota(jnp.int32, s.shape, 1)
+            s = jnp.where(qpos - kpos >= start - q_offset, s, _NEG_INF)
+        m_new = jnp.maximum(m, jnp.max(s, axis=0, keepdims=True))
+        p = jnp.exp(s - m_new)
         c = jnp.exp(m - m_new)
-        l_new = l * c + jnp.sum(p, axis=-1)
-        o_new = o * c[:, None] + jnp.dot(
-            p, v, preferred_element_type=jnp.float32)
+        l_new = l * c + jnp.sum(p, axis=0, keepdims=True)
+        o_new = o * c + lax.dot_general(
+            v, p.astype(v.dtype), (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
         return m_new, l_new, o_new
 
-    m, l, o = lax.fori_loop(0, n_k_blocks, body, (m, l, o))
+    carry = (jnp.full((1, block_q), _NEG_INF, jnp.float32),
+             jnp.zeros((1, block_q), jnp.float32),
+             jnp.zeros((d, block_q), jnp.float32))
+    if causal:
+        unmasked, visited = _causal_k_blocks(pl.program_id(1), block_q,
+                                             block_k, n_k_blocks)
+        carry = lax.fori_loop(0, unmasked, functools.partial(step, False),
+                              carry)
+        carry = lax.fori_loop(unmasked, visited,
+                              functools.partial(step, True), carry)
+    else:
+        carry = lax.fori_loop(0, n_k_blocks, functools.partial(step, False),
+                              carry)
+    m, l, o = carry
     l_safe = jnp.maximum(l, 1e-30)
-    o_ref[...] = (o / l_safe[:, None]).astype(o_ref.dtype)
-    # stats broadcast across a 128-wide lane dim: Mosaic requires the
-    # block's last two dims to be (8,128)-tileable, so a 1-D (block_q,)
-    # stats row cannot be a TPU output block — lane 0 is read back
-    # outside the kernel
-    lse = (m + jnp.log(l_safe)).astype(jnp.float32)
-    lse_ref[...] = jnp.broadcast_to(lse[:, None], (block_q, _LSE_LANES))
+    o_ref[...] = (o / l_safe).T.astype(o_ref.dtype)
+    # a 1-D (block_q,) stats row cannot be a TPU output block (Mosaic
+    # tiles the last two dims): the row is written to every sublane of
+    # one float32 tile, and row 0 is read back outside the kernel
+    lse_ref[...] = jnp.broadcast_to(m + jnp.log(l_safe),
+                                    (_LSE_ROWS, block_q))
+
+
+# forward block extents, largest first; the last is also the backward's
+# key block where the caller fixes none
+_FLASH_BLOCKS = (512, 256, 128)
+
+
+def _flash_blocks(sq, sk, block_q=None, block_k=None):
+    """``(block_q, block_k)`` of the forward kernel: what the caller
+    fixed, else the largest of 512/256/128 that divides the sequence.
+    On a v5e small blocks pay for their bookkeeping, not their products:
+    at (8·16, 1024, 64) bfloat16 causal a call takes 1.87 ms at 128/128,
+    0.86 at 256/256, 0.52 at 512/512, 0.57 at 1024/1024 (PERF.md, PR
+    26), though 512/512 computes 3 of 4 blocks where 128/128 computes
+    36 of 64.  None where a block does not divide its sequence: the
+    kernel has no partial blocks."""
+    blocks = tuple(
+        block or next((b for b in _FLASH_BLOCKS if seq % b == 0), None)
+        for seq, block in ((sq, block_q), (sk, block_k)))
+    if None in blocks or sq % blocks[0] or sk % blocks[1]:
+        return None
+    return blocks
 
 
 def _flash_block_layout(bh, sq, sk, d, block_q):
@@ -161,13 +236,18 @@ def _flash_block_layout(bh, sq, sk, d, block_q):
     ]
     out_blocks = [
         ((None, block_q, d), (bh, sq, d)),              # o
-        ((None, block_q, _LSE_LANES), (bh, sq, _LSE_LANES)),  # lse
+        ((None, _LSE_ROWS, block_q), (bh, _LSE_ROWS, sq)),  # lse
     ]
     return in_blocks, out_blocks
 
 
+@functools.partial(jax.jit, static_argnames=(
+    "causal", "scale", "block_q", "block_k", "interpret"))
 def _flash_forward_kernel_call(q, k, v, causal, scale, block_q, block_k,
                                interpret):
+    """(o, lse) of the forward kernel.  Jitted so that a model's layers,
+    which call it with the same shapes, trace and lower the kernel once
+    and not once a layer (24 layers of GPT-2-medium: 4–5 s of set-up)."""
     import jax.experimental.pallas as pl
 
     B, H, Sq, D = q.shape
@@ -190,7 +270,7 @@ def _flash_forward_kernel_call(q, k, v, causal, scale, block_q, block_k,
         ],
         out_specs=[
             pl.BlockSpec(ob[0], lambda b, i: (b, i, 0)),
-            pl.BlockSpec(lseb[0], lambda b, i: (b, i, 0)),
+            pl.BlockSpec(lseb[0], lambda b, i: (b, 0, i)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct(ob[1], q.dtype),
@@ -199,7 +279,7 @@ def _flash_forward_kernel_call(q, k, v, causal, scale, block_q, block_k,
         name="flash_forward",
         interpret=interpret,
     )(q3, k3, v3)
-    return out.reshape(B, H, Sq, D), lse[..., 0].reshape(B, H, Sq)
+    return out.reshape(B, H, Sq, D), lse[:, 0].reshape(B, H, Sq)
 
 
 def _flash_backward_blockwise(q, k, v, o, lse, do, causal, scale, block_k):
@@ -248,8 +328,8 @@ def _flash_backward_blockwise(q, k, v, o, lse, do, causal, scale, block_k):
     return dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype)
 
 
-def flash_attention(q, k, v, causal=False, scale=None, block_q=128,
-                    block_k=128, interpret=None):
+def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
+                    block_k=None, interpret=None):
     """Fused attention; q/k/v (B, H, S, D).  The Pallas kernel where the
     computation is placed on a TPU, the jnp reference elsewhere
     (``kernels.common.dispatch``: decided when the enclosing step is
@@ -258,24 +338,36 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=128,
     explicit ``interpret`` runs the kernel either way: ``True`` through
     the Pallas interpreter (tests), ``False`` through Mosaic.
 
+    The kernel multiplies in the operands' own dtype and sums in
+    float32: q·kᵀ of bfloat16 operands loses nothing (a product of two
+    bfloat16 numbers is exact in float32), p is rounded to v's dtype
+    before p·v, and m, l, o and the saved lse are float32.  With
+    ``causal`` (both offsets 0) each query block reads key blocks
+    0 .. ⌈(i + 1)·block_q / block_k⌉ − 1 and masks only those the
+    diagonal crosses; the blocks above it are never read.
+
     Differentiable: the forward runs the fused kernel and saves the
     logsumexp stats; the backward is the blockwise flash backward
     (recompute per kv block from the stats — O(Sq·block_k) live memory,
     never the (Sq, Sk) score matrix), attached via custom_vjp.
 
-    Sequence lengths must be multiples of the block sizes for the kernel
-    path (pad upstream); otherwise falls back to the reference
+    ``block_q``/``block_k`` default to the largest of 512/256/128 that
+    divides the sequence (``_flash_blocks``; the backward's key block to
+    128).  Sequence lengths must be multiples of the block sizes for the
+    kernel path (pad upstream); otherwise falls back to the reference
     implementation.
     """
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[-1])
-    sq, sk = q.shape[-2], k.shape[-2]
 
     def reference(q, k, v):
         return attention_reference(q, k, v, causal=causal, scale=scale)
 
-    if sq % block_q or sk % block_k:   # hard kernel constraint
+    blocks = _flash_blocks(q.shape[-2], k.shape[-2], block_q, block_k)
+    if blocks is None:                 # hard kernel constraint
         return reference(q, k, v)
+    bwd_block_k = block_k or _FLASH_BLOCKS[-1]   # the backward's, as it was
+    block_q, block_k = blocks
 
     def kernel(q, k, v, interpret=False):
         @jax.custom_vjp
@@ -292,7 +384,7 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=128,
         def _fa_bwd(res, ct):
             q, k, v, out, lse = res
             return _flash_backward_blockwise(q, k, v, out, lse, ct, causal,
-                                             scale, block_k)
+                                             scale, bwd_block_k)
 
         _fa.defvjp(_fa_fwd, _fa_bwd)
         return _fa(q, k, v)
@@ -448,17 +540,18 @@ def sharded_self_attention(q, k, v, causal=False):
                      check_vma=check_vma)(q, k, v)
 
 
-def flash_kernel_spec(batch_heads=8, seq_q=512, seq_k=512, head_dim=64,
-                      block_q=128, dtype="bfloat16"):
+def flash_kernel_spec(batch_heads=8, seq_q=1024, seq_k=1024, head_dim=64,
+                      block_q=None, dtype="bfloat16"):
     """MXL-K kernel spec for the flash forward pallas_call.
 
     Built from the same :func:`_flash_block_layout` the kernel itself
     uses, at a representative training shape, so the static tile
     validator (analysis/tiling.py) checks the blocks that actually run.
-    The lse output deliberately carries ``_LSE_LANES`` lanes: a 1-D
+    The lse output deliberately carries ``_LSE_ROWS`` sublanes: a 1-D
     ``(block_q,)`` stats row is exactly the historical bug Mosaic
-    rejected (no lane dimension to tile).
+    rejected (no second dimension to tile).
     """
+    block_q, _block_k = _flash_blocks(seq_q, seq_k, block_q)
     in_blocks, out_blocks = _flash_block_layout(batch_heads, seq_q, seq_k,
                                                 head_dim, block_q)
     blocks = []

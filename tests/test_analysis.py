@@ -654,7 +654,7 @@ def test_k_min_tile_table():
 
 
 def test_k_registered_flash_spec_is_clean():
-    """The FIXED flash kernel (lse broadcast across _LSE_LANES) must
+    """The FIXED flash kernel (lse row stored once per _LSE_ROWS) must
     lint clean — including its head_dim=64 lane dims, legal because the
     blocks cover the whole array dim (Mosaic pads the single tile)."""
     from mxnet_tpu.analysis.tiling import (KERNEL_SPECS,

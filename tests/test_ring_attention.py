@@ -14,6 +14,7 @@ from jax import shard_map
 import mxnet_tpu as mx
 from mxnet_tpu import symbol as sym
 from mxnet_tpu.parallel.ring_attention import (
+    _causal_k_blocks, _flash_blocks, _flash_forward_kernel_call,
     attention_reference, blockwise_combine, flash_attention, ring_attention)
 from mxnet_tpu.test_utils import (assert_almost_equal,
                                   check_numeric_gradient, tpu_lowering_text)
@@ -207,13 +208,20 @@ def test_transformer_sharded_trainer_sp():
     assert_almost_equal(outs["single"], outs["sp"], rtol=1e-3, atol=1e-4)
 
 
+def _flash_calls(text):
+    """(Mosaic kernels lowered, calls of the jitted forward kernel)."""
+    return (text.count("tpu_custom_call"),
+            text.count("call @_flash_forward_kernel_call"))
+
+
 def test_mesh_steps_carry_the_flash_kernel_per_device():
     """GSPMD cannot partition a Mosaic kernel ("wrap the call in a
     shard_map" — what the four-chip host said in PR 21), so a step
     sharded over a mesh WITHOUT a sequence axis must run the flash path
     per device: the dp=4 ShardedTrainer step and the Module mesh
-    group's fused step both lower for a TPU with one Mosaic call per
-    layer, and on the cpu mesh they compute what one device computes."""
+    group's fused step both lower for a TPU with one call of the Mosaic
+    kernel per layer (the kernel itself lowered once: its call is
+    jitted), and on the cpu mesh they compute what one device computes."""
     from mxnet_tpu.parallel import make_mesh
     from mxnet_tpu.parallel.trainer import ShardedTrainer
     from mxnet_tpu import optimizer as opt_mod
@@ -238,7 +246,7 @@ def test_mesh_steps_carry_the_flash_kernel_per_device():
         outs[dp] = np.asarray(out[0])
         with tr._sp_scope():
             text = tpu_lowering_text(tr._jit_step, *tr._abstract_args)
-        assert text.count("tpu_custom_call") == L, dp
+        assert _flash_calls(text) == (1, L), dp
     assert_almost_equal(outs[1], outs[4], rtol=1e-3, atol=1e-4)
 
     mod = mx.mod.Module(net, context=[mx.cpu(i) for i in range(4)])
@@ -256,7 +264,7 @@ def test_mesh_steps_carry_the_flash_kernel_per_device():
             {n: a.data for n, a in exe.aux_dict.items()},
             jax.random.PRNGKey(0), mod._fused_holder["states"],
             jnp.float32(0.1), jnp.float32(0.0), jnp.int32(1))
-    assert text.count("tpu_custom_call") == L
+    assert _flash_calls(text) == (1, L)
     # the same graph bound on one device afterwards gets its own program
     one = mx.mod.Module(net, context=mx.cpu(5))
     one.bind(data_shapes=[("data", (B, S))],
@@ -283,3 +291,140 @@ def test_flash_kernel_differentiable(causal):
     for a, b in zip(gf, gr):
         assert_almost_equal(np.asarray(a), np.asarray(b),
                             rtol=1e-3, atol=1e-4)
+
+
+# ----------------------------------------------------------------------
+# the forward kernel's inner loop: which key blocks a causal query block
+# visits, and what dtype it multiplies in
+# ----------------------------------------------------------------------
+@pytest.mark.parametrize("args,want", [
+    ((1024, 1024), (512, 512)),             # gpt2m_train_s1024: 3 of 4
+    ((768, 384), (256, 128)),
+    ((256, 4096), (256, 512)),
+    ((1024, 1024, 128), (128, 512)),        # a fixed block is kept
+    ((16, 16, 8, 8), (8, 8)),
+    ((100, 128), None),                     # no block tiles 100:
+    ((128, 128, None, 48), None),           # the reference runs instead
+])
+def test_flash_blocks_from_shapes(args, want):
+    assert _flash_blocks(*args) == want
+
+
+def test_flash_default_blocks_match_reference():
+    """No block given: the kernel runs (not the fallback) on blocks
+    chosen from the shapes, here 256 by 128, and matches."""
+    q = rng.randn(1, 1, 256, 8).astype(np.float32)
+    k, v = (rng.randn(1, 1, 384, 8).astype(np.float32) for _ in range(2))
+    assert _flash_blocks(256, 384) == (256, 128)
+    got = flash_attention(q, k, v, causal=True, interpret=True)
+    assert_almost_equal(np.asarray(got),
+                        np.asarray(attention_reference(q, k, v, causal=True)),
+                        rtol=1e-4, atol=1e-5)
+
+
+@pytest.mark.parametrize("sq,sk,block_q,block_k,want", [
+    (1024, 1024, 128, 128, (36, 64)),       # gpt2m_train_s1024's shape
+    (1024, 1024, 256, 128, (20, 32)),
+    (1024, 1024, 128, 256, (20, 32)),
+    (1024, 1024, 512, 512, (3, 4)),
+    (512, 1024, 128, 128, (10, 32)),        # sq < sk
+    (1024, 512, 128, 128, (26, 32)),        # sq > sk: the bound clamps
+    (96, 48, 16, 8, (30, 36)),
+    (64, 64, 8, 16, (20, 32)),
+    (16, 16, 8, 1, (24, 32)),
+])
+def test_causal_visit_count(sq, sk, block_q, block_k, want):
+    """Query block i reads key blocks [0, visited): exactly those that
+    hold a key some row of it may see (none skipped, none wholly masked
+    read), and masks only [unmasked, visited), the ones not wholly
+    visible to its first row."""
+    n_q, n_k = sq // block_q, sk // block_k
+    total = 0
+    for i in range(n_q):
+        first, last = i * block_q, (i + 1) * block_q - 1
+        unmasked, visited = _causal_k_blocks(i, block_q, block_k, n_k)
+        assert isinstance(visited, int) and isinstance(unmasked, int)
+        assert visited == sum(j * block_k <= last for j in range(n_k))
+        assert unmasked == sum((j + 1) * block_k - 1 <= first
+                               for j in range(n_k))
+        assert 0 <= unmasked <= visited <= n_k and visited >= 1
+        total += visited
+    assert (total, n_q * n_k) == want
+
+
+@pytest.mark.parametrize("block_q,block_k", [(8, 8), (16, 8), (8, 16)])
+def test_flash_causal_skips_blocks_above_the_diagonal(block_q, block_k):
+    """Blocks past the diagonal are not read, not read-and-masked: with
+    the last key block of v all NaN, a kernel that visits it gives every
+    row NaN (0 × NaN), one that stops at the diagonal only the query
+    blocks that reach it."""
+    q, k, v = _qkv(B=1, H=2, S=32, D=8)
+    v_nan = v.copy()
+    v_nan[..., 32 - block_k:, :] = np.nan
+    clean = (32 - block_k) // block_q * block_q     # whole q blocks before
+    got = np.asarray(flash_attention(q, k, v_nan, causal=True,
+                                     block_q=block_q, block_k=block_k,
+                                     interpret=True))
+    want = np.asarray(attention_reference(q, k, v, causal=True))
+    assert np.isfinite(got[..., :clean, :]).all()
+    assert_almost_equal(got[..., :clean, :], want[..., :clean, :],
+                        rtol=1e-4, atol=1e-5)
+    # the same call without causal reads every block
+    assert np.isnan(np.asarray(flash_attention(
+        q, k, v_nan, block_q=block_q, block_k=block_k,
+        interpret=True))).all()
+
+
+def _bf16_qkv(S, D):
+    q, k, v = (jnp.asarray(a, jnp.bfloat16) for a in _qkv(1, 2, S, D))
+    return (q, k, v), tuple(a.astype(jnp.float32) for a in (q, k, v))
+
+
+def _max_rel(got, want):
+    want = np.asarray(want, np.float32)
+    return float(np.max(np.abs(np.asarray(got, np.float32) - want))
+                 / np.max(np.abs(want)))
+
+
+# D=16: scale 1/4 folds into bfloat16 q exactly; D=8: it cannot, and is
+# applied to the float32 scores
+@pytest.mark.parametrize("D", [16, 8])
+@pytest.mark.parametrize("causal", [False, True])
+def test_flash_bfloat16_operands_keep_the_scores(causal, D):
+    """bfloat16 q, k, v are multiplied as they are and summed in
+    float32: the scores lose nothing (lse to 1e-5 of the float32
+    log-sum-exp of the same values), and the output differs from the
+    float32 reference by p's and its own rounding to bfloat16."""
+    (q, k, v), (qf, kf, vf) = _bf16_qkv(32, D)
+    scale = float(D) ** -0.5
+    got, lse = _flash_forward_kernel_call(q, k, v, causal, scale, 8, 8,
+                                          True)
+    assert got.dtype == jnp.bfloat16 and lse.dtype == jnp.float32
+    s = jnp.einsum("...qd,...kd->...qk", qf, kf) * scale
+    if causal:
+        s = jnp.where(jnp.tril(jnp.ones((32, 32), bool)), s, -jnp.inf)
+    assert_almost_equal(np.asarray(lse),
+                        np.asarray(jax.nn.logsumexp(s, axis=-1)),
+                        rtol=1e-5, atol=1e-5)
+    want = attention_reference(qf, kf, vf, causal=causal, scale=scale)
+    assert _max_rel(got, want) <= 1e-2
+
+
+@pytest.mark.parametrize("causal", [False, True])
+def test_flash_bfloat16_gradient_matches_reference(causal):
+    """The blockwise backward reads the forward's lse: with bfloat16
+    operands it must still give the float32 reference's gradient, to
+    bfloat16's rounding."""
+    (q, k, v), wide = _bf16_qkv(32, 16)
+
+    def loss(fn, q, k, v):
+        return jnp.sum(jnp.sin(fn(q, k, v).astype(jnp.float32)))
+
+    got = jax.grad(lambda *a: loss(lambda q, k, v: flash_attention(
+        q, k, v, causal=causal, block_q=8, block_k=8, interpret=True), *a),
+        argnums=(0, 1, 2))(q, k, v)
+    want = jax.grad(lambda *a: loss(lambda q, k, v: attention_reference(
+        q, k, v, causal=causal), *a), argnums=(0, 1, 2))(*wide)
+    for g, w in zip(got, want):
+        assert g.dtype == jnp.bfloat16
+        assert _max_rel(g, w) <= 1e-2
