@@ -32,6 +32,7 @@ spending chip time).  It is never chosen by the program, and what it
 prints is marked ``"rehearsal": true`` — not a chip result.
 """
 import argparse
+import functools
 import json
 import os
 import sys
@@ -51,7 +52,7 @@ FULL = {
                   decode_buckets=(1, 4), max_new=8, kv_blocks=128,
                   kv_block_size=32,
                   prompt_lengths=(5, 40, 64, 100, 200, 256, 17, 130)),
-    "kernels": dict(flash=(8, 8, 1024, 64),
+    "kernels": dict(flash=(8, 8, 1024, 64), flash_latent=(1, 32, 8192, 192, 128),
                     qmm=((8, 768, 3072), (256, 768, 3072), (8, 3072, 768),
                          (8, 768, 50257), (256, 768, 50257))),
 }
@@ -64,7 +65,7 @@ TINY = {
                   prompt_buckets=(8, 16), decode_buckets=(1, 2), max_new=4,
                   kv_blocks=32, kv_block_size=8,
                   prompt_lengths=(3, 8, 12, 16, 5)),
-    "kernels": dict(flash=(1, 2, 256, 8),
+    "kernels": dict(flash=(1, 2, 256, 8), flash_latent=(1, 2, 256, 24, 16),
                     qmm=((8, 256, 384), (300, 600, 1000))),
 }
 
@@ -479,10 +480,19 @@ def phase_kernels(env, cfg, lm_params):
         with jax.default_matmul_precision("highest"):
             return jax.jit(fn)(*args)
 
-    # flash-attention forward (+ the blockwise backward its stats feed)
-    for dt, tol in ((jnp.float32, 2e-2), (jnp.bfloat16, 4e-2)):
-        q, k, v = (put(jnp.asarray(rng.randn(*cfg["flash"]), dt))
-                   for _ in range(3))
+    # flash-attention forward (+ the blockwise backward its stats feed):
+    # 64-wide heads in both dtypes, and latent attention's shape — q and k
+    # 192 wide, v 128, 8,192 keys — in bfloat16.  The reference goes by
+    # query blocks, each against the keys up to its end, so that 8,192
+    # keys of 32 heads fit beside the kernel's own buffers.
+    b, h, s, d = cfg["flash"]
+    cases = [((b, h, s, d, d), jnp.float32, 2e-2, ""),
+             ((b, h, s, d, d), jnp.bfloat16, 4e-2, ""),
+             (cfg["flash_latent"], jnp.bfloat16, 4e-2, ",latent")]
+    for (b, h, s, d_qk, d_v), dt, tol, tag in cases:
+        q, k = (put(jnp.asarray(rng.randn(b, h, s, d_qk), dt))
+                for _ in range(2))
+        v = put(jnp.asarray(rng.randn(b, h, s, d_v), dt))
 
         def loss(fn, q, k, v):
             o = fn(q, k, v)
@@ -492,10 +502,14 @@ def phase_kernels(env, cfg, lm_params):
             return flash_attention(q, k, v, causal=True,
                                    interpret=interpret)
 
-        def reference(q, k, v):
-            return attention_reference(
-                q.astype(jnp.float32), k.astype(jnp.float32),
-                v.astype(jnp.float32), causal=True)
+        def reference(q, k, v, step=min(1024, s)):
+            q, k, v = (t.astype(jnp.float32) for t in (q, k, v))
+            return jnp.concatenate([
+                jax.checkpoint(functools.partial(
+                    attention_reference, causal=True, q_offset=i))(
+                        q[..., i:i + step, :], k[..., :i + step, :],
+                        v[..., :i + step, :])
+                for i in range(0, s, step)], axis=-2)
 
         grad = lambda fn: jax.value_and_grad(    # noqa: E731
             lambda q, k, v: loss(fn, q, k, v), argnums=(0, 1, 2),
@@ -504,10 +518,11 @@ def phase_kernels(env, cfg, lm_params):
         (_, want_o), want_g = highest(grad(reference), q, k, v)
         errs = [_rel_err(got_o, want_o)] + [
             _rel_err(g, w) for g, w in zip(got_g, want_g)]
-        name = "flash_attention[%s]" % jnp.dtype(dt).name
+        name = "flash_attention[%s%s]" % (jnp.dtype(dt).name, tag)
         require(max(errs) <= tol, "%s: out/dq/dk/dv errors %s exceed %g"
                 % (name, errs, tol))
         out[name] = round(max(errs), 5)
+        del q, k, v, got_o, got_g, want_o, want_g
 
     # weight-only quantized matmul, FFN shapes and the LM head
     for m, k, n in cfg["qmm"]:

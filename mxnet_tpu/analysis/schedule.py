@@ -237,7 +237,7 @@ def _min_microbatches_for(k, t_fwd, t_bwd, xfer, bound, start):
 # ----------------------------------------------------------------------
 def _moe_nodes(ctx):
     return [n for n in ctx.op_nodes()
-            if type(n.op).op_name == "MoE"]
+            if type(n.op).op_name in ("MoE", "RoutedExperts")]
 
 
 def schedule_report(ctx):
@@ -408,15 +408,19 @@ def _moe_report(ctx):
             for d in data[:-1]:
                 tokens *= int(d)
         topk = min(int(p.top_k), int(p.num_experts))
-        cap = moe_capacity(tokens, p.num_experts, topk,
-                           p.capacity_factor) if tokens else 0
+        # RoutedExperts has no capacity: it computes every assignment
+        cf = float(getattr(p, "capacity_factor", 0.0))
+        cap = moe_capacity(tokens, p.num_experts, topk, cf) if tokens else 0
         balance = None
         if tokens and cap:
             balanced = tokens * topk / float(p.num_experts)
             balance = min(1.0, cap / balanced) if balanced else None
         out.append({"node": n.name, "num_experts": int(p.num_experts),
+                    # the expert stacks' leading axis, which 'ep' shards
+                    "experts_held": int(getattr(p, "num_local_experts", 0)
+                                        or p.num_experts),
                     "top_k": topk,
-                    "capacity_factor": float(p.capacity_factor),
+                    "capacity_factor": cf,
                     "tokens": tokens, "capacity": cap,
                     "expert_balance": balance})
     return out
@@ -591,7 +595,7 @@ def _rule_e006(ctx):
     if ep <= 1:
         return
     for s in rep["moe"]:
-        if s["num_experts"] % ep == 0:
+        if s["experts_held"] % ep == 0:
             continue
         ctx.report(s["node"],
                    "%d experts do not divide over the ep=%d mesh axis: "
@@ -599,7 +603,7 @@ def _rule_e006(ctx):
                    "(every rank holds every expert) and the all-to-all "
                    "dispatch is unbalanced by construction — pick a "
                    "multiple of %d experts"
-                   % (s["num_experts"], ep, ep))
+                   % (s["experts_held"], ep, ep))
 
 
 @register_rule("MXL-E007", "warning",

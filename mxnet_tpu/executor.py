@@ -118,6 +118,18 @@ class _Program:
         return self._jit_forward_mon
 
 
+def zero_cotangent(tree):
+    """Zero cotangents for outputs nothing differentiates (auxiliary
+    state): zeros of a floating leaf's own dtype, and for an integer leaf
+    (an op's counters) the ``float0`` zeros jax asks for."""
+    def zero(a):
+        if jnp.issubdtype(a.dtype, jnp.inexact):
+            return jnp.zeros_like(a)
+        return _np.zeros(a.shape, jax.dtypes.float0)
+
+    return jax.tree_util.tree_map(zero, tree)
+
+
 def _mirror_segments(op_nodes):
     """Partition the op schedule into checkpoint segments — the
     jax-native MakeBackwardPass mirror map (static_graph.cc:396-440).
@@ -333,9 +345,12 @@ def _build_program(symbol, group2ctx):
                               is_train, monitor)
                 continue
 
-            ext_keys = sorted(
-                {(id(c), ci) for n in nodes for c, ci in n.inputs
-                 if seg_of.get(id(c), -2) != si})
+            # in the order the segment first reads them: an order by
+            # id() differs from process to process, and with it the
+            # lowered text and the compile cache's key
+            ext_keys = list(dict.fromkeys(
+                (id(c), ci) for n in nodes for c, ci in n.inputs
+                if seg_of.get(id(c), -2) != si))
             out_keys = ext_needed[si]
             aux_names = _seg_aux_names(nodes)
             seg_keys = (rngs[rng_i:rng_i + seg_n_rng]
@@ -377,7 +392,7 @@ def _build_program(symbol, group2ctx):
         if out_grads is None:  # implicit loss-layer heads: cotangent of ones
             out_grads = [jnp.ones_like(o) for o in outs]
         grads = vjp_fn((out_grads,
-                        jax.tree_util.tree_map(jnp.zeros_like, aux_out)))[0]
+                        zero_cotangent(aux_out)))[0]
         return outs, aux_out, grads
 
     return _Program(trace, jax.jit(trace, static_argnames=("is_train",)),
@@ -451,8 +466,10 @@ class Executor:
             _, _, aux_shapes = symbol.infer_shape(**shapes)
             if aux_shapes is None:
                 raise MXNetError("bind: cannot infer aux shapes")
-            aux_list = [a if a is not None else zeros(s, ctx=self._ctx)
-                        for a, s in zip(aux_list, aux_shapes)]
+            aux_list = [a if a is not None
+                        else zeros(s, ctx=self._ctx, dtype=t)
+                        for a, s, t in zip(aux_list, aux_shapes,
+                                           _aux_dtypes(symbol))]
         self.aux_arrays = aux_list
         self.aux_dict = dict(zip(self._aux_names, aux_list))
         self._check_placement()
@@ -778,7 +795,7 @@ class Executor:
             (outs, aux_out), vjp_fn = jax.vjp(f, wrt)
             ones = [jnp.ones_like(o) for o in outs]
             grads = vjp_fn(
-                (ones, jax.tree_util.tree_map(jnp.zeros_like, aux_out)))[0]
+                (ones, zero_cotangent(aux_out)))[0]
             new_w, new_s = {}, {}
             for n in wrt_names:
                 g = pre(grads[n])
@@ -959,6 +976,14 @@ class Executor:
         return "\n".join(lines)
 
 
+def _aux_dtypes(symbol, type_dict=None, floating=_np.float32):
+    """The dtype each auxiliary state is allocated in: ``floating``, but
+    an op's own where it declares an integer one (counters)."""
+    _, _, aux_types = symbol.infer_type(**(type_dict or {}))
+    return [t if not _np.issubdtype(t, _np.floating) else _np.dtype(floating)
+            for t in aux_types]
+
+
 def simple_bind(symbol, ctx, grad_req="write", type_dict=None, group2ctx=None,
                 shared_exec=None, validate=None, **kwargs):
     """Allocate arg/grad/aux arrays from inferred shapes and bind
@@ -978,7 +1003,8 @@ def simple_bind(symbol, ctx, grad_req="write", type_dict=None, group2ctx=None,
              else dict(zip(arg_names, grad_req)).get(name, "null"))
         if req != "null":
             grads[name] = zeros(shape, ctx=ctx, dtype=dtype)
-    aux = [zeros(s, ctx=ctx) for s in aux_shapes]
+    aux = [zeros(s, ctx=ctx, dtype=t)
+           for s, t in zip(aux_shapes, _aux_dtypes(symbol, type_dict))]
     return Executor(symbol, ctx, args, grads, grad_req, aux,
                     group2ctx=group2ctx, shared_exec=shared_exec,
                     validate=validate)

@@ -1,4 +1,6 @@
-"""Transformer operators: LayerNorm, MultiHeadAttention.
+"""Transformer operators: LayerNorm, RMSNorm, MultiHeadAttention,
+MultiHeadLatentAttention (low-rank query and key/value paths, one rotary
+key shared by all heads).
 
 TPU-native extensions beyond the reference op set (the reference predates
 transformers; SURVEY §5 notes its only long-sequence tools are bucketing
@@ -54,6 +56,198 @@ class LayerNorm(OperatorProperty):
         norm = data[ax] if data else ()
         return {"out": [tuple(data)],
                 "in": [None, (norm,), (norm,)]}
+
+
+def rms_norm(x, gamma, eps):
+    """x / sqrt(mean(x²) + eps) · gamma over the last axis; the mean and
+    the scaling in float32 whatever x is, the result in x's dtype."""
+    x32 = x.astype(jnp.float32)
+    inv = jnp.reciprocal(jnp.sqrt(
+        jnp.mean(jnp.square(x32), axis=-1, keepdims=True) + eps))
+    return (x32 * inv * gamma.astype(jnp.float32)).astype(x.dtype)
+
+
+class _RMSNormParam(ParamStruct):
+    eps = Field(float, default=1e-6)
+
+
+@register_op("RMSNorm")
+class RMSNorm(OperatorProperty):
+    """y = x / sqrt(mean(x²) + eps) * gamma over the last axis: no mean
+    is subtracted and there is no shift."""
+    param_cls = _RMSNormParam
+
+    def list_arguments(self):
+        return ["data", "gamma"]
+
+    def infer_shape(self, in_shapes):
+        data = in_shapes[0]
+        if data is None:
+            require_known("RMSNorm", in_shapes[:1], ["data"])
+        return [data, (data[-1],)], [data], []
+
+    def forward(self, inputs, aux, is_train, rng):
+        return [rms_norm(inputs[0], inputs[1], self.param.eps)], None
+
+    def cost_flops(self, in_shapes, out_shapes):
+        # square, sum, scale, gain: four vector passes over the input
+        return 4.0 * float(_np.prod(in_shapes[0], dtype=_np.int64))
+
+    def cost_reduce_len(self, in_shapes, out_shapes):
+        return int(in_shapes[0][-1])
+
+    def infer_sharding(self, in_specs, in_shapes, out_shapes, mesh_shape):
+        data = in_specs[0]
+        return {"out": [tuple(data)],
+                "in": [None, (data[-1] if data else (),)]}
+
+
+def rotary_interleaved(x, theta):
+    """Rotary position embedding of x (..., S, D) whose pairs lie
+    interleaved, (x0, x1), (x2, x3), …: pair i of position p is turned
+    by the angle p · theta^(−2i/D).  The pairs are brought to halves
+    first, (x0, x2, …, x1, x3, …), then rotated as halves, so the
+    result is in the half layout; a score q·k does not depend on the
+    layout as long as q and k share it.  float32 inside, x's dtype out."""
+    s, d = x.shape[-2], x.shape[-1]
+    x32 = x.astype(jnp.float32)
+    x32 = jnp.concatenate([x32[..., 0::2], x32[..., 1::2]], axis=-1)
+    inv_freq = 1.0 / (float(theta) ** (
+        jnp.arange(0, d, 2, dtype=jnp.float32) / d))
+    ang = jnp.arange(s, dtype=jnp.float32)[:, None] * inv_freq
+    cos = jnp.concatenate([jnp.cos(ang), jnp.cos(ang)], axis=-1)
+    sin = jnp.concatenate([jnp.sin(ang), jnp.sin(ang)], axis=-1)
+    half = d // 2
+    turned = jnp.concatenate([-x32[..., half:], x32[..., :half]], axis=-1)
+    return (x32 * cos + turned * sin).astype(x.dtype)
+
+
+class _MLAParam(ParamStruct):
+    num_heads = Field(int, required=True, lower=1)
+    q_lora_rank = Field(int, required=True, lower=1)
+    kv_lora_rank = Field(int, required=True, lower=1)
+    qk_nope_head_dim = Field(int, required=True, lower=1)
+    qk_rope_head_dim = Field(int, required=True, lower=2)
+    v_head_dim = Field(int, required=True, lower=1)
+    rope_theta = Field(float, default=10000.0)
+    eps = Field(float, default=1e-6, doc="of the two inner RMSNorms")
+
+
+@register_op("MultiHeadLatentAttention")
+class MultiHeadLatentAttention(OperatorProperty):
+    """Latent attention (DeepSeek-V2/V3's MLA), data (B, S, E) -> (B, S, E).
+
+    The query goes through a rank-``q_lora_rank`` bottleneck with an
+    RMSNorm inside it; keys and values come from one rank-``kv_lora_rank``
+    latent (RMSNorm inside) plus ONE rotary key of ``qk_rope_head_dim``
+    that every head shares.  Per head, q = [q_nope ; rot(q_rope)],
+    k = [k_nope ; rot(k_rope)] (``qk_nope_head_dim + qk_rope_head_dim``
+    wide), v is ``v_head_dim`` wide; causal softmax(q kᵀ / sqrt(width of
+    q)) v;
+    heads concatenated -> ``out_weight``.  No biases.  Weights are
+    (out_features, in_features).  The attention itself is the flash
+    path of ``parallel/ring_attention.py``, which takes q/k of one width
+    and v of another.
+    """
+    param_cls = _MLAParam
+    mxu = True
+
+    def list_arguments(self):
+        return ["data", "q_a_weight", "q_a_norm_gamma", "q_b_weight",
+                "kv_a_weight", "kv_a_norm_gamma", "kv_b_weight",
+                "out_weight"]
+
+    def _dims(self):
+        p = self.param
+        return (p.num_heads, p.q_lora_rank, p.kv_lora_rank,
+                p.qk_nope_head_dim, p.qk_rope_head_dim, p.v_head_dim)
+
+    def infer_shape(self, in_shapes):
+        data = in_shapes[0]
+        if data is None:
+            require_known("MultiHeadLatentAttention", in_shapes[:1],
+                          ["data"])
+        if len(data) != 3:
+            raise MXNetError("MultiHeadLatentAttention: data must be "
+                             "(B, S, E)")
+        E = data[2]
+        H, rq, rkv, nope, rope, dv = self._dims()
+        if rope % 2:
+            raise MXNetError("qk_rope_head_dim must be even (rotary pairs)")
+        return ([data, (rq, E), (rq,), (H * (nope + rope), rq),
+                 (rkv + rope, E), (rkv,), (H * (nope + dv), rkv),
+                 (E, H * dv)], [data], [])
+
+    def cost_mxu_dims(self, in_shapes, out_shapes):
+        B, S, E = in_shapes[0]
+        H, rq, rkv, nope, rope, dv = self._dims()
+        T = B * S
+        return [(T, E, rq), (T, rq, H * (nope + rope)), (T, E, rkv + rope),
+                (T, rkv, H * (nope + dv)), (T, H * dv, E),
+                (S, nope + rope, S), (S, S, dv)]
+
+    def cost_flops(self, in_shapes, out_shapes):
+        B, S, _E = in_shapes[0]
+        H = self.param.num_heads
+        dims = self.cost_mxu_dims(in_shapes, out_shapes)
+        proj = sum(2 * m * k * n for m, k, n in dims[:5])
+        attn = sum(2 * B * H * m * k * n for m, k, n in dims[5:])
+        return float(proj + attn)
+
+    def cost_reduce_len(self, in_shapes, out_shapes):
+        return int(in_shapes[0][1])     # softmax over the key axis
+
+    def forward(self, inputs, aux, is_train, rng):
+        x, wqa, gq, wqb, wkva, gkv, wkvb, wo = inputs
+        B, S, _E = x.shape
+        H, _rq, rkv, nope, rope, dv = self._dims()
+        p = self.param
+
+        def heads(t, width):    # (B, S, H*width) -> (B, H, S, width)
+            return t.reshape(B, S, H, width).transpose(0, 2, 1, 3)
+
+        q = heads(rms_norm(x @ wqa.T, gq, p.eps) @ wqb.T, nope + rope)
+        q = jnp.concatenate(
+            [q[..., :nope], rotary_interleaved(q[..., nope:], p.rope_theta)],
+            axis=-1)
+        ckv = x @ wkva.T                                    # (B, S, rkv+rope)
+        k_rope = rotary_interleaved(ckv[..., rkv:], p.rope_theta)
+        kv = heads(rms_norm(ckv[..., :rkv], gkv, p.eps) @ wkvb.T, nope + dv)
+        k = jnp.concatenate(
+            [kv[..., :nope],
+             jnp.broadcast_to(k_rope[:, None], (B, H, S, rope))], axis=-1)
+        from ..parallel.ring_attention import sharded_self_attention
+        o = sharded_self_attention(q, k, kv[..., nope:], causal=True)
+        return [o.transpose(0, 2, 1, 3).reshape(B, S, H * dv) @ wo.T], None
+
+    def infer_sharding(self, in_specs, in_shapes, out_shapes, mesh_shape):
+        """Head-parallel over whatever axis shards the rows of the two
+        up-projections (``q_b``, ``kv_b``: heads), closed by a
+        row-parallel ``out_weight``; the two low-rank down-projections
+        and their norms stay replicated (they are shared by all heads)."""
+        data = in_specs[0]
+        head = tuple(in_specs[3][0] if in_specs[3] else ())
+        out_c = tuple(in_specs[7][1] if len(in_specs[7]) > 1 else ())
+        batch = tuple(data[0] if data else ())
+        seq = tuple(data[1] if len(data) > 1 else ())
+        required = [None] * len(in_specs)
+        required[6] = (head, ())        # kv_b splits as q_b does
+        out = {"out": [(batch, seq, ())], "in": required}
+        if head and head == out_c:
+            out["reduce"] = {head: "head-parallel latent attention closed "
+                                   "by row-parallel out projection: partial "
+                                   "sums over %s" % "+".join(head)}
+        elif head or out_c:
+            axes = head or out_c
+            out["notes"] = [{
+                "kind": "attn_unreduced", "arg": 3 if head else 7,
+                "axes": axes,
+                "message": "latent attention is head-parallel over %s but "
+                           "the out projection does not close it with a "
+                           "matching row-parallel reduction: XLA "
+                           "all-gathers the per-head activations instead"
+                           % "+".join(axes)}]
+        return out
 
 
 class _MHAParam(ParamStruct):

@@ -1,5 +1,12 @@
 """Mixture-of-Experts FFN with expert parallelism.
 
+Two routed layers live here.  ``MoE`` (below) is the dense-mask form for
+moderate expert counts.  ``RoutedExperts`` (further down) is the sorted
+form for layers of hundreds of experts of which a device holds a share:
+it routes over all of them, sorts the (token, expert) assignments by
+expert and runs one grouped matrix product per projection over the
+experts held, for every assignment that landed on them.
+
 Beyond-reference (SURVEY's parallelism table lists expert parallelism as
 absent from the reference): a Switch-style routed FFN whose expert
 weights carry a leading ``num_experts`` axis — shard that axis over an
@@ -24,6 +31,10 @@ each one ``lax.all_to_all`` — the collective pair MXL-E008 prices per
 rank and replays through the MXL-D trace diff.
 """
 from __future__ import annotations
+
+import functools
+
+import numpy as _np
 
 import jax
 import jax.numpy as jnp
@@ -214,3 +225,335 @@ def _moe_cost(op, in_shapes, out_shapes):
     ffn = 2.0 * T * topk * E * H * 2
     return {"flops": gate + ffn, "mxu": True,
             "mxu_dims": [(T * topk, E, H), (T * topk, H, E)]}
+
+
+# ----------------------------------------------------------------------
+# RoutedExperts: sigmoid-scored top-k routing over all the experts, the
+# experts held here computed by sorted, grouped matrix products
+# ----------------------------------------------------------------------
+def gated_ffn(x, w_gate, w_up, w_down):
+    """W_down(silu(W_gate x) ⊙ W_up x); weights (out_features, in_features)."""
+    return (jax.nn.silu(x @ w_gate.T) * (x @ w_up.T)) @ w_down.T
+
+
+def route_sigmoid_topk(h, router_weight, bias, top_k, scaling=1.0):
+    """``(idx (T, k) int32, w (T, k) float32)``: scores s = sigmoid(h W_rᵀ)
+    in float32; the ``top_k`` largest of s + bias are chosen (the bias
+    steers the choice only); the weights are s at the chosen experts,
+    divided by their sum, times ``scaling``.
+    The choice carries no gradient; the weights carry s's."""
+    scores = jax.nn.sigmoid(jnp.dot(h, router_weight.T,
+                                    preferred_element_type=jnp.float32))
+    _, idx = lax.top_k(scores + bias.astype(jnp.float32), top_k)
+    chosen = idx[..., None] == jnp.arange(scores.shape[-1])
+    w = jnp.sum(jnp.where(chosen, scores[:, None, :], 0.0), axis=-1)
+    w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
+    return idx.astype(jnp.int32), w * scaling
+
+
+def _sorted_assignments(idx, first, n_local):
+    """The (token, expert) assignments sorted by expert held.
+
+    ``idx`` (T, k): the experts each token chose.  Returns ``order`` (the
+    T·k slots, those on experts ``first .. first + n_local − 1`` first
+    and grouped by expert, in token order within an expert; every other
+    slot after them) and ``counts`` (n_local,): the assignments on each
+    expert held."""
+    held = (idx >= first) & (idx < first + n_local)
+    eid = jnp.where(held, idx - first, n_local).reshape(-1)
+    order = jnp.argsort(eid, stable=True).astype(jnp.int32)
+    counts = jnp.sum(eid[:, None] == jnp.arange(n_local)[None, :], axis=0,
+                     dtype=jnp.int32)
+    return order, counts
+
+
+#: rows of the sorted assignment list that ``RoutedExperts`` computes at a
+#: time: twice what a balanced router sends 16 of 256 experts at 8,192
+#: tokens of 8 choices, so that the usual step is one chunk
+CHUNK_ROWS = 8192
+
+
+def _chunk_rows(n_slots, chunk_rows):
+    """Rows of the sorted list computed at a time: ``chunk_rows`` where
+    it divides the list, else the whole list."""
+    return chunk_rows if n_slots % chunk_rows == 0 else n_slots
+
+
+def _chunk_plan(c, rows, tok, w_sorted, counts):
+    """What chunk ``c`` of the sorted list holds: its rows' tokens and
+    weights, which of its rows are assignments on experts held, and how
+    many rows of each expert held fall inside it."""
+    lo = c * rows
+    ends = jnp.cumsum(counts)
+    starts = ends - counts
+    sizes = jnp.clip(jnp.minimum(ends, lo + rows) - jnp.maximum(starts, lo),
+                     0, None).astype(jnp.int32)
+    valid = (lo + jnp.arange(rows)) < ends[-1]
+    return (lax.dynamic_slice_in_dim(tok, lo, rows),
+            lax.dynamic_slice_in_dim(w_sorted, lo, rows), valid[:, None],
+            sizes)
+
+
+def _grouped_ffn(x, w_gate, w_up, w_down, sizes):
+    """The gated FFN of rows sorted by expert: row r of group e goes
+    through expert e's three matrices (``lax.ragged_dot``: on a TPU one
+    grouped Mosaic product a projection, which visits only the row tiles
+    that groups cover).  Rows past the last group come out undefined:
+    the caller masks them."""
+    gate = lax.ragged_dot(x, w_gate.transpose(0, 2, 1), sizes)
+    up = lax.ragged_dot(x, w_up.transpose(0, 2, 1), sizes)
+    return lax.ragged_dot(jax.nn.silu(gate) * up,
+                          w_down.transpose(0, 2, 1), sizes)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7))
+def routed_experts(h, w, idx, w_gate, w_up, w_down, first, chunk_rows):
+    """Σ over a token's assignments on experts held of wᵢ · Eᵢ(h).
+
+    ``h`` (T, E); ``idx``, ``w`` (T, k): every token's chosen experts and
+    their weights; ``w_gate``/``w_up`` (L, H, E), ``w_down`` (L, E, H):
+    the L experts held, which are experts ``first .. first + L − 1``.
+    Every assignment on an expert held is computed, however many there
+    are: the sorted list is walked in chunks of ``chunk_rows`` rows for
+    as long as it holds such assignments (a balanced router fills the
+    first chunk half; a router that sends everything here walks them
+    all).  Assignments on other experts add nothing: theirs is another
+    device's part.  Returns (y (T, E) in h's dtype, counts (L,) int32).
+    """
+    return _routed_fwd(h, w, idx, w_gate, w_up, w_down, first,
+                       chunk_rows)[0]
+
+
+def _routed_fwd(h, w, idx, w_gate, w_up, w_down, first, chunk_rows):
+    n_tok, k = idx.shape
+    order, counts = _sorted_assignments(idx, first, w_gate.shape[0])
+    rows = _chunk_rows(n_tok * k, chunk_rows)
+    tok = order // k
+    w_sorted = w.reshape(-1)[order]
+
+    def chunk(c, y):
+        tok_c, w_c, valid, sizes = _chunk_plan(c, rows, tok, w_sorted,
+                                               counts)
+        x = jnp.where(valid, h[tok_c], 0)
+        o = _grouped_ffn(x, w_gate, w_up, w_down, sizes)
+        o = jnp.where(valid, o, 0).astype(jnp.float32) * w_c[:, None]
+        return y.at[tok_c].add(o)
+
+    n_chunks = (jnp.sum(counts) + rows - 1) // rows
+    y = lax.fori_loop(0, n_chunks, chunk,
+                      jnp.zeros(h.shape, jnp.float32))
+    return ((y.astype(h.dtype), counts),
+            (h, w, idx, w_gate, w_up, w_down, order, counts))
+
+
+def _routed_bwd(first, chunk_rows, res, cts):
+    """Walks the same chunks: each recomputes its rows' FFN and takes
+    its vjp, so nothing is kept from the forward but the sorted order."""
+    h, w, idx, w_gate, w_up, w_down, order, counts = res
+    dy = cts[0]
+    n_tok, k = idx.shape
+    rows = _chunk_rows(n_tok * k, chunk_rows)
+    tok = order // k
+    w_sorted = w.reshape(-1)[order]
+
+    def chunk(c, carry):
+        dh, dw_sorted, d_gate, d_up, d_down = carry
+        tok_c, w_c, valid, sizes = _chunk_plan(c, rows, tok, w_sorted,
+                                               counts)
+        x = jnp.where(valid, h[tok_c], 0)
+        dy_c = jnp.where(valid, dy[tok_c], 0)
+        o, vjp = jax.vjp(
+            lambda x, a, b, c_: _grouped_ffn(x, a, b, c_, sizes),
+            x, w_gate, w_up, w_down)
+        o = jnp.where(valid, o, 0)
+        dw_c = jnp.sum(o.astype(jnp.float32) * dy_c.astype(jnp.float32),
+                       axis=-1)
+        dx, dg, du, dd = vjp((dy_c.astype(jnp.float32)
+                              * w_c[:, None]).astype(o.dtype))
+        dh = dh.at[tok_c].add(jnp.where(valid, dx, 0).astype(jnp.float32))
+        dw_sorted = lax.dynamic_update_slice_in_dim(dw_sorted, dw_c,
+                                                    c * rows, axis=0)
+        return (dh, dw_sorted, d_gate + dg.astype(jnp.float32),
+                d_up + du.astype(jnp.float32),
+                d_down + dd.astype(jnp.float32))
+
+    n_chunks = (jnp.sum(counts) + rows - 1) // rows
+    zeros32 = functools.partial(jnp.zeros, dtype=jnp.float32)
+    dh, dw_sorted, d_gate, d_up, d_down = lax.fori_loop(
+        0, n_chunks, chunk,
+        (zeros32(h.shape), zeros32((n_tok * k,)), zeros32(w_gate.shape),
+         zeros32(w_up.shape), zeros32(w_down.shape)))
+    # back from sorted rows to (token, choice) slots: the inverse of a
+    # permutation is a gather too
+    dw = dw_sorted[jnp.argsort(order)].reshape(n_tok, k)
+    return (dh.astype(h.dtype), dw.astype(w.dtype), None,
+            d_gate.astype(w_gate.dtype), d_up.astype(w_up.dtype),
+            d_down.astype(w_down.dtype))
+
+
+routed_experts.defvjp(_routed_fwd, _routed_bwd)
+
+
+class _RoutedExpertsParam(ParamStruct):
+    num_experts = Field(int, required=True, lower=2,
+                        doc="experts the router scores (its width)")
+    hidden_size = Field(int, required=True, lower=1,
+                        doc="width of one expert's gated FFN")
+    top_k = Field(int, required=True, lower=1)
+    num_local_experts = Field(
+        int, default=0, lower=0,
+        doc="experts held here (0: all of them); the expert weights' "
+            "leading axis, which an 'ep' mesh axis shards")
+    first_expert = Field(int, default=0, lower=0,
+                         doc="index of the first expert held")
+    shared_hidden_size = Field(
+        int, default=0, lower=0,
+        doc="width of the shared expert every token goes through (0: none)")
+    routed_scaling_factor = Field(float, default=1.0)
+
+
+#: the counters a RoutedExperts node keeps as auxiliary state, summed on
+#: the device over the training steps it has run (int32; read them with
+#: ``routing_counters``)
+ROUTING_COUNTERS = ("local_assignments", "expert_tokens", "peak_tokens_sum",
+                    "peak_tokens_max")
+
+
+@register_op("RoutedExperts")
+class RoutedExperts(OperatorProperty):
+    """Routed gated-SiLU FFN over the experts held here, plus a shared one.
+
+    data (..., E) -> (..., E).  s = sigmoid(h W_rᵀ) over all
+    ``num_experts`` in float32; the ``top_k`` largest of s + ``router_bias``
+    are chosen (the bias is auxiliary state: it steers the choice, takes
+    no gradient and is left as it was); w = s at the chosen experts,
+    normalised over them and scaled.  y = Σᵢ wᵢ Eᵢ(h) over the chosen
+    experts *held here* (``first_expert .. first_expert +
+    num_local_experts − 1``) — every such assignment, whatever the
+    imbalance; what the other experts would add is another device's part
+    and is left out — plus the shared expert.  No biases; weights are
+    (out_features, in_features), the experts' stacked on a leading axis.
+
+    Auxiliary state besides the bias, summed over training steps:
+    ``local_assignments`` (1,) assignments that landed on experts held;
+    ``expert_tokens`` (L,) the same per expert; ``peak_tokens_sum`` (1,)
+    the busiest expert's tokens, summed over steps; ``peak_tokens_max``
+    (1,) its running maximum.
+    """
+    param_cls = _RoutedExpertsParam
+    mxu = True
+
+    def _held(self):
+        return self.param.num_local_experts or self.param.num_experts
+
+    def list_arguments(self):
+        args = ["data", "router_weight", "expert_gate_weight",
+                "expert_up_weight", "expert_down_weight"]
+        if self.param.shared_hidden_size:
+            args += ["shared_gate_weight", "shared_up_weight",
+                     "shared_down_weight"]
+        return args
+
+    def list_auxiliary_states(self):
+        return ["router_bias"] + list(ROUTING_COUNTERS)
+
+    def infer_shape(self, in_shapes):
+        data = in_shapes[0]
+        if data is None:
+            require_known("RoutedExperts", in_shapes[:1], ["data"])
+        p = self.param
+        E, N, H, L = data[-1], p.num_experts, p.hidden_size, self._held()
+        if p.top_k > N:
+            raise MXNetError("RoutedExperts: top_k (%d) > num_experts (%d)"
+                             % (p.top_k, N))
+        if p.first_expert + L > N:
+            raise MXNetError("RoutedExperts: experts %d..%d held of %d"
+                             % (p.first_expert, p.first_expert + L - 1, N))
+        shapes = [data, (N, E), (L, H, E), (L, H, E), (L, E, H)]
+        if p.shared_hidden_size:
+            S = p.shared_hidden_size
+            shapes += [(S, E), (S, E), (E, S)]
+        return shapes, [data], [(N,), (1,), (L,), (1,), (1,)]
+
+    def infer_type(self, in_types):
+        known = [t for t in in_types if t is not None]
+        base = known[0] if known else None
+        return ([base] * len(self.list_arguments()), [base],
+                [base] + [_np.dtype("int32")] * len(ROUTING_COUNTERS))
+
+    def forward(self, inputs, aux, is_train, rng):
+        p = self.param
+        x, w_router, w_gate, w_up, w_down = inputs[:5]
+        h = x.reshape(-1, x.shape[-1])
+        idx, w = route_sigmoid_topk(h, w_router, aux[0], p.top_k,
+                                    p.routed_scaling_factor)
+        y, counts = routed_experts(h, w, idx, w_gate, w_up, w_down,
+                                   p.first_expert, CHUNK_ROWS)
+        if p.shared_hidden_size:
+            y = y + gated_ffn(h, *inputs[5:8])
+        if not is_train:
+            return [y.reshape(x.shape)], None
+        _bias, total, per_expert, peak_sum, peak_max = aux
+        peak = jnp.max(counts).reshape(1)
+
+        def add(old, new):
+            return old + new.astype(old.dtype)
+
+        return [y.reshape(x.shape)], [
+            aux[0], add(total, jnp.sum(counts).reshape(1)),
+            add(per_expert, counts), add(peak_sum, peak),
+            jnp.maximum(peak_max, peak.astype(peak_max.dtype))]
+
+
+def routing_counters(aux, node_name):
+    """{counter: numpy array} of one RoutedExperts node out of an
+    auxiliary-state dict (``<node>_<counter>`` keys)."""
+    return {c: _np.asarray(aux["%s_%s" % (node_name, c)])
+            for c in ROUTING_COUNTERS}
+
+
+@register_sharding_rule("RoutedExperts")
+def _routed_transfer(op, in_specs, in_shapes, out_shapes, mesh_shape):
+    """Output follows the data spec.  Expert stacks sharded over an
+    expert-parallel axis make every member hold a share of the experts:
+    tokens reach the experts and the partial results come back over the
+    all-to-all pair, priced as for ``MoE``; router and shared expert are
+    replicated (every member computes them alike)."""
+    data_spec = tuple(in_specs[0] or ())
+    gate_spec = tuple(in_specs[2] or ())
+    ep_axes = tuple(gate_spec[0]) if gate_spec else ()
+    notes = []
+    if ep_axes:
+        for leg in ("dispatch", "combine"):
+            notes.append({
+                "kind": "alltoall", "arg": 0, "axes": ep_axes,
+                "message": "RoutedExperts %s: routed tokens exchanged "
+                           "with the %s expert shards over an "
+                           "all-to-all" % (leg, "+".join(ep_axes))})
+    required = [None] * len(in_specs)
+    for i in (3, 4):                    # up, down split as gate does
+        required[i] = (ep_axes,) + ((),) * 2
+    return {"out": [data_spec], "in": required, "notes": notes}
+
+
+@register_cost_rule("RoutedExperts")
+def _routed_cost(op, in_shapes, out_shapes):
+    """The routed plan: the router over every expert, ``top_k`` gated
+    FFNs a token of which the share held here is computed, and the
+    shared expert for every token."""
+    data = in_shapes[0]
+    if data is None:
+        return {}
+    T = 1
+    for d in data[:-1]:
+        T *= int(d)
+    p = op.param
+    E, N, H = int(data[-1]), int(p.num_experts), int(p.hidden_size)
+    held = int(p.num_local_experts or N)
+    rows = max(1, T * int(p.top_k) * held // N)     # expected, balanced
+    S = int(p.shared_hidden_size)
+    flops = 2.0 * T * N * E + 6.0 * rows * E * H + 6.0 * T * E * S
+    dims = [(T, E, N), (rows, E, H), (rows, E, H), (rows, H, E)]
+    if S:
+        dims += [(T, E, S), (T, E, S), (T, S, E)]
+    return {"flops": flops, "mxu": True, "mxu_dims": dims}

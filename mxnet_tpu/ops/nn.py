@@ -26,7 +26,7 @@ from .registry import (OperatorProperty, register_op, require_known,
 # ----------------------------------------------------------------------
 class _ActivationParam(ParamStruct):
     act_type = Field(str, required=True,
-                     enum=("relu", "sigmoid", "tanh", "softrelu"))
+                     enum=("relu", "sigmoid", "tanh", "softrelu", "silu"))
 
 
 @register_op("Activation")
@@ -39,6 +39,7 @@ class Activation(OperatorProperty):
         "sigmoid": jax.nn.sigmoid,
         "tanh": jnp.tanh,
         "softrelu": jax.nn.softplus,
+        "silu": jax.nn.silu,        # x·sigmoid(x): the gate of a gated FFN
     }
 
     def forward(self, inputs, aux, is_train, rng):

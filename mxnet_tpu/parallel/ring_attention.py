@@ -91,7 +91,7 @@ def blockwise_combine(q, kv_blocks, causal=False, scale=None, q_offset=0,
     batch_shape = q.shape[:-1]
     m = jnp.full(batch_shape, _NEG_INF, jnp.float32)
     l = jnp.zeros(batch_shape, jnp.float32)
-    o = jnp.zeros(q.shape, jnp.float32)
+    o = jnp.zeros(batch_shape + (kv_blocks[0][1].shape[-1],), jnp.float32)
     if kv_offsets is None:
         kv_offsets = []
         off = 0
@@ -143,12 +143,14 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_k, causal,
     The scores are held keys-by-queries, s = k·qᵀ (block_k, block_q):
     the per-query statistics m, l are then (1, block_q) rows, a vector
     register per 128 queries where a (block_q, 1) column takes one per
-    8, and o accumulates as (d, block_q) with no lane left empty at
-    d = 64.  Outputs the normalized o block and the logsumexp stats
+    8, and o accumulates as (d_v, block_q) with no lane left empty at
+    d_v = 64.  q and k share one width, v and o another (latent
+    attention: 192 against 128).  Outputs the normalized o block and the logsumexp stats
     (saved for the blockwise backward)."""
     import jax.experimental.pallas as pl
 
-    block_q, d = q_ref.shape
+    block_q = q_ref.shape[0]
+    d_v = v_ref.shape[1]        # its own width: q·kᵀ contracts over q's
     n_k_blocks = seq_k // block_k
     q_offset = pl.program_id(1) * block_q
     q = q_ref[...]
@@ -157,7 +159,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_k, causal,
         q = q * scale
 
     def step(masked, i, carry):
-        m, l, o = carry             # (1, block_q) twice, (d, block_q)
+        m, l, o = carry             # (1, block_q) twice, (d_v, block_q)
         start = pl.multiple_of(i * block_k, block_k)
         k = k_ref[pl.ds(start, block_k), :]
         v = v_ref[pl.ds(start, block_k), :]
@@ -180,7 +182,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_k, causal,
 
     carry = (jnp.full((1, block_q), _NEG_INF, jnp.float32),
              jnp.zeros((1, block_q), jnp.float32),
-             jnp.zeros((d, block_q), jnp.float32))
+             jnp.zeros((d_v, block_q), jnp.float32))
     if causal:
         unmasked, visited = _causal_k_blocks(pl.program_id(1), block_q,
                                              block_k, n_k_blocks)
@@ -223,19 +225,21 @@ def _flash_blocks(sq, sk, block_q=None, block_k=None):
     return blocks
 
 
-def _flash_block_layout(bh, sq, sk, d, block_q):
+def _flash_block_layout(bh, sq, sk, d, block_q, d_v=None):
     """(block, array) pairs of the forward pallas_call, in q/k/v then
     o/lse order — the ONE place the kernel's block shapes live, shared
     by the call below and the registered MXL-K kernel spec
     (``flash_kernel_spec``) so the static tile validator always checks
-    what actually runs."""
+    what actually runs.  ``d`` is the width of q and k, ``d_v`` that of
+    v and o where it differs."""
+    d_v = d if d_v is None else d_v
     in_blocks = [
         ((None, block_q, d), (bh, sq, d)),              # q
         ((None, sk, d), (bh, sk, d)),                   # k
-        ((None, sk, d), (bh, sk, d)),                   # v
+        ((None, sk, d_v), (bh, sk, d_v)),               # v
     ]
     out_blocks = [
-        ((None, block_q, d), (bh, sq, d)),              # o
+        ((None, block_q, d_v), (bh, sq, d_v)),          # o
         ((None, _LSE_ROWS, block_q), (bh, _LSE_ROWS, sq)),  # lse
     ]
     return in_blocks, out_blocks
@@ -251,13 +255,13 @@ def _flash_forward_kernel_call(q, k, v, causal, scale, block_q, block_k,
     import jax.experimental.pallas as pl
 
     B, H, Sq, D = q.shape
-    sk = k.shape[-2]
+    sk, d_v = v.shape[-2:]
     q3 = q.reshape(B * H, Sq, D)
     k3 = k.reshape(B * H, sk, D)
-    v3 = v.reshape(B * H, sk, D)
+    v3 = v.reshape(B * H, sk, d_v)
 
     (qb, kb, vb), (ob, lseb) = _flash_block_layout(B * H, Sq, sk, D,
-                                                   block_q)
+                                                   block_q, d_v)
     kernel = functools.partial(_flash_kernel, block_k=block_k,
                                causal=causal, scale=scale, seq_k=sk)
     out, lse = pl.pallas_call(
@@ -279,19 +283,32 @@ def _flash_forward_kernel_call(q, k, v, causal, scale, block_q, block_k,
         name="flash_forward",
         interpret=interpret,
     )(q3, k3, v3)
-    return out.reshape(B, H, Sq, D), lse[:, 0].reshape(B, H, Sq)
+    return out.reshape(B, H, Sq, d_v), lse[:, 0].reshape(B, H, Sq)
+
+
+# the backward's query chunk: sequences longer than this are walked in
+# chunks of it, so that under ``causal`` a key block meets only the
+# queries at or below it
+_BWD_Q_CHUNK = 1024
 
 
 def _flash_backward_blockwise(q, k, v, o, lse, do, causal, scale, block_k):
     """Flash-attention backward: blockwise recompute from the saved
     logsumexp stats — per-iteration footprint is O(Sq · block_k), never
     the full (Sq, Sk) score matrix (the training-path memory guarantee
-    the fused forward alone does not give).
+    the fused forward alone does not give).  q and k share one width, v,
+    o and do another.
 
     Standard identities (p = exp(s·scale − lse)):
         dv_j = pᵀ @ do
         ds   = p ⊙ (do @ vᵀ − rowsum(do ⊙ o)) · scale
         dq  += ds @ k_j,   dk_j = dsᵀ @ q
+
+    A sequence of more than ``_BWD_Q_CHUNK`` queries is walked in chunks
+    of that many, and under ``causal`` a key block starts at the chunk
+    its first key lies in: the chunks above the diagonal hold no visible
+    key and are not computed (half the work at 8,192 queries).  A
+    shorter sequence is one chunk, computed whole.
     """
     qf = q.astype(jnp.float32)
     dof = do.astype(jnp.float32)
@@ -299,6 +316,25 @@ def _flash_backward_blockwise(q, k, v, o, lse, do, causal, scale, block_k):
     sq = q.shape[-2]
     sk = k.shape[-2]
     n_blocks = sk // block_k
+    chunk = _BWD_Q_CHUNK if sq > _BWD_Q_CHUNK and sq % _BWD_Q_CHUNK == 0 \
+        else sq
+    n_chunks = sq // chunk
+
+    def chunked(x, axis):
+        """``x`` with its query axis split chunk-major: (n_chunks, ...,
+        chunk, ...).  A chunk is then one index of the leading axis, which
+        a loop reads and updates in place."""
+        if n_chunks == 1:
+            return x
+        axis = axis % x.ndim
+        x = x.reshape(x.shape[:axis] + (n_chunks, chunk) + x.shape[axis + 1:])
+        return jnp.moveaxis(x, axis, 0)
+
+    qf, dof = chunked(qf, -2), chunked(dof, -2)
+    lse, delta = chunked(lse, -1), chunked(delta, -1)
+
+    def at(x, j):
+        return x if n_chunks == 1 else x[j]
 
     def body(i, carry):
         dq, dk, dv = carry
@@ -306,31 +342,47 @@ def _flash_backward_blockwise(q, k, v, o, lse, do, causal, scale, block_k):
                                       axis=-2).astype(jnp.float32)
         vb = lax.dynamic_slice_in_dim(v, i * block_k, block_k,
                                       axis=-2).astype(jnp.float32)
-        s = jnp.einsum("...qd,...kd->...qk", qf, kb) * scale
-        if causal:
-            qpos = jnp.arange(sq)[:, None]
-            kpos = i * block_k + jnp.arange(block_k)[None, :]
-            s = jnp.where(qpos >= kpos, s, _NEG_INF)
-        p = jnp.exp(s - lse[..., None])
-        dvb = jnp.einsum("...qk,...qd->...kd", p, dof)
-        dp = jnp.einsum("...qd,...kd->...qk", dof, vb)
-        ds = p * (dp - delta[..., None]) * scale
-        dq = dq + jnp.einsum("...qk,...kd->...qd", ds, kb)
-        dkb = jnp.einsum("...qk,...qd->...kd", ds, qf)
+
+        def against(j, inner):
+            dq, dkb, dvb = inner
+            qc, doc = at(qf, j), at(dof, j)
+            s = jnp.einsum("...qd,...kd->...qk", qc, kb) * scale
+            if causal:
+                qpos = j * chunk + jnp.arange(chunk)[:, None]
+                kpos = i * block_k + jnp.arange(block_k)[None, :]
+                s = jnp.where(qpos >= kpos, s, _NEG_INF)
+            p = jnp.exp(s - at(lse, j)[..., None])
+            dvb = dvb + jnp.einsum("...qk,...qd->...kd", p, doc)
+            dp = jnp.einsum("...qd,...kd->...qk", doc, vb)
+            ds = p * (dp - at(delta, j)[..., None]) * scale
+            dqc = jnp.einsum("...qk,...kd->...qd", ds, kb)
+            dq = dq + dqc if n_chunks == 1 else dq.at[j].add(dqc)
+            dkb = dkb + jnp.einsum("...qk,...qd->...kd", ds, qc)
+            return dq, dkb, dvb
+
+        inner = (dq, jnp.zeros_like(kb), jnp.zeros_like(vb))
+        if n_chunks == 1:
+            dq, dkb, dvb = against(0, inner)
+        else:
+            first = (i * block_k) // chunk if causal else 0
+            dq, dkb, dvb = lax.fori_loop(first, n_chunks, against, inner)
         dk = lax.dynamic_update_slice_in_dim(dk, dkb, i * block_k, axis=-2)
         dv = lax.dynamic_update_slice_in_dim(dv, dvb, i * block_k, axis=-2)
         return dq, dk, dv
 
-    dq0 = jnp.zeros(q.shape, jnp.float32)
+    dq0 = jnp.zeros(qf.shape, jnp.float32)
     dk0 = jnp.zeros(k.shape, jnp.float32)
     dv0 = jnp.zeros(v.shape, jnp.float32)
     dq, dk, dv = lax.fori_loop(0, n_blocks, body, (dq0, dk0, dv0))
+    if n_chunks > 1:
+        dq = jnp.moveaxis(dq, 0, -3).reshape(q.shape)
     return dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype)
 
 
 def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
                     block_k=None, interpret=None):
-    """Fused attention; q/k/v (B, H, S, D).  The Pallas kernel where the
+    """Fused attention; q/k (B, H, S, D), v (B, H, S, D_v) with D_v = D
+    or not (the output is as wide as v).  The Pallas kernel where the
     computation is placed on a TPU, the jnp reference elsewhere
     (``kernels.common.dispatch``: decided when the enclosing step is
     lowered, so a compile-only lowering against a TPU topology carries
@@ -420,7 +472,7 @@ def ring_attention(q, k, v, axis_name="sp", causal=False, scale=None):
     zero = q[..., 0].astype(jnp.float32) * 0.0
     m0 = zero + _NEG_INF
     l0 = zero
-    o0 = q.astype(jnp.float32) * 0.0
+    o0 = jnp.broadcast_to(zero[..., None], zero.shape + (v.shape[-1],))
     q_offset = my * chunk
 
     perm = [(i, (i + 1) % n) for i in range(n)]
@@ -541,7 +593,7 @@ def sharded_self_attention(q, k, v, causal=False):
 
 
 def flash_kernel_spec(batch_heads=8, seq_q=1024, seq_k=1024, head_dim=64,
-                      block_q=None, dtype="bfloat16"):
+                      block_q=None, dtype="bfloat16", head_dim_v=None):
     """MXL-K kernel spec for the flash forward pallas_call.
 
     Built from the same :func:`_flash_block_layout` the kernel itself
@@ -553,7 +605,8 @@ def flash_kernel_spec(batch_heads=8, seq_q=1024, seq_k=1024, head_dim=64,
     """
     block_q, _block_k = _flash_blocks(seq_q, seq_k, block_q)
     in_blocks, out_blocks = _flash_block_layout(batch_heads, seq_q, seq_k,
-                                                head_dim, block_q)
+                                                head_dim, block_q,
+                                                head_dim_v)
     blocks = []
     for name, (blk, arr) in zip(("q", "k", "v"), in_blocks):
         blocks.append({"role": "in", "name": name, "block": blk,

@@ -145,7 +145,7 @@ class ShardedTrainer(object):
         self.param_names = [n for n in self._arg_names
                             if n not in self.data_names
                             and n not in self.label_names]
-        from ..executor import _build_program
+        from ..executor import _build_program, zero_cotangent
         program = _build_program(symbol, {})
         self._trace = program.trace
         self._needs_rng = program.needs_rng
@@ -202,8 +202,7 @@ class ShardedTrainer(object):
 
             (outs, aux_out), vjp_fn = jax.vjp(run, params)
             ones = [jnp.ones_like(o) for o in outs]
-            zero_aux = jax.tree_util.tree_map(jnp.zeros_like, aux_out)
-            grads = vjp_fn((ones, zero_aux))[0]
+            grads = vjp_fn((ones, zero_cotangent(aux_out)))[0]
             if self._bucket_grads:
                 grads = _overlap.interleave_grad_buckets(grads)
 
@@ -269,8 +268,7 @@ class ShardedTrainer(object):
             scale = sstate["scale"]
             (outs, aux_out), vjp_fn = jax.vjp(run, params)
             ones = [jnp.ones_like(o) for o in outs]
-            zero_aux = jax.tree_util.tree_map(jnp.zeros_like, aux_out)
-            grads = vjp_fn((ones, zero_aux))[0]
+            grads = vjp_fn((ones, zero_cotangent(aux_out)))[0]
             if self._bucket_grads:
                 grads = _overlap.interleave_grad_buckets(grads)
 
@@ -413,13 +411,32 @@ class ShardedTrainer(object):
                 opt_state[name] = jax.tree_util.tree_map(
                     lambda a, _n=name: put_replicated_host(
                         a, self.opt_state_sharding(_n, a.shape)), s)
+        return params, opt_state, self._init_aux(aux_map, dtype)
+
+    def _aux_dtypes(self, dtype):
+        """{aux name: dtype}: ``dtype`` for the floating states, an op's
+        own for those it declares otherwise (integer counters)."""
+        from ..executor import _aux_dtypes
+        return dict(zip(self._aux_names, _aux_dtypes(
+            self.symbol, {n: dtype for n in self.data_names}, dtype)))
+
+    def _init_aux(self, aux_map, dtype):
+        from .sharding import put_replicated_host
+        dtypes = self._aux_dtypes(dtype)
         aux = {}
         for name in self._aux_names:
-            init_val = jnp.ones(aux_map[name], dtype=dtype) \
+            init_val = jnp.ones(aux_map[name], dtype=dtypes[name]) \
                 if name.endswith("moving_var") else \
-                jnp.zeros(aux_map[name], dtype=dtype)
+                jnp.zeros(aux_map[name], dtype=dtypes[name])
             aux[name] = put_replicated_host(init_val, self._replicated())
-        return params, opt_state, aux
+        return aux
+
+    def init_aux(self, data_shapes, label_shapes=None, dtype=_np.float32):
+        """The auxiliary state alone, as :meth:`init_params` makes it
+        (moving variances 1, everything else 0; integer where an op keeps
+        counters): for callers that bring their own parameters."""
+        _shape_map, aux_map = self._shape_maps(data_shapes, label_shapes)
+        return self._init_aux(aux_map, dtype)
 
     def _shape_maps(self, data_shapes, label_shapes=None):
         shapes = dict(data_shapes)
@@ -456,7 +473,9 @@ class ShardedTrainer(object):
                 opt_state[n] = jax.tree_util.tree_map(
                     lambda a, _n=n: _abs(
                         a.shape, self.opt_state_sharding(_n, a.shape)), s)
-        aux = {n: _abs(aux_map[n], self._replicated())
+        aux_dtypes = self._aux_dtypes(dtype)
+        aux = {n: jax.ShapeDtypeStruct(tuple(aux_map[n]), aux_dtypes[n],
+                                       sharding=self._replicated())
                for n in self._aux_names}
         return params, opt_state, aux
 

@@ -509,7 +509,7 @@ class _GradProp:
     def forward(self, inputs, aux, is_train, rng):
         import jax
         import jax.numpy as jnp
-        from .executor import _zero_key
+        from .executor import _zero_key, zero_cotangent
         n = len(self._base_args)
         arg_vals = dict(zip(self._base_args, inputs[:n]))
         head_grads = list(inputs[n:])
@@ -527,7 +527,7 @@ class _GradProp:
         wrt_in = {w: arg_vals[w] for w in self._wrt}
         (outs, aux_out), vjp_fn = jax.vjp(f, wrt_in)
         cot = ([jnp.asarray(h, o.dtype) for h, o in zip(head_grads, outs)],
-               jax.tree_util.tree_map(jnp.zeros_like, aux_out))
+               zero_cotangent(aux_out))
         grads = vjp_fn(cot)[0]
         return [grads[w] for w in self._wrt], None
 

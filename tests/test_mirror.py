@@ -268,3 +268,53 @@ def test_transformer_mirror_blocks_numerics_and_residuals():
     if rp is None:
         pytest.skip("saved_residuals introspection unavailable")
     assert rm < rp, (rm, rp)
+
+
+_LOWER_SCRIPT = """
+import hashlib, sys
+sys.path.insert(0, %r)
+import jax, jax.numpy as jnp
+from mxnet_tpu.executor import _build_program, _zero_key
+from mxnet_tpu.models import transformer_mla_moe
+# blocks that share variables (the embedding, the head) and hold a dozen
+# weights each: what their segments read is a long list
+net = transformer_mla_moe.get_symbol(
+    vocab_size=64, num_layers=3, dim=32, seq_len=16, num_heads=2,
+    q_lora_rank=16, kv_lora_rank=8, qk_nope_head_dim=8, qk_rope_head_dim=8,
+    v_head_dim=8, intermediate_size=64, moe_intermediate_size=16,
+    n_routed_experts=4, num_experts_per_tok=2, mirror_blocks=True)
+prog = _build_program(net, {})
+shapes, _, aux_shapes = net.infer_shape(
+    data=(1, 16), softmax_label=(1, 16), mtp_label=(1, 16))
+_, _, aux_types = net.infer_type()
+args = {n: jnp.zeros(s, jnp.float32)
+        for n, s in zip(net.list_arguments(), shapes)}
+aux = {n: jnp.zeros(s, t) for n, s, t in zip(
+    net.list_auxiliary_states(), aux_shapes, aux_types)}
+def loss(a):
+    outs, _aux = prog.trace(a, aux, _zero_key(), True)
+    return sum(jnp.sum(o) for o in outs)
+text = jax.jit(jax.grad(loss)).lower(args).as_text()
+assert "optimization_barrier" in text
+print(hashlib.sha256(text.encode()).hexdigest())
+"""
+
+
+def test_mirrored_step_lowers_to_the_same_text_in_every_process():
+    """A segment's inputs must not be ordered by ``id()``: the lowered
+    text, and with it the persistent compile cache's key, would differ
+    from process to process and the step would compile in every run
+    (it did: PERF.md section 6, PR 27)."""
+    import os
+    import subprocess
+    import sys
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    digests = set()
+    for hashseed in ("1", "2", "3"):
+        # another hash seed moves the interpreter's allocations too
+        env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONHASHSEED=hashseed)
+        out = subprocess.run([sys.executable, "-c", _LOWER_SCRIPT % root],
+                             env=env, check=True, capture_output=True,
+                             text=True)
+        digests.add(out.stdout.split()[-1])
+    assert len(digests) == 1, digests

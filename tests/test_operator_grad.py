@@ -240,6 +240,37 @@ GRAD_SPECS = {
          "mh_qkv_bias": _f64(12) * 0.1, "mh_out_weight": _f64(4, 4) * 0.4,
          "mh_out_bias": _f64(4) * 0.1},
         {"rtol": 5e-2, "atol": 5e-2}),
+    "RMSNorm": lambda: (
+        sym.RMSNorm(V("a"), name="rn"),
+        {"a": _f64(4, 3), "rn_gamma": _pos64(3)},
+        {"rtol": 5e-2, "atol": 5e-2}),
+    "MultiHeadLatentAttention": lambda: (
+        sym.MultiHeadLatentAttention(
+            V("a"), num_heads=2, q_lora_rank=4, kv_lora_rank=4,
+            qk_nope_head_dim=2, qk_rope_head_dim=2, v_head_dim=2,
+            rope_theta=100.0, name="la"),
+        {"a": _f64(1, 4, 6), "la_q_a_weight": _f64(4, 6) * 0.6,
+         "la_q_a_norm_gamma": _pos64(4), "la_q_b_weight": _f64(8, 4) * 0.6,
+         "la_kv_a_weight": _f64(6, 6) * 0.6,
+         "la_kv_a_norm_gamma": _pos64(4), "la_kv_b_weight": _f64(8, 4) * 0.6,
+         "la_out_weight": _f64(6, 4) * 0.6},
+        {"rtol": 5e-2, "atol": 5e-2}),
+    "RoutedExperts": lambda: (
+        sym.RoutedExperts(V("a"), num_experts=4, hidden_size=4, top_k=2,
+                          shared_hidden_size=4, routed_scaling_factor=2.5,
+                          name="re"),
+        # every token is positive in each coordinate, so experts 0 and 1
+        # score over a half and experts 2 and 3 under it: the choice never
+        # flips inside the numeric-diff epsilon, the weights still move
+        {"a": (_distinct64(6, 4) + 0.2) * 2.0,
+         "re_router_weight": np.diag([3.0, 3.0, -3.0, -3.0]),
+         "re_expert_gate_weight": _f64(4, 4, 4) * 0.4,
+         "re_expert_up_weight": _f64(4, 4, 4) * 0.4,
+         "re_expert_down_weight": _f64(4, 4, 4) * 0.4,
+         "re_shared_gate_weight": _f64(4, 4) * 0.4,
+         "re_shared_up_weight": _f64(4, 4) * 0.4,
+         "re_shared_down_weight": _f64(4, 4) * 0.4},
+        {"rtol": 5e-2, "atol": 5e-3}),
     "SequenceLast": lambda: (sym.SequenceLast(V("a")),
                              {"a": _f64(4, 2, 3)}, {}),
     "SequenceReverse": lambda: (sym.SequenceReverse(V("a")),
