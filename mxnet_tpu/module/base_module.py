@@ -124,6 +124,14 @@ class BaseModule(object):
         self.forward(data_batch, is_train=True)
         self.backward()
 
+    def prepare(self, data_batch):
+        """Get ready for ``data_batch``, the one the next
+        ``forward_backward`` will be handed (``None``: for none after
+        all).  :meth:`fit` calls it once the running step has been
+        dispatched and before it waits for the step's output.  A module
+        that has nothing to do ahead of the dispatch leaves it at this;
+        :class:`Module` issues the batch's copy to the device."""
+
     def set_params(self, arg_params, aux_params, allow_missing=False,
                    force_init=True):
         self.init_params(initializer=None, arg_params=arg_params,
@@ -233,7 +241,13 @@ class BaseModule(object):
             monitor=None, prefetch=None):
         """Parity: base_module.py:273 — the canonical train loop.
 
-        ``prefetch``: True/False forces the async device feed on/off
+        The copy of a batch to the device is always one batch ahead:
+        once step N is dispatched the loop fetches batch N+1 and hands it
+        to :meth:`prepare`, so the copy runs beside step N; step N+1 is
+        dispatched only after step N's metric and callbacks.
+
+        ``prefetch`` is about the HOST fetch: True/False puts the
+        iterator's ``next()`` on a thread of its own or not
         (:class:`mxnet_tpu.parallel.overlap.DevicePrefetcher`); None
         defers to ``MXTPU_PREFETCH``.  Batch order and losses are
         identical either way — only the wait moves off the loop.
@@ -277,6 +291,7 @@ class BaseModule(object):
                 eval_batch_end_callback, monitor, sentinel, begin_epoch,
                 num_epoch)
         finally:
+            self.prepare(None)      # a step that raised leaves no copy
             if own_prefetch is not None:
                 own_prefetch.close()
 
@@ -288,30 +303,40 @@ class BaseModule(object):
         """The epoch loop body of :meth:`fit` (split out so the async
         feed can be closed in exactly one ``finally``).
 
-        Every iteration is one ``fit_step`` span from the fetch to the
+        Every iteration is one ``fit_step`` span from the dispatch to the
         last callback, so that all a step costs hangs from one root:
-        ``data_wait``, then ``h2d`` and ``step_dispatch`` (opened where
-        the executor group copies and calls), ``update`` unless the step
-        was fused, ``metric`` with its ``metric_sync``, ``batch_end``.
-        The fetch that finds the epoch's end leaves a ``fit_step`` with
-        no ``step_dispatch``; ``epoch_end`` then covers the parameters'
-        round trip through the host and the epoch-end callbacks."""
+        ``h2d`` only where the batch was not copied ahead (an epoch's
+        first), ``step_dispatch``, ``update`` unless the step was fused,
+        then the NEXT batch's ``data_wait`` and its ``h2d``
+        (:meth:`prepare`: the copy runs while this step does), ``metric``
+        with its ``metric_sync``, ``batch_end``.  An epoch's first fetch
+        is a ``data_wait`` of its own ahead of the first root; the fetch
+        that finds the epoch's end sits in the last step's root;
+        ``epoch_end`` then covers the parameters' round trip through the
+        host and the epoch-end callbacks.
+
+        Step N+1 is dispatched only after step N's metric and callbacks:
+        at ``batch_end`` the outputs, the state and the bound inputs are
+        step N's, and every batch taken from the iterator has been
+        dispatched when the one after the next is asked for."""
         from ..resilience import sentinel as _sentinel_mod
         span = _obs.span
         num_step = 0
+
+        def fetch(batches):
+            with span("data_wait", step=num_step + 1) as wait:
+                batch = next(batches, None)
+                wait.log = batch is not None    # finding the end: no event
+            return batch
+
         for epoch in range(begin_epoch, num_epoch):
             tic = time.time()
             eval_metric.reset()
             batches = iter(train_data)
             nbatch = -1
-            while True:
+            data_batch = fetch(batches)
+            while data_batch is not None:
                 with span("fit_step", step=num_step + 1) as fit_step:
-                    with span("data_wait", step=num_step + 1) as wait:
-                        data_batch = next(batches, None)
-                        # the fetch that found the end is no event
-                        wait.log = fit_step.log = data_batch is not None
-                    if data_batch is None:
-                        break
                     nbatch += 1
                     if monitor is not None:
                         monitor.tic()
@@ -326,7 +351,15 @@ class BaseModule(object):
                             num_step, grad_norm=gnorm) != _sentinel_mod.OK
                     if not skip:
                         self.update()
-                    self.update_metric(eval_metric, data_batch.label)
+                    # the step is on its way: fetch the next batch and let
+                    # its copy run beside it, before the metric blocks on
+                    # the step's output.  The labels are taken first: an
+                    # iterator may hand out one DataBatch again and again
+                    labels = data_batch.label
+                    next_batch = fetch(batches)
+                    if next_batch is not None:
+                        self.prepare(next_batch)
+                    self.update_metric(eval_metric, labels)
                     if monitor is not None:
                         monitor.toc_print()
                     if batch_end_callback is not None:
@@ -334,11 +367,13 @@ class BaseModule(object):
                             _call(batch_end_callback, _BatchEndParam(
                                 epoch=epoch, nbatch=nbatch,
                                 eval_metric=eval_metric, locals=locals()))
-                # the whole iteration, fetch included: what a step costs
+                # the whole iteration, the next fetch included: what a
+                # step costs
                 _obs.record_step(
                     num_step, fit_step.dur_s, epoch=epoch,
                     batch_size=_batch_num_samples(data_batch),
                     skipped=skip or None, timing="iteration")
+                data_batch = next_batch
 
             for name, val in eval_metric.get_name_value():
                 self.logger.info("Epoch[%d] Train-%s=%f", epoch, name, val)

@@ -26,7 +26,7 @@ import numpy as _np
 from ..base import MXNetError
 from ..ndarray import NDArray, zeros, concatenate
 from ..executor_manager import (_split_input_slice, _check_arguments,
-                                _bind_exec, _load_data, _load_label)
+                                _bind_exec)
 from ..io import DataDesc
 from ..observability import spans as _spans
 
@@ -160,6 +160,18 @@ class DataParallelExecutorGroup(object):
         self.aux_arrays = [[e.aux_dict[name] for e in self.execs]
                            for name in self.aux_names]
 
+        # the batch copied ahead of its step (``stage_data_batch``), and
+        # how many batches were bound from it / copied at dispatch.  Inputs
+        # that view a shared buffer (a smaller bucket's) cannot hold a
+        # copy beside them
+        self._staged = None
+        self._can_stage = not any(
+            nd._parent is not None
+            for per_exec in self.data_arrays + self.label_arrays
+            for _, nd in per_exec)
+        self.n_staged = 0
+        self.n_loaded = 0
+
     # ------------------------------------------------------------------
     # sharded-mode plumbing
     # ------------------------------------------------------------------
@@ -240,25 +252,57 @@ class DataParallelExecutorGroup(object):
         return out
 
     # ------------------------------------------------------------------
+    def _batch_sources(self, data_batch):
+        """``(bound array, source)`` of every input ``data_batch`` fills:
+        what :meth:`load_data_batch` hands to ``_set_data``.  A mesh group
+        issues its ``device_put`` here; a per-context group yields the
+        host slices, which the bound arrays copy."""
+        sources = [(self.data_arrays, data_batch.data)]
+        if self.label_arrays and data_batch.label:
+            sources.append((self.label_arrays, data_batch.label))
+        for targets, srcs in sources:
+            for per_exec, src in zip(targets, srcs):
+                if self.sharded:
+                    yield per_exec[0][1], self._put_sharded(
+                        src, self._data_sharding)
+                    continue
+                if isinstance(src, NDArray):
+                    src = src.asnumpy()
+                for islice, dst in per_exec:
+                    yield dst, src[islice]
+
+    def stage_data_batch(self, data_batch):
+        """Issue ``data_batch``'s copies to the device now and keep the
+        device arrays beside the batch they came from, without rebinding
+        the inputs: the bound data and labels stay the running step's.
+        The next :meth:`load_data_batch` binds them if it is handed this
+        batch, and drops them whatever it is handed.  ``None`` drops the
+        stage; a group that cannot stage (its inputs are views) stages
+        nothing."""
+        self._staged = None
+        if data_batch is None or not self._can_stage:
+            return
+        with _spans.span("h2d", ahead=1):
+            self._staged = (data_batch, [
+                (dst, dst._placed(src))
+                for dst, src in self._batch_sources(data_batch)])
+
     def load_data_batch(self, data_batch):
+        staged, self._staged = self._staged, None
+        if staged is not None and staged[0] is data_batch:
+            # copied while the step before ran (``stage_data_batch``)
+            self.n_staged += 1
+            for dst, arr in staged[1]:
+                dst._set_data(arr)
+            return
+        del staged      # another batch's stage goes before this one's copy
+        self.n_loaded += 1
         # ``h2d`` is the ISSUE of the copies: ``device_put`` may return
         # before the bytes have moved, so what is still in flight shows up
         # as device idle time after the dispatch, not in this span
         with _spans.span("h2d"):
-            if self.sharded:
-                exec_ = self.execs[0]
-                for name, src in zip(self.data_names, data_batch.data):
-                    exec_.arg_dict[name]._set_data(
-                        self._put_sharded(src, self._data_sharding))
-                if self.label_arrays and data_batch.label:
-                    for name, src in zip(self.label_names,
-                                         data_batch.label):
-                        exec_.arg_dict[name]._set_data(
-                            self._put_sharded(src, self._data_sharding))
-                return
-            _load_data(data_batch, self.data_arrays)
-            if self.label_arrays and data_batch.label:
-                _load_label(data_batch, self.label_arrays)
+            for dst, src in self._batch_sources(data_batch):
+                dst._set_data(src)
 
     def forward(self, data_batch=None, is_train=None):
         if data_batch is not None:

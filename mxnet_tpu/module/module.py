@@ -345,6 +345,13 @@ class Module(BaseModule):
             self._exec_group.forward_backward(data_batch)
             self._fused_update_done = False
 
+    def prepare(self, data_batch):
+        """Issue ``data_batch``'s copy to the device ahead of its step;
+        the next ``forward`` / ``forward_backward`` binds the copy if it
+        is handed this batch and copies as ever otherwise."""
+        if self._exec_group is not None:
+            self._exec_group.stage_data_batch(data_batch)
+
     def update(self):
         self._assert_binded()
         if not self.optimizer_initialized:

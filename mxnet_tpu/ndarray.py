@@ -23,6 +23,7 @@ TPU-native reimplementation of the reference's NDArray
 from __future__ import annotations
 
 import struct
+import sys
 import threading
 import weakref
 
@@ -66,7 +67,7 @@ class NDArray:
     """
 
     __slots__ = ("_storage", "_ctx", "_writable", "_parent", "_getter",
-                 "_setter", "__weakref__")
+                 "_setter", "_fresh", "__weakref__")
 
     def __init__(self, data, ctx=None, writable=True, _parent=None,
                  _getter=None, _setter=None):
@@ -76,6 +77,7 @@ class NDArray:
         self._getter = _getter
         self._setter = _setter
         self._writable = writable
+        self._fresh = None
         if _parent is not None:
             self._storage = None
             self._ctx = _parent._ctx
@@ -109,17 +111,52 @@ class NDArray:
         """
         if not self._writable:
             raise MXNetError("trying to write to a read-only NDArray")
+        if self._parent is not None:
+            value = jnp.asarray(value, dtype=self.dtype)
+            if value.shape != self.shape:
+                value = jnp.broadcast_to(value, self.shape)
+            self._parent._set_data(self._setter(self._parent.data, value))
+        else:
+            self._storage = self._placed(value)
+
+    def _placed(self, value):
+        """``value`` as :meth:`_set_data` stores it in an array that is no
+        view: of this array's dtype and shape, on its context's device.
+        Handed back to :meth:`_set_data` it is bound as it is, so a copy
+        can be issued ahead of the rebind (``executor_group``'s stage)."""
         # a host value bound for storage is shaped on the host, so it
         # reaches the context's device in one transfer
-        on_host = self._parent is None and not isinstance(value, jax.Array)
-        xp = _np if on_host else jnp
+        xp = jnp if isinstance(value, jax.Array) else _np
         value = xp.asarray(value, dtype=self.dtype)
         if value.shape != self.shape:
             value = xp.broadcast_to(value, self.shape)
-        if self._parent is not None:
-            self._parent._set_data(self._setter(self._parent.data, value))
-        else:
-            self._storage = _on_device(value, self._ctx.jax_device)
+        return _on_device(value, self._ctx.jax_device)
+
+    def _bind_fresh(self, value):
+        """:meth:`_set_data` for a buffer that a compiled call has just
+        produced for this array alone (a train step's new weight), marked
+        as such so that :meth:`_donatable` may hand it on."""
+        self._set_data(value)
+        self._fresh = None if self._parent is not None \
+            else weakref.ref(self._storage)
+
+    def _donatable(self):
+        """This array's buffer if a call may consume (donate) it, else
+        ``None`` (hand the call a copy).  jax arrays are immutable, so
+        buffers are shared freely: ``copy``, ``copyto``, ``set_params``
+        and a caller's own dict bind one jax array twice, a zero-copy host
+        view holds it, and ``device_put`` makes new arrays over the old
+        buffer.  Donating a shared buffer would delete it under the
+        others.  So only a buffer that :meth:`_bind_fresh` bound and that
+        nothing else refers to is given away (the source system's engine
+        asks the same of a chunk before it writes in place)."""
+        buf = self._storage         # ``None`` in a view
+        fresh = self._fresh
+        # held here: the slot, ``buf``, and getrefcount's own argument
+        if buf is not None and fresh is not None and fresh() is buf \
+                and sys.getrefcount(buf) <= 3:
+            return buf
+        return None
 
     # ------------------------------------------------------------------
     # basic properties

@@ -4,6 +4,7 @@ Mirrors the reference's tests/python/unittest/test_module.py and
 tests/python/train/test_mlp.py (small end-to-end runs asserting an accuracy
 threshold, SURVEY §4).
 """
+import jax
 import numpy as np
 import pytest
 
@@ -585,3 +586,413 @@ def test_optimizer_states_roundtrip_fused(tmp_path):
     for k in direct:
         np.testing.assert_allclose(resumed[k], direct[k],
                                    rtol=1e-5, atol=1e-6, err_msg=k)
+
+
+# ----------------------------------------------------------------------
+# fit copies batch N+1 while step N runs (prepare / stage_data_batch)
+# ----------------------------------------------------------------------
+_AHEAD_CONTEXTS = [pytest.param(lambda: mx.cpu(), id="one"),
+                   pytest.param(lambda: [mx.cpu(0), mx.cpu(1)], id="mesh2")]
+_AHEAD_OPT = {"learning_rate": 0.2, "momentum": 0.9, "wd": 1e-3}
+
+
+def _ahead_start(ctx, X, y, batch_size=16):
+    """A bound module with seeded parameters, its iterator and the
+    arguments ``fit`` and a hand-written loop both start from."""
+    train = mx.io.NDArrayIter(X, y, batch_size=batch_size)
+    mod = mx.mod.Module(mx.models.get_mlp(2, (8,)), context=ctx)
+    mod.bind(train.provide_data, train.provide_label)
+    mx.random.seed(5)
+    mod.init_params(initializer=mx.init.Uniform(0.1))
+    arg_params, aux_params = mod.get_params()
+    return train, mod, dict(
+        arg_params={k: v.copy() for k, v in arg_params.items()},
+        aux_params={k: v.copy() for k, v in aux_params.items()},
+        kvstore="device" if isinstance(ctx, list) else "local",
+        optimizer="sgd", optimizer_params=_AHEAD_OPT)
+
+
+def _seen(mod):
+    """What a callback can read of the step just done, as host copies."""
+    group = mod._exec_group
+    return {
+        "out": mod.get_outputs()[0].asnumpy().copy(),
+        "params": {k: v[0].asnumpy().copy() for k, v in zip(
+            group.param_names, group.param_arrays)},
+        "state": {k: np.asarray(v).copy()
+                  for k, v in mod._fused_holder["states"].items()},
+        "data": group.data_arrays[0][0][1].asnumpy().copy(),
+        "label": group.label_arrays[0][0][1].asnumpy().copy()}
+
+
+def _hand_loop(ctx, X, y, num_epoch=1):
+    """The loop as it stood before the look-ahead: copy at the dispatch,
+    metric after it, nothing ahead.  Returns the module, the metric and
+    what stood after every step."""
+    train, mod, start = _ahead_start(ctx, X, y)
+    mod.set_params(start["arg_params"], start["aux_params"])
+    mod.init_optimizer(kvstore=start["kvstore"], optimizer="sgd",
+                       optimizer_params=_AHEAD_OPT)
+    metric = mx.metric.create("ce")
+    steps = []
+    for _ in range(num_epoch):
+        metric.reset()
+        for batch in train:
+            mod.forward_backward(batch)
+            mod.update()
+            mod.update_metric(metric, batch.label)
+            steps.append(dict(_seen(mod), metric=metric.get()[1]))
+        train.reset()
+    return mod, metric, steps
+
+
+def _assert_same(a, b):
+    assert set(a) == set(b)
+    for k in a:
+        if isinstance(a[k], dict):
+            _assert_same(a[k], b[k])
+        else:
+            np.testing.assert_array_equal(a[k], b[k], err_msg=k)
+
+
+@pytest.mark.parametrize("make_ctx", _AHEAD_CONTEXTS)
+def test_fit_look_ahead_is_bit_identical_to_a_hand_loop(make_ctx):
+    """Parameters, momentum and metric after k steps of ``fit`` are those
+    of forward_backward / update / update_metric written out by hand."""
+    X, y = _toy_problem(n=80)
+    hand, hand_metric, _ = _hand_loop(make_ctx(), X, y, num_epoch=2)
+    train, mod, start = _ahead_start(make_ctx(), X, y)
+    metric = mx.metric.create("ce")
+    mod.fit(train, eval_metric=metric, num_epoch=2, **start)
+    assert mod._exec_group.sharded == isinstance(make_ctx(), list)
+    assert mod._exec_group.execs[0]._n_fused_step == 10
+    _assert_same(_seen(mod), _seen(hand))
+    for got, want in zip(mod.get_params(), hand.get_params()):
+        _assert_same({k: v.asnumpy() for k, v in got.items()},
+                     {k: v.asnumpy() for k, v in want.items()})
+    assert metric.get() == hand_metric.get()
+
+
+@pytest.mark.parametrize("make_ctx", _AHEAD_CONTEXTS)
+def test_fit_callback_at_step_n_sees_step_n(make_ctx):
+    """At ``batch_end`` the outputs, parameters, optimizer state and the
+    BOUND data and labels are the step's own, though the next batch's
+    copy has been issued: step N+1 is not dispatched before it."""
+    X, y = _toy_problem(n=80)
+    _, _, want = _hand_loop(make_ctx(), X, y)
+    train, mod, start = _ahead_start(make_ctx(), X, y)
+    got = []
+
+    def batch_end(p):
+        group = mod._exec_group
+        assert group.execs[0]._n_fused_step == p.nbatch + 1
+        # every batch but the epoch's last has the next one staged
+        assert (group._staged is None) == (p.nbatch == 4)
+        got.append(dict(_seen(mod), metric=p.eval_metric.get()[1]))
+
+    mod.fit(train, eval_metric="ce", num_epoch=1,
+            batch_end_callback=batch_end, **start)
+    assert len(got) == len(want) == 5
+    for k, (a, b) in enumerate(zip(got, want)):
+        _assert_same(dict(a, metric=np.float64(a["metric"])),
+                     dict(b, metric=np.float64(b["metric"])))
+        np.testing.assert_array_equal(a["data"], X[16 * k:16 * (k + 1)])
+        np.testing.assert_array_equal(a["label"], y[16 * k:16 * (k + 1)])
+
+
+class _OneBatchIter(mx.io.DataIter):
+    """Hands out ONE DataBatch object again and again, with new contents."""
+
+    def __init__(self, X, y, batch_size):
+        super().__init__()
+        self.X, self.y, self.batch_size = X, y, batch_size
+        self.provide_data = [("data", (batch_size,) + X.shape[1:])]
+        self.provide_label = [("softmax_label", (batch_size,))]
+        self.batch = mx.io.DataBatch(data=None, label=None, pad=0, index=None)
+        self.cursor = 0
+        self.handed = 0
+
+    def reset(self):
+        self.cursor = 0
+
+    def next(self):
+        lo, hi = self.cursor, self.cursor + self.batch_size
+        if hi > len(self.X):
+            raise StopIteration
+        self.cursor = hi
+        self.handed += 1
+        self.batch.data = [mx.nd.array(self.X[lo:hi])]
+        self.batch.label = [mx.nd.array(self.y[lo:hi])]
+        return self.batch
+
+
+def test_fit_metric_gets_its_own_labels_from_a_reused_batch():
+    X, y = _toy_problem(n=80)
+    _, want_metric, want = _hand_loop(mx.cpu(), X, y)
+    _, mod, start = _ahead_start(mx.cpu(), X, y)
+    metric = mx.metric.create("ce")
+    got = []
+    mod.fit(_OneBatchIter(X, y, 16), eval_metric=metric, num_epoch=1,
+            batch_end_callback=lambda p: got.append(_seen(mod)), **start)
+    assert metric.get() == want_metric.get()
+    for a, b in zip(got, want):
+        _assert_same(a, {n: b[n] for n in a})
+    # the one object was staged and found again at every later dispatch
+    assert (mod._exec_group.n_staged, mod._exec_group.n_loaded) == (4, 1)
+
+
+def test_fit_two_epochs_neither_lose_nor_repeat_a_batch():
+    X, y = _toy_problem(n=80)
+    train, mod, start = _ahead_start(mx.cpu(), X, y)
+    feed = _OneBatchIter(X, y, 16)
+    bound, counts = [], []
+
+    def batch_end(p):
+        group = mod._exec_group
+        bound.append(group.data_arrays[0][0][1].asnumpy().copy())
+        counts.append((p.epoch, p.nbatch, group.n_staged, group.n_loaded))
+
+    mod.fit(feed, num_epoch=2, batch_end_callback=batch_end, **start)
+    assert feed.handed == 10                    # 5 an epoch, none left over
+    for k, data in enumerate(bound):
+        lo = 16 * (k % 5)
+        np.testing.assert_array_equal(data, X[lo:lo + 16])
+    # an epoch's first batch is copied at its dispatch, the others ahead
+    assert counts == [(e, b, 4 * e + b, e + 1)
+                      for e in range(2) for b in range(5)]
+    group = mod._exec_group
+    assert group._staged is None
+    assert (group.n_staged, group.n_loaded) == (8, 2)
+
+
+def test_score_after_fit_takes_the_unstaged_path():
+    X, y = _toy_problem(n=80)
+    train, mod, start = _ahead_start(mx.cpu(), X, y)
+    mod.fit(train, num_epoch=1, **start)
+    group = mod._exec_group
+    assert (group.n_staged, group.n_loaded) == (4, 1)
+    train.reset()
+    mod.score(train, "acc")
+    assert (group.n_staged, group.n_loaded) == (4, 6)
+    assert len(mod.predict(train)) == 80
+    assert (group.n_staged, group.n_loaded) == (4, 11)
+
+
+def test_a_stage_is_dropped_by_another_batch_none_and_a_failed_step():
+    X, y = _toy_problem(n=48)
+    train, mod, start = _ahead_start(mx.cpu(), X, y)
+    group = mod._exec_group
+    first, second, third = list(train)
+    bound = group.data_arrays[0][0][1]
+    mod.forward(first, is_train=False)
+    # staging binds nothing
+    mod.prepare(second)
+    assert group._staged[0] is second
+    np.testing.assert_array_equal(bound.asnumpy(), X[:16])
+    # a user's own loop hands over another batch: copied as ever, and the
+    # stage does not outlive it
+    mod.forward(third, is_train=False)
+    assert group._staged is None
+    np.testing.assert_array_equal(bound.asnumpy(), X[32:])
+    assert (group.n_staged, group.n_loaded) == (0, 2)
+    mod.forward(second, is_train=False)
+    np.testing.assert_array_equal(bound.asnumpy(), X[16:32])
+    assert (group.n_staged, group.n_loaded) == (0, 3)
+    # withdrawn
+    mod.prepare(second)
+    mod.prepare(None)
+    assert group._staged is None
+    # a callback that raises ends fit with nothing staged
+    train.reset()
+
+    def boom(p):
+        assert group._staged is not None
+        raise RuntimeError("boom")
+
+    with pytest.raises(RuntimeError, match="boom"):
+        mod.fit(train, num_epoch=1, batch_end_callback=boom, **start)
+    assert group._staged is None
+
+
+def test_bucketing_fit_still_trains_with_the_default_prepare():
+    """BucketingModule keeps the base class's ``prepare``: its buckets
+    copy at the dispatch, and a smaller bucket, whose inputs view the
+    largest one's buffer, could hold no copy beside them anyway."""
+    batch_size, vocab = 8, 20
+
+    def sym_gen(seq_len):
+        data = mx.sym.Variable("data")
+        label = mx.sym.Variable("softmax_label")
+        embed = mx.sym.Embedding(data, name="embed", input_dim=vocab,
+                                 output_dim=6)
+        pooled = mx.sym.sum_axis(embed, axis=1)
+        fc = mx.sym.FullyConnected(pooled, name="fc", num_hidden=2)
+        return (mx.sym.SoftmaxOutput(fc, label=label, name="softmax"),
+                ("data",), ("softmax_label",))
+
+    class Feed(mx.io.DataIter):
+        default_bucket_key = 12
+        provide_data = [("data", (batch_size, 12))]
+        provide_label = [("softmax_label", (batch_size,))]
+
+        def __init__(self):
+            super().__init__()
+            self.batch_size = batch_size
+            rng = np.random.RandomState(0)
+            self.batches = []
+            for i in range(12):
+                seq_len = (12, 8)[i % 2]
+                tokens = rng.randint(0, vocab // 2, (batch_size, seq_len))
+                label = rng.randint(0, 2, (batch_size,))
+                # the class decides which half of the vocabulary is used
+                tokens = tokens + (vocab // 2) * label[:, None]
+                self.batches.append(mx.io.DataBatch(
+                    data=[mx.nd.array(tokens.astype(np.float32))],
+                    label=[mx.nd.array(label.astype(np.float32))], pad=0,
+                    bucket_key=seq_len,
+                    provide_data=[("data", (batch_size, seq_len))],
+                    provide_label=[("softmax_label", (batch_size,))]))
+            self.cursor = 0
+
+        def reset(self):
+            self.cursor = 0
+
+        def next(self):
+            if self.cursor == len(self.batches):
+                raise StopIteration
+            self.cursor += 1
+            return self.batches[self.cursor - 1]
+
+    feed = Feed()
+    mod = mx.mod.BucketingModule(sym_gen, default_bucket_key=12,
+                                 context=mx.cpu())
+    assert type(mod).prepare is mx.mod.BaseModule.prepare
+    mod.fit(feed, eval_metric="acc", num_epoch=6, optimizer="sgd",
+            optimizer_params={"learning_rate": 0.5},
+            initializer=mx.init.Uniform(0.1))
+    feed.reset()
+    assert dict(mod.score(feed, "acc"))["accuracy"] > 0.9
+    groups = [m._exec_group for m in mod._buckets.values()]
+    assert len(groups) == 2
+    assert sum(g.n_staged for g in groups) == 0
+    assert sum(g.n_loaded for g in groups) == 6 * 12 + 12
+    # the smaller bucket's inputs are views: asked to stage, it declines
+    small = mod._buckets[8]._exec_group
+    assert small.data_arrays[0][0][1]._parent is not None
+    small.stage_data_batch(feed.batches[1])
+    assert small._staged is None
+
+
+# ----------------------------------------------------------------------
+# the fused step donates what it replaces, and takes nothing shared
+# ----------------------------------------------------------------------
+def _stepping_module(ctx):
+    X, y = _toy_problem(n=64)
+    train, mod, start = _ahead_start(ctx, X, y)
+    mod.set_params(start["arg_params"], start["aux_params"])
+    mod.init_optimizer(kvstore=start["kvstore"], optimizer="sgd",
+                       optimizer_params=_AHEAD_OPT)
+    return mod, list(train)
+
+
+def _step(mod, batch):
+    mod.forward_backward(batch)
+    mod.update()
+
+
+@pytest.mark.parametrize("make_ctx", _AHEAD_CONTEXTS)
+def test_fused_step_outputs_reuse_its_inputs_buffers(make_ctx):
+    """Weights, old gradients, auxiliary and optimizer state are donated:
+    the lowered step aliases every one of them to an output, so that the
+    runtime allocates no buffer for them at the dispatch."""
+    import warnings
+    mod, batches = _stepping_module(make_ctx())
+    exe = mod._exec_group.execs[0]
+    with warnings.catch_warnings():
+        warnings.simplefilter("error")      # "donated buffers not usable"
+        _step(mod, batches[0])
+    states = mod._exec_group._ensure_on_mesh(
+        (exe.init_fused_states(mod._optimizer),))[0]
+    text = exe._lower_fused(mod._optimizer, states).as_text()
+    n_wrt = len(exe._get_fused(mod._optimizer)[0])
+    assert n_wrt == 4
+    n_state = len(jax.tree_util.tree_leaves(states))
+    assert text.count("tf.aliasing_output") + text.count(
+        "jax.buffer_donor") == 2 * n_wrt + len(exe.aux_dict) + n_state
+
+
+@pytest.mark.parametrize("make_ctx", _AHEAD_CONTEXTS)
+def test_fused_step_gives_away_only_what_is_its_own(make_ctx, monkeypatch):
+    """From the second step on every weight, gradient and auxiliary state
+    is the step's own output and is handed on as it is; the first step
+    after the parameters were set copies instead, and so does any step
+    for a buffer that something else still refers to."""
+    from mxnet_tpu import executor
+    mod, batches = _stepping_module(make_ctx())
+    exe = mod._exec_group.execs[0]
+    copied = []
+    real = executor._consumable
+
+    def counting(arrays, **kw):
+        copied.append(sorted(n for n, a in arrays.items()
+                             if a._donatable() is None))
+        return real(arrays, **kw)
+
+    monkeypatch.setattr(executor, "_consumable", counting)
+    _step(mod, batches[0])
+    wrt = sorted(exe._get_fused(mod._optimizer)[0])
+    assert copied == [wrt, wrt, sorted(exe.aux_dict)]
+    del copied[:]
+    _step(mod, batches[1])
+    assert copied == [[], [], []]
+    # something else refers to one weight's buffer: that one is copied
+    held = exe.arg_dict["fc1_weight"].copy()
+    kept = held.asnumpy().copy()
+    del copied[:]
+    _step(mod, batches[2])
+    assert copied == [["fc1_weight"], [], []]
+    np.testing.assert_array_equal(held.asnumpy(), kept)
+    # a parameter set from outside is not the step's own any more
+    exe.arg_dict["fc2_bias"]._set_data(exe.arg_dict["fc2_bias"].data * 1)
+    del copied[:]
+    _step(mod, batches[3])
+    assert copied == [["fc2_bias"], [], []]     # ``held`` has the old one
+    del copied[:]
+    _step(mod, batches[0])
+    assert copied == [[], [], []]
+
+
+def test_fused_step_leaves_every_shared_buffer_readable():
+    """What a caller holds stays whole over the steps that follow: the
+    dict it passed to ``fit``, a ``copy()``, ``get_params()``'s arrays, a
+    raw jax array, and an array that ``device_put`` made over the same
+    buffer (a replicated put aliases its source's shard)."""
+    X, y = _toy_problem(n=64)
+    train, mod, start = _ahead_start([mx.cpu(0), mx.cpu(1)], X, y)
+    mine = {k: v.asnumpy().copy() for k, v in start["arg_params"].items()}
+    seen = {}
+
+    def batch_end(p):
+        group = mod._exec_group
+        if p.epoch == 0 and p.nbatch == 0:
+            exe = group.execs[0]
+            seen["copy"] = exe.arg_dict["fc1_weight"].copy()
+            seen["raw"] = exe.arg_dict["fc1_bias"].data
+            seen["grad"] = exe.grad_dict["fc2_weight"].data
+            seen["put"] = jax.device_put(exe.arg_dict["fc2_weight"].data,
+                                         group._repl_sharding)
+            seen["mom"] = dict(mod._fused_holder["states"])
+            seen["then"] = {k: np.asarray(v.data if hasattr(v, "asnumpy")
+                                          else v).copy()
+                            for k, v in seen.items() if k != "mom"}
+
+    mod.fit(train, num_epoch=2, batch_end_callback=batch_end, **start)
+    for k, v in start["arg_params"].items():
+        np.testing.assert_array_equal(v.asnumpy(), mine[k])
+    for k, want in seen["then"].items():
+        got = seen[k]
+        np.testing.assert_array_equal(
+            np.asarray(got.data if hasattr(got, "asnumpy") else got), want)
+    # the optimizer state was donated before this PR and is: a caller
+    # reads it inside the callback, as the benchmark does
+    assert all(v.is_deleted() for v in seen["mom"].values())

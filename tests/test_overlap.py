@@ -139,6 +139,42 @@ def test_prefetcher_reset_mid_epoch():
         pf.close()
 
 
+@pytest.mark.parametrize("ctx", [
+    pytest.param(lambda: mx.cpu(), id="one"),
+    pytest.param(lambda: [mx.cpu(0), mx.cpu(1)], id="mesh2")])
+def test_fit_prefetch_composes_with_the_look_ahead(ctx):
+    """``prefetch=True`` puts the HOST fetch on a thread; ``fit`` copies
+    one batch ahead to the DEVICE either way.  Same batches, same order:
+    parameters, momentum and metric are bit-identical."""
+    rng = np.random.RandomState(3)
+    X = rng.randn(96, 10).astype(np.float32)
+    y = (X.sum(axis=1) > 0).astype(np.float32)
+    ran = {}
+    for prefetch in (False, True):
+        mx.random.seed(9)
+        mod = mx.mod.Module(mx.models.get_mlp(2, (8,)), context=ctx())
+        metric = mx.metric.create("ce")
+        mod.fit(mx.io.NDArrayIter(X, y, batch_size=16), eval_metric=metric,
+                kvstore="device", optimizer="sgd",
+                optimizer_params={"learning_rate": 0.2, "momentum": 0.9},
+                initializer=mx.init.Uniform(0.1), num_epoch=2,
+                prefetch=prefetch)
+        group = mod._exec_group
+        # the thread changes who fetches, not where the copy is issued
+        assert (group.n_staged, group.n_loaded) == (10, 2)
+        assert group._staged is None
+        ran[prefetch] = (
+            {k: v.asnumpy() for k, v in mod.get_params()[0].items()},
+            {k: np.asarray(v) for k, v in
+             mod._fused_holder["states"].items()},
+            metric.get())
+    for got, want in zip(ran[True][:2], ran[False][:2]):
+        assert set(got) == set(want)
+        for k in want:
+            np.testing.assert_array_equal(got[k], want[k], err_msg=k)
+    assert ran[True][2] == ran[False][2]
+
+
 # ---------------------------------------------------------------------------
 # AsyncLauncher
 # ---------------------------------------------------------------------------

@@ -22,7 +22,15 @@ from mxnet_tpu.observability import (aggregate, counters, events, flight,
                                      phases, spans)
 
 _ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-FIT_CHILDREN = ["data_wait", "h2d", "step_dispatch", "metric", "batch_end"]
+
+
+def fit_children(k, n, fused=True):
+    """The children of step ``k``'s root in an epoch of ``n`` steps: the
+    first batch is copied at its dispatch, every later one while the step
+    before it runs, and the last step's fetch finds the epoch's end."""
+    return ((["h2d"] if k == 1 else []) + ["step_dispatch"]
+            + ([] if fused else ["update"]) + ["data_wait"]
+            + (["h2d"] if k < n else []) + ["metric", "batch_end"])
 
 
 @pytest.fixture(autouse=True)
@@ -216,7 +224,7 @@ def test_fit_leaves_one_root_per_step_in_order():
     for k, root in enumerate(got, 1):
         assert root.name == "fit_step" and root.parent_id is None
         assert root.step == k
-        assert [c.name for c in root.children] == FIT_CHILDREN
+        assert [c.name for c in root.children] == fit_children(k, 3)
         (metric,) = root.named("metric")
         assert metric.children and \
             {c.name for c in metric.children} == {"metric_sync"}
@@ -224,13 +232,22 @@ def test_fit_leaves_one_root_per_step_in_order():
         assert times == sorted(times)
         assert root.t0_ns <= times[0] and times[-1] <= root.t1_ns
         assert 0 <= spans.self_ns(root) <= root.dur_ns
-    # the fetch that found the epoch's end: a root with no dispatch; then
-    # the parameters' round trip through the host, under a span of its own
+        # the fetch inside step k's root is batch k+1's, and so is the
+        # copy that follows it: issued once step k has been dispatched
+        (dispatch,) = root.named("step_dispatch")
+        (wait,) = root.named("data_wait")
+        assert wait.step == k + 1 and wait.t0_ns >= dispatch.t1_ns
+        ahead = [c for c in root.named("h2d") if c.t0_ns >= wait.t1_ns]
+        assert len(ahead) == (1 if k < 3 else 0)
+    # the first batch's fetch stands before the first root; the fetch
+    # that found the epoch's end is in the last step's root, which has a
+    # dispatch like any other; then the parameters' round trip through
+    # the host, under a span of its own
     roots = [r for r in spans.snapshot() if r["parent_id"] is None]
-    assert [r["name"] for r in roots] == ["fit_step"] * 4 + ["epoch_end"]
-    last = roots[-2]["id"]
-    assert [r["name"] for r in spans.snapshot()
-            if r["parent_id"] == last] == ["data_wait"]
+    assert [r["name"] for r in roots] == \
+        ["data_wait"] + ["fit_step"] * 3 + ["epoch_end"]
+    assert roots[0]["step"] == 1
+    assert roots[0]["t1_ns"] <= roots[1]["t0_ns"]
     assert roots[-1]["step"] == 3
 
 
@@ -247,10 +264,9 @@ def test_a_second_fit_is_told_apart_though_step_numbers_repeat():
 def test_non_fused_fit_has_an_update_span(monkeypatch):
     monkeypatch.setenv("MXNET_MODULE_FUSED", "0")
     _fit()
-    for root in spans.steps(3):
-        assert [c.name for c in root.children] == [
-            "data_wait", "h2d", "step_dispatch", "update", "metric",
-            "batch_end"]
+    for k, root in enumerate(spans.steps(3), 1):
+        assert [c.name for c in root.children] == fit_children(
+            k, 3, fused=False)
 
 
 def test_fit_with_the_sentinel_on(monkeypatch):
@@ -416,6 +432,9 @@ def test_fit_log_has_steps_spans_and_no_record_of_the_end_fetch(
                                   if r["kind"] == "span")
     assert by_name["fit_step"] == by_name["data_wait"] == 3
     assert by_name["step_dispatch"] == by_name["metric"] == 3
+    # one copy at the first dispatch, two issued a batch ahead
+    h2d = [r for r in recs if r["kind"] == "span" and r["name"] == "h2d"]
+    assert [r.get("ahead") for r in h2d] == [None, 1, 1]
     steps = [r for r in recs if r["kind"] == "step"]
     assert [r["step"] for r in steps] == [1, 2, 3]
     assert all(r["timing"] == "iteration" and r["batch_size"] == 10
@@ -424,8 +443,9 @@ def test_fit_log_has_steps_spans_and_no_record_of_the_end_fetch(
     assert [r["dur_ms"] for r in steps] == pytest.approx(
         [r["dur_ms"] for r in recs
          if r["kind"] == "span" and r["name"] == "fit_step"], abs=0.0011)
-    # the ring holds the end fetch all the same
-    assert sum(r["name"] == "fit_step" for r in spans.snapshot()) == 4
+    # the ring holds the end fetch all the same, in the last step's root
+    assert sum(r["name"] == "data_wait" for r in spans.snapshot()) == 4
+    assert sum(r["name"] == "fit_step" for r in spans.snapshot()) == 3
 
 
 def test_timed_iter_spans_every_fetch_and_logs_the_items(monkeypatch,

@@ -258,10 +258,9 @@ def test_mesh_steps_carry_the_flash_kernel_per_device():
     exe = group.execs[0]
     assert group.sharded and exe._n_fused_step == 1
     with group._mesh_scope():
-        _wrt, step = exe._get_fused(mod._optimizer)
+        wrt, step = exe._get_fused(mod._optimizer)
         text = tpu_lowering_text(
-            step, {n: a.data for n, a in exe.arg_dict.items()},
-            {n: a.data for n, a in exe.aux_dict.items()},
+            step, *exe._fused_operands(wrt, donate=False),
             jax.random.PRNGKey(0), mod._fused_holder["states"],
             jnp.float32(0.1), jnp.float32(0.0), jnp.int32(1))
     assert _flash_calls(text) == (1, L)
