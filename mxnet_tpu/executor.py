@@ -26,6 +26,8 @@ from .context import Context
 from .ndarray import NDArray, zeros
 from . import random as _random
 from .observability import spans as _spans
+from .train_step import (apply_updates, compute_cast, loss_and_grads,
+                         no_cast, preprocess_grads)
 
 _ZERO_KEY = None
 
@@ -135,18 +137,6 @@ def _consumable(arrays, beside=None):
     return out
 
 
-def zero_cotangent(tree):
-    """Zero cotangents for outputs nothing differentiates (auxiliary
-    state): zeros of a floating leaf's own dtype, and for an integer leaf
-    (an op's counters) the ``float0`` zeros jax asks for."""
-    def zero(a):
-        if jnp.issubdtype(a.dtype, jnp.inexact):
-            return jnp.zeros_like(a)
-        return _np.zeros(a.shape, jax.dtypes.float0)
-
-    return jax.tree_util.tree_map(zero, tree)
-
-
 def _mirror_segments(op_nodes):
     """Partition the op schedule into checkpoint segments — the
     jax-native MakeBackwardPass mirror map (static_graph.cc:396-440).
@@ -155,9 +145,9 @@ def _mirror_segments(op_nodes):
     need_mirror rules (static_graph.cc:409-425): its ``force_mirroring``
     attr, or MXNET_BACKWARD_DO_MIRROR=1 for every op type outside the
     reference's skip list (heavy MXU ops whose recompute costs more than
-    the activation is worth), except every MXNET_BACKWARD_MIRROR_STEP-th
-    eligible node (a periodic keep so recompute chains stay bounded;
-    <=0 means no periodic keep).  Consecutive mirrored nodes form ONE
+    the activation is worth), except every 100th eligible node (the
+    reference's default: a periodic keep so recompute chains stay
+    bounded).  Consecutive mirrored nodes form ONE
     ``jax.checkpoint`` segment — internals dropped from the residual set
     and recomputed in backward — split at differing ``mirror_stage``
     attrs so users can pin stage boundaries.  ``op_nodes`` excludes
@@ -167,10 +157,7 @@ def _mirror_segments(op_nodes):
     """
     import os as _os
     do_mirror = int(_os.environ.get("MXNET_BACKWARD_DO_MIRROR", "0") or 0)
-    mirror_step = int(_os.environ.get("MXNET_BACKWARD_MIRROR_STEP",
-                                      "100") or 100)
-    if mirror_step <= 0:
-        mirror_step = 1 << 62   # never hit the periodic keep
+    mirror_step = 100
     counter = [0]
     env_skip = {"Convolution", "FullyConnected", "Concat", "SoftmaxOutput",
                 "CuDNNBatchNorm"}
@@ -224,7 +211,7 @@ def program_registry_stats():
 
 def _bind_env_fingerprint(validate_mode):
     """Host state a program build bakes in beyond (symbol, group2ctx):
-    the compute dtype, the backward-mirror envs read by
+    the compute dtype, the backward-mirror env read by
     ``_mirror_segments``, and the active validation-rules fingerprint.
     Folded into both the per-symbol ``_jit_cache`` key and (via
     ``ctx_key``) the global ``_PROGRAM_REGISTRY`` key so a flag flip
@@ -238,7 +225,6 @@ def _bind_env_fingerprint(validate_mode):
         rules = (validate_mode,) + tuple(sorted(RULE_REGISTRY))
     return (os.environ.get("MXNET_COMPUTE_DTYPE", ""),
             os.environ.get("MXNET_BACKWARD_DO_MIRROR", ""),
-            os.environ.get("MXNET_BACKWARD_MIRROR_STEP", ""),
             rules)
 
 
@@ -400,17 +386,8 @@ def _build_program(symbol, group2ctx):
     def fwd_bwd(arg_values, aux_values, rng, out_grads, wrt):
         """Forward + vjp in ONE XLA computation (replaces the reference's
         explicit Backward nodes, static_graph.cc:395)."""
-        def f(wrt_values):
-            merged = dict(arg_values)
-            merged.update(wrt_values)
-            return trace(merged, aux_values, rng, True)
-
-        (outs, aux_out), vjp_fn = jax.vjp(f, wrt)
-        if out_grads is None:  # implicit loss-layer heads: cotangent of ones
-            out_grads = [jnp.ones_like(o) for o in outs]
-        grads = vjp_fn((out_grads,
-                        zero_cotangent(aux_out)))[0]
-        return outs, aux_out, grads
+        return loss_and_grads(trace, no_cast, wrt, arg_values, aux_values,
+                              rng, out_grads)
 
     return _Program(trace, jax.jit(trace, static_argnames=("is_train",)),
                     jax.jit(fwd_bwd), needs_rng)
@@ -744,7 +721,8 @@ class Executor:
         return self.outputs
 
     # -- fused train step (fwd + bwd + optimizer update, ONE dispatch) --
-    def _fused_compute_dtype(self):
+    @staticmethod
+    def _fused_compute_dtype():
         """Optional reduced-precision compute for the fused step
         (MXNET_COMPUTE_DTYPE=bfloat16): fwd+bwd run at MXU rate while
         master weights, optimizer state, grads and aux stay f32 — the
@@ -752,18 +730,8 @@ class Executor:
         import os
         name = os.environ.get("MXNET_COMPUTE_DTYPE", "").strip()
         if not name or name in ("float32", "f32"):
-            return None, frozenset()
-        cdt = jnp.dtype(name)
-        # never cast integer-valued float inputs: labels and Embedding
-        # vocab ids above 256 would silently round in bf16
-        exempt = {n for n in self._arg_names if n.endswith("label")}
-        for node in self._symbol._topo():
-            if node.op is not None and \
-                    getattr(node.op, "op_name", "") == "Embedding":
-                src, _ = node.inputs[0]
-                if src.is_variable:
-                    exempt.add(src.name)
-        return cdt, frozenset(exempt)
+            return None
+        return jnp.dtype(name)
 
     def _build_fused_step(self, optimizer):
         """Jit fwd+bwd+update as one XLA computation — the full analog of
@@ -773,8 +741,6 @@ class Executor:
         trace = self._program.trace
         wrt_names = tuple(n for n in self._arg_names
                           if self._grad_req.get(n, "null") != "null")
-        upd = optimizer.update_fn
-        pre = optimizer._preprocess_grad
         # per-param lr/wd multipliers are static floats at trace time
         # (reference _get_lr/_get_wd, optimizer.py:122-141)
         name2idx = {n: i for i, n in optimizer.idx2name.items()}
@@ -786,45 +752,21 @@ class Executor:
             wdm[n] = optimizer.wd_mult.get(
                 idx, optimizer.wd_mult.get(n, 1.0))
 
-        cdt, exempt = self._fused_compute_dtype()
-
-        def cast(name, a):
-            if cdt is None or name in exempt or \
-                    not jnp.issubdtype(a.dtype, jnp.floating):
-                return a
-            return a.astype(cdt)
+        # an Executor knows its labels by their names
+        cast = compute_cast(self._symbol, self._fused_compute_dtype(),
+                            [n for n in self._arg_names
+                             if n.endswith("label")])
 
         def step(wrt, old_grads, arg_values, aux_values, rng, states, lr,
                  wd, t):
             # ``old_grads`` is there to be donated: the new gradients take
             # its buffers, as the new weights take ``wrt``'s
             del old_grads
-
-            def f(wrt_values):
-                # the cast is INSIDE f: vjp through astype returns f32
-                # cotangents for the f32 master weights
-                merged = {n: cast(n, v) for n, v in arg_values.items()}
-                merged.update({n: cast(n, v)
-                               for n, v in wrt_values.items()})
-                aux_in = {n: cast(n, v) for n, v in aux_values.items()}
-                outs, aux_out = trace(merged, aux_in, rng, True)
-                if cdt is not None:     # aux (bn stats) stored f32
-                    aux_out = {k: v.astype(aux_values[k].dtype)
-                               for k, v in aux_out.items()}
-                return outs, aux_out
-
-            (outs, aux_out), vjp_fn = jax.vjp(f, wrt)
-            ones = [jnp.ones_like(o) for o in outs]
-            grads = vjp_fn(
-                (ones, zero_cotangent(aux_out)))[0]
-            new_w, new_s = {}, {}
-            for n in wrt_names:
-                g = pre(grads[n])
-                w, s = upd(wrt[n], g, states.get(n),
-                           lr * lrm[n], wd * wdm[n], t)
-                new_w[n] = w
-                if s is not None:
-                    new_s[n] = s
+            outs, aux_out, grads = loss_and_grads(
+                trace, cast, wrt, arg_values, aux_values, rng)
+            new_w, new_s = apply_updates(
+                optimizer, wrt, preprocess_grads(optimizer, grads), states,
+                lr, wd, t, lr_mult=lrm, wd_mult=wdm)
             return outs, aux_out, grads, new_w, new_s
 
         # Everything the step replaces is donated — the weights, the old

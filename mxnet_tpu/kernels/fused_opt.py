@@ -1,6 +1,6 @@
 """Fused optimizer step: bucketed flatten -> update -> unflatten.
 
-The per-leaf optimizer tree-map in ``ShardedTrainer.train_step`` costs
+The per-leaf loop of ``train_step.apply_updates`` costs
 one fusion boundary (and on real hardware, one kernel launch) per
 parameter; a transformer with hundreds of small norm/bias leaves spends
 more time between updates than in them.  This module replaces the loop
@@ -8,7 +8,7 @@ with one sweep per size-targeted bucket:
 
 1. leaves are grouped by dtype and packed into buckets by
    ``parallel.overlap.partition_buckets`` (the PR-8 size-targeted
-   partition, same knob family: ``MXTPU_FUSED_OPT_BUCKET_MB``);
+   partition) of 64 MB;
 2. each bucket's weights/grads/state leaves are flattened and
    concatenated into single vectors INSIDE the traced step;
 3. the optimizer's pure ``update_fn`` runs once on the concatenated
@@ -20,9 +20,8 @@ with one sweep per size-targeted bucket:
 
 Bit-identity: this is only legal for optimizers whose update is purely
 elementwise (``Optimizer.elementwise``) — then flatten/concat commutes
-with the update exactly, including the grad preproceessing (rescale +
-clip are elementwise too), so the fused step is bit-identical to the
-tree-map path (asserted on a multi-device mesh by
+with the update exactly, so the fused step is bit-identical to the
+per-leaf path (asserted on a multi-device mesh by
 tests/test_kernels.py).  LAMB (per-tensor trust ratios) and SGLD
 (per-leaf noise draws) refuse the fused path and fall back.
 
@@ -45,6 +44,7 @@ __all__ = ["fused_opt_mode", "supports_fused", "plan_buckets",
            "fused_apply", "fused_opt_kernel_spec"]
 
 _LANES = 128
+_BUCKET_NBYTES = 64 << 20     # size target of one concatenated bucket
 
 
 def fused_opt_mode(explicit=None):
@@ -59,18 +59,6 @@ def fused_opt_mode(explicit=None):
         raise MXNetError("MXTPU_FUSED_OPT must be '', '1' or 'kernel', "
                          "got %r" % (mode,))
     return mode
-
-
-def bucket_nbytes(explicit=None):
-    """Bucket size target in bytes (``MXTPU_FUSED_OPT_BUCKET_MB``,
-    default 64 MB)."""
-    if explicit is not None:
-        return int(explicit)
-    try:
-        mb = float(env_flag("MXTPU_FUSED_OPT_BUCKET_MB") or 64)
-    except ValueError:
-        mb = 64.0
-    return int(mb * (1 << 20))
 
 
 def supports_fused(optimizer):
@@ -89,7 +77,7 @@ def plan_buckets(params, names=None, nbytes=None):
     by_dtype = {}
     for n in names:
         by_dtype.setdefault(str(_np.dtype(params[n].dtype)), []).append(n)
-    target = bucket_nbytes(nbytes)
+    target = _BUCKET_NBYTES if nbytes is None else int(nbytes)
     buckets = []
     for _dt, group in sorted(by_dtype.items()):
         sized = [(n, _nbytes(params[n])) for n in group]
@@ -189,18 +177,12 @@ def _sweep_call(w, g, state_leaves, lr, wd, t, update, interpret,
 # the fused apply
 # ----------------------------------------------------------------------
 def fused_apply(optimizer, params, grads, opt_state, lr, wd, t,
-                names=None, nbytes=None, mode=None, interpret=None,
-                preprocess=None, postprocess=None):
+                names=None, nbytes=None, mode=None, interpret=None):
     """One fused optimizer step over ``names`` (default: all params).
 
     Pure/traceable; returns ``(new_params, new_opt_state)`` dicts for
-    exactly the covered names.  ``preprocess`` (grad transform, e.g.
-    ``Optimizer._preprocess_grad``) runs on the concatenated vector —
-    elementwise, so identical to per-leaf application.  ``postprocess``
-    (per-leaf hook ``fn(name, new_w, old_w) -> new_w``) runs after
-    unflatten — the seam where the trainer re-pins zero1 sharding
-    constraints and applies sentinel gating per leaf, exactly as the
-    tree-map path does.
+    exactly the covered names.  ``grads`` are preprocessed, as
+    ``train_step.apply_updates`` takes them.
     """
     import jax
     import jax.numpy as jnp
@@ -228,8 +210,6 @@ def fused_apply(optimizer, params, grads, opt_state, lr, wd, t,
         w_flat = jnp.concatenate(
             [jnp.ravel(params[n]) for n in bucket])
         g_flat = jnp.concatenate([jnp.ravel(grads[n]) for n in bucket])
-        if preprocess is not None:
-            g_flat = preprocess(g_flat)
         state_leaves = _concat_state(optimizer, opt_state, bucket)
         scalars = [jnp.asarray(v, jnp.float32) for v in (lr, wd, t)]
 
@@ -248,11 +228,8 @@ def fused_apply(optimizer, params, grads, opt_state, lr, wd, t,
         offset = 0
         for n, size in zip(bucket, sizes):
             shape = tuple(params[n].shape)
-            leaf_w = jax.lax.dynamic_slice_in_dim(nw, offset, size) \
-                .reshape(shape)
-            if postprocess is not None:
-                leaf_w = postprocess(n, leaf_w, params[n])
-            new_params[n] = leaf_w
+            new_params[n] = jax.lax.dynamic_slice_in_dim(
+                nw, offset, size).reshape(shape)
             if ns:
                 leaves = [jax.lax.dynamic_slice_in_dim(s, offset, size)
                           .reshape(shape) for s in ns]

@@ -13,13 +13,12 @@ executors + host/PS gradient reduction (``comm.h:186-345``,
 ``kvstore_dist.h:181-226``).
 
 The legacy per-context slicing group (reference semantics,
-``executor_group.py:104``) remains for heterogeneous contexts, indivisible
-batches, or ``MXNET_MODULE_SHARDED=0``.
+``executor_group.py:104``) remains for heterogeneous contexts and
+indivisible batches.
 """
 from __future__ import annotations
 
 import logging
-import os
 
 import numpy as _np
 
@@ -85,8 +84,7 @@ class DataParallelExecutorGroup(object):
             self._data_sharding = shared_group._data_sharding
             self._repl_sharding = shared_group._repl_sharding
             self._n_proc = shared_group._num_proc
-        elif len(contexts) > 1 and os.environ.get(
-                "MXNET_MODULE_SHARDED", "1") != "0":
+        elif len(contexts) > 1:
             self._try_init_mesh(contexts, logger)
         if self.sharded:
             # one executor over the mesh sees the full (global) batch

@@ -37,6 +37,7 @@ def shard_map(f, **kw):
 
 
 from .mesh import make_mesh  # noqa: F401  (re-exported convenience)
+from ..train_step import apply_updates, preprocess_grads
 
 __all__ = ["pipeline_apply", "GPipeTrainer", "build_1f1b_tables",
            "schedule_occupancy"]
@@ -435,30 +436,35 @@ class GPipeTrainer:
             return fn(params["embed"], params["layers"], params["head"],
                       batch)
 
-        opt_update = self.optimizer.update_fn
-        preprocess = self.optimizer._preprocess_grad
+        return self._jit_update(jax.value_and_grad(loss_fn))
+
+    def _jit_update(self, loss_and_grads):
+        """The jitted step of either schedule: ``loss_and_grads(params,
+        batch)``, then the optimizer's update over each group's leaves,
+        params and optimizer state donated."""
+        optimizer = self.optimizer
 
         def step(params, opt_state, batch, lr, wd, num_update):
-            loss, grads = jax.value_and_grad(loss_fn)(params, batch)
+            loss, grads = loss_and_grads(params, batch)
             new_params, new_state = {}, {}
             for k in params:
                 flat_p, treedef = jax.tree_util.tree_flatten(params[k])
-                flat_g = jax.tree_util.tree_leaves(grads[k])
-                outs = [opt_update(p, preprocess(g), s, lr, wd,
-                                   num_update)
-                        for p, g, s in zip(flat_p, flat_g, opt_state[k])]
+                flat_g = dict(enumerate(jax.tree_util.tree_leaves(grads[k])))
+                new_p, new_s = apply_updates(
+                    optimizer, dict(enumerate(flat_p)),
+                    preprocess_grads(optimizer, flat_g),
+                    dict(enumerate(opt_state[k])), lr, wd, num_update)
                 new_params[k] = jax.tree_util.tree_unflatten(
-                    treedef, [o[0] for o in outs])
-                new_state[k] = [o[1] for o in outs]
+                    treedef, [new_p[i] for i in range(len(flat_p))])
+                new_state[k] = [new_s.get(i) for i in range(len(flat_p))]
             return new_params, new_state, loss
 
-        donate = (0, 1)
-        return jax.jit(step, donate_argnums=donate)
+        return jax.jit(step, donate_argnums=(0, 1))
 
     def _build_1f1b(self):
-        """The 1F1B step: same signature and update loop as the GPipe
-        path, but fwd+bwd run interleaved per microbatch through
-        :func:`_pipeline_1f1b` (manual vjp schedule) instead of
+        """The 1F1B step: the GPipe path's signature and update
+        (:meth:`_jit_update`), but fwd+bwd run interleaved per microbatch
+        through :func:`_pipeline_1f1b` (manual vjp schedule) instead of
         ``jax.value_and_grad`` over the fwd-only pipeline.  The loss is
         the mean of per-microbatch head losses, accumulated in
         microbatch order — bit-identical to
@@ -526,24 +532,7 @@ class GPipeTrainer:
             return fn(params["embed"], params["layers"], params["head"],
                       batch)
 
-        opt_update = self.optimizer.update_fn
-        preprocess = self.optimizer._preprocess_grad
-
-        def step(params, opt_state, batch, lr, wd, num_update):
-            loss, grads = loss_and_grads(params, batch)
-            new_params, new_state = {}, {}
-            for k in params:
-                flat_p, treedef = jax.tree_util.tree_flatten(params[k])
-                flat_g = jax.tree_util.tree_leaves(grads[k])
-                outs = [opt_update(p, preprocess(g), s, lr, wd,
-                                   num_update)
-                        for p, g, s in zip(flat_p, flat_g, opt_state[k])]
-                new_params[k] = jax.tree_util.tree_unflatten(
-                    treedef, [o[0] for o in outs])
-                new_state[k] = [o[1] for o in outs]
-            return new_params, new_state, loss
-
-        return jax.jit(step, donate_argnums=(0, 1))
+        return self._jit_update(loss_and_grads)
 
     def schedule_occupancy(self):
         """Measured schedule occupancy (bubble fraction etc.) of the
@@ -580,8 +569,8 @@ class GPipeTrainer:
                               P("dp") if "dp" in self.mesh.axis_names
                               and self.dp > 1 else P())), batch)
         self.params, self.opt_state, loss = self._jit_step(
-            self.params, self.opt_state, batch_dev, jnp.float32(lr),
-            jnp.float32(opt.wd), jnp.int32(self.num_update))
+            self.params, self.opt_state, batch_dev, _np.float32(lr),
+            _np.float32(opt.wd), _np.int32(self.num_update))
         return float(loss)
 
     # -- checkpoint / resume (same orbax layout as ShardedTrainer) ----

@@ -33,15 +33,14 @@ __all__ = ["ShardedTrainer", "ShardedPredictor"]
 def _abstractify(a):
     """ShapeDtypeStruct (with sharding when present) for jit.lower().
 
-    Single-device shardings (the uncommitted rng key, host scalars) are
-    dropped: baking them in would make lower() reject the mix with
-    mesh-sharded arguments that the real dispatch accepts."""
+    Single-device shardings (the uncommitted rng key) are dropped:
+    baking them in would make lower() reject the mix with mesh-sharded
+    arguments that the real dispatch accepts.  Host scalars have none."""
     from jax.sharding import SingleDeviceSharding
     sh = getattr(a, "sharding", None)
     if sh is not None and not isinstance(sh, SingleDeviceSharding):
         return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sh)
-    a = jnp.asarray(a)
-    return jax.ShapeDtypeStruct(a.shape, a.dtype)
+    return jax.ShapeDtypeStruct(_np.shape(a), a.dtype)
 
 
 def _place_batch(batch, sharding_fn):
@@ -71,8 +70,7 @@ class ShardedTrainer(object):
 
     def __init__(self, symbol, optimizer, mesh, data_names=("data",),
                  label_names=("softmax_label",), rules=None, seq_axis=None,
-                 donate=True, compute_dtype=None, remat=False,
-                 cast_exempt=(), zero1=False, fsdp=False, sentinel=None,
+                 compute_dtype=None, remat=False, cast_exempt=(), zero1=False, fsdp=False, sentinel=None,
                  loss_scale_init=2.0 ** 15, loss_scale_growth=200,
                  step_timeout_s=None):
         self.symbol = symbol
@@ -119,7 +117,6 @@ class ShardedTrainer(object):
         # step watchdog timeout (None = env MXTPU_STEP_TIMEOUT_S at call
         # time, so a launcher can arm it without touching user code)
         self.step_timeout_s = step_timeout_s
-        self._donate = bool(donate)
         # allreduce-over-backward: chain per-bucket optimization
         # barriers through the traced grads (reverse-topo, ~MXTPU_
         # BUCKET_MB each) so XLA emits one collective per bucket as its
@@ -128,13 +125,12 @@ class ShardedTrainer(object):
         self._bucket_grads = _overlap.bucket_bytes() > 0 \
             and self.mesh.size > 1
         # fused optimizer sweep (MXTPU_FUSED_OPT): replace the per-leaf
-        # update tree-map with one bucketed flatten/update/unflatten —
+        # update loop with one bucketed flatten/update/unflatten —
         # bit-identical, elementwise optimizers only.  The Pallas sweep
         # ('kernel') is a single-device program; on a multi-device mesh
         # it degrades to the fused XLA sweep ('1'), which GSPMD
         # partitions like any other elementwise computation.
         from ..kernels import fused_opt as _fused
-        self._fused_mod = _fused
         self._fused_opt = _fused.fused_opt_mode() \
             if _fused.supports_fused(optimizer) else ""
         if self._fused_opt == "kernel" and self.mesh.size > 1:
@@ -145,14 +141,14 @@ class ShardedTrainer(object):
         self.param_names = [n for n in self._arg_names
                             if n not in self.data_names
                             and n not in self.label_names]
-        from ..executor import _build_program, zero_cotangent
+        from ..executor import _build_program
+        from ..train_step import (apply_updates, cast_each, compute_cast,
+                                  loss_and_grads, preprocess_grads)
         program = _build_program(symbol, {})
         self._trace = program.trace
         self._needs_rng = program.needs_rng
         self.num_update = 0
 
-        opt_update = optimizer.update_fn
-        preprocess = optimizer._preprocess_grad
         trace = self._trace
         if self.remat:
             base_trace = trace
@@ -160,157 +156,70 @@ class ShardedTrainer(object):
             def trace(args, aux, rng, is_train):
                 return jax.checkpoint(
                     lambda a: base_trace(a, aux, rng, is_train))(args)
-        cdt = self.compute_dtype
-        # integer-valued inputs must never be cast to bf16: bf16 represents
-        # integers exactly only up to 256, so class labels and Embedding
-        # vocab ids above that would silently round to the wrong id.
-        # Exempt labels, caller-listed names, and any variable feeding an
-        # Embedding's id slot (detected from the graph).
-        exempt = set(self.label_names) | set(cast_exempt)
-        for node in symbol._topo():
-            if node.op is not None \
-                    and getattr(node.op, "op_name", "") == "Embedding":
-                src, _ = node.inputs[0]
-                if src.is_variable:
-                    exempt.add(src.name)
-        self._cast_exempt = frozenset(exempt)
-        exempt_keys = self._cast_exempt
-
-        def _to_compute(tree):
-            if cdt is None:
-                return tree
-            return jax.tree_util.tree_map(
-                lambda a: a.astype(cdt)
-                if jnp.issubdtype(a.dtype, jnp.floating) else a, tree)
-
-        def _batch_to_compute(batch):
-            if cdt is None:
-                return batch
-            return {k: (v if k in exempt_keys else _to_compute(v))
-                    for k, v in batch.items()}
-
-        def train_step(params, opt_state, aux, batch, rng, lr, wd, t):
-            """One fused step: fwd + bwd + psum(grad) + update."""
-            def run(p):
-                args = dict(_to_compute(p))
-                args.update(_batch_to_compute(batch))
-                outs, aux_out = trace(args, _to_compute(aux), rng, True)
-                if cdt is not None:  # aux (bn stats) stored f32
-                    aux_out = {k: v.astype(aux[k].dtype)
-                               for k, v in aux_out.items()}
-                return outs, aux_out
-
-            (outs, aux_out), vjp_fn = jax.vjp(run, params)
-            ones = [jnp.ones_like(o) for o in outs]
-            grads = vjp_fn((ones, zero_cotangent(aux_out)))[0]
-            if self._bucket_grads:
-                grads = _overlap.interleave_grad_buckets(grads)
-
-            new_params = {}
-            new_opt_state = {}
-            if self._fused_opt:
-                fused_w, fused_s = self._fused_mod.fused_apply(
-                    optimizer, params, grads, opt_state, lr, wd, t,
-                    mode=self._fused_opt, preprocess=preprocess)
-                leaf_iter = ((n, fused_w[n], fused_s[n]) for n in params)
-            else:
-                def _leafwise():
-                    for name in params:
-                        g = preprocess(grads[name])
-                        yield (name,) + opt_update(
-                            params[name], g, opt_state.get(name), lr, wd, t)
-                leaf_iter = _leafwise()
-            for name, w, s in leaf_iter:
-                if self.zero1:
-                    # pin layouts: state stays dp-sharded, weights come
-                    # back replicated (XLA inserts the all-gather) — the
-                    # ZeRO-1 contract
-                    w = jax.lax.with_sharding_constraint(
-                        w, self.param_sharding(name, w.shape))
-                    if s is not None:
-                        s = jax.tree_util.tree_map(
-                            lambda a: jax.lax.with_sharding_constraint(
-                                a, self.opt_state_sharding(name, a.shape)),
-                            s)
-                new_params[name] = w
-                if s is not None:
-                    new_opt_state[name] = s
-            return new_params, new_opt_state, aux_out, outs
+        cast = compute_cast(symbol, self.compute_dtype, self.label_names,
+                            cast_exempt)
+        self._cast_exempt = cast.exempt
 
         growth = jnp.int32(self._loss_scale_growth)
         min_scale, max_scale = jnp.float32(1.0), jnp.float32(2.0 ** 24)
 
-        def train_step_sentinel(params, opt_state, aux, batch, rng, lr,
-                                wd, t, sstate):
-            """train_step + the compiled numeric gate: check every
-            gradient finite and WHERE the update —
-            a non-finite step keeps the old params/state/aux, halves
-            the loss scale, and bumps the skip counter, all without a
-            host round-trip (the sentinel contract, docs/resilience.md)."""
-            def run(p):
-                args = dict(_to_compute(p))
-                args.update(_batch_to_compute(batch))
-                outs, aux_out = trace(args, _to_compute(aux), rng, True)
-                if cdt is not None:
-                    aux_out = {k: v.astype(aux[k].dtype)
-                               for k, v in aux_out.items()}
-                return outs, aux_out
+        def train_step(params, opt_state, aux, batch, rng, lr, wd, t,
+                       sstate=None):
+            """One fused step: fwd + bwd + psum(grad) + update.
 
-            # NOTE on the loss scale: the built-in loss heads keep the
-            # reference's backward semantics (SoftmaxOutput bwd =
-            # p - onehot, head gradient IGNORED unless out_grad=True),
-            # so a scaled cotangent seed would not reach the gradients
-            # — the gate therefore checks the TRUE grads, and the
-            # dynamic scale is pure backoff state: halved on a bad
-            # step, grown after good ones, exported via
-            # sentinel_stats() for losses that do consume it
-            # (out_grad=True heads, custom grad_scale).
-            scale = sstate["scale"]
-            (outs, aux_out), vjp_fn = jax.vjp(run, params)
-            ones = [jnp.ones_like(o) for o in outs]
-            grads = vjp_fn((ones, zero_cotangent(aux_out)))[0]
+            With ``sstate`` (the sentinel's state) the step is gated on
+            every gradient being finite: a step that is not keeps the old
+            params/state/aux, halves the loss scale and bumps the skip
+            counter, all without a host round-trip (the sentinel
+            contract, docs/resilience.md)."""
+            outs, aux_out, grads = loss_and_grads(trace, cast, params, batch,
+                                                  aux, rng)
             if self._bucket_grads:
                 grads = _overlap.interleave_grad_buckets(grads)
+            grads = preprocess_grads(optimizer, grads)
+            new_params, new_opt_state = apply_updates(
+                optimizer, params, grads, opt_state, lr, wd, t,
+                fused=self._fused_opt)
+            if sstate is not None:
+                # NOTE on the loss scale: the built-in loss heads keep the
+                # reference's backward semantics (SoftmaxOutput bwd =
+                # p - onehot, head gradient IGNORED unless out_grad=True),
+                # so a scaled cotangent seed would not reach the gradients
+                # — the gate therefore checks the TRUE grads, and the
+                # dynamic scale is pure backoff state: halved on a bad
+                # step, grown after good ones, exported via
+                # sentinel_stats() for losses that do consume it
+                # (out_grad=True heads, custom grad_scale).
+                finite = jnp.bool_(True)
+                for name in params:
+                    finite = jnp.logical_and(
+                        finite, jnp.all(jnp.isfinite(grads[name])))
 
-            gs = {name: preprocess(grads[name]) for name in params}
-            finite = jnp.bool_(True)
-            for name in params:
-                finite = jnp.logical_and(
-                    finite, jnp.all(jnp.isfinite(gs[name])))
+                def keep_old(new, old):
+                    return jax.tree_util.tree_map(
+                        lambda n, o: jnp.where(finite, n, o), new, old)
 
-            new_params = {}
-            new_opt_state = {}
-            if self._fused_opt:
-                # gs is already preprocessed (the gate checks the true
-                # grads), so no preprocess hook here
-                fused_w, fused_s = self._fused_mod.fused_apply(
-                    optimizer, params, gs, opt_state, lr, wd, t,
-                    mode=self._fused_opt)
-                leaf_iter = ((n, fused_w[n], fused_s[n]) for n in params)
-            else:
-                leaf_iter = ((name,) + opt_update(
-                    params[name], gs[name], opt_state.get(name), lr, wd, t)
-                    for name in params)
-            for name, w, s in leaf_iter:
-                w = jnp.where(finite, w, params[name])
-                if s is not None:
-                    s = jax.tree_util.tree_map(
-                        lambda new, old: jnp.where(finite, new, old),
-                        s, opt_state[name])
-                if self.zero1:
-                    w = jax.lax.with_sharding_constraint(
-                        w, self.param_sharding(name, w.shape))
-                    if s is not None:
-                        s = jax.tree_util.tree_map(
-                            lambda a: jax.lax.with_sharding_constraint(
-                                a, self.opt_state_sharding(name, a.shape)),
-                            s)
-                new_params[name] = w
-                if s is not None:
-                    new_opt_state[name] = s
-            aux_out = jax.tree_util.tree_map(
-                lambda new, old: jnp.where(finite, new, old), aux_out, aux)
+                new_params = keep_old(new_params, params)
+                new_opt_state = keep_old(
+                    new_opt_state, {n: opt_state[n] for n in new_opt_state})
+                aux_out = keep_old(aux_out, aux)
+            if self.zero1:
+                # pin layouts: state stays dp-sharded, weights come back
+                # replicated (XLA inserts the all-gather) — the ZeRO-1
+                # contract
+                new_params = {
+                    n: jax.lax.with_sharding_constraint(
+                        w, self.param_sharding(n, w.shape))
+                    for n, w in new_params.items()}
+                new_opt_state = {
+                    n: jax.tree_util.tree_map(
+                        lambda a, _n=n: jax.lax.with_sharding_constraint(
+                            a, self.opt_state_sharding(_n, a.shape)), s)
+                    for n, s in new_opt_state.items()}
+            if sstate is None:
+                return new_params, new_opt_state, aux_out, outs
 
+            scale = sstate["scale"]
             good = jnp.where(finite, sstate["good_steps"] + 1,
                              jnp.int32(0))
             grow = good >= growth
@@ -328,14 +237,11 @@ class ShardedTrainer(object):
             }
             return new_params, new_opt_state, aux_out, outs, new_sstate
 
-        if self.sentinel:
-            donate_argnums = (0, 1, 2, 8) if donate else ()
-            self._jit_step = jax.jit(train_step_sentinel,
-                                     donate_argnums=donate_argnums)
-        else:
-            donate_argnums = (0, 1, 2) if donate else ()
-            self._jit_step = jax.jit(train_step,
-                                     donate_argnums=donate_argnums)
+        # everything the step replaces is donated (the sentinel's state
+        # with it): in-place parameter updates
+        self._jit_step = jax.jit(
+            train_step,
+            donate_argnums=(0, 1, 2, 8) if self.sentinel else (0, 1, 2))
         self._abstract_args = None   # ShapeDtypeStructs of the step args
         self._lowered = None         # cached jax.stages.Lowered
         self._compiled_step = None   # cached jax.stages.Compiled of it
@@ -344,9 +250,9 @@ class ShardedTrainer(object):
         _overlap.enable_persistent_cache()
 
         def eval_step(params, aux, batch, rng):
-            args = dict(_to_compute(params))
-            args.update(_batch_to_compute(batch))
-            outs, _ = trace(args, _to_compute(aux), rng, False)
+            args = cast_each(cast, params)
+            args.update(cast_each(cast, batch))
+            outs, _ = trace(args, cast_each(cast, aux), rng, False)
             return outs
 
         self._jit_eval = jax.jit(eval_step)
@@ -673,8 +579,8 @@ class ShardedTrainer(object):
             lr = opt.lr
         if rng is None:
             from .. import random as _random
-            rng = _random.next_key() if self._needs_rng \
-                else jax.random.PRNGKey(0)
+            from ..executor import _zero_key
+            rng = _random.next_key() if self._needs_rng else _zero_key()
 
         from .. import resilience as _resilience
         inj = _resilience.injector()
@@ -686,9 +592,10 @@ class ShardedTrainer(object):
                     if name in batch:
                         batch[name] = _resilience.poison_nan(batch[name])
 
+        # host scalars ride with the call, as in Executor.fused_step
         step_args = (params, opt_state, aux, batch, rng,
-                     jnp.float32(lr), jnp.float32(opt.wd),
-                     jnp.int32(self.num_update))
+                     _np.float32(lr), _np.float32(opt.wd),
+                     _np.int32(self.num_update))
         if self.sentinel:
             if self._sentinel_state is None:
                 self._sentinel_state = self._init_sentinel_state()
@@ -783,8 +690,7 @@ class ShardedTrainer(object):
             tuple(repr(d) for d in self.mesh.devices.flat),
             _overlap.rules_fingerprint(self.rules),
             str(self.compute_dtype), self.seq_axis, self.remat,
-            self.zero1, self.fsdp, self.sentinel, self._donate,
-            self._bucket_grads, self._fused_opt,
+            self.zero1, self.fsdp, self.sentinel, self._bucket_grads, self._fused_opt,
             sorted(self._cast_exempt),
             _overlap.optimizer_fingerprint(self.optimizer),
             jax.__version__)
@@ -932,26 +838,20 @@ class ShardedPredictor(object):
             self.aux[name] = put_replicated_host(
                 host, NamedSharding(mesh, P()))
 
-        cdt = self.compute_dtype
-
-        def _cast(tree):
-            if cdt is None:
-                return tree
-            return jax.tree_util.tree_map(
-                lambda a: a.astype(cdt)
-                if jnp.issubdtype(a.dtype, jnp.floating) else a, tree)
+        from ..train_step import cast_each, compute_cast
+        cast = compute_cast(symbol, self.compute_dtype, self.label_names)
 
         def forward(params, aux, batch, rng):
-            args = dict(_cast(params))
+            args = cast_each(cast, params)
             # loss-layer label slots bind as zeros (predict contract)
             for n in self._arg_names:
                 if n not in args and n not in batch:
                     shape = self._label_shape(n, batch)
                     args[n] = jnp.zeros(shape, jnp.float32)
-            args.update({k: _cast(v) if k not in self.label_names
-                         else v for k, v in batch.items()})
-            outs, _ = self._trace(args, _cast(aux), rng, False)
-            return [o.astype(jnp.float32) if cdt is not None
+            args.update(cast_each(cast, batch))
+            outs, _ = self._trace(args, cast_each(cast, aux), rng, False)
+            return [o.astype(jnp.float32)
+                    if self.compute_dtype is not None
                     and jnp.issubdtype(o.dtype, jnp.floating) else o
                     for o in outs]
 

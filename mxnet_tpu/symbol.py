@@ -509,7 +509,8 @@ class _GradProp:
     def forward(self, inputs, aux, is_train, rng):
         import jax
         import jax.numpy as jnp
-        from .executor import _zero_key, zero_cotangent
+        from .executor import _zero_key
+        from .train_step import zero_cotangent
         n = len(self._base_args)
         arg_vals = dict(zip(self._base_args, inputs[:n]))
         head_grads = list(inputs[n:])

@@ -254,6 +254,136 @@ def test_bf16_embedding_ids_stay_exact():
     assert emb_delta[996] == 0 and emb_delta[992] == 0
 
 
+# ----------------------------------------------------------------------
+# one cast rule (train_step.compute_cast) behind every front end
+# ----------------------------------------------------------------------
+_CAST_V, _CAST_D = 1200, 8
+
+
+def _embedding_net():
+    """Ids and labels of 999 are 1000 in bfloat16: a front end that casts
+    either looks up, or credits, row 1000."""
+    data = mx.sym.Variable("data")
+    e = mx.sym.Embedding(data, input_dim=_CAST_V, output_dim=_CAST_D,
+                         name="embed")
+    out = mx.sym.FullyConnected(e, num_hidden=_CAST_V, name="head")
+    return mx.sym.SoftmaxOutput(out, name="softmax")
+
+
+def _cast_batch():
+    ids = np.full((4,), 999.0, np.float32)
+    return {"data": ids, "softmax_label": ids.copy()}
+
+
+def _one_step_module(monkeypatch):
+    monkeypatch.setenv("MXNET_COMPUTE_DTYPE", "bfloat16")
+    batch = _cast_batch()
+    it = mx.io.NDArrayIter(batch["data"], batch["softmax_label"],
+                           batch_size=4, label_name="softmax_label")
+    mod = mx.mod.Module(_embedding_net(), context=mx.cpu())
+    mod.bind(data_shapes=it.provide_data, label_shapes=it.provide_label)
+    mod.init_params(mx.init.Uniform(0.1))
+    mod.init_optimizer(optimizer="sgd",
+                       optimizer_params={"learning_rate": 1.0})
+    before = {k: v.asnumpy() for k, v in mod.get_params()[0].items()}
+    mod.forward_backward(next(iter(it)))
+    mod.update()
+    assert mod._exec_group.execs[0]._n_fused_step == 1
+    after = {k: v.asnumpy() for k, v in mod.get_params()[0].items()}
+    return (after["embed_weight"] - before["embed_weight"],
+            after["head_bias"] - before["head_bias"])
+
+
+def _one_step_trainer(monkeypatch):
+    import jax
+    tr = parallel.ShardedTrainer(
+        _embedding_net(), mx.optimizer.create("sgd", learning_rate=1.0),
+        parallel.make_mesh(jax.devices()[:1], dp=1),
+        compute_dtype="bfloat16")
+    assert tr._cast_exempt == {"data", "softmax_label"}
+    params, opt_state, aux = tr.init_params(
+        {"data": (4,)}, label_shapes={"softmax_label": (4,)})
+    before = {k: np.asarray(v) for k, v in params.items()}
+    params, _, _, _ = tr.step(params, opt_state, aux,
+                              tr.shard_batch(_cast_batch()))
+    return (np.asarray(params["embed_weight"]) - before["embed_weight"],
+            np.asarray(params["head_bias"]) - before["head_bias"])
+
+
+def _one_step_gpipe(monkeypatch):
+    import jax
+    import jax.numpy as jnp
+    from mxnet_tpu.parallel.pipeline import GPipeTrainer
+
+    def embed(ep, batch):
+        return jnp.take(ep["table"], batch["data"].astype(jnp.int32), axis=0)
+
+    def block(lp, h):
+        return h + jnp.tanh(h @ lp["w"])
+
+    def head_loss(hp, h, batch):
+        logp = jax.nn.log_softmax(h @ hp["w"] + hp["b"])
+        labels = batch["softmax_label"].astype(jnp.int32)
+        return -jnp.mean(jnp.take_along_axis(logp, labels[:, None], axis=1))
+
+    rs = np.random.RandomState(0)
+    params = {
+        "embed": {"table": rs.randn(_CAST_V, _CAST_D).astype(np.float32)},
+        "layers": {"w": rs.randn(2, _CAST_D, _CAST_D).astype(np.float32)
+                   * 0.1},
+        "head": {"w": rs.randn(_CAST_D, _CAST_V).astype(np.float32) * 0.1,
+                 "b": np.zeros((_CAST_V,), np.float32)}}
+    tr = GPipeTrainer(embed, block, head_loss, params,
+                      parallel.make_mesh(jax.devices()[:2], pp=2),
+                      mx.optimizer.create("sgd", learning_rate=1.0),
+                      num_microbatches=2)
+    tr.step(_cast_batch())
+    return (np.asarray(tr.params["embed"]["table"])
+            - params["embed"]["table"],
+            np.asarray(tr.params["head"]["b"]) - params["head"]["b"])
+
+
+@pytest.mark.parametrize("one_step", [_one_step_module, _one_step_trainer,
+                                      _one_step_gpipe],
+                         ids=["module", "trainer", "gpipe"])
+def test_front_ends_exempt_ids_and_labels_from_the_cast(one_step,
+                                                        monkeypatch):
+    """``Module``'s fused step, ``ShardedTrainer.step`` and
+    ``GPipeTrainer.step`` leave the same inputs in their stored dtype
+    under bfloat16 compute: what feeds an Embedding's id slot, and the
+    label."""
+    embed_delta, bias_delta = one_step(monkeypatch)
+    rows = np.abs(embed_delta).sum(axis=1)
+    assert rows[999] > 0 and rows[1000] == 0        # looked up row 999
+    assert np.count_nonzero(rows) == 1
+    assert bias_delta[999] > 0 > bias_delta[1000]   # credited class 999
+
+
+def test_sharded_predictor_bf16_keeps_embedding_ids_exact():
+    """``ShardedPredictor(compute_dtype="bfloat16")`` looks up the rows
+    the float32 predictor does: ids above 256 are not cast with the
+    data (they were: 999 read row 1000)."""
+    import jax
+    rs = np.random.RandomState(1)
+    arg_params = {
+        "embed_weight": rs.randn(_CAST_V, _CAST_D).astype(np.float32),
+        "head_weight": rs.randn(_CAST_V, _CAST_D).astype(np.float32) * 0.5,
+        "head_bias": np.zeros((_CAST_V,), np.float32)}
+    ids = np.array([999.0, 257.0, 1001.0, 3.0], np.float32)
+    mesh = parallel.make_mesh(jax.devices()[:2], dp=2)
+    f32 = parallel.ShardedPredictor(_embedding_net(), mesh, arg_params)
+    bf16 = parallel.ShardedPredictor(_embedding_net(), mesh, arg_params,
+                                     compute_dtype="bfloat16")
+    want = np.log(f32.predict({"data": ids})[0])
+    got = np.log(bf16.predict({"data": ids})[0])
+    # bfloat16 products move a log-probability by hundredths; another
+    # row's (ids as bfloat16 would read: 1000, 256, 1000, 3) by ones
+    rounded = np.log(f32.predict(
+        {"data": np.array([1000.0, 256.0, 1000.0, 3.0], np.float32)})[0])
+    assert np.abs(rounded - want)[:3].max() > 1.0
+    np.testing.assert_allclose(got, want, atol=0.1)
+
+
 def test_zero1_optimizer_state_sharding():
     """ZeRO-1 (beyond-reference): momentum state lives dp-sharded (1/dp
     per rank), parameters stay replicated, and training matches the

@@ -538,3 +538,77 @@ def test_idle_gaps_reads_a_recorded_capture(idle_gaps_tool, capsys):
     assert r["named_share"] == pytest.approx(0.0)
     assert idle_gaps_tool.main([small]) == 0
     assert "(no host span)" in capsys.readouterr().out
+
+
+# ----------------------------------------------------------------------
+# what a step costs the host beside its one dispatch: no other program
+# ----------------------------------------------------------------------
+def _stepper_trainer():
+    from mxnet_tpu import parallel
+    opt = mx.optimizer.create("sgd", learning_rate=0.1, momentum=0.9,
+                              wd=1e-4)
+    tr = parallel.ShardedTrainer(_net(), opt, parallel.auto_mesh())
+    mx.random.seed(0)
+    state = list(tr.init_params({"data": (16, 8)},
+                                label_shapes={"softmax_label": (16,)}))
+    rng = np.random.RandomState(0)
+    batch = tr.shard_batch(
+        {"data": rng.rand(16, 8).astype(np.float32),
+         "softmax_label": (rng.rand(16) * 4).astype(np.float32)})
+
+    def step():
+        state[:] = tr.step(*state, batch)[:3]
+    return step, "train_step"
+
+
+def _stepper_module():
+    mod = mx.mod.Module(_net(), context=mx.cpu())
+    it = _iter(batches=1, batch_size=16)
+    mod.bind(data_shapes=it.provide_data, label_shapes=it.provide_label)
+    mod.init_params(mx.init.Uniform(0.1))
+    mod.init_optimizer(optimizer="sgd", optimizer_params={
+        "learning_rate": 0.1, "momentum": 0.9, "wd": 1e-4})
+    batch = next(iter(it))
+    exe = mod._exec_group.execs[0]
+
+    def step():
+        n = exe._n_fused_step
+        mod.forward_backward(batch)
+        mod.update()
+        assert exe._n_fused_step == n + 1
+    return step, "step"
+
+
+@pytest.mark.parametrize("make", [_stepper_trainer, _stepper_module],
+                         ids=["ShardedTrainer.step", "Executor.fused_step"])
+def test_a_step_compiles_one_program_and_none_for_its_scalars(make):
+    """``lr``, ``wd`` and the update count ride with the jitted call as
+    host numbers: with every cache emptied, three steps compile the step
+    and nothing else — no ``convert_element_type`` (what ``jnp.float32(lr)``
+    is: a device program and a transfer of its own, every step) and no
+    ``PRNGKey`` for a graph that draws nothing."""
+    import jax
+
+    class Names(logging.Handler):
+        def __init__(self):
+            super().__init__()
+            self.names = []
+
+        def emit(self, record):
+            msg = record.getMessage()
+            if msg.startswith("Compiling "):
+                self.names.append(msg.split()[1])
+
+    step, name = make()
+    step()                  # binds, and makes what a process makes once
+    seen = Names()
+    logger = logging.getLogger("jax")
+    logger.addHandler(seen)
+    jax.clear_caches()
+    try:
+        with jax.log_compiles():
+            for _ in range(3):
+                step()
+    finally:
+        logger.removeHandler(seen)
+    assert seen.names == ["jit(%s)" % name]

@@ -365,19 +365,21 @@ def test_sentinel_grad_norm_module_structure():
 # ----------------------------------------------------------------------
 # fused trainer: compiled sentinel gate + injected faults
 # ----------------------------------------------------------------------
-def _mlp():
+def _mlp(bn=False):
     data = mx.sym.Variable("data")
     fc1 = mx.sym.FullyConnected(data, num_hidden=8, name="fc1")
+    if bn:      # auxiliary states for the sentinel's gate to keep
+        fc1 = mx.sym.BatchNorm(fc1, name="bn")
     act = mx.sym.Activation(fc1, act_type="relu")
     fc2 = mx.sym.FullyConnected(act, num_hidden=4, name="fc2")
     return mx.sym.SoftmaxOutput(fc2, name="softmax")
 
 
-def _trainer(sentinel=False, step_timeout_s=None, lr=0.5):
+def _trainer(sentinel=False, step_timeout_s=None, lr=0.5, bn=False):
     mesh = parallel.make_mesh(jax.devices()[:2], dp=2)
     opt = mx.optimizer.create("sgd", learning_rate=lr, momentum=0.9,
                               rescale_grad=1.0 / 16)
-    tr = parallel.ShardedTrainer(_mlp(), opt, mesh, sentinel=sentinel,
+    tr = parallel.ShardedTrainer(_mlp(bn), opt, mesh, sentinel=sentinel,
                                  step_timeout_s=step_timeout_s)
     mx.random.seed(3)
     params, opt_state, aux = tr.init_params(
@@ -393,31 +395,45 @@ def _host(params):
     return {k: np.asarray(jax.device_get(v)) for k, v in params.items()}
 
 
-def test_trainer_sentinel_skips_injected_nan_step(monkeypatch):
+@pytest.mark.parametrize("fused", ["", "1"])
+def test_trainer_sentinel_skips_injected_nan_step(monkeypatch, fused):
     """Acceptance (a): NaN injected at step k -> that step is skipped
-    (params unchanged), loss scale halves, training continues."""
-    tr, params, opt_state, aux, batch = _trainer(sentinel=True)
+    (params, optimizer state and auxiliary states bit-identical), loss
+    scale halves, training continues — the one gate behind the per-leaf
+    update and behind ``MXTPU_FUSED_OPT=1``'s sweep."""
+    monkeypatch.setenv("MXTPU_FUSED_OPT", fused)
+    tr, params, opt_state, aux, batch = _trainer(sentinel=True, bn=True)
+    assert tr._fused_opt == fused
     _arm(monkeypatch, "step=3:kind=nan")
+
+    def host(*trees):
+        return [np.asarray(a) for a in jax.tree_util.tree_leaves(trees)]
 
     scale0 = None
     for step in range(1, 6):
-        before = _host(params)
+        before = host(params, opt_state, aux)
+        n_params = len(params)
         params, opt_state, aux, outs = tr.step(params, opt_state, aux,
                                                batch)
-        after = _host(params)
+        after = host(params, opt_state, aux)
         stats = tr.sentinel_stats()
         if step == 1:
             scale0 = stats["scale"]
         if step == 3:
-            for name in before:
-                assert np.array_equal(before[name], after[name]), \
-                    "poisoned step %d must not move %r" % (step, name)
+            assert len(before) == len(after) == 2 * n_params + len(aux)
+            for b, a in zip(before, after):
+                assert np.array_equal(b, a), \
+                    "poisoned step %d must move nothing" % step
+            assert not np.isfinite(np.asarray(outs[0])).all()
             assert stats["skipped"] == 1
             assert stats["scale"] == scale0 / 2
         else:
-            moved = any(not np.array_equal(before[n], after[n])
-                        for n in before)
-            assert moved, "clean step %d should update params" % step
+            # (a bias ahead of BatchNorm has no gradient: not every leaf)
+            moved = [not np.array_equal(b, a)
+                     for b, a in zip(before, after)]
+            assert any(moved[:n_params]) and any(moved[-len(aux):]) \
+                and any(moved[n_params:-len(aux)]), \
+                "clean step %d should update params, state, aux" % step
             assert np.isfinite(np.asarray(outs[0])).all()
     stats = tr.sentinel_stats()
     assert stats["skipped"] == 1 and stats["last_good"] == 5
