@@ -480,7 +480,7 @@ def phase_kernels(env, cfg, lm_params):
         with jax.default_matmul_precision("highest"):
             return jax.jit(fn)(*args)
 
-    # flash-attention forward (+ the blockwise backward its stats feed):
+    # flash-attention forward (+ the backward kernel its stats feed):
     # 64-wide heads in both dtypes, and latent attention's shape — q and k
     # 192 wide, v 128, 8,192 keys — in bfloat16.  The reference goes by
     # query blocks, each against the keys up to its end, so that 8,192

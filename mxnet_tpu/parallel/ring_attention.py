@@ -5,17 +5,19 @@ sequence parallelism": the reference only offers bucketing and pipeline
 LSTM) — it is the TPU-native capability that replaces those workarounds
 for long sequences:
 
-- ``flash_attention``: fused online-softmax attention as a Pallas TPU
-  kernel (MXU matmuls, no (seq, seq) materialization in HBM) where the
-  computation is placed on a TPU; the jnp reference implementation
-  anywhere else, so tests/CPU paths stay exact.  The kernel multiplies
-  q, k, v in the dtype they arrive in (bfloat16 operands are not
-  widened; float32 operands get the product they always got, at
-  Mosaic's default precision), sums every product in float32, rounds p
-  to v's dtype for p·v only, and keeps the softmax state and the saved
-  logsumexp in float32.
-  Under ``causal`` a query block reads the key blocks up to the
-  diagonal and no further: masked blocks are skipped, not computed.
+- ``flash_attention``: fused online-softmax attention as a pair of
+  Pallas TPU kernels, forward and backward (MXU matmuls, no (seq, seq)
+  materialization in HBM in either) where the computation is placed on
+  a TPU; the jnp reference implementation anywhere else, so tests/CPU
+  paths stay exact.  Both kernels multiply q, k, v (and do) in the
+  dtype they arrive in (bfloat16 operands are not widened; float32
+  operands get the product they always got, at Mosaic's default
+  precision), sum every product in float32, round p (and ds) to the
+  operands' dtype only for the products that consume them, and keep the
+  softmax state, the saved logsumexp and the gradients' accumulators in
+  float32.  Under ``causal`` a query block meets the key blocks up to
+  the diagonal and no further, in both directions: masked blocks are
+  skipped, not computed.
 - ``ring_attention``: blockwise attention over a ``Mesh`` axis ("sp"):
   each device holds a sequence chunk of q/k/v; k/v chunks rotate around
   the ring via ``lax.ppermute`` while the online-softmax state (o, m, l)
@@ -129,6 +131,15 @@ def _scale_folds_into(scale, dtype):
     return dtype == jnp.float32 or math.frexp(scale)[0] == 0.5
 
 
+def _mask_past_diagonal(s, key_lead):
+    """A keys-by-queries score tile with every key its query must not see
+    set to ``_NEG_INF``; ``key_lead`` is the position of the tile's first
+    key less that of its first query."""
+    kpos = lax.broadcasted_iota(jnp.int32, s.shape, 0)
+    qpos = lax.broadcasted_iota(jnp.int32, s.shape, 1)
+    return jnp.where(qpos - kpos >= key_lead, s, _NEG_INF)
+
+
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_k, causal,
                   scale, seq_k):
     """Grid: (batch*heads, q_blocks).  One q block against the key
@@ -145,8 +156,8 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_k, causal,
     register per 128 queries where a (block_q, 1) column takes one per
     8, and o accumulates as (d_v, block_q) with no lane left empty at
     d_v = 64.  q and k share one width, v and o another (latent
-    attention: 192 against 128).  Outputs the normalized o block and the logsumexp stats
-    (saved for the blockwise backward)."""
+    attention: 192 against 128).  Outputs the normalized o block and the
+    logsumexp stats (saved for the backward kernel)."""
     import jax.experimental.pallas as pl
 
     block_q = q_ref.shape[0]
@@ -168,9 +179,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_k, causal,
         if not fold:
             s = s * scale
         if masked:
-            kpos = lax.broadcasted_iota(jnp.int32, s.shape, 0)
-            qpos = lax.broadcasted_iota(jnp.int32, s.shape, 1)
-            s = jnp.where(qpos - kpos >= start - q_offset, s, _NEG_INF)
+            s = _mask_past_diagonal(s, start - q_offset)
         m_new = jnp.maximum(m, jnp.max(s, axis=0, keepdims=True))
         p = jnp.exp(s - m_new)
         c = jnp.exp(m - m_new)
@@ -203,8 +212,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_k, causal,
                                     (_LSE_ROWS, block_q))
 
 
-# forward block extents, largest first; the last is also the backward's
-# key block where the caller fixes none
+# block extents of both kernels, largest first
 _FLASH_BLOCKS = (512, 256, 128)
 
 
@@ -286,97 +294,185 @@ def _flash_forward_kernel_call(q, k, v, causal, scale, block_q, block_k,
     return out.reshape(B, H, Sq, d_v), lse[:, 0].reshape(B, H, Sq)
 
 
-# the backward's query chunk: sequences longer than this are walked in
-# chunks of it, so that under ``causal`` a key block meets only the
-# queries at or below it
-_BWD_Q_CHUNK = 1024
+def _causal_q_blocks(k_block, block_q, block_k, n_q_blocks):
+    """The mirror of :func:`_causal_k_blocks` for key block ``k_block``:
+    ``(visited, unmasked)`` — query blocks ``[0, visited)`` end before
+    its first key and are never read, ``[visited, unmasked)`` are
+    crossed by the diagonal and need the mask, ``[unmasked,
+    n_q_blocks)`` see every key of the block."""
+    first_key = k_block * block_k
+    clamp = min if isinstance(k_block, int) else jnp.minimum
+    visited = clamp(first_key // block_q, n_q_blocks)
+    unmasked = clamp((first_key + block_k + block_q - 2) // block_q,
+                     n_q_blocks)
+    return visited, unmasked
 
 
-def _flash_backward_blockwise(q, k, v, o, lse, do, causal, scale, block_k):
-    """Flash-attention backward: blockwise recompute from the saved
-    logsumexp stats — per-iteration footprint is O(Sq · block_k), never
-    the full (Sq, Sk) score matrix (the training-path memory guarantee
-    the fused forward alone does not give).  q and k share one width, v,
-    o and do another.
+def _flash_backward_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                           dq_ref, dk_ref, dv_ref, dq_acc, *, block_q,
+                           causal, scale):
+    """Grid: (batch*heads, k_blocks), the key blocks in order.  One key
+    block against the query blocks that can see it: all of them, or
+    under ``causal`` those from the diagonal down
+    (``_causal_q_blocks``), of which only the ones the diagonal crosses
+    are masked.  With p = exp(s·scale − lse) recomputed from the saved
+    logsumexp and delta = rowsum(do ⊙ o):
 
-    Standard identities (p = exp(s·scale − lse)):
-        dv_j = pᵀ @ do
-        ds   = p ⊙ (do @ vᵀ − rowsum(do ⊙ o)) · scale
-        dq  += ds @ k_j,   dk_j = dsᵀ @ q
+        dv_j = pᵀ·do      ds = p ⊙ (do·vᵀ − delta)
+        dk_j = scale · dsᵀ·q      dq += scale · ds·k_j
 
-    A sequence of more than ``_BWD_Q_CHUNK`` queries is walked in chunks
-    of that many, and under ``causal`` a key block starts at the chunk
-    its first key lies in: the chunks above the diagonal hold no visible
-    key and are not computed (half the work at 8,192 queries).  A
-    shorter sequence is one chunk, computed whole.
-    """
-    qf = q.astype(jnp.float32)
-    dof = do.astype(jnp.float32)
-    delta = jnp.sum(dof * o.astype(jnp.float32), axis=-1)   # (B, H, Sq)
-    sq = q.shape[-2]
-    sk = k.shape[-2]
-    n_blocks = sk // block_k
-    chunk = _BWD_Q_CHUNK if sq > _BWD_Q_CHUNK and sq % _BWD_Q_CHUNK == 0 \
-        else sq
-    n_chunks = sq // chunk
+    The tiles are held keys-by-queries like the forward's, (block_k,
+    block_q), so lse and delta are (1, block_q) rows and dv, dk come out
+    of plain products; dq is accumulated transposed, (d, block_q) a
+    query block, in a float32 VMEM scratch that lives across the key
+    blocks of one (batch·head) and is written out, cast once, after the
+    last.  q, k, v, do are multiplied as they come; p and ds are rounded
+    to their dtype for the products that consume them; every sum, the
+    softmax arithmetic and the three accumulators are float32."""
+    import jax.experimental.pallas as pl
 
-    def chunked(x, axis):
-        """``x`` with its query axis split chunk-major: (n_chunks, ...,
-        chunk, ...).  A chunk is then one index of the leading axis, which
-        a loop reads and updates in place."""
-        if n_chunks == 1:
-            return x
-        axis = axis % x.ndim
-        x = x.reshape(x.shape[:axis] + (n_chunks, chunk) + x.shape[axis + 1:])
-        return jnp.moveaxis(x, axis, 0)
+    block_k = k_ref.shape[0]
+    n_q_blocks = q_ref.shape[0] // block_q
+    j = pl.program_id(1)
+    k_offset = j * block_k
+    k = k_ref[...]
+    v = v_ref[...]
+    fold = _scale_folds_into(scale, k.dtype)
+    k_scaled = k * scale if fold else k
 
-    qf, dof = chunked(qf, -2), chunked(dof, -2)
-    lse, delta = chunked(lse, -1), chunked(delta, -1)
+    @pl.when(j == 0)
+    def _():
+        dq_acc[...] = jnp.zeros_like(dq_acc)
 
-    def at(x, j):
-        return x if n_chunks == 1 else x[j]
+    def step(masked, i, carry):
+        dk, dv = carry              # (block_k, d), (block_k, d_v)
+        start = pl.multiple_of(i * block_q, block_q)
+        q = q_ref[pl.ds(start, block_q), :]
+        do = do_ref[pl.ds(start, block_q), :]
+        s = lax.dot_general(k_scaled, q, (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32)
+        if not fold:
+            s = s * scale
+        if masked:
+            s = _mask_past_diagonal(s, k_offset - start)
+        p = jnp.exp(s - lse_ref[i])
+        dv = dv + jnp.dot(p.astype(do.dtype), do,
+                          preferred_element_type=jnp.float32)
+        dp = lax.dot_general(v, do, (((1,), (1,)), ((), ())),
+                             preferred_element_type=jnp.float32)
+        ds = (p * (dp - delta_ref[i])).astype(q.dtype)
+        dk = dk + jnp.dot(ds, q, preferred_element_type=jnp.float32)
+        dq_acc[i] += lax.dot_general(k, ds, (((0,), (0,)), ((), ())),
+                                     preferred_element_type=jnp.float32)
+        return dk, dv
 
-    def body(i, carry):
-        dq, dk, dv = carry
-        kb = lax.dynamic_slice_in_dim(k, i * block_k, block_k,
-                                      axis=-2).astype(jnp.float32)
-        vb = lax.dynamic_slice_in_dim(v, i * block_k, block_k,
-                                      axis=-2).astype(jnp.float32)
+    carry = (jnp.zeros(k_ref.shape, jnp.float32),
+             jnp.zeros(v_ref.shape, jnp.float32))
+    if causal:
+        visited, unmasked = _causal_q_blocks(j, block_q, block_k,
+                                             n_q_blocks)
+        carry = lax.fori_loop(visited, unmasked,
+                              functools.partial(step, True), carry)
+        carry = lax.fori_loop(unmasked, n_q_blocks,
+                              functools.partial(step, False), carry)
+    else:
+        carry = lax.fori_loop(0, n_q_blocks, functools.partial(step, False),
+                              carry)
+    dk, dv = carry
+    dk_ref[...] = (dk * scale).astype(dk_ref.dtype)
+    dv_ref[...] = dv.astype(dv_ref.dtype)
 
-        def against(j, inner):
-            dq, dkb, dvb = inner
-            qc, doc = at(qf, j), at(dof, j)
-            s = jnp.einsum("...qd,...kd->...qk", qc, kb) * scale
-            if causal:
-                qpos = j * chunk + jnp.arange(chunk)[:, None]
-                kpos = i * block_k + jnp.arange(block_k)[None, :]
-                s = jnp.where(qpos >= kpos, s, _NEG_INF)
-            p = jnp.exp(s - at(lse, j)[..., None])
-            dvb = dvb + jnp.einsum("...qk,...qd->...kd", p, doc)
-            dp = jnp.einsum("...qd,...kd->...qk", doc, vb)
-            ds = p * (dp - at(delta, j)[..., None]) * scale
-            dqc = jnp.einsum("...qk,...kd->...qd", ds, kb)
-            dq = dq + dqc if n_chunks == 1 else dq.at[j].add(dqc)
-            dkb = dkb + jnp.einsum("...qk,...qd->...kd", ds, qc)
-            return dq, dkb, dvb
+    @pl.when(j == pl.num_programs(1) - 1)
+    def _():
+        def write(i, _):
+            start = pl.multiple_of(i * block_q, block_q)
+            dq_ref[pl.ds(start, block_q), :] = \
+                (dq_acc[i] * scale).T.astype(dq_ref.dtype)
+            return 0
+        lax.fori_loop(0, n_q_blocks, write, 0)
 
-        inner = (dq, jnp.zeros_like(kb), jnp.zeros_like(vb))
-        if n_chunks == 1:
-            dq, dkb, dvb = against(0, inner)
-        else:
-            first = (i * block_k) // chunk if causal else 0
-            dq, dkb, dvb = lax.fori_loop(first, n_chunks, against, inner)
-        dk = lax.dynamic_update_slice_in_dim(dk, dkb, i * block_k, axis=-2)
-        dv = lax.dynamic_update_slice_in_dim(dv, dvb, i * block_k, axis=-2)
-        return dq, dk, dv
 
-    dq0 = jnp.zeros(qf.shape, jnp.float32)
-    dk0 = jnp.zeros(k.shape, jnp.float32)
-    dv0 = jnp.zeros(v.shape, jnp.float32)
-    dq, dk, dv = lax.fori_loop(0, n_blocks, body, (dq0, dk0, dv0))
-    if n_chunks > 1:
-        dq = jnp.moveaxis(dq, 0, -3).reshape(q.shape)
-    return dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype)
+def _flash_backward_block_layout(bh, sq, sk, d, block_q, block_k, d_v=None):
+    """(block, array) pairs of the backward pallas_call, in q/k/v/do/
+    lse/delta then dq/dk/dv order, and the shape of its float32 dq
+    scratch: shared by the call below and
+    ``flash_backward_kernel_spec``, like :func:`_flash_block_layout`.  q, do and dq are whole per
+    (batch·head), k, v, dk, dv go by key blocks; lse and delta are one
+    (1, block_q) row a query block, indexed by the block."""
+    d_v = d if d_v is None else d_v
+    rows = ((None, sq // block_q, 1, block_q),
+            (bh, sq // block_q, 1, block_q))
+    in_blocks = [
+        ((None, sq, d), (bh, sq, d)),                   # q
+        ((None, block_k, d), (bh, sk, d)),              # k
+        ((None, block_k, d_v), (bh, sk, d_v)),          # v
+        ((None, sq, d_v), (bh, sq, d_v)),               # do
+        rows,                                           # lse
+        rows,                                           # delta
+    ]
+    out_blocks = [
+        ((None, sq, d), (bh, sq, d)),                   # dq
+        ((None, block_k, d), (bh, sk, d)),              # dk
+        ((None, block_k, d_v), (bh, sk, d_v)),          # dv
+    ]
+    return in_blocks, out_blocks, (sq // block_q, d, block_q)
+
+
+def _vmem_bytes(shape, itemsize):
+    """Bytes a VMEM buffer of ``shape`` takes: the last dim padded to
+    128 lanes, the one before it to a 32-bit tile's 8 sublanes."""
+    *lead, rows, lanes = shape
+    rows = -(-rows * itemsize // 32) * 32 // itemsize
+    return math.prod(lead) * rows * (-(-lanes // 128) * 128) * itemsize
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "causal", "scale", "block_q", "block_k", "interpret"))
+def _flash_backward_kernel_call(q, k, v, o, lse, do, causal, scale,
+                                block_q, block_k, interpret):
+    """(dq, dk, dv) of the backward kernel from the forward's residuals.
+    Jitted for the reason the forward call is: a model's layers lower it
+    once."""
+    import jax.experimental.pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    B, H, Sq, D = q.shape
+    sk, d_v = v.shape[-2:]
+    bh, n_q = B * H, Sq // block_q
+    delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
+    operands = (q.reshape(bh, Sq, D), k.reshape(bh, sk, D),
+                v.reshape(bh, sk, d_v), do.reshape(bh, Sq, d_v),
+                lse.reshape(bh, n_q, 1, block_q),
+                delta.reshape(bh, n_q, 1, block_q))
+    ins, outs, acc = _flash_backward_block_layout(bh, Sq, sk, D, block_q,
+                                                  block_k, d_v)
+    # every block twice (the pipeline's two buffers), the scratch, and
+    # room for the (block_k, block_q) float32 tiles between the products
+    vmem = sum(2 * _vmem_bytes(blk[1:], x.dtype.itemsize)
+               for (blk, _arr), x in zip(ins + outs, operands + (q, k, v))) \
+        + _vmem_bytes(acc, 4) + 8 * block_q * block_k * 4 + (4 << 20)
+    kernel = functools.partial(_flash_backward_kernel, block_q=block_q,
+                               causal=causal, scale=scale)
+    whole = lambda b, j: (b, 0, 0)          # noqa: E731
+    by_key = lambda b, j: (b, j, 0)         # noqa: E731
+    rows = lambda b, j: (b, 0, 0, 0)        # noqa: E731
+    dq, dk, dv = pl.pallas_call(
+        kernel,
+        grid=(bh, sk // block_k),
+        in_specs=[pl.BlockSpec(blk, index) for (blk, _arr), index in zip(
+            ins, (whole, by_key, by_key, whole, rows, rows))],
+        out_specs=[pl.BlockSpec(blk, index) for (blk, _arr), index in zip(
+            outs, (whole, by_key, by_key))],
+        out_shape=[jax.ShapeDtypeStruct(arr, x.dtype)
+                   for (_blk, arr), x in zip(outs, (q, k, v))],
+        scratch_shapes=[pltpu.VMEM(acc, jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=int(vmem)),
+        name="flash_backward",
+        interpret=interpret,
+    )(*operands)
+    return dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape)
 
 
 def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
@@ -399,14 +495,19 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
     diagonal crosses; the blocks above it are never read.
 
     Differentiable: the forward runs the fused kernel and saves the
-    logsumexp stats; the backward is the blockwise flash backward
-    (recompute per kv block from the stats — O(Sq·block_k) live memory,
-    never the (Sq, Sk) score matrix), attached via custom_vjp.
+    logsumexp stats; the backward is a second Pallas kernel
+    (``_flash_backward_kernel``, attached via custom_vjp) under the same
+    rules: it recomputes p a (block_k, block_q) tile at a time from the
+    stats, never the (Sq, Sk) score matrix; multiplies q, k, v, do as
+    they come and rounds p and ds to their dtype for the products that
+    consume them; sums, and accumulates dq, dk, dv, in float32; and
+    under ``causal`` a key block meets only the query blocks from the
+    diagonal down.  It takes every shape the forward kernel takes.
 
     ``block_q``/``block_k`` default to the largest of 512/256/128 that
-    divides the sequence (``_flash_blocks``; the backward's key block to
-    128).  Sequence lengths must be multiples of the block sizes for the
-    kernel path (pad upstream); otherwise falls back to the reference
+    divides the sequence (``_flash_blocks``), for both kernels.
+    Sequence lengths must be multiples of the block sizes for the kernel
+    path (pad upstream); otherwise falls back to the reference
     implementation.
     """
     if scale is None:
@@ -418,7 +519,6 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
     blocks = _flash_blocks(q.shape[-2], k.shape[-2], block_q, block_k)
     if blocks is None:                 # hard kernel constraint
         return reference(q, k, v)
-    bwd_block_k = block_k or _FLASH_BLOCKS[-1]   # the backward's, as it was
     block_q, block_k = blocks
 
     def kernel(q, k, v, interpret=False):
@@ -435,8 +535,9 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
 
         def _fa_bwd(res, ct):
             q, k, v, out, lse = res
-            return _flash_backward_blockwise(q, k, v, out, lse, ct, causal,
-                                             scale, bwd_block_k)
+            return _flash_backward_kernel_call(
+                q, k, v, out, lse, ct, causal, scale, block_q, block_k,
+                interpret)
 
         _fa.defvjp(_fa_fwd, _fa_bwd)
         return _fa(q, k, v)
@@ -592,6 +693,15 @@ def sharded_self_attention(q, k, v, causal=False):
                      check_vma=check_vma)(q, k, v)
 
 
+def _kernel_spec(name, grid, blocks):
+    """A spec dict for analysis/tiling.py from ``(role, name, (block,
+    array), dtype)`` rows."""
+    return {"name": name, "origin": "mxnet_tpu/parallel/ring_attention.py",
+            "grid": grid,
+            "blocks": [{"role": role, "name": n, "block": blk, "array": arr,
+                        "dtype": dt} for role, n, (blk, arr), dt in blocks]}
+
+
 def flash_kernel_spec(batch_heads=8, seq_q=1024, seq_k=1024, head_dim=64,
                       block_q=None, dtype="bfloat16", head_dim_v=None):
     """MXL-K kernel spec for the flash forward pallas_call.
@@ -604,26 +714,37 @@ def flash_kernel_spec(batch_heads=8, seq_q=1024, seq_k=1024, head_dim=64,
     rejected (no second dimension to tile).
     """
     block_q, _block_k = _flash_blocks(seq_q, seq_k, block_q)
-    in_blocks, out_blocks = _flash_block_layout(batch_heads, seq_q, seq_k,
-                                                head_dim, block_q,
-                                                head_dim_v)
-    blocks = []
-    for name, (blk, arr) in zip(("q", "k", "v"), in_blocks):
-        blocks.append({"role": "in", "name": name, "block": blk,
-                       "array": arr, "dtype": dtype})
-    for name, (blk, arr) in zip(("o", "lse"), out_blocks):
-        blocks.append({"role": "out", "name": name, "block": blk,
-                       "array": arr,
-                       "dtype": "float32" if name == "lse" else dtype})
-    return {"name": "flash_forward",
-            "origin": "mxnet_tpu/parallel/ring_attention.py",
-            "grid": (batch_heads, seq_q // block_q),
-            "blocks": blocks}
+    ins, outs = _flash_block_layout(batch_heads, seq_q, seq_k, head_dim,
+                                    block_q, head_dim_v)
+    return _kernel_spec(
+        "flash_forward", (batch_heads, seq_q // block_q),
+        [("in", n, b, dtype) for n, b in zip(("q", "k", "v"), ins)]
+        + [("out", "o", outs[0], dtype), ("out", "lse", outs[1], "float32")])
+
+
+def flash_backward_kernel_spec(batch_heads=8, seq_q=1024, seq_k=1024,
+                               head_dim=64, dtype="bfloat16",
+                               head_dim_v=None):
+    """MXL-K kernel spec for the flash backward pallas_call, from the
+    :func:`_flash_backward_block_layout` the call itself uses and the
+    blocks ``flash_attention`` gives it for these shapes.  lse and
+    delta are float32 (1, block_q) rows, one a query block: each block
+    covers its array's last two dims whole."""
+    block_q, block_k = _flash_blocks(seq_q, seq_k)
+    ins, outs, _acc = _flash_backward_block_layout(
+        batch_heads, seq_q, seq_k, head_dim, block_q, block_k, head_dim_v)
+    return _kernel_spec(
+        "flash_backward", (batch_heads, seq_k // block_k),
+        [("in", n, b, "float32" if n in ("lse", "delta") else dtype)
+         for n, b in zip(("q", "k", "v", "do", "lse", "delta"), ins)]
+        + [("out", n, b, dtype) for n, b in zip(("dq", "dk", "dv"), outs)])
 
 
 try:
     from ..analysis.tiling import register_kernel_spec as _register_spec
     _register_spec("parallel.ring_attention.flash_forward",
                    flash_kernel_spec)
+    _register_spec("parallel.ring_attention.flash_backward",
+                   flash_backward_kernel_spec)
 except Exception:            # analysis package optional at import time
     pass

@@ -662,7 +662,36 @@ def test_k_registered_flash_spec_is_clean():
                                            kernel_spec_issues)
     _ensure_builtin_specs()
     assert "parallel.ring_attention.flash_forward" in KERNEL_SPECS
+    assert "parallel.ring_attention.flash_backward" in KERNEL_SPECS
     assert kernel_spec_issues() == []
+
+
+@pytest.mark.parametrize("shape", [
+    (128, 1024, 64, None),          # gpt2m_train_s1024
+    (32, 8192, 192, 128),           # joyai_flash_train_s8192
+    (4, 384, 64, None),             # 128-blocks
+])
+def test_k_flash_backward_spec_follows_the_call(shape):
+    """The backward's registered layout is the call's own: blocks from
+    ``_flash_blocks`` for the shapes, q/do/dq whole per batch·head, k,
+    v, dk, dv by key blocks, lse and delta as (1, block_q) rows — and it
+    lints clean at both LM cells' shapes."""
+    from mxnet_tpu.analysis.tiling import spec_findings
+    from mxnet_tpu.parallel.ring_attention import (
+        _flash_blocks, flash_backward_kernel_spec)
+    bh, seq, d, d_v = shape
+    spec = flash_backward_kernel_spec(bh, seq, seq, d, head_dim_v=d_v)
+    block_q, block_k = _flash_blocks(seq, seq)
+    assert spec["name"] == "flash_backward"
+    assert spec["grid"] == (bh, seq // block_k)
+    blocks = {b["name"]: b for b in spec["blocks"]}
+    assert list(blocks) == ["q", "k", "v", "do", "lse", "delta",
+                            "dq", "dk", "dv"]
+    assert blocks["q"]["block"] == blocks["dq"]["block"] == (None, seq, d)
+    assert blocks["dv"]["block"] == (None, block_k, d_v or d)
+    assert blocks["lse"]["block"] == (None, seq // block_q, 1, block_q)
+    assert blocks["delta"]["dtype"] == "float32"
+    assert spec_findings(spec) == []
 
 
 def test_k_flash_lse_regression_fixture():

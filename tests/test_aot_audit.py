@@ -116,8 +116,11 @@ def test_every_shipped_kernel_compiles_under_mosaic():
     kernels = json.loads(line)["kernels"]
     refused = {k: v for k, v in kernels.items() if not v["ok"]}
     assert not refused and p.returncode == 0, (refused, p.stderr[-1500:])
-    for want in ("flash_fwd_bwd[bfloat16]",
-                 "quantized_matmul[float32,8x768->50257]",
+    # the forward kernel and the backward kernel, one Mosaic call each
+    for want in ("flash_fwd_bwd[float32]", "flash_fwd_bwd[bfloat16]",
+                 "flash_fwd_bwd[bfloat16,latent]"):
+        assert kernels[want]["mosaic_calls"] == 2, want
+    for want in ("quantized_matmul[float32,8x768->50257]",
                  "quantized_matmul[bfloat16,256x768->3072]",
                  "fused_opt_sweep[float32,25557032]"):
         assert kernels[want]["mosaic_calls"] >= 1, want

@@ -175,27 +175,35 @@ def test_flash_forward_and_backward_widths(shape):
         _close(a.astype(jnp.float32), b_.astype(jnp.float32), tol)
 
 
+@pytest.mark.parametrize("block_q,block_k", [(8, 8), (16, 8), (8, 16)])
 @pytest.mark.parametrize("causal", [True, False])
-def test_flash_backward_walks_long_sequences_in_query_chunks(causal):
-    """More queries than ``_BWD_Q_CHUNK``: the backward walks them in
-    chunks and, under ``causal``, skips those above the diagonal."""
-    s = 2 * ra._BWD_Q_CHUNK
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_flash_backward_kernel_unequal_widths(dtype, causal, block_q,
+                                              block_k):
+    """The backward kernel with q and k 24 wide against v, o and do 16
+    wide, over several query and key blocks (and, without ``causal``,
+    more keys than queries): dq, dk and dv against ``jax.grad`` of the
+    float32 reference."""
+    sk = 32 if causal else 48
     ks = jax.random.split(jax.random.PRNGKey(8), 4)
-    q = jax.random.normal(ks[0], (1, 1, s, 16), jnp.float32)
-    k = jax.random.normal(ks[1], (1, 1, s, 16), jnp.float32)
-    v = jax.random.normal(ks[2], (1, 1, s, 8), jnp.float32)
-    do = jax.random.normal(ks[3], (1, 1, s, 8), jnp.float32)
-    o, vjp = jax.vjp(lambda q, k, v: ra.attention_reference(
-        q, k, v, causal=causal), q, k, v)
-    scale = 0.25
-    sc = jnp.einsum("...qd,...kd->...qk", q, k) * scale
-    if causal:
-        sc = jnp.where(jnp.tril(jnp.ones((s, s), bool)), sc, -jnp.inf)
-    lse = jax.scipy.special.logsumexp(sc, axis=-1)
-    got = ra._flash_backward_blockwise(q, k, v, o, lse, do, causal, scale,
-                                       128)
-    for a, b_ in zip(got, vjp(do)):
-        _close(a, b_, 2e-4)
+    q = jax.random.normal(ks[0], (1, 2, 32, 24), dtype)
+    k = jax.random.normal(ks[1], (1, 2, sk, 24), dtype)
+    v = jax.random.normal(ks[2], (1, 2, sk, 16), dtype)
+    w = jax.random.normal(ks[3], (1, 2, 32, 16), jnp.float32)
+
+    def grads(fn, *args):
+        return jax.grad(lambda q, k, v: jnp.sum(
+            fn(q, k, v).astype(jnp.float32) * w), (0, 1, 2))(*args)
+
+    got = grads(lambda q, k, v: ra.flash_attention(
+        q, k, v, causal=causal, block_q=block_q, block_k=block_k,
+        interpret=True), q, k, v)
+    want = grads(lambda q, k, v: ra.attention_reference(
+        q, k, v, causal=causal), *(a.astype(jnp.float32) for a in (q, k, v)))
+    for g, x, want_g in zip(got, (q, k, v), want):
+        assert g.dtype == x.dtype and g.shape == x.shape
+        _close(g.astype(jnp.float32), want_g,
+               2e-5 if dtype == "float32" else 2e-2)
 
 
 # -- latent attention ------------------------------------------------------------

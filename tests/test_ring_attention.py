@@ -14,8 +14,8 @@ from jax import shard_map
 import mxnet_tpu as mx
 from mxnet_tpu import symbol as sym
 from mxnet_tpu.parallel.ring_attention import (
-    _causal_k_blocks, _flash_blocks, _flash_forward_kernel_call,
-    attention_reference, blockwise_combine, flash_attention, ring_attention)
+    _causal_k_blocks, _causal_q_blocks, _flash_blocks,
+    _flash_forward_kernel_call, attention_reference, blockwise_combine, flash_attention, ring_attention)
 from mxnet_tpu.test_utils import (assert_almost_equal,
                                   check_numeric_gradient, tpu_lowering_text)
 
@@ -209,9 +209,11 @@ def test_transformer_sharded_trainer_sp():
 
 
 def _flash_calls(text):
-    """(Mosaic kernels lowered, calls of the jitted forward kernel)."""
+    """(Mosaic kernels lowered, calls of the jitted forward kernel, calls
+    of the jitted backward kernel)."""
     return (text.count("tpu_custom_call"),
-            text.count("call @_flash_forward_kernel_call"))
+            text.count("call @_flash_forward_kernel_call"),
+            text.count("call @_flash_backward_kernel_call"))
 
 
 def test_mesh_steps_carry_the_flash_kernel_per_device():
@@ -219,9 +221,10 @@ def test_mesh_steps_carry_the_flash_kernel_per_device():
     shard_map" — what the four-chip host said in PR 21), so a step
     sharded over a mesh WITHOUT a sequence axis must run the flash path
     per device: the dp=4 ShardedTrainer step and the Module mesh
-    group's fused step both lower for a TPU with one call of the Mosaic
-    kernel per layer (the kernel itself lowered once: its call is
-    jitted), and on the cpu mesh they compute what one device computes."""
+    group's fused step both lower for a TPU with one call of the forward
+    kernel and one of the backward kernel per layer (each kernel lowered
+    once: its call is jitted), and on the cpu mesh they compute what one
+    device computes."""
     from mxnet_tpu.parallel import make_mesh
     from mxnet_tpu.parallel.trainer import ShardedTrainer
     from mxnet_tpu import optimizer as opt_mod
@@ -246,7 +249,7 @@ def test_mesh_steps_carry_the_flash_kernel_per_device():
         outs[dp] = np.asarray(out[0])
         with tr._sp_scope():
             text = tpu_lowering_text(tr._jit_step, *tr._abstract_args)
-        assert _flash_calls(text) == (1, L), dp
+        assert _flash_calls(text) == (2, L, L), dp
     assert_almost_equal(outs[1], outs[4], rtol=1e-3, atol=1e-4)
 
     mod = mx.mod.Module(net, context=[mx.cpu(i) for i in range(4)])
@@ -263,7 +266,7 @@ def test_mesh_steps_carry_the_flash_kernel_per_device():
             step, *exe._fused_operands(wrt, donate=False),
             jax.random.PRNGKey(0), mod._fused_holder["states"],
             jnp.float32(0.1), jnp.float32(0.0), jnp.int32(1))
-    assert _flash_calls(text) == (1, L)
+    assert _flash_calls(text) == (2, L, L)
     # the same graph bound on one device afterwards gets its own program
     one = mx.mod.Module(net, context=mx.cpu(5))
     one.bind(data_shapes=[("data", (B, S))],
@@ -273,9 +276,9 @@ def test_mesh_steps_carry_the_flash_kernel_per_device():
 
 @pytest.mark.parametrize("causal", [False, True])
 def test_flash_kernel_differentiable(causal):
-    """The pallas forward carries a blockwise flash backward (recompute
-    from saved logsumexp, O(Sq·block_k) memory) — must match reference
-    grads exactly."""
+    """The pallas forward carries the flash backward kernel (recompute
+    from saved logsumexp, a (block_k, block_q) tile at a time) — must
+    match reference grads exactly."""
     q, k, v = _qkv(B=1, H=1, S=16, D=8)
 
     def loss_flash(q, k, v):
@@ -409,9 +412,111 @@ def test_flash_bfloat16_operands_keep_the_scores(causal, D):
     assert _max_rel(got, want) <= 1e-2
 
 
+# ----------------------------------------------------------------------
+# the backward kernel: same dtype rule, same diagonal, by key blocks
+# ----------------------------------------------------------------------
+def _grads(fn, q, k, v, w):
+    return jax.grad(lambda q, k, v: jnp.sum(
+        fn(q, k, v).astype(jnp.float32) * w), argnums=(0, 1, 2))(q, k, v)
+
+
+@pytest.mark.parametrize("block_q,block_k", [(8, 8), (16, 8), (8, 16)])
+@pytest.mark.parametrize("causal", [False, True])
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_flash_backward_kernel_matches_reference(dtype, causal, block_q,
+                                                 block_k):
+    """dq, dk, dv of the Pallas backward against ``jax.grad`` of the
+    float32 reference, over several query and key blocks: float32
+    operands to float32's rounding, bfloat16 operands (multiplied as
+    they are, p and ds rounded for their products) to bfloat16's."""
+    r = np.random.RandomState(30)
+    q, k, v, w = (jnp.asarray(r.randn(1, 2, 32, 16), dt)
+                  for dt in (dtype, dtype, dtype, jnp.float32))
+    got = _grads(lambda q, k, v: flash_attention(
+        q, k, v, causal=causal, block_q=block_q, block_k=block_k,
+        interpret=True), q, k, v, w)
+    want = _grads(lambda q, k, v: attention_reference(
+        q, k, v, causal=causal),
+        *(a.astype(jnp.float32) for a in (q, k, v)), w)
+    tol = 1e-5 if dtype == "float32" else 2e-2
+    for g, x, want_g in zip(got, (q, k, v), want):
+        assert g.dtype == x.dtype and g.shape == x.shape
+        assert _max_rel(g, want_g) <= tol
+
+
+@pytest.mark.parametrize("sq,sk,block_q,block_k,want", [
+    (1024, 1024, 128, 128, (36, 64)),       # the rows of
+    (1024, 1024, 256, 128, (20, 32)),       # test_causal_visit_count:
+    (1024, 1024, 128, 256, (20, 32)),       # the backward visits the
+    (1024, 1024, 512, 512, (3, 4)),         # block pairs the forward
+    (8192, 8192, 512, 512, (136, 256)),     # does, and no other
+    (512, 1024, 128, 128, (10, 32)),
+    (1024, 512, 128, 128, (26, 32)),
+    (96, 48, 16, 8, (30, 36)),
+    (64, 64, 8, 16, (20, 32)),
+])
+def test_causal_backward_visit_count(sq, sk, block_q, block_k, want):
+    """Key block j meets query blocks [visited, n_q): exactly those with
+    a row that may see one of its keys, and masks only [visited,
+    unmasked), the ones whose first row does not see its last key — the
+    pairs, and the masked pairs, that ``_causal_k_blocks`` gives the
+    forward."""
+    n_q, n_k = sq // block_q, sk // block_k
+    pairs, masked = set(), set()
+    for j in range(n_k):
+        visited, unmasked = _causal_q_blocks(j, block_q, block_k, n_q)
+        assert isinstance(visited, int) and isinstance(unmasked, int)
+        assert 0 <= visited <= unmasked <= n_q
+        pairs.update((i, j) for i in range(visited, n_q))
+        masked.update((i, j) for i in range(visited, unmasked))
+    forward, forward_masked = set(), set()
+    for i in range(n_q):
+        unmasked, visited = _causal_k_blocks(i, block_q, block_k, n_k)
+        forward.update((i, j) for j in range(visited))
+        forward_masked.update((i, j) for j in range(unmasked, visited))
+    assert pairs == forward and masked == forward_masked
+    assert (len(pairs), n_q * n_k) == want
+
+
+@pytest.mark.parametrize("block_q,block_k", [(8, 8), (16, 8), (8, 16)])
+def test_flash_backward_causal_skips_blocks_above_the_diagonal(block_q,
+                                                               block_k):
+    """Pairs past the diagonal are not read, not read-and-masked: with
+    the first query block of ``do`` all NaN, a key block that lies
+    wholly past that query block never meets it and keeps finite dk and
+    dv (one that meets it under the mask gets 0 × NaN), and the other
+    query blocks keep their dq."""
+    q, k, v = _qkv(B=1, H=2, S=32, D=8)
+    do = rng.randn(1, 2, 32, 8).astype(np.float32)
+    do_nan = do.copy()
+    do_nan[..., :block_q, :] = np.nan
+    do_zero = do.copy()
+    do_zero[..., :block_q, :] = 0.0
+    clean = -(-block_q // block_k) * block_k    # first key past the block
+
+    def vjp(fn, do):
+        return [np.asarray(g) for g in jax.vjp(fn, q, k, v)[1](do)]
+
+    dq, dk, dv = vjp(lambda q, k, v: flash_attention(
+        q, k, v, causal=True, block_q=block_q, block_k=block_k,
+        interpret=True), do_nan)
+    want = vjp(lambda q, k, v: attention_reference(q, k, v, causal=True),
+               do_zero)
+    for got, ref, first in ((dq, want[0], block_q), (dk, want[1], clean),
+                            (dv, want[2], clean)):
+        assert np.isfinite(got[..., first:, :]).all()
+        assert_almost_equal(got[..., first:, :], ref[..., first:, :],
+                            rtol=1e-4, atol=1e-5)
+    assert np.isnan(dq[..., :block_q, :]).all()
+    # the same call without causal: every key block meets the NaN block
+    _dq, dk, dv = vjp(lambda q, k, v: flash_attention(
+        q, k, v, block_q=block_q, block_k=block_k, interpret=True), do_nan)
+    assert np.isnan(dk).all() and np.isnan(dv).all()
+
+
 @pytest.mark.parametrize("causal", [False, True])
 def test_flash_bfloat16_gradient_matches_reference(causal):
-    """The blockwise backward reads the forward's lse: with bfloat16
+    """The backward kernel reads the forward's lse: with bfloat16
     operands it must still give the float32 reference's gradient, to
     bfloat16's rounding."""
     (q, k, v), wide = _bf16_qkv(32, 16)

@@ -8,8 +8,9 @@ so tier-1 cannot see what Mosaic refuses; this check can, and costs no
 chip time:
 
 1. every Pallas kernel the tree ships, at the widths chip_smoke.py runs
-   them (its phases 2-3): the flash-attention forward with its
-   blockwise backward (s1024 d64), the weight-only quantized matmul at
+   them (its phases 2-3): the flash-attention forward and backward
+   kernels (s1024 d64; s8192 at latent attention's 192/128), the
+   weight-only quantized matmul at
    GPT-2-small FFN shapes and at the LM head of 50,257, and the fused
    optimizer sweep at one bucket the size of ResNet-50's parameters;
 2. the transformer fused train step (models/transformer.py), on one
@@ -57,17 +58,23 @@ def kernel_cases():
     def sds(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype)
 
+    def flash_loss(q, k, v):
+        out = flash_attention(q, k, v, causal=True)
+        return jnp.sum(out.astype(jnp.float32))
+
     cases = []
     for dt in (jnp.float32, jnp.bfloat16):
         qkv = sds(widths["flash"], dt)         # train/lm: b8 h8 s1024 d64
-
-        def flash_loss(q, k, v):
-            out = flash_attention(q, k, v, causal=True)
-            return jnp.sum(out.astype(jnp.float32))
-
         cases.append(("flash_fwd_bwd[%s]" % jnp.dtype(dt).name,
                       jax.grad(flash_loss, argnums=(0, 1, 2)),
                       (qkv, qkv, qkv)))
+    # latent attention: 8,192 keys, q/k 192 wide, v 128 — the most VMEM
+    # either kernel is asked for (q, do, dq whole per batch·head)
+    b, h, s, d_qk, d_v = widths["flash_latent"]
+    qk, v = sds((b, h, s, d_qk), jnp.bfloat16), sds((b, h, s, d_v),
+                                                    jnp.bfloat16)
+    cases.append(("flash_fwd_bwd[bfloat16,latent]",
+                  jax.grad(flash_loss, argnums=(0, 1, 2)), (qk, qk, v)))
     # serve/lm (GPT-2 small): decode rows 8, prefill rows 256
     for m, k, n in widths["qmm"]:
         for dt in (jnp.float32, jnp.bfloat16):
