@@ -53,6 +53,7 @@ FULL = {
                   kv_block_size=32,
                   prompt_lengths=(5, 40, 64, 100, 200, 256, 17, 130)),
     "kernels": dict(flash=(8, 8, 1024, 64), flash_latent=(1, 32, 8192, 192, 128),
+                    flash_grouped=(1, 8, 2, 8192, 128),
                     qmm=((8, 768, 3072), (256, 768, 3072), (8, 3072, 768),
                          (8, 768, 50257), (256, 768, 50257))),
 }
@@ -66,6 +67,7 @@ TINY = {
                   kv_blocks=32, kv_block_size=8,
                   prompt_lengths=(3, 8, 12, 16, 5)),
     "kernels": dict(flash=(1, 2, 256, 8), flash_latent=(1, 2, 256, 24, 16),
+                    flash_grouped=(1, 4, 2, 256, 16),
                     qmm=((8, 256, 384), (300, 600, 1000))),
 }
 
@@ -481,18 +483,23 @@ def phase_kernels(env, cfg, lm_params):
             return jax.jit(fn)(*args)
 
     # flash-attention forward (+ the backward kernel its stats feed):
-    # 64-wide heads in both dtypes, and latent attention's shape — q and k
-    # 192 wide, v 128, 8,192 keys — in bfloat16.  The reference goes by
-    # query blocks, each against the keys up to its end, so that 8,192
-    # keys of 32 heads fit beside the kernel's own buffers.
+    # 64-wide heads in both dtypes, latent attention's shape — q and k
+    # 192 wide, v 128, 8,192 keys — and grouped queries' — 8 heads of 128
+    # on 2 key/value heads, 8,192 keys — in bfloat16.  The reference goes
+    # by query blocks, each against the keys up to its end, so that 8,192
+    # keys of 32 heads fit beside the kernel's own buffers; it repeats
+    # k and v over a group, which the kernels do not.
     b, h, s, d = cfg["flash"]
-    cases = [((b, h, s, d, d), jnp.float32, 2e-2, ""),
-             ((b, h, s, d, d), jnp.bfloat16, 4e-2, ""),
-             (cfg["flash_latent"], jnp.bfloat16, 4e-2, ",latent")]
-    for (b, h, s, d_qk, d_v), dt, tol, tag in cases:
-        q, k = (put(jnp.asarray(rng.randn(b, h, s, d_qk), dt))
-                for _ in range(2))
-        v = put(jnp.asarray(rng.randn(b, h, s, d_v), dt))
+    lb, lh, ls, l_qk, l_v = cfg["flash_latent"]
+    gb, gh, gkv, gs, gd = cfg["flash_grouped"]
+    cases = [((b, h, h, s, d, d), jnp.float32, 2e-2, ""),
+             ((b, h, h, s, d, d), jnp.bfloat16, 4e-2, ""),
+             ((lb, lh, lh, ls, l_qk, l_v), jnp.bfloat16, 4e-2, ",latent"),
+             ((gb, gh, gkv, gs, gd, gd), jnp.bfloat16, 4e-2, ",grouped")]
+    for (b, h, h_kv, s, d_qk, d_v), dt, tol, tag in cases:
+        q = put(jnp.asarray(rng.randn(b, h, s, d_qk), dt))
+        k = put(jnp.asarray(rng.randn(b, h_kv, s, d_qk), dt))
+        v = put(jnp.asarray(rng.randn(b, h_kv, s, d_v), dt))
 
         def loss(fn, q, k, v):
             o = fn(q, k, v)

@@ -1,6 +1,7 @@
 """Transformer operators: LayerNorm, RMSNorm, MultiHeadAttention,
 MultiHeadLatentAttention (low-rank query and key/value paths, one rotary
-key shared by all heads).
+key shared by all heads), CompressedConvAttention (attention inside a
+compressed latent mixed by two causal convolutions, grouped query heads).
 
 TPU-native extensions beyond the reference op set (the reference predates
 transformers; SURVEY §5 notes its only long-sequence tools are bucketing
@@ -13,6 +14,7 @@ from __future__ import annotations
 import numpy as _np
 
 import jax.numpy as jnp
+from jax import lax
 
 from ..base import MXNetError
 from ..dparam import Field, ParamStruct
@@ -120,6 +122,191 @@ def rotary_interleaved(x, theta):
     half = d // 2
     turned = jnp.concatenate([-x32[..., half:], x32[..., :half]], axis=-1)
     return (x32 * cos + turned * sin).astype(x.dtype)
+
+
+def rotary_half(x, theta, rotary_dim=None):
+    """Rotary position embedding of x (..., S, D) in the half-split
+    layout, on the first ``rotary_dim`` channels (all of them by
+    default): channel i < rotary_dim/2 of position p pairs with channel
+    i + rotary_dim/2 and the pair is turned by the angle
+    p · theta^(−2i/rotary_dim); the channels from ``rotary_dim`` on pass
+    through.  float32 inside, x's dtype out."""
+    s, d = x.shape[-2], x.shape[-1]
+    r = d if rotary_dim is None else int(rotary_dim)
+    half = r // 2
+    x32 = x.astype(jnp.float32)
+    inv_freq = 1.0 / (float(theta) ** (
+        jnp.arange(0, r, 2, dtype=jnp.float32) / r))
+    ang = jnp.arange(s, dtype=jnp.float32)[:, None] * inv_freq
+    cos, sin = jnp.cos(ang), jnp.sin(ang)
+    a, b = x32[..., :half], x32[..., half:r]
+    return jnp.concatenate([a * cos - b * sin, b * cos + a * sin,
+                            x32[..., r:]], axis=-1).astype(x.dtype)
+
+
+def shift_tokens(z, n, axis=1):
+    """z moved ``n`` positions later along ``axis``, zeros in front: the
+    value at position t is z's at t − n (what a causal convolution's tap
+    n reads)."""
+    if n == 0:
+        return z
+    pad = [(0, 0)] * z.ndim
+    pad[axis] = (n, 0)
+    return lax.slice_in_dim(jnp.pad(z, pad), 0, z.shape[axis], axis=axis)
+
+
+def causal_conv_pair(z, w0, w1):
+    """The two causal convolutions of compressed convolutional attention
+    on z (B, S, H, d): a depthwise one over the sequence, ``w0`` (taps,
+    H·d), then one that mixes the d channels of each head, ``w1`` (taps,
+    H, d, d).  A convolution's last tap reads the current token, the one
+    before it the previous token, and so on; positions before the first
+    read zeros.  Sums in float32, z's dtype out."""
+    B, S, H, d = z.shape
+    t0, t1 = w0.shape[0], w1.shape[0]
+    taps0 = w0.astype(jnp.float32).reshape(t0, H, d)
+    z32 = z.astype(jnp.float32)
+    z0 = sum(shift_tokens(z32, t0 - 1 - j) * taps0[j]
+             for j in range(t0)).astype(z.dtype)
+    stacked = jnp.stack([shift_tokens(z0, t1 - 1 - j) for j in range(t1)],
+                        axis=3)                         # (B, S, H, taps, d)
+    return jnp.einsum("bshtc,thcd->bshd", stacked, w1,
+                      preferred_element_type=jnp.float32).astype(z.dtype)
+
+
+class _CCAParam(ParamStruct):
+    num_heads = Field(int, required=True, lower=1, doc="query heads")
+    num_kv_heads = Field(int, required=True, lower=2,
+                         doc="key/value heads: even (the value's second "
+                             "half is the previous token's), a divisor of "
+                             "num_heads")
+    head_dim = Field(int, required=True, lower=2)
+    conv_taps0 = Field(int, default=2, lower=1,
+                       doc="taps of the depthwise convolution")
+    conv_taps1 = Field(int, default=2, lower=1,
+                       doc="taps of the convolution that mixes a head's "
+                           "channels")
+    rope_theta = Field(float, default=10000.0)
+    partial_rotary_factor = Field(float, default=1.0,
+                                  doc="share of a head that is rotated")
+    eps = Field(float, default=1e-5, doc="of the q and k normalisation")
+
+
+@register_op("CompressedConvAttention")
+class CompressedConvAttention(OperatorProperty):
+    """Compressed convolutional attention (Zyphra's CCA, arXiv:2510.04476
+    section 3, grouped-query form), data (B, S, E) -> (B, S, E).
+
+    Everything between the down-projections and the out-projection
+    happens in a latent narrower than E: H = ``num_heads`` query heads
+    and H_kv = ``num_kv_heads`` key/value heads of d = ``head_dim``,
+    g = H / H_kv query heads a key/value head.
+
+    1. q̃ = u W_qᵀ (E → H·d), k̃ = u W_kᵀ (E → H_kv·d), ṽ = u W_vᵀ
+       (E → H_kv·d).
+    2. value shift: the first half of v's channels (key/value heads
+       0 .. H_kv/2 − 1) are ṽ's at the current token, the second half
+       ṽ's at the previous token (zeros at token 0).
+    3. q̃ and k̃ each go through two causal convolutions with weights of
+       their own (:func:`causal_conv_pair`).
+    4. q-k mean of the values before the convolutions: m_q[h] =
+       ½(q̃[h] + k̃[h // g]), m_k[j] = ½(k̃[j] + mean of q̃ over group
+       j);  q′ = conv(q̃) + m_q, k′ = conv(k̃) + m_k.
+    5. q̂ = q′ / rms(q′), k̂ = τ_j · k′ / rms(k′) per head over its d
+       channels (no gain; τ = ``k_temp``, a learned scalar a key/value
+       head), in float32.
+    6. rotary on the first ``partial_rotary_factor``·d channels of each
+       head of q̂ and k̂, half-split pairing (:func:`rotary_half`).
+    7. causal softmax(q̂ k̂ᵀ / √d) v, query head h on key/value head
+       h // g (the flash path of ``parallel/ring_attention.py``, which
+       indexes k and v by group and repeats neither); heads concatenated
+       -> ``out_weight`` (H·d → E).
+
+    No biases; weights are (out_features, in_features)."""
+    param_cls = _CCAParam
+    mxu = True
+
+    def list_arguments(self):
+        return ["data", "q_weight", "k_weight", "v_weight",
+                "q_conv0_weight", "q_conv1_weight", "k_conv0_weight",
+                "k_conv1_weight", "k_temp", "out_weight"]
+
+    def infer_shape(self, in_shapes):
+        data = in_shapes[0]
+        if data is None:
+            require_known("CompressedConvAttention", in_shapes[:1], ["data"])
+        if len(data) != 3:
+            raise MXNetError("CompressedConvAttention: data must be "
+                             "(B, S, E)")
+        p = self.param
+        E, H, K, d = data[2], p.num_heads, p.num_kv_heads, p.head_dim
+        if H % K or K % 2:
+            raise MXNetError("CompressedConvAttention: %d query heads on "
+                             "%d key/value heads (want an even number that "
+                             "divides the query heads)" % (H, K))
+        if int(round(p.partial_rotary_factor * d)) % 2:
+            raise MXNetError("the rotated part of a head must be even")
+        t0, t1 = p.conv_taps0, p.conv_taps1
+        return ([data, (H * d, E), (K * d, E), (K * d, E),
+                 (t0, H * d), (t1, H, d, d), (t0, K * d), (t1, K, d, d),
+                 (K,), (E, H * d)], [data], [])
+
+    def cost_mxu_dims(self, in_shapes, out_shapes):
+        B, S, E = in_shapes[0]
+        p = self.param
+        H, K, d = p.num_heads, p.num_kv_heads, p.head_dim
+        T = B * S
+        return [(T, E, H * d), (T, E, K * d), (T, E, K * d), (T, H * d, E),
+                (T * (H + K), p.conv_taps1 * d, d), (S, d, S), (S, S, d)]
+
+    def cost_flops(self, in_shapes, out_shapes):
+        B = in_shapes[0][0]
+        dims = self.cost_mxu_dims(in_shapes, out_shapes)
+        proj = sum(2 * m * k * n for m, k, n in dims[:5])
+        attn = sum(2 * B * self.param.num_heads * m * k * n
+                   for m, k, n in dims[5:])
+        return float(proj + attn)
+
+    def cost_reduce_len(self, in_shapes, out_shapes):
+        return int(in_shapes[0][1])     # softmax over the key axis
+
+    def forward(self, inputs, aux, is_train, rng):
+        x, wq, wk, wv, qc0, qc1, kc0, kc1, temp, wo = inputs
+        B, S, _E = x.shape
+        p = self.param
+        H, K, d = p.num_heads, p.num_kv_heads, p.head_dim
+        g = H // K
+        q = (x @ wq.T).reshape(B, S, H, d)
+        k = (x @ wk.T).reshape(B, S, K, d)
+        v = x @ wv.T                                    # (B, S, K·d)
+        half = K * d // 2
+        v = jnp.concatenate([v[..., :half],
+                             shift_tokens(v[..., half:], 1)], axis=-1)
+        q32 = q.astype(jnp.float32).reshape(B, S, K, g, d)
+        k32 = k.astype(jnp.float32)
+        mean_q = 0.5 * (q32 + k32[:, :, :, None, :])
+        mean_k = 0.5 * (k32 + jnp.mean(q32, axis=3))
+        q = causal_conv_pair(q, qc0, qc1).astype(jnp.float32) \
+            + mean_q.reshape(B, S, H, d)
+        k = causal_conv_pair(k, kc0, kc1).astype(jnp.float32) + mean_k
+
+        def unit_rms(t):
+            return t * lax.rsqrt(jnp.mean(jnp.square(t), axis=-1,
+                                          keepdims=True) + p.eps)
+
+        q = unit_rms(q)
+        k = unit_rms(k) * temp.astype(jnp.float32)[:, None]
+        rot = int(round(p.partial_rotary_factor * d))
+
+        def heads(t):       # (B, S, heads, d) -> (B, heads, S, d) rotated
+            return rotary_half(t.transpose(0, 2, 1, 3), p.rope_theta,
+                               rot).astype(x.dtype)
+
+        from ..parallel.ring_attention import sharded_self_attention
+        o = sharded_self_attention(
+            heads(q), heads(k),
+            v.reshape(B, S, K, d).transpose(0, 2, 1, 3), causal=True)
+        return [o.transpose(0, 2, 1, 3).reshape(B, S, H * d) @ wo.T], None
 
 
 class _MLAParam(ParamStruct):

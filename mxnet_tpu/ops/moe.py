@@ -2,10 +2,13 @@
 
 Two routed layers live here.  ``MoE`` (below) is the dense-mask form for
 moderate expert counts.  ``RoutedExperts`` (further down) is the sorted
-form for layers of hundreds of experts of which a device holds a share:
-it routes over all of them, sorts the (token, expert) assignments by
-expert and runs one grouped matrix product per projection over the
-experts held, for every assignment that landed on them.
+form for layers of which a device holds a share of the experts: it
+routes over all of them, sorts the (token, expert) assignments by expert
+and runs one grouped matrix product per projection over the experts
+held, for every assignment that landed on them.  It scores with a
+sigmoid of its own router weight, or takes the scores from a router
+outside it: ``MLPRouter`` (after it) is one, an MLP over a stream that
+runs from layer to layer beside the residual.
 
 Beyond-reference (SURVEY's parallelism table lists expert parallelism as
 absent from the reference): a Switch-style routed FFN whose expert
@@ -42,6 +45,7 @@ from jax import lax
 
 from ..base import MXNetError
 from ..dparam import Field, ParamStruct
+from .attention import rms_norm
 from .registry import (OperatorProperty, register_cost_rule, register_op,
                        register_sharding_rule, require_known)
 
@@ -236,19 +240,31 @@ def gated_ffn(x, w_gate, w_up, w_down):
     return (jax.nn.silu(x @ w_gate.T) * (x @ w_up.T)) @ w_down.T
 
 
-def route_sigmoid_topk(h, router_weight, bias, top_k, scaling=1.0):
-    """``(idx (T, k) int32, w (T, k) float32)``: scores s = sigmoid(h W_rᵀ)
-    in float32; the ``top_k`` largest of s + bias are chosen (the bias
-    steers the choice only); the weights are s at the chosen experts,
-    divided by their sum, times ``scaling``.
-    The choice carries no gradient; the weights carry s's."""
-    scores = jax.nn.sigmoid(jnp.dot(h, router_weight.T,
-                                    preferred_element_type=jnp.float32))
+def route_topk(scores, bias, top_k, scaling=1.0, normalize=True):
+    """``(idx (T, k) int32, w (T, k) float32)`` from float32 ``scores``
+    (T, N): the ``top_k`` largest of scores + bias are chosen (the bias
+    steers the choice only); the weights are the scores at the chosen
+    experts — divided by their sum where ``normalize`` — times
+    ``scaling``.  The choice carries no gradient; the weights carry the
+    scores'."""
     _, idx = lax.top_k(scores + bias.astype(jnp.float32), top_k)
     chosen = idx[..., None] == jnp.arange(scores.shape[-1])
     w = jnp.sum(jnp.where(chosen, scores[:, None, :], 0.0), axis=-1)
-    w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
+    if normalize:
+        w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
     return idx.astype(jnp.int32), w * scaling
+
+
+def sigmoid_scores(h, router_weight):
+    """s = sigmoid(h W_rᵀ) (T, N), summed and kept in float32."""
+    return jax.nn.sigmoid(jnp.dot(h, router_weight.T,
+                                  preferred_element_type=jnp.float32))
+
+
+def route_sigmoid_topk(h, router_weight, bias, top_k, scaling=1.0):
+    """:func:`route_topk` over :func:`sigmoid_scores`, the chosen weights
+    normalised."""
+    return route_topk(sigmoid_scores(h, router_weight), bias, top_k, scaling)
 
 
 def _sorted_assignments(idx, first, n_local):
@@ -410,6 +426,16 @@ class _RoutedExpertsParam(ParamStruct):
         int, default=0, lower=0,
         doc="width of the shared expert every token goes through (0: none)")
     routed_scaling_factor = Field(float, default=1.0)
+    score_func = Field(
+        str, default="sigmoid", enum=("sigmoid", "given"),
+        doc="sigmoid: the op scores, s = sigmoid(h W_rᵀ) with a "
+            "router_weight of its own; given: the scores are the second "
+            "input, (T, num_experts), made by a router outside the op "
+            "(their gradient goes back to it)")
+    norm_topk_prob = Field(
+        bool, default=True,
+        doc="divide the chosen scores by their sum (False: a chosen "
+            "score is the weight as it stands)")
 
 
 #: the counters a RoutedExperts node keeps as auxiliary state, summed on
@@ -424,10 +450,12 @@ class RoutedExperts(OperatorProperty):
     """Routed gated-SiLU FFN over the experts held here, plus a shared one.
 
     data (..., E) -> (..., E).  s = sigmoid(h W_rᵀ) over all
-    ``num_experts`` in float32; the ``top_k`` largest of s + ``router_bias``
+    ``num_experts`` in float32 — or, with ``score_func="given"``, the
+    ``scores`` input as a router outside the op made it (a softmax over
+    an MLP's logits, say); the ``top_k`` largest of s + ``router_bias``
     are chosen (the bias is auxiliary state: it steers the choice, takes
     no gradient and is left as it was); w = s at the chosen experts,
-    normalised over them and scaled.  y = Σᵢ wᵢ Eᵢ(h) over the chosen
+    normalised over them (``norm_topk_prob``) and scaled.  y = Σᵢ wᵢ Eᵢ(h) over the chosen
     experts *held here* (``first_expert .. first_expert +
     num_local_experts − 1``) — every such assignment, whatever the
     imbalance; what the other experts would add is another device's part
@@ -446,9 +474,13 @@ class RoutedExperts(OperatorProperty):
     def _held(self):
         return self.param.num_local_experts or self.param.num_experts
 
+    def _given(self):
+        return self.param.score_func == "given"
+
     def list_arguments(self):
-        args = ["data", "router_weight", "expert_gate_weight",
-                "expert_up_weight", "expert_down_weight"]
+        args = ["data", "scores" if self._given() else "router_weight",
+                "expert_gate_weight", "expert_up_weight",
+                "expert_down_weight"]
         if self.param.shared_hidden_size:
             args += ["shared_gate_weight", "shared_up_weight",
                      "shared_down_weight"]
@@ -469,7 +501,8 @@ class RoutedExperts(OperatorProperty):
         if p.first_expert + L > N:
             raise MXNetError("RoutedExperts: experts %d..%d held of %d"
                              % (p.first_expert, p.first_expert + L - 1, N))
-        shapes = [data, (N, E), (L, H, E), (L, H, E), (L, E, H)]
+        router = tuple(data[:-1]) + (N,) if self._given() else (N, E)
+        shapes = [data, router, (L, H, E), (L, H, E), (L, E, H)]
         if p.shared_hidden_size:
             S = p.shared_hidden_size
             shapes += [(S, E), (S, E), (E, S)]
@@ -483,10 +516,14 @@ class RoutedExperts(OperatorProperty):
 
     def forward(self, inputs, aux, is_train, rng):
         p = self.param
-        x, w_router, w_gate, w_up, w_down = inputs[:5]
+        x, router, w_gate, w_up, w_down = inputs[:5]
         h = x.reshape(-1, x.shape[-1])
-        idx, w = route_sigmoid_topk(h, w_router, aux[0], p.top_k,
-                                    p.routed_scaling_factor)
+        if self._given():
+            scores = router.reshape(-1, p.num_experts).astype(jnp.float32)
+        else:
+            scores = sigmoid_scores(h, router)
+        idx, w = route_topk(scores, aux[0], p.top_k,
+                            p.routed_scaling_factor, p.norm_topk_prob)
         y, counts = routed_experts(h, w, idx, w_gate, w_up, w_down,
                                    p.first_expert, CHUNK_ROWS)
         if p.shared_hidden_size:
@@ -503,6 +540,85 @@ class RoutedExperts(OperatorProperty):
             aux[0], add(total, jnp.sum(counts).reshape(1)),
             add(per_expert, counts), add(peak_sum, peak),
             jnp.maximum(peak_max, peak.astype(peak_max.dtype))]
+
+
+class _MLPRouterParam(ParamStruct):
+    num_experts = Field(int, required=True, lower=2,
+                        doc="experts scored (the softmax's width)")
+    hidden_size = Field(int, required=True, lower=1,
+                        doc="width of the router's stream and of its MLP")
+    has_state = Field(bool, default=True,
+                      doc="takes the previous layer's router stream (False: "
+                          "the first layer, whose stream starts at 0)")
+    eps = Field(float, default=1e-5, doc="of the RMSNorm on the stream")
+
+
+@register_op("MLPRouter")
+class MLPRouter(OperatorProperty):
+    """A router with a stream of its own (the ZAYA1 report,
+    arXiv:2511.17127): data (T, E), state (T, R) -> scores (T, N)
+    float32, state (T, R).
+
+    r = h W_dᵀ;  s = r + γ · s_prev (γ a learned scalar; no s_prev and no
+    γ where ``has_state`` is False);  z = W_3 gelu(W_2 gelu(W_1
+    RMSNorm(s))) with tanh-approximated GELUs and no biases, the last
+    product summed and kept in float32;  scores = softmax(z) over all
+    ``num_experts`` in float32.  ``state`` out is s: the next layer's
+    router mixes it in, so a layer's routing sees the layers before and
+    its gradient reaches their W_d and γ.  Feed ``scores`` to
+    ``RoutedExperts(score_func="given")``."""
+    param_cls = _MLPRouterParam
+    mxu = True
+
+    def list_arguments(self):
+        state = ["state", "state_gain"] if self.param.has_state else []
+        return ["data"] + state + ["down_weight", "norm_gamma", "fc1_weight",
+                                   "fc2_weight", "out_weight"]
+
+    def list_outputs(self):
+        return ["scores", "state"]
+
+    def infer_shape(self, in_shapes):
+        data = in_shapes[0]
+        if data is None:
+            require_known("MLPRouter", in_shapes[:1], ["data"])
+        if len(data) != 2:
+            raise MXNetError("MLPRouter: data must be (tokens, E)")
+        T, E = data
+        R, N = self.param.hidden_size, self.param.num_experts
+        state = [(T, R), (1,)] if self.param.has_state else []
+        return ([data] + state + [(R, E), (R,), (R, R), (R, R), (N, R)],
+                [(T, N), (T, R)], [])
+
+    def infer_type(self, in_types):
+        known = [t for t in in_types if t is not None]
+        base = known[0] if known else None
+        return ([base] * len(self.list_arguments()),
+                [_np.dtype("float32"), base], [])
+
+    def forward(self, inputs, aux, is_train, rng):
+        h, rest = inputs[0], list(inputs[1:])
+        if self.param.has_state:
+            prev, gain = rest[:2]
+            rest = rest[2:]
+        w_down, gamma, w1, w2, w_out = rest
+        s = h @ w_down.T
+        if self.param.has_state:
+            s = s + gain * prev
+        z = rms_norm(s, gamma, self.param.eps)
+        z = jax.nn.gelu(z @ w1.T, approximate=True)
+        z = jax.nn.gelu(z @ w2.T, approximate=True)
+        logits = jnp.dot(z, w_out.T, preferred_element_type=jnp.float32)
+        return [jax.nn.softmax(logits, axis=-1), s], None
+
+    def cost_mxu_dims(self, in_shapes, out_shapes):
+        T, E = in_shapes[0]
+        R, N = self.param.hidden_size, self.param.num_experts
+        return [(T, E, R), (T, R, R), (T, R, R), (T, R, N)]
+
+    def cost_flops(self, in_shapes, out_shapes):
+        return float(sum(2 * m * k * n for m, k, n in
+                         self.cost_mxu_dims(in_shapes, out_shapes)))
 
 
 def routing_counters(aux, node_name):
@@ -552,8 +668,11 @@ def _routed_cost(op, in_shapes, out_shapes):
     held = int(p.num_local_experts or N)
     rows = max(1, T * int(p.top_k) * held // N)     # expected, balanced
     S = int(p.shared_hidden_size)
-    flops = 2.0 * T * N * E + 6.0 * rows * E * H + 6.0 * T * E * S
-    dims = [(T, E, N), (rows, E, H), (rows, E, H), (rows, H, E)]
+    given = p.score_func == "given"     # the router is another node
+    flops = (0.0 if given else 2.0 * T * N * E) + 6.0 * rows * E * H \
+        + 6.0 * T * E * S
+    dims = ([] if given else [(T, E, N)]) \
+        + [(rows, E, H), (rows, E, H), (rows, H, E)]
     if S:
         dims += [(T, E, S), (T, E, S), (T, S, E)]
     return {"flops": flops, "mxu": True, "mxu_dims": dims}

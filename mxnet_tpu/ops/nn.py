@@ -9,6 +9,8 @@ BatchNorm keeps the reference's aux-state contract
 """
 from __future__ import annotations
 
+import functools
+
 import numpy as _np
 
 import jax
@@ -26,7 +28,8 @@ from .registry import (OperatorProperty, register_op, require_known,
 # ----------------------------------------------------------------------
 class _ActivationParam(ParamStruct):
     act_type = Field(str, required=True,
-                     enum=("relu", "sigmoid", "tanh", "softrelu", "silu"))
+                     enum=("relu", "sigmoid", "tanh", "softrelu", "silu",
+                           "gelu"))
 
 
 @register_op("Activation")
@@ -40,6 +43,8 @@ class Activation(OperatorProperty):
         "tanh": jnp.tanh,
         "softrelu": jax.nn.softplus,
         "silu": jax.nn.silu,        # x·sigmoid(x): the gate of a gated FFN
+        # the tanh approximation (``gelu_new`` / ``gelu_pytorch_tanh``)
+        "gelu": functools.partial(jax.nn.gelu, approximate=True),
     }
 
     def forward(self, inputs, aux, is_train, rng):

@@ -51,13 +51,19 @@ _LSE_ROWS = 8
 
 def attention_reference(q, k, v, causal=False, scale=None,
                         q_offset=0, kv_offset=0):
-    """Plain softmax attention; q (..., Sq, D), k/v (..., Sk, D).
+    """Plain softmax attention; q (..., Sq, D), k/v (..., Sk, D).  Where
+    q is (B, H, Sq, D) and k/v have fewer heads (grouped queries: H a
+    multiple of theirs), query head h attends key/value head
+    h // (H / H_kv): k and v are repeated, here and nowhere else.
 
     ``q_offset``/``kv_offset`` are the global positions of element 0 (used
     for causal masking of sequence chunks).
     """
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[-1])
+    group = _head_group(q, k)
+    if group > 1:
+        k, v = (jnp.repeat(t, group, axis=1) for t in (k, v))
     s = jnp.einsum("...qd,...kd->...qk", q, k) * scale
     if causal:
         qpos = jnp.arange(q.shape[-2])[:, None] + q_offset
@@ -66,6 +72,17 @@ def attention_reference(q, k, v, causal=False, scale=None,
     p = jax.nn.softmax(s, axis=-1)
     return jnp.einsum("...qk,...kd->...qd", p, v.astype(p.dtype)) \
         .astype(q.dtype)
+
+
+def _head_group(q, k):
+    """Query heads a key/value head of (B, H, S, D) operands: 1, or H /
+    H_kv where k has fewer heads than q."""
+    if q.ndim != 4 or q.shape[1] == k.shape[1]:
+        return 1
+    if q.shape[1] % k.shape[1]:
+        raise ValueError("%d query heads do not group over %d key/value "
+                         "heads" % (q.shape[1], k.shape[1]))
+    return q.shape[1] // k.shape[1]
 
 
 def _block_step(q, k, v, scale, causal, q_offset, kv_offset, m, l, o):
@@ -233,18 +250,19 @@ def _flash_blocks(sq, sk, block_q=None, block_k=None):
     return blocks
 
 
-def _flash_block_layout(bh, sq, sk, d, block_q, d_v=None):
+def _flash_block_layout(bh, sq, sk, d, block_q, d_v=None, group=1):
     """(block, array) pairs of the forward pallas_call, in q/k/v then
     o/lse order — the ONE place the kernel's block shapes live, shared
     by the call below and the registered MXL-K kernel spec
     (``flash_kernel_spec``) so the static tile validator always checks
     what actually runs.  ``d`` is the width of q and k, ``d_v`` that of
-    v and o where it differs."""
+    v and o where it differs; ``group`` the query heads a key/value head
+    (k and v then hold ``bh / group`` heads)."""
     d_v = d if d_v is None else d_v
     in_blocks = [
         ((None, block_q, d), (bh, sq, d)),              # q
-        ((None, sk, d), (bh, sk, d)),                   # k
-        ((None, sk, d_v), (bh, sk, d_v)),               # v
+        ((None, sk, d), (bh // group, sk, d)),          # k
+        ((None, sk, d_v), (bh // group, sk, d_v)),      # v
     ]
     out_blocks = [
         ((None, block_q, d_v), (bh, sq, d_v)),          # o
@@ -259,26 +277,37 @@ def _flash_forward_kernel_call(q, k, v, causal, scale, block_q, block_k,
                                interpret):
     """(o, lse) of the forward kernel.  Jitted so that a model's layers,
     which call it with the same shapes, trace and lower the kernel once
-    and not once a layer (24 layers of GPT-2-medium: 4–5 s of set-up)."""
+    and not once a layer (24 layers of GPT-2-medium: 4–5 s of set-up).
+
+    Grouped queries (k and v of fewer heads than q): the grid stays one
+    program a query head and query block, and the index map sends query
+    head b to key/value head b // group, so k and v are never repeated;
+    a group's heads follow one another in the grid and find their k and
+    v block already in VMEM."""
     import jax.experimental.pallas as pl
 
     B, H, Sq, D = q.shape
     sk, d_v = v.shape[-2:]
+    group = _head_group(q, k)
     q3 = q.reshape(B * H, Sq, D)
-    k3 = k.reshape(B * H, sk, D)
-    v3 = v.reshape(B * H, sk, d_v)
+    k3 = k.reshape(B * H // group, sk, D)
+    v3 = v.reshape(B * H // group, sk, d_v)
 
     (qb, kb, vb), (ob, lseb) = _flash_block_layout(B * H, Sq, sk, D,
-                                                   block_q, d_v)
+                                                   block_q, d_v, group)
     kernel = functools.partial(_flash_kernel, block_k=block_k,
                                causal=causal, scale=scale, seq_k=sk)
+    if group == 1:
+        kv_head = lambda b, i: (b, 0, 0)                # noqa: E731
+    else:
+        kv_head = lambda b, i: (b // group, 0, 0)       # noqa: E731
     out, lse = pl.pallas_call(
         kernel,
         grid=(B * H, Sq // block_q),
         in_specs=[
             pl.BlockSpec(qb[0], lambda b, i: (b, i, 0)),
-            pl.BlockSpec(kb[0], lambda b, i: (b, 0, 0)),
-            pl.BlockSpec(vb[0], lambda b, i: (b, 0, 0)),
+            pl.BlockSpec(kb[0], kv_head),
+            pl.BlockSpec(vb[0], kv_head),
         ],
         out_specs=[
             pl.BlockSpec(ob[0], lambda b, i: (b, i, 0)),
@@ -310,10 +339,10 @@ def _causal_q_blocks(k_block, block_q, block_k, n_q_blocks):
 
 def _flash_backward_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                            dq_ref, dk_ref, dv_ref, dq_acc, *, block_q,
-                           causal, scale):
-    """Grid: (batch*heads, k_blocks), the key blocks in order.  One key
-    block against the query blocks that can see it: all of them, or
-    under ``causal`` those from the diagonal down
+                           causal, scale, group):
+    """Grid: (batch*key/value heads, k_blocks), the key blocks in order.
+    One key block against the query blocks that can see it: all of them,
+    or under ``causal`` those from the diagonal down
     (``_causal_q_blocks``), of which only the ones the diagonal crosses
     are masked.  With p = exp(s·scale − lse) recomputed from the saved
     logsumexp and delta = rowsum(do ⊙ o):
@@ -328,11 +357,18 @@ def _flash_backward_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     blocks of one (batch·head) and is written out, cast once, after the
     last.  q, k, v, do are multiplied as they come; p and ds are rounded
     to their dtype for the products that consume them; every sum, the
-    softmax arithmetic and the three accumulators are float32."""
+    softmax arithmetic and the three accumulators are float32.
+
+    Grouped queries: the ``group`` query heads of this key/value head lie
+    one after another along the rows of q, do, dq (and of lse, delta and
+    the scratch, by query blocks), each a sequence of its own; the key
+    block meets every head's query blocks in turn and dk, dv sum over
+    them in the same float32 accumulators — k and v are read once a
+    group, dk and dv written once."""
     import jax.experimental.pallas as pl
 
     block_k = k_ref.shape[0]
-    n_q_blocks = q_ref.shape[0] // block_q
+    n_q_blocks = q_ref.shape[0] // (block_q * group)    # of one head
     j = pl.program_id(1)
     k_offset = j * block_k
     k = k_ref[...]
@@ -344,9 +380,11 @@ def _flash_backward_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     def _():
         dq_acc[...] = jnp.zeros_like(dq_acc)
 
-    def step(masked, i, carry):
+    def step(masked, first, i, carry):
+        # query block i of the head whose blocks start at row ``first``
         dk, dv = carry              # (block_k, d), (block_k, d_v)
-        start = pl.multiple_of(i * block_q, block_q)
+        row = i + first if first else i
+        start = pl.multiple_of(row * block_q, block_q)
         q = q_ref[pl.ds(start, block_q), :]
         do = do_ref[pl.ds(start, block_q), :]
         s = lax.dot_general(k_scaled, q, (((1,), (1,)), ((), ())),
@@ -354,30 +392,34 @@ def _flash_backward_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         if not fold:
             s = s * scale
         if masked:
-            s = _mask_past_diagonal(s, k_offset - start)
-        p = jnp.exp(s - lse_ref[i])
+            s = _mask_past_diagonal(s, k_offset - i * block_q)
+        p = jnp.exp(s - lse_ref[row])
         dv = dv + jnp.dot(p.astype(do.dtype), do,
                           preferred_element_type=jnp.float32)
         dp = lax.dot_general(v, do, (((1,), (1,)), ((), ())),
                              preferred_element_type=jnp.float32)
-        ds = (p * (dp - delta_ref[i])).astype(q.dtype)
+        ds = (p * (dp - delta_ref[row])).astype(q.dtype)
         dk = dk + jnp.dot(ds, q, preferred_element_type=jnp.float32)
-        dq_acc[i] += lax.dot_general(k, ds, (((0,), (0,)), ((), ())),
-                                     preferred_element_type=jnp.float32)
+        dq_acc[row] += lax.dot_general(k, ds, (((0,), (0,)), ((), ())),
+                                       preferred_element_type=jnp.float32)
         return dk, dv
 
     carry = (jnp.zeros(k_ref.shape, jnp.float32),
              jnp.zeros(v_ref.shape, jnp.float32))
-    if causal:
-        visited, unmasked = _causal_q_blocks(j, block_q, block_k,
-                                             n_q_blocks)
-        carry = lax.fori_loop(visited, unmasked,
-                              functools.partial(step, True), carry)
-        carry = lax.fori_loop(unmasked, n_q_blocks,
-                              functools.partial(step, False), carry)
-    else:
-        carry = lax.fori_loop(0, n_q_blocks, functools.partial(step, False),
-                              carry)
+    for head in range(group):
+        first = head * n_q_blocks
+        if causal:
+            visited, unmasked = _causal_q_blocks(j, block_q, block_k,
+                                                 n_q_blocks)
+            carry = lax.fori_loop(
+                visited, unmasked, functools.partial(step, True, first),
+                carry)
+            carry = lax.fori_loop(
+                unmasked, n_q_blocks, functools.partial(step, False, first),
+                carry)
+        else:
+            carry = lax.fori_loop(
+                0, n_q_blocks, functools.partial(step, False, first), carry)
     dk, dv = carry
     dk_ref[...] = (dk * scale).astype(dk_ref.dtype)
     dv_ref[...] = dv.astype(dv_ref.dtype)
@@ -389,33 +431,37 @@ def _flash_backward_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             dq_ref[pl.ds(start, block_q), :] = \
                 (dq_acc[i] * scale).T.astype(dq_ref.dtype)
             return 0
-        lax.fori_loop(0, n_q_blocks, write, 0)
+        lax.fori_loop(0, group * n_q_blocks, write, 0)
 
 
-def _flash_backward_block_layout(bh, sq, sk, d, block_q, block_k, d_v=None):
+def _flash_backward_block_layout(bh, sq, sk, d, block_q, block_k, d_v=None,
+                                 group=1):
     """(block, array) pairs of the backward pallas_call, in q/k/v/do/
     lse/delta then dq/dk/dv order, and the shape of its float32 dq
     scratch: shared by the call below and
-    ``flash_backward_kernel_spec``, like :func:`_flash_block_layout`.  q, do and dq are whole per
-    (batch·head), k, v, dk, dv go by key blocks; lse and delta are one
-    (1, block_q) row a query block, indexed by the block."""
+    ``flash_backward_kernel_spec``, like :func:`_flash_block_layout`.
+    ``bh`` counts key/value heads; the ``group`` query heads of each lie
+    along the rows (``rows`` = group·sq).  q, do and dq are whole per
+    (batch·key/value head), k, v, dk, dv go by key blocks; lse and delta
+    are one (1, block_q) row a query block, indexed by the block."""
     d_v = d if d_v is None else d_v
-    rows = ((None, sq // block_q, 1, block_q),
-            (bh, sq // block_q, 1, block_q))
+    rows = group * sq
+    stats = ((None, rows // block_q, 1, block_q),
+             (bh, rows // block_q, 1, block_q))
     in_blocks = [
-        ((None, sq, d), (bh, sq, d)),                   # q
+        ((None, rows, d), (bh, rows, d)),               # q
         ((None, block_k, d), (bh, sk, d)),              # k
         ((None, block_k, d_v), (bh, sk, d_v)),          # v
-        ((None, sq, d_v), (bh, sq, d_v)),               # do
-        rows,                                           # lse
-        rows,                                           # delta
+        ((None, rows, d_v), (bh, rows, d_v)),           # do
+        stats,                                          # lse
+        stats,                                          # delta
     ]
     out_blocks = [
-        ((None, sq, d), (bh, sq, d)),                   # dq
+        ((None, rows, d), (bh, rows, d)),               # dq
         ((None, block_k, d), (bh, sk, d)),              # dk
         ((None, block_k, d_v), (bh, sk, d_v)),          # dv
     ]
-    return in_blocks, out_blocks, (sq // block_q, d, block_q)
+    return in_blocks, out_blocks, (rows // block_q, d, block_q)
 
 
 def _vmem_bytes(shape, itemsize):
@@ -438,21 +484,24 @@ def _flash_backward_kernel_call(q, k, v, o, lse, do, causal, scale,
 
     B, H, Sq, D = q.shape
     sk, d_v = v.shape[-2:]
-    bh, n_q = B * H, Sq // block_q
+    group = _head_group(q, k)
+    # a key/value head's query heads are neighbours in (B, H, ...): they
+    # become one run of group·Sq rows by a reshape that moves nothing
+    bh, rows = B * H // group, group * Sq
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
-    operands = (q.reshape(bh, Sq, D), k.reshape(bh, sk, D),
-                v.reshape(bh, sk, d_v), do.reshape(bh, Sq, d_v),
-                lse.reshape(bh, n_q, 1, block_q),
-                delta.reshape(bh, n_q, 1, block_q))
+    operands = (q.reshape(bh, rows, D), k.reshape(bh, sk, D),
+                v.reshape(bh, sk, d_v), do.reshape(bh, rows, d_v),
+                lse.reshape(bh, rows // block_q, 1, block_q),
+                delta.reshape(bh, rows // block_q, 1, block_q))
     ins, outs, acc = _flash_backward_block_layout(bh, Sq, sk, D, block_q,
-                                                  block_k, d_v)
+                                                  block_k, d_v, group)
     # every block twice (the pipeline's two buffers), the scratch, and
     # room for the (block_k, block_q) float32 tiles between the products
     vmem = sum(2 * _vmem_bytes(blk[1:], x.dtype.itemsize)
                for (blk, _arr), x in zip(ins + outs, operands + (q, k, v))) \
         + _vmem_bytes(acc, 4) + 8 * block_q * block_k * 4 + (4 << 20)
     kernel = functools.partial(_flash_backward_kernel, block_q=block_q,
-                               causal=causal, scale=scale)
+                               causal=causal, scale=scale, group=group)
     whole = lambda b, j: (b, 0, 0)          # noqa: E731
     by_key = lambda b, j: (b, j, 0)         # noqa: E731
     rows = lambda b, j: (b, 0, 0, 0)        # noqa: E731
@@ -477,8 +526,11 @@ def _flash_backward_kernel_call(q, k, v, o, lse, do, causal, scale,
 
 def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
                     block_k=None, interpret=None):
-    """Fused attention; q/k (B, H, S, D), v (B, H, S, D_v) with D_v = D
-    or not (the output is as wide as v).  The Pallas kernel where the
+    """Fused attention; q (B, H, S, D), k (B, H_kv, S, D), v (B, H_kv, S,
+    D_v) with D_v = D or not (the output is as wide as v) and H_kv = H or
+    a divisor of it (grouped queries: head h attends key/value head
+    h // (H / H_kv); both kernels index k and v by that, neither repeats
+    them, and dk, dv come back summed over a group).  The Pallas kernel where the
     computation is placed on a TPU, the jnp reference elsewhere
     (``kernels.common.dispatch``: decided when the enclosing step is
     lowered, so a compile-only lowering against a TPU topology carries
@@ -665,8 +717,11 @@ def sharded_self_attention(q, k, v, causal=False):
     from jax import shard_map
     from jax.sharding import PartitionSpec as P
     mesh = ctx.mesh
+    group = _head_group(q, k)
 
     if ctx.seq_axis in mesh.axis_names:
+        if group > 1:       # the ring's einsums pair heads one to one
+            k, v = (jnp.repeat(t, group, axis=1) for t in (k, v))
         spec = P(ctx.batch_axis, None, ctx.seq_axis, None)
         check_vma = True
 
@@ -682,8 +737,10 @@ def sharded_self_attention(q, k, v, causal=False):
             size = mesh.shape.get(axis, 1) if axis else 1
             return axis if size > 1 and dim % size == 0 else None
 
+        # heads split only where the key/value heads do: a device keeps
+        # whole groups
         spec = P(split(ctx.batch_axis, q.shape[0]),
-                 split("tp", q.shape[1]), None, None)
+                 split("tp", k.shape[1]), None, None)
         check_vma = False       # pallas_call outputs declare no vma
 
         def att(q, k, v):
@@ -703,7 +760,8 @@ def _kernel_spec(name, grid, blocks):
 
 
 def flash_kernel_spec(batch_heads=8, seq_q=1024, seq_k=1024, head_dim=64,
-                      block_q=None, dtype="bfloat16", head_dim_v=None):
+                      block_q=None, dtype="bfloat16", head_dim_v=None,
+                      group=1):
     """MXL-K kernel spec for the flash forward pallas_call.
 
     Built from the same :func:`_flash_block_layout` the kernel itself
@@ -715,7 +773,7 @@ def flash_kernel_spec(batch_heads=8, seq_q=1024, seq_k=1024, head_dim=64,
     """
     block_q, _block_k = _flash_blocks(seq_q, seq_k, block_q)
     ins, outs = _flash_block_layout(batch_heads, seq_q, seq_k, head_dim,
-                                    block_q, head_dim_v)
+                                    block_q, head_dim_v, group)
     return _kernel_spec(
         "flash_forward", (batch_heads, seq_q // block_q),
         [("in", n, b, dtype) for n, b in zip(("q", "k", "v"), ins)]
@@ -724,7 +782,7 @@ def flash_kernel_spec(batch_heads=8, seq_q=1024, seq_k=1024, head_dim=64,
 
 def flash_backward_kernel_spec(batch_heads=8, seq_q=1024, seq_k=1024,
                                head_dim=64, dtype="bfloat16",
-                               head_dim_v=None):
+                               head_dim_v=None, group=1):
     """MXL-K kernel spec for the flash backward pallas_call, from the
     :func:`_flash_backward_block_layout` the call itself uses and the
     blocks ``flash_attention`` gives it for these shapes.  lse and
@@ -732,9 +790,10 @@ def flash_backward_kernel_spec(batch_heads=8, seq_q=1024, seq_k=1024,
     covers its array's last two dims whole."""
     block_q, block_k = _flash_blocks(seq_q, seq_k)
     ins, outs, _acc = _flash_backward_block_layout(
-        batch_heads, seq_q, seq_k, head_dim, block_q, block_k, head_dim_v)
+        batch_heads // group, seq_q, seq_k, head_dim, block_q, block_k,
+        head_dim_v, group)
     return _kernel_spec(
-        "flash_backward", (batch_heads, seq_k // block_k),
+        "flash_backward", (batch_heads // group, seq_k // block_k),
         [("in", n, b, "float32" if n in ("lse", "delta") else dtype)
          for n, b in zip(("q", "k", "v", "do", "lse", "delta"), ins)]
         + [("out", n, b, dtype) for n, b in zip(("dq", "dk", "dv"), outs)])

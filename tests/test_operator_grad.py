@@ -271,6 +271,30 @@ GRAD_SPECS = {
          "re_shared_up_weight": _f64(4, 4) * 0.4,
          "re_shared_down_weight": _f64(4, 4) * 0.4},
         {"rtol": 5e-2, "atol": 5e-3}),
+    "CompressedConvAttention": lambda: (
+        sym.CompressedConvAttention(
+            V("a"), num_heads=4, num_kv_heads=2, head_dim=4,
+            rope_theta=100.0, partial_rotary_factor=0.5, name="ca"),
+        {"a": _f64(1, 5, 6), "ca_q_weight": _f64(16, 6) * 0.6,
+         "ca_k_weight": _f64(8, 6) * 0.6, "ca_v_weight": _f64(8, 6) * 0.6,
+         "ca_q_conv0_weight": _f64(2, 16) * 0.5 + 0.5,
+         "ca_q_conv1_weight": _f64(2, 4, 4, 4) * 0.5,
+         "ca_k_conv0_weight": _f64(2, 8) * 0.5 + 0.5,
+         "ca_k_conv1_weight": _f64(2, 2, 4, 4) * 0.5,
+         "ca_k_temp": _pos64(2), "ca_out_weight": _f64(6, 16) * 0.6},
+        {"rtol": 5e-2, "atol": 5e-2}),
+    # the scores sum to one a token: they are weighed by a fixed "w" so
+    # that their gradient shows in the sum of the outputs
+    "MLPRouter": lambda: (
+        (lambda r: sym.Group([r[0] * V("w"), r[1]]))(sym.MLPRouter(
+            V("a"), state=V("s"), num_experts=4, hidden_size=3, name="ro")),
+        {"a": _f64(5, 6), "s": _f64(5, 3), "w": _f64(5, 4),
+         "ro_state_gain": _pos64(1), "ro_down_weight": _f64(3, 6) * 0.6,
+         "ro_norm_gamma": _pos64(3), "ro_fc1_weight": _f64(3, 3),
+         "ro_fc2_weight": _f64(3, 3), "ro_out_weight": _f64(4, 3)},
+        {"grad_nodes": ["a", "s", "ro_state_gain", "ro_down_weight",
+                        "ro_norm_gamma", "ro_fc1_weight", "ro_fc2_weight",
+                        "ro_out_weight"], "rtol": 5e-2, "atol": 5e-3}),
     "SequenceLast": lambda: (sym.SequenceLast(V("a")),
                              {"a": _f64(4, 2, 3)}, {}),
     "SequenceReverse": lambda: (sym.SequenceReverse(V("a")),

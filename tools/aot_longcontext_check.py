@@ -75,6 +75,13 @@ def kernel_cases():
                                                     jnp.bfloat16)
     cases.append(("flash_fwd_bwd[bfloat16,latent]",
                   jax.grad(flash_loss, argnums=(0, 1, 2)), (qk, qk, v)))
+    # grouped queries: 8 heads of 128 on 2 key/value heads, 8,192 keys —
+    # the backward holds a group's q, do and dq whole, four heads' worth
+    b, h, h_kv, s, d = widths["flash_grouped"]
+    q, kv = sds((b, h, s, d), jnp.bfloat16), sds((b, h_kv, s, d),
+                                                 jnp.bfloat16)
+    cases.append(("flash_fwd_bwd[bfloat16,grouped]",
+                  jax.grad(flash_loss, argnums=(0, 1, 2)), (q, kv, kv)))
     # serve/lm (GPT-2 small): decode rows 8, prefill rows 256
     for m, k, n in widths["qmm"]:
         for dt in (jnp.float32, jnp.bfloat16):
