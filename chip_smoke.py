@@ -467,6 +467,7 @@ def phase_kernels(env, cfg, lm_params):
     import jax
     import jax.numpy as jnp
     from mxnet_tpu import optimizer as opt_mod
+    from mxnet_tpu.executor import mirror_checkpoint
     from mxnet_tpu.kernels import fused_opt, quantize
     from mxnet_tpu.parallel.ring_attention import (attention_reference,
                                                    flash_attention)
@@ -529,7 +530,25 @@ def phase_kernels(env, cfg, lm_params):
         require(max(errs) <= tol, "%s: out/dq/dk/dv errors %s exceed %g"
                 % (name, errs, tol))
         out[name] = round(max(errs), 5)
-        del q, k, v, got_o, got_g, want_o, want_g
+
+        # a mirrored block around the kernel, as the executor lowers one:
+        # its gradient is the unmirrored block's, with the tanh recomputed
+        # and the kernel not.  The two programs compile the backward's
+        # float32 row sums (delta) apart, so a ds may round to its other
+        # neighbour: one step of the dtype at most (dv, which reads no
+        # delta, came out equal to the bit)
+        def block(q, k, v):
+            return kernel(q, jnp.tanh(k), v)
+
+        _, plain_g = jax.jit(grad(block))(q, k, v)
+        _, kept_g = jax.jit(grad(mirror_checkpoint(block)))(q, k, v)
+        errs = [_rel_err(g, w) for g, w in zip(kept_g, plain_g)]
+        step = max(1e-6, 2 * float(jnp.finfo(dt).eps))
+        require(max(errs) <= step,
+                "%s mirrored: dq/dk/dv differ from the unmirrored block's "
+                "by %s (a step is %g)" % (name, errs, step))
+        out[name + ",mirrored"] = round(max(errs), 5)
+        del q, k, v, got_o, got_g, want_o, want_g, plain_g, kept_g
 
     # weight-only quantized matmul, FFN shapes and the LM head
     for m, k, n in cfg["qmm"]:

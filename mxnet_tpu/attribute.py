@@ -55,9 +55,17 @@ def mirror_scope(stage_name, enabled=True):
     recompute: ``force_mirroring`` (overrides the env knob's conv skip
     list) + ``mirror_stage=stage_name`` (segment boundary — ops sharing
     a stage form ONE jax.checkpoint segment in the executor's mirror
-    lowering, executor.py ``_mirror_segments``).  ``enabled=False``
-    returns a no-op context so model builders can expose a
-    ``mirror_blocks`` flag without branching (models/resnet.py,
+    lowering, executor.py ``_mirror_segments``).  A segment saves its
+    inputs and recomputes the rest in backward, but for the values their
+    producer names in ``executor.KEPT``: what the flash attention kernel
+    hands its backward (q, k, v, the output, the softmax statistics),
+    because recomputing the last two is a second call of the kernel and
+    they fit only the operands they were made from — so a block with
+    attention in it runs the forward kernel once, and a block with none
+    saves its inputs alone.  What is kept depends only on what the
+    traced segment holds; nothing here or in the environment selects it.
+    ``enabled=False`` returns a no-op context so model builders can
+    expose a ``mirror_blocks`` flag without branching (models/resnet.py,
     models/transformer.py)."""
     if not enabled:
         import contextlib

@@ -19,6 +19,7 @@ from __future__ import annotations
 import numpy as _np
 
 import jax
+import jax.extend
 import jax.numpy as jnp
 
 from .base import MXNetError
@@ -26,6 +27,7 @@ from .context import Context
 from .ndarray import NDArray, zeros
 from . import random as _random
 from .observability import spans as _spans
+from .parallel.ring_attention import FLASH_RESIDUALS
 from .train_step import (apply_updates, compute_cast, loss_and_grads,
                          no_cast, preprocess_grads)
 
@@ -42,13 +44,18 @@ def _zero_key():
 __all__ = ["Executor", "simple_bind", "trace_residual_bytes"]
 
 
-def trace_residual_bytes(trace, arg_values, aux_values, wrt_names):
-    """Bytes of residuals jax's vjp would save across ``trace`` when
-    differentiating wrt ``wrt_names`` — the backend-independent
-    activation-memory number (what mirroring shrinks).  Shared by
-    Executor.backward_residual_bytes, the multichip dryrun, and the
-    mirror tests."""
-    from jax._src.ad_checkpoint import saved_residuals
+def _saved_residuals(trace, arg_values, aux_values, wrt_names):
+    """``[(aval, checkpoint_name or None)]`` of what jax's vjp would
+    save across ``trace`` when differentiating wrt ``wrt_names``: the
+    residual outputs of the linearized trace's jaxpr (how
+    ``jax.ad_checkpoint.saved_residuals`` finds them), each with the
+    name its producer gave it.  jax's own listing describes a residual
+    by the equation that made it, so it calls a named value ``named``
+    only where nothing stands between: here the name is followed back
+    through the ``reduce_precision`` jax puts on a saved value that the
+    forward pass also reads, through the branches of the ``cond`` that
+    ``kernels.common.dispatch`` stages and through the ``shard_map``
+    that ``sharded_self_attention`` wraps the kernel in."""
     wrt = {n: arg_values[n] for n in wrt_names}
 
     def f(wrt_values):
@@ -56,13 +63,74 @@ def trace_residual_bytes(trace, arg_values, aux_values, wrt_names):
         merged.update(wrt_values)
         return trace(merged, aux_values, _zero_key(), True)
 
-    total = 0
-    for aval, _desc in saved_residuals(f, wrt):
-        size = getattr(aval, "size", None)
-        dtype = getattr(aval, "dtype", None)
-        if size is not None and dtype is not None:
-            total += int(size) * dtype.itemsize
-    return total
+    closed, (_outs, f_jvp) = jax.make_jaxpr(
+        lambda w: jax.linearize(f, w), return_shape=True)(wrt)
+    jaxpr = closed.jaxpr
+    n_res = len(jax.tree_util.tree_leaves(f_jvp))
+    residuals = jaxpr.outvars[len(jaxpr.outvars) - n_res:]
+    name_of = _namer(jaxpr)
+    return [(v.aval, name_of(v)) for v in residuals]
+
+
+def _namer(jaxpr):
+    """``var -> the checkpoint_name it carries, or None`` for the
+    variables of ``jaxpr`` (see ``_saved_residuals``)."""
+    made_by = {v: e for e in jaxpr.eqns for v in e.outvars}
+    named_as = {e.invars[0]: e for e in jaxpr.eqns
+                if e.primitive.name == "name"}
+
+    def name_of(var):
+        while isinstance(var, jax.extend.core.Var):
+            eqn = named_as.get(var) or made_by.get(var)
+            if eqn is None:
+                return None
+            if eqn.primitive.name == "name":
+                return eqn.params["name"]
+            if eqn.primitive.name == "reduce_precision":
+                var = eqn.invars[0]
+                continue
+            if eqn.primitive.name not in ("cond", "shard_map"):
+                return None
+            # the equation's output i is its inner jaxprs' output i
+            i = eqn.outvars.index(var)
+            for inner in eqn.params.get("branches") or (
+                    eqn.params["jaxpr"],):
+                inner = getattr(inner, "jaxpr", inner)
+                name = _namer(inner)(inner.outvars[i])
+                if name is not None:
+                    return name
+            return None
+    return name_of
+
+
+def _nbytes(aval):
+    size = getattr(aval, "size", None)
+    dtype = getattr(aval, "dtype", None)
+    if size is None or dtype is None:
+        return 0
+    return int(size) * dtype.itemsize
+
+
+def trace_residual_bytes(trace, arg_values, aux_values, wrt_names):
+    """Bytes of residuals jax's vjp would save across ``trace`` when
+    differentiating wrt ``wrt_names`` — the backend-independent
+    activation-memory number (what mirroring shrinks; the values a
+    mirrored segment keeps by name, ``trace_mirror_kept``, are counted).
+    Shared by Executor.backward_residual_bytes, the multichip dryrun, and
+    the mirror tests."""
+    return sum(_nbytes(aval) for aval, _name in _saved_residuals(
+        trace, arg_values, aux_values, wrt_names))
+
+
+def trace_mirror_kept(trace, arg_values, aux_values, wrt_names):
+    """``[(name, bytes)]`` of the residuals of ``trace`` that are saved
+    under a ``checkpoint_name``: what the mirrored segments keep by
+    ``KEPT`` instead of recomputing, one entry a value (the same names
+    come back once a block; outside a mirrored segment a named value is
+    saved like any other and listed too).  Empty where no segment holds
+    a producer that names anything."""
+    return [(name, _nbytes(aval)) for aval, name in _saved_residuals(
+        trace, arg_values, aux_values, wrt_names) if name is not None]
 
 
 def _as_list(obj, names, what):
@@ -84,15 +152,29 @@ class _Program:
     """Compiled form of a symbol graph: pure trace + jitted entries."""
 
     __slots__ = ("trace", "jit_forward", "jit_fwd_bwd", "needs_rng",
-                 "_jit_forward_mon", "monitor_sink")
+                 "mirrored", "_jit_forward_mon", "monitor_sink")
 
-    def __init__(self, trace, jit_forward, jit_fwd_bwd, needs_rng):
+    def __init__(self, trace, jit_forward, jit_fwd_bwd, needs_rng,
+                 mirrored=False):
         self.trace = trace
         self.jit_forward = jit_forward
         self.jit_fwd_bwd = jit_fwd_bwd
         self.needs_rng = needs_rng
+        self.mirrored = mirrored        # some segment is a checkpoint
         self._jit_forward_mon = None
         self.monitor_sink = None
+
+    def mirror_kept(self, arg_values, aux_values, wrt_names):
+        """``[(name, bytes)]`` of what the mirrored segments save by name
+        (``KEPT``) instead of recomputing, for these shapes: the counter
+        that says the kernel's second forward call is gone.  Traced on
+        demand, like ``trace_residual_bytes``; ``[]`` for a program with
+        no mirrored segment, whose named values are saved like all its
+        others."""
+        if not self.mirrored:
+            return []
+        return trace_mirror_kept(self.trace, arg_values, aux_values,
+                                 wrt_names)
 
     def jit_forward_monitored(self):
         """Compiled forward that streams every op output to the installed
@@ -137,6 +219,28 @@ def _consumable(arrays, beside=None):
     return out
 
 
+# The ``checkpoint_name``s a mirrored segment saves instead of
+# recomputing.  The rule: a producer names what a kernel hands its own
+# backward when recomputing it costs a second call of the kernel, and it
+# names that set whole — the flash forward's operands q, k, v with its
+# output and softmax statistics.  The operands alone would be cheap to
+# recompute, but in bfloat16 a recomputation is not the first computation
+# to the bit, and statistics kept from the first call no longer normalise
+# scores made from recomputed operands (PERF.md section 6, PR 32: a 1 %
+# error in ZAYA1's gradient norms).  Held a block: 336 MB in JoyAI (q and
+# k 101 MB each), 42 MB in ZAYA1, against a block input of 33.5 MB.  A
+# segment that holds no such name saves what a policy-less checkpoint
+# saves: its inputs.
+KEPT = FLASH_RESIDUALS
+
+
+def mirror_checkpoint(fn):
+    """``fn`` as a mirrored segment runs it: recomputed in backward from
+    its inputs, but for the values named in ``KEPT``."""
+    return jax.checkpoint(
+        fn, policy=jax.checkpoint_policies.save_only_these_names(*KEPT))
+
+
 def _mirror_segments(op_nodes):
     """Partition the op schedule into checkpoint segments — the
     jax-native MakeBackwardPass mirror map (static_graph.cc:396-440).
@@ -149,7 +253,8 @@ def _mirror_segments(op_nodes):
     reference's default: a periodic keep so recompute chains stay
     bounded).  Consecutive mirrored nodes form ONE
     ``jax.checkpoint`` segment — internals dropped from the residual set
-    and recomputed in backward — split at differing ``mirror_stage``
+    and recomputed in backward, but for the values their producer names
+    in ``KEPT`` — split at differing ``mirror_stage``
     attrs so users can pin stage boundaries.  ``op_nodes`` excludes
     variables (hoisted to a prelude: a weight/bias variable must not
     break an otherwise-contiguous mirror run).  Returns
@@ -254,7 +359,10 @@ def _build_program(symbol, group2ctx):
     graph_executor.cc:391) with XLA inserting the transfers.  Mirrored
     nodes (static_graph.cc:396 MakeBackwardPass) lower to per-segment
     ``jax.checkpoint``: their activations leave the residual set and are
-    recomputed during the vjp — the TPU-native memory/FLOPs trade.
+    recomputed during the vjp — the TPU-native memory/FLOPs trade.  The
+    checkpoint (``mirror_checkpoint``) saves the names in ``KEPT``, so a
+    block that holds the flash kernel recomputes everything but the
+    kernel's call and its operands.
     """
     topo = symbol._topo()
     heads = list(symbol._heads)
@@ -375,7 +483,7 @@ def _build_program(symbol, group2ctx):
                 return [local[k] for k in _out_keys], local_aux_out
 
             seg_aux_in = {a: aux_values[a] for a in aux_names}
-            seg_outs, seg_aux_out = jax.checkpoint(seg_fn)(
+            seg_outs, seg_aux_out = mirror_checkpoint(seg_fn)(
                 [values[k] for k in ext_keys], seg_aux_in, seg_keys)
             for k, v in zip(out_keys, seg_outs):
                 values[k] = v
@@ -390,7 +498,7 @@ def _build_program(symbol, group2ctx):
                               rng, out_grads)
 
     return _Program(trace, jax.jit(trace, static_argnames=("is_train",)),
-                    jax.jit(fwd_bwd), needs_rng)
+                    jax.jit(fwd_bwd), needs_rng, mirrored=any_mirror)
 
 class Executor:
     """Parity: include/mxnet/symbolic.h:323 + python/mxnet/executor.py."""
@@ -885,19 +993,28 @@ class Executor:
         return self._lower_fused(optimizer, states).compile(
             ).memory_analysis()
 
+    def _bound_for_vjp(self):
+        return ({n: a.data for n, a in self.arg_dict.items()},
+                {n: a.data for n, a in self.aux_dict.items()},
+                tuple(n for n in self._arg_names
+                      if self._grad_req.get(n, "null") != "null"))
+
     def backward_residual_bytes(self):
         """Bytes of residuals jax saves between forward and backward for
         the bound shapes — the activation-memory quantity mirroring
         (``force_mirroring``/MXNET_BACKWARD_DO_MIRROR ->
-        ``jax.checkpoint``) exists to shrink.  Backend-independent: read
-        from the partial-eval trace, not the compiled executable (XLA:CPU
-        does not attribute temp buffers)."""
-        arg_values = {n: a.data for n, a in self.arg_dict.items()}
-        aux_values = {n: a.data for n, a in self.aux_dict.items()}
-        wrt_names = tuple(n for n in self._arg_names
-                          if self._grad_req.get(n, "null") != "null")
-        return trace_residual_bytes(self._program.trace, arg_values,
-                                    aux_values, wrt_names)
+        ``jax.checkpoint``) exists to shrink.  It counts what a mirrored
+        segment keeps by name (``mirror_kept``: the flash kernel's
+        operands, output and statistics) beside the segments' inputs.
+        Backend-independent: read from the partial-eval trace, not the
+        compiled executable (XLA:CPU does not attribute temp buffers)."""
+        return trace_residual_bytes(self._program.trace,
+                                    *self._bound_for_vjp())
+
+    def mirror_kept(self):
+        """``[(name, bytes)]`` the mirrored segments keep by name for the
+        bound shapes (``_Program.mirror_kept``)."""
+        return self._program.mirror_kept(*self._bound_for_vjp())
 
     def init_fused_states(self, optimizer):
         """Optimizer-state arrays for every learnable arg (fused path)."""

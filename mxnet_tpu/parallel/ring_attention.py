@@ -38,10 +38,22 @@ import math
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 
 __all__ = ["attention_reference", "flash_attention", "ring_attention",
            "blockwise_combine", "sequence_parallel",
-           "current_sequence_parallel", "attention_scope"]
+           "current_sequence_parallel", "attention_scope",
+           "FLASH_RESIDUALS"]
+
+# What the flash forward hands its backward — q, k, v, the output and the
+# logsumexp — each under a ``checkpoint_name``: a ``jax.checkpoint`` whose
+# policy saves these names (the executor's mirrored segments) does not run
+# the kernel again to recompute the last two.  The operands are named with
+# them because the five belong together: q, k, v recomputed in bfloat16
+# differ in the last bit from the first call's (XLA fuses a recomputation
+# differently), and statistics kept from the first call then fit them no
+# longer (PERF.md section 6, PR 32).
+FLASH_RESIDUALS = ("flash_q", "flash_k", "flash_v", "flash_out", "flash_lse")
 
 _NEG_INF = -1e30
 # sublanes of a float32 tile: the logsumexp row is stored once per
@@ -546,8 +558,12 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
     0 .. ⌈(i + 1)·block_q / block_k⌉ − 1 and masks only those the
     diagonal crosses; the blocks above it are never read.
 
-    Differentiable: the forward runs the fused kernel and saves the
-    logsumexp stats; the backward is a second Pallas kernel
+    Differentiable: the forward runs the fused kernel and saves its
+    operands, its output and the logsumexp stats, each under a
+    ``checkpoint_name`` (``FLASH_RESIDUALS``: a checkpoint that saves
+    those names does not run the kernel again, and its backward reads
+    what the forward read and wrote); the backward is a second Pallas
+    kernel
     (``_flash_backward_kernel``, attached via custom_vjp) under the same
     rules: it recomputes p a (block_k, block_q) tile at a time from the
     stats, never the (Sq, Sk) score matrix; multiplies q, k, v, do as
@@ -583,7 +599,11 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
         def _fa_fwd(q, k, v):
             out, lse = _flash_forward_kernel_call(
                 q, k, v, causal, scale, block_q, block_k, interpret)
-            return out, (q, k, v, out, lse)
+            # a name is the identity: outside a checkpoint it lowers to
+            # nothing
+            res = tuple(checkpoint_name(x, name) for x, name in zip(
+                (q, k, v, out, lse), FLASH_RESIDUALS))
+            return res[3], res
 
         def _fa_bwd(res, ct):
             q, k, v, out, lse = res
