@@ -54,6 +54,8 @@ FULL = {
                   prompt_lengths=(5, 40, 64, 100, 200, 256, 17, 130)),
     "kernels": dict(flash=(8, 8, 1024, 64), flash_latent=(1, 32, 8192, 192, 128),
                     flash_grouped=(1, 8, 2, 8192, 128),
+                    flash_gqa256=(1, 16, 2, 8192, 256),
+                    delta_rule=(1, 4096, 32, 128, 128, 512),
                     qmm=((8, 768, 3072), (256, 768, 3072), (8, 3072, 768),
                          (8, 768, 50257), (256, 768, 50257))),
 }
@@ -68,6 +70,8 @@ TINY = {
                   prompt_lengths=(3, 8, 12, 16, 5)),
     "kernels": dict(flash=(1, 2, 256, 8), flash_latent=(1, 2, 256, 24, 16),
                     flash_grouped=(1, 4, 2, 256, 16),
+                    flash_gqa256=(1, 8, 1, 256, 32),
+                    delta_rule=(1, 128, 2, 16, 8, 64),
                     qmm=((8, 256, 384), (300, 600, 1000))),
 }
 
@@ -486,17 +490,21 @@ def phase_kernels(env, cfg, lm_params):
     # flash-attention forward (+ the backward kernel its stats feed):
     # 64-wide heads in both dtypes, latent attention's shape — q and k
     # 192 wide, v 128, 8,192 keys — and grouped queries' — 8 heads of 128
-    # on 2 key/value heads, 8,192 keys — in bfloat16.  The reference goes
+    # on 2 key/value heads, and 16 heads of 256 on 2 (the forward asks for
+    # VMEM, the backward splits each group of eight over four programs),
+    # 8,192 keys — in bfloat16.  The reference goes
     # by query blocks, each against the keys up to its end, so that 8,192
     # keys of 32 heads fit beside the kernel's own buffers; it repeats
     # k and v over a group, which the kernels do not.
     b, h, s, d = cfg["flash"]
     lb, lh, ls, l_qk, l_v = cfg["flash_latent"]
     gb, gh, gkv, gs, gd = cfg["flash_grouped"]
+    wb, wh, wkv, ws, wd = cfg["flash_gqa256"]
     cases = [((b, h, h, s, d, d), jnp.float32, 2e-2, ""),
              ((b, h, h, s, d, d), jnp.bfloat16, 4e-2, ""),
              ((lb, lh, lh, ls, l_qk, l_v), jnp.bfloat16, 4e-2, ",latent"),
-             ((gb, gh, gkv, gs, gd, gd), jnp.bfloat16, 4e-2, ",grouped")]
+             ((gb, gh, gkv, gs, gd, gd), jnp.bfloat16, 4e-2, ",grouped"),
+             ((wb, wh, wkv, ws, wd, wd), jnp.bfloat16, 4e-2, ",gqa256")]
     for (b, h, h_kv, s, d_qk, d_v), dt, tol, tag in cases:
         q = put(jnp.asarray(rng.randn(b, h, s, d_qk), dt))
         k = put(jnp.asarray(rng.randn(b, h_kv, s, d_qk), dt))
@@ -549,6 +557,46 @@ def phase_kernels(env, cfg, lm_params):
                 "by %s (a step is %g)" % (name, errs, step))
         out[name + ",mirrored"] = round(max(errs), 5)
         del q, k, v, got_o, got_g, want_o, want_g, plain_g, kept_g
+
+    # the gated delta rule: the chunk-parallel form the op runs against the
+    # recurrence a token at a time, outputs over the long sequence and every
+    # gradient over a short one (the recurrence's backward keeps a state a
+    # token).  float32 operands still multiply at the chip's default
+    # precision in the chunked form, so both dtypes are held to rounding
+    # of bfloat16's order.
+    from mxnet_tpu.ops import linear_attention as la
+    rb, rs, rh, r_dk, r_dv, r_short = cfg["delta_rule"]
+    for dt, tol in ((jnp.float32, 2e-2), (jnp.bfloat16, 4e-2)):
+        q = la.l2_normalise(rng.randn(rb, rs, rh, r_dk)) * r_dk ** -0.5
+        k = la.l2_normalise(rng.randn(rb, rs, rh, r_dk) + 0.5)
+        q, k = put(q.astype(dt)), put(k.astype(dt))
+        v = put(jnp.asarray(rng.randn(rb, rs, rh, r_dv), dt))
+        g = put(-jnp.exp(jnp.asarray(rng.uniform(-6, 2, (rb, rs, rh)),
+                                     jnp.float32)))
+        beta = put(jax.nn.sigmoid(jnp.asarray(rng.randn(rb, rs, rh),
+                                              jnp.float32)))
+        args = (q, k, v, g, beta)
+        name = "gated_delta_rule[%s]" % jnp.dtype(dt).name
+        got = jax.jit(la.gated_delta_rule)(*args)
+        want = highest(la.gated_delta_rule_recurrent, *args)
+        err = _rel_err(got, want)
+        require(err <= tol, "%s: output error %g exceeds %g"
+                % (name, err, tol))
+
+        def rule_loss(fn, *a):
+            return jnp.sum(jnp.sin(fn(*a).astype(jnp.float32)))
+
+        short = tuple(t[:, :r_short] for t in args)
+        wrt = (0, 1, 2, 3, 4)
+        got_g = jax.jit(jax.grad(functools.partial(
+            rule_loss, la.gated_delta_rule), wrt))(*short)
+        want_g = highest(jax.grad(functools.partial(
+            rule_loss, la.gated_delta_rule_recurrent), wrt), *short)
+        errs = [_rel_err(a, b) for a, b in zip(got_g, want_g)]
+        require(max(errs) <= 2 * tol, "%s: dq/dk/dv/dg/dbeta errors %s "
+                "exceed %g" % (name, errs, 2 * tol))
+        out[name] = round(max([err] + errs), 5)
+        del q, k, v, g, beta, args, short, got, want, got_g, want_g
 
     # weight-only quantized matmul, FFN shapes and the LM head
     for m, k, n in cfg["qmm"]:

@@ -26,6 +26,7 @@ from . import transformer
 from . import transformer_moe
 from . import transformer_mla_moe
 from . import transformer_cca_moe
+from . import transformer_hybrid_moe
 from .mlp import get_symbol as get_mlp
 from .lenet import get_symbol as get_lenet
 from .alexnet import get_symbol as get_alexnet
@@ -36,7 +37,7 @@ from .inception_v3 import get_symbol as get_inception_v3
 from .resnet import get_symbol as get_resnet
 
 __all__ = ["transformer", "transformer_moe", "transformer_mla_moe",
-           "transformer_cca_moe", "mlp", "lenet", "alexnet",
+           "transformer_cca_moe", "transformer_hybrid_moe", "mlp", "lenet", "alexnet",
            "vgg", "googlenet",
            "inception_bn", "inception_v3", "resnet", "lstm", "gru", "rnn",
            "get_mlp", "get_lenet", "get_alexnet", "get_vgg",

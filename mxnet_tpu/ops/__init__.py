@@ -9,3 +9,4 @@ from . import rnn     # noqa: F401
 from . import vision  # noqa: F401
 from . import attention  # noqa: F401
 from . import moe     # noqa: F401
+from . import linear_attention  # noqa: F401

@@ -1,7 +1,9 @@
 """Transformer operators: LayerNorm, RMSNorm, MultiHeadAttention,
 MultiHeadLatentAttention (low-rank query and key/value paths, one rotary
 key shared by all heads), CompressedConvAttention (attention inside a
-compressed latent mixed by two causal convolutions, grouped query heads).
+compressed latent mixed by two causal convolutions, grouped query heads),
+GatedAttention (grouped query heads, RMSNorm on q and k, a partial rotary,
+a sigmoid gate on the output).
 
 TPU-native extensions beyond the reference op set (the reference predates
 transformers; SURVEY §5 notes its only long-sequence tools are bucketing
@@ -13,6 +15,7 @@ from __future__ import annotations
 
 import numpy as _np
 
+import jax
 import jax.numpy as jnp
 from jax import lax
 
@@ -307,6 +310,99 @@ class CompressedConvAttention(OperatorProperty):
             heads(q), heads(k),
             v.reshape(B, S, K, d).transpose(0, 2, 1, 3), causal=True)
         return [o.transpose(0, 2, 1, 3).reshape(B, S, H * d) @ wo.T], None
+
+
+class _GatedAttentionParam(ParamStruct):
+    num_heads = Field(int, required=True, lower=1, doc="query heads")
+    num_kv_heads = Field(int, required=True, lower=1,
+                         doc="key/value heads, a divisor of num_heads")
+    head_dim = Field(int, required=True, lower=2)
+    rope_theta = Field(float, default=10000.0)
+    partial_rotary_factor = Field(float, default=1.0,
+                                  doc="share of a head that is rotated")
+    eps = Field(float, default=1e-6, doc="of the q and k RMSNorms")
+
+
+@register_op("GatedAttention")
+class GatedAttention(OperatorProperty):
+    """Softmax attention with grouped query heads, per-head RMSNorm on q
+    and k, a partial rotary and a sigmoid output gate (the full-attention
+    layer of the ``qwen3_next`` family), data (B, S, E) -> (B, S, E).
+
+    H = ``num_heads`` query heads on H_kv = ``num_kv_heads`` key/value
+    heads of d = ``head_dim``.  [q_h ‖ gate_h] = u W_qᵀ a head (E → H·2d);
+    k, v = u W_kᵀ, u W_vᵀ (E → H_kv·d);  q and k through an RMSNorm over
+    d with a gain vector each (``q_norm_gamma``, ``k_norm_gamma``: one for
+    all heads);  rotary on the first ``partial_rotary_factor``·d channels,
+    half-split pairing (:func:`rotary_half`);  causal
+    softmax(q kᵀ / √d) v, query head h on key/value head h // (H / H_kv)
+    (the flash path of ``parallel/ring_attention.py``, k and v of H_kv
+    heads, repeated nowhere);  y = (o ⊙ sigmoid(gate)) W_oᵀ (H·d → E): the
+    gate is elementwise, outside the kernel.  No biases; weights are
+    (out_features, in_features)."""
+    param_cls = _GatedAttentionParam
+    mxu = True
+
+    def list_arguments(self):
+        return ["data", "q_weight", "k_weight", "v_weight", "q_norm_gamma",
+                "k_norm_gamma", "out_weight"]
+
+    def infer_shape(self, in_shapes):
+        data = in_shapes[0]
+        if data is None:
+            require_known("GatedAttention", in_shapes[:1], ["data"])
+        if len(data) != 3:
+            raise MXNetError("GatedAttention: data must be (B, S, E)")
+        p = self.param
+        E, H, K, d = data[2], p.num_heads, p.num_kv_heads, p.head_dim
+        if H % K:
+            raise MXNetError("GatedAttention: %d query heads do not group "
+                             "over %d key/value heads" % (H, K))
+        if int(round(p.partial_rotary_factor * d)) % 2:
+            raise MXNetError("the rotated part of a head must be even")
+        return ([data, (2 * H * d, E), (K * d, E), (K * d, E), (d,), (d,),
+                 (E, H * d)], [data], [])
+
+    def cost_mxu_dims(self, in_shapes, out_shapes):
+        B, S, E = in_shapes[0]
+        p = self.param
+        H, K, d = p.num_heads, p.num_kv_heads, p.head_dim
+        T = B * S
+        return [(T, E, 2 * H * d), (T, E, K * d), (T, E, K * d),
+                (T, H * d, E), (S, d, S), (S, S, d)]
+
+    def cost_flops(self, in_shapes, out_shapes):
+        B = in_shapes[0][0]
+        dims = self.cost_mxu_dims(in_shapes, out_shapes)
+        proj = sum(2 * m * k * n for m, k, n in dims[:4])
+        attn = sum(2 * B * self.param.num_heads * m * k * n
+                   for m, k, n in dims[4:])
+        return float(proj + attn)
+
+    def cost_reduce_len(self, in_shapes, out_shapes):
+        return int(in_shapes[0][1])     # softmax over the key axis
+
+    def forward(self, inputs, aux, is_train, rng):
+        x, wq, wk, wv, gq, gk, wo = inputs
+        B, S, _E = x.shape
+        p = self.param
+        H, K, d = p.num_heads, p.num_kv_heads, p.head_dim
+        qg = (x @ wq.T).reshape(B, S, H, 2 * d)
+        q, gate = qg[..., :d], qg[..., d:]
+        k = (x @ wk.T).reshape(B, S, K, d)
+        v = (x @ wv.T).reshape(B, S, K, d)
+        rot = int(round(p.partial_rotary_factor * d))
+
+        def heads(t, gamma):    # normalised, (B, heads, S, d), rotated
+            return rotary_half(rms_norm(t, gamma, p.eps)
+                               .transpose(0, 2, 1, 3), p.rope_theta, rot)
+
+        from ..parallel.ring_attention import sharded_self_attention
+        o = sharded_self_attention(heads(q, gq), heads(k, gk),
+                                   v.transpose(0, 2, 1, 3), causal=True)
+        o = o.transpose(0, 2, 1, 3).astype(jnp.float32) \
+            * jax.nn.sigmoid(gate.astype(jnp.float32))
+        return [o.astype(x.dtype).reshape(B, S, H * d) @ wo.T], None
 
 
 class _MLAParam(ParamStruct):

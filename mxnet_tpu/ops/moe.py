@@ -5,8 +5,8 @@ moderate expert counts.  ``RoutedExperts`` (further down) is the sorted
 form for layers of which a device holds a share of the experts: it
 routes over all of them, sorts the (token, expert) assignments by expert
 and runs one grouped matrix product per projection over the experts
-held, for every assignment that landed on them.  It scores with a
-sigmoid of its own router weight, or takes the scores from a router
+held, for every assignment that landed on them.  It scores with a sigmoid
+or a softmax over its own router weight, or takes the scores from a router
 outside it: ``MLPRouter`` (after it) is one, an MLP over a stream that
 runs from layer to layer beside the residual.
 
@@ -232,8 +232,8 @@ def _moe_cost(op, in_shapes, out_shapes):
 
 
 # ----------------------------------------------------------------------
-# RoutedExperts: sigmoid-scored top-k routing over all the experts, the
-# experts held here computed by sorted, grouped matrix products
+# RoutedExperts: top-k routing on sigmoid or softmax scores over all the
+# experts, the experts held here computed by sorted, grouped matrix products
 # ----------------------------------------------------------------------
 def gated_ffn(x, w_gate, w_up, w_down):
     """W_down(silu(W_gate x) ⊙ W_up x); weights (out_features, in_features)."""
@@ -259,6 +259,14 @@ def sigmoid_scores(h, router_weight):
     """s = sigmoid(h W_rᵀ) (T, N), summed and kept in float32."""
     return jax.nn.sigmoid(jnp.dot(h, router_weight.T,
                                   preferred_element_type=jnp.float32))
+
+
+def softmax_scores(h, router_weight):
+    """p = softmax(h W_rᵀ) over all N experts (T, N), the logits summed and
+    the softmax taken in float32."""
+    return jax.nn.softmax(jnp.dot(h, router_weight.T,
+                                  preferred_element_type=jnp.float32),
+                          axis=-1)
 
 
 def route_sigmoid_topk(h, router_weight, bias, top_k, scaling=1.0):
@@ -427,11 +435,16 @@ class _RoutedExpertsParam(ParamStruct):
         doc="width of the shared expert every token goes through (0: none)")
     routed_scaling_factor = Field(float, default=1.0)
     score_func = Field(
-        str, default="sigmoid", enum=("sigmoid", "given"),
-        doc="sigmoid: the op scores, s = sigmoid(h W_rᵀ) with a "
+        str, default="sigmoid", enum=("sigmoid", "softmax", "given"),
+        doc="sigmoid / softmax: the op scores, s = sigmoid(h W_rᵀ) or "
+            "softmax(h W_rᵀ) over all num_experts, in float32, with a "
             "router_weight of its own; given: the scores are the second "
             "input, (T, num_experts), made by a router outside the op "
             "(their gradient goes back to it)")
+    shared_gate = Field(
+        bool, default=False,
+        doc="the shared expert's result is scaled by sigmoid(h w_sᵀ), "
+            "w_s = shared_score_weight (1, E), a number a token")
     norm_topk_prob = Field(
         bool, default=True,
         doc="divide the chosen scores by their sum (False: a chosen "
@@ -450,7 +463,8 @@ class RoutedExperts(OperatorProperty):
     """Routed gated-SiLU FFN over the experts held here, plus a shared one.
 
     data (..., E) -> (..., E).  s = sigmoid(h W_rᵀ) over all
-    ``num_experts`` in float32 — or, with ``score_func="given"``, the
+    ``num_experts`` in float32 — or softmax(h W_rᵀ) with
+    ``score_func="softmax"``, or, with ``score_func="given"``, the
     ``scores`` input as a router outside the op made it (a softmax over
     an MLP's logits, say); the ``top_k`` largest of s + ``router_bias``
     are chosen (the bias is auxiliary state: it steers the choice, takes
@@ -459,7 +473,8 @@ class RoutedExperts(OperatorProperty):
     experts *held here* (``first_expert .. first_expert +
     num_local_experts − 1``) — every such assignment, whatever the
     imbalance; what the other experts would add is another device's part
-    and is left out — plus the shared expert.  No biases; weights are
+    and is left out — plus the shared expert, times sigmoid(h w_sᵀ) where
+    ``shared_gate`` is set.  No biases; weights are
     (out_features, in_features), the experts' stacked on a leading axis.
 
     Auxiliary state besides the bias, summed over training steps:
@@ -484,6 +499,8 @@ class RoutedExperts(OperatorProperty):
         if self.param.shared_hidden_size:
             args += ["shared_gate_weight", "shared_up_weight",
                      "shared_down_weight"]
+            if self.param.shared_gate:
+                args.append("shared_score_weight")
         return args
 
     def list_auxiliary_states(self):
@@ -506,6 +523,11 @@ class RoutedExperts(OperatorProperty):
         if p.shared_hidden_size:
             S = p.shared_hidden_size
             shapes += [(S, E), (S, E), (E, S)]
+            if p.shared_gate:
+                shapes.append((1, E))
+        elif p.shared_gate:
+            raise MXNetError("RoutedExperts: shared_gate without a shared "
+                             "expert")
         return shapes, [data], [(N,), (1,), (L,), (1,), (1,)]
 
     def infer_type(self, in_types):
@@ -520,6 +542,8 @@ class RoutedExperts(OperatorProperty):
         h = x.reshape(-1, x.shape[-1])
         if self._given():
             scores = router.reshape(-1, p.num_experts).astype(jnp.float32)
+        elif p.score_func == "softmax":
+            scores = softmax_scores(h, router)
         else:
             scores = sigmoid_scores(h, router)
         idx, w = route_topk(scores, aux[0], p.top_k,
@@ -527,7 +551,12 @@ class RoutedExperts(OperatorProperty):
         y, counts = routed_experts(h, w, idx, w_gate, w_up, w_down,
                                    p.first_expert, CHUNK_ROWS)
         if p.shared_hidden_size:
-            y = y + gated_ffn(h, *inputs[5:8])
+            shared = gated_ffn(h, *inputs[5:8])
+            if p.shared_gate:
+                gate = jax.nn.sigmoid(jnp.dot(
+                    h, inputs[8].T, preferred_element_type=jnp.float32))
+                shared = (shared.astype(jnp.float32) * gate).astype(y.dtype)
+            y = y + shared
         if not is_train:
             return [y.reshape(x.shape)], None
         _bias, total, per_expert, peak_sum, peak_max = aux
@@ -670,7 +699,7 @@ def _routed_cost(op, in_shapes, out_shapes):
     S = int(p.shared_hidden_size)
     given = p.score_func == "given"     # the router is another node
     flops = (0.0 if given else 2.0 * T * N * E) + 6.0 * rows * E * H \
-        + 6.0 * T * E * S
+        + 6.0 * T * E * S + (2.0 * T * E if p.shared_gate else 0.0)
     dims = ([] if given else [(T, E, N)]) \
         + [(rows, E, H), (rows, E, H), (rows, H, E)]
     if S:

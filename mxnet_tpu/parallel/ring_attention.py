@@ -243,6 +243,18 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_k, causal,
 
 # block extents of both kernels, largest first
 _FLASH_BLOCKS = (512, 256, 128)
+# VMEM a Mosaic kernel gets without asking, and what the backward kernel
+# may ask for of a v5e core's 128 MiB before it splits a group (below)
+_SCOPED_VMEM_DEFAULT = 16 << 20
+_VMEM_BUDGET = 100 << 20
+
+
+def _vmem_bytes(shape, itemsize):
+    """Bytes a VMEM buffer of ``shape`` takes: the last dim padded to
+    128 lanes, the one before it to a 32-bit tile's 8 sublanes."""
+    *lead, rows, lanes = shape
+    rows = -(-rows * itemsize // 32) * 32 // itemsize
+    return math.prod(lead) * rows * (-(-lanes // 128) * 128) * itemsize
 
 
 def _flash_blocks(sq, sk, block_q=None, block_k=None):
@@ -313,6 +325,18 @@ def _flash_forward_kernel_call(q, k, v, causal, scale, block_q, block_k,
         kv_head = lambda b, i: (b, 0, 0)                # noqa: E731
     else:
         kv_head = lambda b, i: (b // group, 0, 0)       # noqa: E731
+    # k and v are whole per key/value head, twice (the pipeline's two
+    # buffers): 16 MiB at 8,192 keys of 256 in bfloat16, with q, o and lse
+    # beside them 17 — past what Mosaic gives a kernel unasked.  Only then
+    # is a limit asked for, so every smaller shape lowers as it always did.
+    blocks = sum(2 * _vmem_bytes(blk[1:], size) for (blk, _arr), size in zip(
+        (qb, kb, vb, ob, lseb), (q.dtype.itemsize,) * 4 + (4,)))
+    params = {}
+    if blocks > _SCOPED_VMEM_DEFAULT - (1 << 20):
+        from jax.experimental.pallas import tpu as pltpu
+        params["compiler_params"] = pltpu.CompilerParams(
+            vmem_limit_bytes=int(blocks + 8 * block_q * block_k * 4
+                                 + (4 << 20)))
     out, lse = pl.pallas_call(
         kernel,
         grid=(B * H, Sq // block_q),
@@ -331,6 +355,7 @@ def _flash_forward_kernel_call(q, k, v, causal, scale, block_q, block_k,
         ],
         name="flash_forward",
         interpret=interpret,
+        **params,
     )(q3, k3, v3)
     return out.reshape(B, H, Sq, d_v), lse[:, 0].reshape(B, H, Sq)
 
@@ -447,41 +472,65 @@ def _flash_backward_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 
 def _flash_backward_block_layout(bh, sq, sk, d, block_q, block_k, d_v=None,
-                                 group=1):
+                                 group=1, split=1):
     """(block, array) pairs of the backward pallas_call, in q/k/v/do/
     lse/delta then dq/dk/dv order, and the shape of its float32 dq
     scratch: shared by the call below and
     ``flash_backward_kernel_spec``, like :func:`_flash_block_layout`.
     ``bh`` counts key/value heads; the ``group`` query heads of each lie
-    along the rows (``rows`` = group·sq).  q, do and dq are whole per
-    (batch·key/value head), k, v, dk, dv go by key blocks; lse and delta
-    are one (1, block_q) row a query block, indexed by the block."""
+    along the rows, ``group / split`` of them a program (``rows`` =
+    group·sq / split; :func:`_flash_backward_split`).  q, do and dq are
+    whole per program, k, v, dk, dv go by key blocks (dk, dv one partial
+    sum a program); lse and delta are one (1, block_q) row a query block,
+    indexed by the block."""
     d_v = d if d_v is None else d_v
-    rows = group * sq
+    rows, programs = group * sq // split, bh * split
     stats = ((None, rows // block_q, 1, block_q),
-             (bh, rows // block_q, 1, block_q))
+             (programs, rows // block_q, 1, block_q))
     in_blocks = [
-        ((None, rows, d), (bh, rows, d)),               # q
+        ((None, rows, d), (programs, rows, d)),         # q
         ((None, block_k, d), (bh, sk, d)),              # k
         ((None, block_k, d_v), (bh, sk, d_v)),          # v
-        ((None, rows, d_v), (bh, rows, d_v)),           # do
+        ((None, rows, d_v), (programs, rows, d_v)),     # do
         stats,                                          # lse
         stats,                                          # delta
     ]
     out_blocks = [
-        ((None, rows, d), (bh, rows, d)),               # dq
-        ((None, block_k, d), (bh, sk, d)),              # dk
-        ((None, block_k, d_v), (bh, sk, d_v)),          # dv
+        ((None, rows, d), (programs, rows, d)),         # dq
+        ((None, block_k, d), (programs, sk, d)),        # dk
+        ((None, block_k, d_v), (programs, sk, d_v)),    # dv
     ]
     return in_blocks, out_blocks, (rows // block_q, d, block_q)
 
 
-def _vmem_bytes(shape, itemsize):
-    """Bytes a VMEM buffer of ``shape`` takes: the last dim padded to
-    128 lanes, the one before it to a 32-bit tile's 8 sublanes."""
-    *lead, rows, lanes = shape
-    rows = -(-rows * itemsize // 32) * 32 // itemsize
-    return math.prod(lead) * rows * (-(-lanes // 128) * 128) * itemsize
+def _flash_backward_split(bh, sq, sk, d, block_q, block_k, d_v, group,
+                          itemsize, budget=None):
+    """``(split, vmem bytes, layout)``: into how many programs a key/value
+    head's group of query heads is divided so that the kernel fits VMEM,
+    what it then asks for, and :func:`_flash_backward_block_layout` at that
+    split.  A program holds its query heads' q, do and dq whole
+    (twice: the pipeline's two buffers) and a float32 dq scratch — 80 MiB
+    at four heads of 8,192 × 128, 268 at eight of 8,192 × 256 — so past
+    ``budget`` the group goes over 2, 4, … programs, each with the whole
+    key/value head's blocks and a float32 partial dk, dv that the caller
+    sums.  1 wherever the whole group fits: those shapes lower as they
+    did before there was a split."""
+    budget = _VMEM_BUDGET if budget is None else budget
+    for split in range(1, group + 1):
+        if group % split:
+            continue
+        layout = _flash_backward_block_layout(
+            bh, sq, sk, d, block_q, block_k, d_v, group, split)
+        ins, outs, acc = layout
+        partial = 4 if split > 1 else itemsize
+        sizes = (itemsize,) * 4 + (4, 4, itemsize, partial, partial)
+        # every block twice, the scratch, and room for the (block_k,
+        # block_q) float32 tiles between the products
+        vmem = sum(2 * _vmem_bytes(blk[1:], size)
+                   for (blk, _arr), size in zip(ins + outs, sizes)) \
+            + _vmem_bytes(acc, 4) + 8 * block_q * block_k * 4 + (4 << 20)
+        if vmem <= budget or split == group:
+            return split, vmem, layout
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -497,35 +546,38 @@ def _flash_backward_kernel_call(q, k, v, o, lse, do, causal, scale,
     B, H, Sq, D = q.shape
     sk, d_v = v.shape[-2:]
     group = _head_group(q, k)
+    bh = B * H // group
+    split, vmem, (ins, outs, acc) = _flash_backward_split(
+        bh, Sq, sk, D, block_q, block_k, d_v, group, q.dtype.itemsize)
     # a key/value head's query heads are neighbours in (B, H, ...): they
-    # become one run of group·Sq rows by a reshape that moves nothing
-    bh, rows = B * H // group, group * Sq
+    # become ``split`` runs of group·Sq / split rows by a reshape that
+    # moves nothing
+    programs, rows = bh * split, group * Sq // split
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
-    operands = (q.reshape(bh, rows, D), k.reshape(bh, sk, D),
-                v.reshape(bh, sk, d_v), do.reshape(bh, rows, d_v),
-                lse.reshape(bh, rows // block_q, 1, block_q),
-                delta.reshape(bh, rows // block_q, 1, block_q))
-    ins, outs, acc = _flash_backward_block_layout(bh, Sq, sk, D, block_q,
-                                                  block_k, d_v, group)
-    # every block twice (the pipeline's two buffers), the scratch, and
-    # room for the (block_k, block_q) float32 tiles between the products
-    vmem = sum(2 * _vmem_bytes(blk[1:], x.dtype.itemsize)
-               for (blk, _arr), x in zip(ins + outs, operands + (q, k, v))) \
-        + _vmem_bytes(acc, 4) + 8 * block_q * block_k * 4 + (4 << 20)
+    operands = (q.reshape(programs, rows, D), k.reshape(bh, sk, D),
+                v.reshape(bh, sk, d_v), do.reshape(programs, rows, d_v),
+                lse.reshape(programs, rows // block_q, 1, block_q),
+                delta.reshape(programs, rows // block_q, 1, block_q))
     kernel = functools.partial(_flash_backward_kernel, block_q=block_q,
-                               causal=causal, scale=scale, group=group)
+                               causal=causal, scale=scale,
+                               group=group // split)
     whole = lambda b, j: (b, 0, 0)          # noqa: E731
     by_key = lambda b, j: (b, j, 0)         # noqa: E731
     rows = lambda b, j: (b, 0, 0, 0)        # noqa: E731
+    if split == 1:
+        kv_by_key, partial = by_key, (k.dtype, v.dtype)
+    else:       # programs b·split .. b·split + split − 1 share head b's k, v
+        kv_by_key = lambda b, j: (b // split, j, 0)     # noqa: E731
+        partial = (jnp.float32, jnp.float32)
     dq, dk, dv = pl.pallas_call(
         kernel,
-        grid=(bh, sk // block_k),
+        grid=(programs, sk // block_k),
         in_specs=[pl.BlockSpec(blk, index) for (blk, _arr), index in zip(
-            ins, (whole, by_key, by_key, whole, rows, rows))],
+            ins, (whole, kv_by_key, kv_by_key, whole, rows, rows))],
         out_specs=[pl.BlockSpec(blk, index) for (blk, _arr), index in zip(
             outs, (whole, by_key, by_key))],
-        out_shape=[jax.ShapeDtypeStruct(arr, x.dtype)
-                   for (_blk, arr), x in zip(outs, (q, k, v))],
+        out_shape=[jax.ShapeDtypeStruct(arr, dtype)
+                   for (_blk, arr), dtype in zip(outs, (q.dtype,) + partial)],
         scratch_shapes=[pltpu.VMEM(acc, jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
@@ -533,6 +585,9 @@ def _flash_backward_kernel_call(q, k, v, o, lse, do, causal, scale,
         name="flash_backward",
         interpret=interpret,
     )(*operands)
+    if split > 1:       # the programs' float32 partial sums, rounded once
+        dk, dv = (t.reshape((bh, split) + t.shape[1:]).sum(axis=1)
+                  .astype(x.dtype) for t, x in ((dk, k), (dv, v)))
     return dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape)
 
 
@@ -805,18 +860,22 @@ def flash_backward_kernel_spec(batch_heads=8, seq_q=1024, seq_k=1024,
                                head_dim_v=None, group=1):
     """MXL-K kernel spec for the flash backward pallas_call, from the
     :func:`_flash_backward_block_layout` the call itself uses and the
-    blocks ``flash_attention`` gives it for these shapes.  lse and
-    delta are float32 (1, block_q) rows, one a query block: each block
-    covers its array's last two dims whole."""
+    blocks and the split of a group ``flash_attention`` gives it for these
+    shapes.  lse and delta are float32 (1, block_q) rows, one a query
+    block: each block covers its array's last two dims whole."""
     block_q, block_k = _flash_blocks(seq_q, seq_k)
-    ins, outs, _acc = _flash_backward_block_layout(
-        batch_heads // group, seq_q, seq_k, head_dim, block_q, block_k,
-        head_dim_v, group)
+    bh = batch_heads // group
+    split, _vmem, (ins, outs, _acc) = _flash_backward_split(
+        bh, seq_q, seq_k, head_dim, block_q, block_k,
+        head_dim if head_dim_v is None else head_dim_v, group,
+        jnp.dtype(dtype).itemsize)
+    partial = "float32" if split > 1 else dtype
     return _kernel_spec(
-        "flash_backward", (batch_heads // group, seq_k // block_k),
+        "flash_backward", (bh * split, seq_k // block_k),
         [("in", n, b, "float32" if n in ("lse", "delta") else dtype)
          for n, b in zip(("q", "k", "v", "do", "lse", "delta"), ins)]
-        + [("out", n, b, dtype) for n, b in zip(("dq", "dk", "dv"), outs)])
+        + [("out", n, b, dt) for n, b, dt in zip(
+            ("dq", "dk", "dv"), outs, (dtype, partial, partial))])
 
 
 try:

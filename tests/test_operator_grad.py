@@ -283,6 +283,43 @@ GRAD_SPECS = {
          "ca_k_conv1_weight": _f64(2, 2, 4, 4) * 0.5,
          "ca_k_temp": _pos64(2), "ca_out_weight": _f64(6, 16) * 0.6},
         {"rtol": 5e-2, "atol": 5e-2}),
+    # a softmax router, two of four a token, and a gated shared
+    # expert: tokens positive in each coordinate keep experts 0 and 1 on
+    # top, so the choice never flips inside the numeric-diff epsilon
+    "RoutedExperts[softmax]": lambda: (
+        sym.RoutedExperts(V("a"), num_experts=4, hidden_size=4, top_k=2,
+                          score_func="softmax", shared_hidden_size=4,
+                          shared_gate=True, name="rs"),
+        {"a": (_distinct64(6, 4) + 0.2) * 2.0,
+         "rs_router_weight": np.diag([3.0, 2.0, -3.0, -3.0]),
+         "rs_expert_gate_weight": _f64(4, 4, 4) * 0.4,
+         "rs_expert_up_weight": _f64(4, 4, 4) * 0.4,
+         "rs_expert_down_weight": _f64(4, 4, 4) * 0.4,
+         "rs_shared_gate_weight": _f64(4, 4) * 0.4,
+         "rs_shared_up_weight": _f64(4, 4) * 0.4,
+         "rs_shared_down_weight": _f64(4, 4) * 0.4,
+         "rs_shared_score_weight": _f64(1, 4) * 0.6},
+        {"rtol": 5e-2, "atol": 5e-3}),
+    "GatedAttention": lambda: (
+        sym.GatedAttention(
+            V("a"), num_heads=4, num_kv_heads=2, head_dim=4,
+            rope_theta=100.0, partial_rotary_factor=0.5, name="ga"),
+        {"a": _f64(1, 5, 6), "ga_q_weight": _f64(32, 6) * 0.6,
+         "ga_k_weight": _f64(8, 6) * 0.6, "ga_v_weight": _f64(8, 6) * 0.6,
+         "ga_q_norm_gamma": _pos64(4), "ga_k_norm_gamma": _pos64(4),
+         "ga_out_weight": _f64(6, 16) * 0.6},
+        {"rtol": 5e-2, "atol": 5e-2}),
+    # eight tokens in chunks of four: the state crosses a chunk's edge
+    "GatedDeltaNet": lambda: (
+        sym.GatedDeltaNet(
+            V("a"), num_key_heads=2, num_value_heads=4, key_head_dim=4,
+            value_head_dim=2, conv_taps=3, chunk=4, name="gd"),
+        {"a": _f64(1, 8, 6), "gd_in_proj_qkvz_weight": _f64(32, 6) * 0.6,
+         "gd_in_proj_ba_weight": _f64(8, 6) * 0.6,
+         "gd_conv_weight": _f64(3, 24) * 0.5 + 0.5,
+         "gd_A_log": _f64(4) * 0.5, "gd_dt_bias": _f64(4) * 0.5,
+         "gd_norm_gamma": _pos64(2), "gd_out_weight": _f64(6, 8) * 0.6},
+        {"rtol": 5e-2, "atol": 5e-2}),
     # the scores sum to one a token: they are weighed by a fixed "w" so
     # that their gradient shows in the sum of the outputs
     "MLPRouter": lambda: (

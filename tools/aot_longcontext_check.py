@@ -9,7 +9,8 @@ chip time:
 
 1. every Pallas kernel the tree ships, at the widths chip_smoke.py runs
    them (its phases 2-3): the flash-attention forward and backward
-   kernels (s1024 d64; s8192 at latent attention's 192/128), the
+   kernels (s1024 d64; s8192 at latent attention's 192/128, at grouped
+   heads of 128 and of 256), the
    weight-only quantized matmul at
    GPT-2-small FFN shapes and at the LM head of 50,257, and the fused
    optimizer sweep at one bucket the size of ResNet-50's parameters;
@@ -81,6 +82,14 @@ def kernel_cases():
     q, kv = sds((b, h, s, d), jnp.bfloat16), sds((b, h_kv, s, d),
                                                  jnp.bfloat16)
     cases.append(("flash_fwd_bwd[bfloat16,grouped]",
+                  jax.grad(flash_loss, argnums=(0, 1, 2)), (q, kv, kv)))
+    # 16 heads of 256 on 2: k and v whole are past the VMEM a kernel gets
+    # unasked (the forward asks), a group's q, do, dq past all of it (the
+    # backward splits the group over programs)
+    b, h, h_kv, s, d = widths["flash_gqa256"]
+    q, kv = sds((b, h, s, d), jnp.bfloat16), sds((b, h_kv, s, d),
+                                                 jnp.bfloat16)
+    cases.append(("flash_fwd_bwd[bfloat16,gqa256]",
                   jax.grad(flash_loss, argnums=(0, 1, 2)), (q, kv, kv)))
     # serve/lm (GPT-2 small): decode rows 8, prefill rows 256
     for m, k, n in widths["qmm"]:
