@@ -55,7 +55,7 @@ FULL = {
     "kernels": dict(flash=(8, 8, 1024, 64), flash_latent=(1, 32, 8192, 192, 128),
                     flash_grouped=(1, 8, 2, 8192, 128),
                     flash_gqa256=(1, 16, 2, 8192, 256),
-                    delta_rule=(1, 4096, 32, 128, 128, 512),
+                    delta_rule=(1, 4096, 16, 32, 128, 128, 1024),
                     qmm=((8, 768, 3072), (256, 768, 3072), (8, 3072, 768),
                          (8, 768, 50257), (256, 768, 50257))),
 }
@@ -71,7 +71,7 @@ TINY = {
     "kernels": dict(flash=(1, 2, 256, 8), flash_latent=(1, 2, 256, 24, 16),
                     flash_grouped=(1, 4, 2, 256, 16),
                     flash_gqa256=(1, 8, 1, 256, 32),
-                    delta_rule=(1, 128, 2, 16, 8, 64),
+                    delta_rule=(1, 128, 1, 2, 128, 128, 128),
                     qmm=((8, 256, 384), (300, 600, 1000))),
 }
 
@@ -558,17 +558,33 @@ def phase_kernels(env, cfg, lm_params):
         out[name + ",mirrored"] = round(max(errs), 5)
         del q, k, v, got_o, got_g, want_o, want_g, plain_g, kept_g
 
-    # the gated delta rule: the chunk-parallel form the op runs against the
-    # recurrence a token at a time, outputs over the long sequence and every
-    # gradient over a short one (the recurrence's backward keeps a state a
-    # token).  float32 operands still multiply at the chip's default
-    # precision in the chunked form, so both dtypes are held to rounding
+    # the gated delta rule: what the op runs against the recurrence a token
+    # at a time, at the timed head geometry (16 key heads on 32 value heads
+    # of 128: the grouped index, dq and dk summed over a group) — the Pallas
+    # kernels, which ``dispatch`` picks because the call is placed on the
+    # chip (the compiled call must hold them; interpreted in the rehearsal),
+    # and beside them the XLA form they replace there, both held to the
+    # recurrence: outputs over the long sequence, every gradient over a
+    # short one that still crosses a grid step (the recurrence's backward
+    # keeps a state a token).  float32 operands multiply at the chip's
+    # default precision in both forms, so both dtypes are held to rounding
     # of bfloat16's order.
     from mxnet_tpu.ops import linear_attention as la
-    rb, rs, rh, r_dk, r_dv, r_short = cfg["delta_rule"]
+    rb, rs, rhk, rh, r_dk, r_dv, r_short = cfg["delta_rule"]
+    rule = functools.partial(la.gated_delta_rule, interpret=True) \
+        if interpret else la.gated_delta_rule
+    forms = (("kernel", rule), ("xla", la.gated_delta_rule_xla))
+
+    def recurrence(q, k, *rest):
+        q, k = (jnp.repeat(t, rh // rhk, axis=2) for t in (q, k))
+        return la.gated_delta_rule_recurrent(q, k, *rest)
+
+    def rule_loss(fn, *a):
+        return jnp.sum(jnp.sin(fn(*a).astype(jnp.float32)))
+
     for dt, tol in ((jnp.float32, 2e-2), (jnp.bfloat16, 4e-2)):
-        q = la.l2_normalise(rng.randn(rb, rs, rh, r_dk)) * r_dk ** -0.5
-        k = la.l2_normalise(rng.randn(rb, rs, rh, r_dk) + 0.5)
+        q = la.l2_normalise(rng.randn(rb, rs, rhk, r_dk)) * r_dk ** -0.5
+        k = la.l2_normalise(rng.randn(rb, rs, rhk, r_dk) + 0.5)
         q, k = put(q.astype(dt)), put(k.astype(dt))
         v = put(jnp.asarray(rng.randn(rb, rs, rh, r_dv), dt))
         g = put(-jnp.exp(jnp.asarray(rng.uniform(-6, 2, (rb, rs, rh)),
@@ -576,27 +592,32 @@ def phase_kernels(env, cfg, lm_params):
         beta = put(jax.nn.sigmoid(jnp.asarray(rng.randn(rb, rs, rh),
                                               jnp.float32)))
         args = (q, k, v, g, beta)
-        name = "gated_delta_rule[%s]" % jnp.dtype(dt).name
-        got = jax.jit(la.gated_delta_rule)(*args)
-        want = highest(la.gated_delta_rule_recurrent, *args)
-        err = _rel_err(got, want)
-        require(err <= tol, "%s: output error %g exceeds %g"
-                % (name, err, tol))
-
-        def rule_loss(fn, *a):
-            return jnp.sum(jnp.sin(fn(*a).astype(jnp.float32)))
-
         short = tuple(t[:, :r_short] for t in args)
         wrt = (0, 1, 2, 3, 4)
-        got_g = jax.jit(jax.grad(functools.partial(
-            rule_loss, la.gated_delta_rule), wrt))(*short)
-        want_g = highest(jax.grad(functools.partial(
-            rule_loss, la.gated_delta_rule_recurrent), wrt), *short)
-        errs = [_rel_err(a, b) for a, b in zip(got_g, want_g)]
-        require(max(errs) <= 2 * tol, "%s: dq/dk/dv/dg/dbeta errors %s "
-                "exceed %g" % (name, errs, 2 * tol))
-        out[name] = round(max([err] + errs), 5)
-        del q, k, v, g, beta, args, short, got, want, got_g, want_g
+        want = highest(recurrence, *args)
+        want_g = highest(jax.grad(functools.partial(rule_loss, recurrence),
+                                  wrt), *short)
+        if not interpret:
+            text = jax.jit(jax.grad(functools.partial(rule_loss, rule),
+                                    wrt)).lower(*short).compile().as_text()
+            require(text.count(MOSAIC) >= 2,
+                    "gated_delta_rule: placed on the chip, its gradient "
+                    "program holds %d Mosaic calls, not the forward and "
+                    "backward kernels" % text.count(MOSAIC))
+        for form, fn in forms:
+            name = "gated_delta_rule[%s,%s]" % (jnp.dtype(dt).name, form)
+            err = _rel_err(jax.jit(fn)(*args), want)
+            require(err <= tol, "%s: output error %g against the "
+                    "recurrence exceeds %g" % (name, err, tol))
+            got_g = jax.jit(jax.grad(functools.partial(rule_loss, fn),
+                                     wrt))(*short)
+            errs = [_rel_err(a, b) for a, b in zip(got_g, want_g)]
+            require(max(errs) <= 2 * tol, "%s: dq/dk/dv/dg/dbeta errors %s "
+                    "against the recurrence exceed %g"
+                    % (name, errs, 2 * tol))
+            out[name] = round(max([err] + errs), 5)
+            del got_g
+        del q, k, v, g, beta, args, short, want, want_g
 
     # weight-only quantized matmul, FFN shapes and the LM head
     for m, k, n in cfg["qmm"]:

@@ -12,20 +12,21 @@ two a call runs is decided by where the computation is placed
 - :mod:`.fused_opt` — the bucketed flatten/update/unflatten optimizer
   sweep replacing the per-leaf tree-map (``MXTPU_FUSED_OPT``).
 
-(The third Pallas kernel in the tree is the flash-attention forward in
-``parallel/ring_attention.py``.)
+(The flash-attention kernels are in ``parallel/ring_attention.py``;
+:mod:`.delta_rule` holds the gated delta rule's forward and backward
+kernels, which ``ops/linear_attention.py`` dispatches to.)
 
-Importing this package registers both kernel specs, so ``mxlint`` /
+Importing this package registers every kernel spec, so ``mxlint`` /
 ``Symbol.validate()`` statically tile-check every block layout the
 kernels use (``analysis.tiling._ensure_builtin_specs`` imports it for
 the same reason).
 """
-from . import quantize, fused_opt                              # noqa: F401
+from . import quantize, fused_opt, delta_rule                  # noqa: F401
 from .quantize import (quantize_params, quantize_symbol,       # noqa: F401
                        quantizable_weights, quantized_matmul)
 from .fused_opt import fused_apply, fused_opt_mode, supports_fused  # noqa: F401,E501
 
-__all__ = ["quantize", "fused_opt",
+__all__ = ["quantize", "fused_opt", "delta_rule",
            "quantize_params", "quantize_symbol", "quantizable_weights",
            "quantized_matmul",
            "fused_apply", "fused_opt_mode", "supports_fused"]

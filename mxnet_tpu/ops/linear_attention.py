@@ -10,13 +10,13 @@ layer of the ``transformers`` library).  Per value head, with a state S
     o_t = Sᵀ q_t
 
 :func:`gated_delta_rule_recurrent` is that recurrence, one token at a time
-(the definition; tests and ``chip_smoke.py`` hold the fast form to it).
+(the definition; tests and ``chip_smoke.py`` hold the fast forms to it).
 :func:`gated_delta_rule` is its chunk-parallel form, the one the op runs:
 within a chunk of C tokens every δ depends on the δ before it through a
-unit lower-triangular system, which is solved for all chunks at once (the
+unit lower-triangular system, which is solved for a chunk at once (the
 WY form: with G the running sum of g inside the chunk and
 A = tril(β K Kᵀ ⊙ exp(G_i − G_j), −1), T = (I + A)⁻¹, W = T (β e^G K),
-U = T β V), and one ``lax.scan`` carries S from chunk to chunk:
+U = T β V), and S is carried from chunk to chunk:
 
     V' = U − W S;   O = (Q e^G) S + tril(Q Kᵀ ⊙ exp(G_i − G_j)) V';
     S ← e^{G_C} S + (K e^{G_C − G})ᵀ V'
@@ -26,8 +26,28 @@ their operands in the dtype q, k, v come in and sum in float32; T is
 found in float32 (:func:`unit_lower_inverse`: block substitution, matrix
 products only).  No exp ever takes a positive argument: the factors are
 exp(G_i − G_j) for i ≥ j, e^G and e^{G_C − G}, all at most 1, whatever the
-decay.  Plain ``jax.numpy`` and ``lax`` inside the one XLA step;
-differentiated by ``jax``'s autodiff of the scan.
+decay.
+
+Which form runs where.  One algorithm, two implementations, chosen by
+``kernels.common.dispatch`` when the enclosing step is lowered, as
+``flash_attention`` is: **where the step is placed on a TPU** and the
+shapes allow (``kernels.delta_rule.delta_blocks``: whole blocks of chunks,
+a chunk a power of two, head widths of whole registers, a working set that
+VMEM holds), two Pallas kernels
+(``kernels/delta_rule.py``: ``gated_delta_forward``, and by
+``jax.custom_vjp`` ``gated_delta_backward``) that keep a chunk's working
+set in VMEM and read q and k of the key heads as they are; **everywhere
+else** (the CPU, the tests, a shape the kernels refuse)
+:func:`gated_delta_rule_xla`: plain ``jax.numpy`` for all chunks at once
+and one ``lax.scan`` over the chunks inside the one XLA step,
+differentiated by ``jax``'s autodiff of the scan — the kernels' reference,
+with the same rounding points (but one, in dg: ``kernels/delta_rule.py``
+names it).  On a mesh of several chips (the step traced under
+``parallel.ring_attention.attention_scope``) that choice is made per device
+under ``shard_map``, the batch split over dp and the key heads over tp,
+because GSPMD does not partition a Mosaic kernel; where the mesh shards the
+sequence itself the state would cross devices, and the XLA form runs,
+partitioned by GSPMD.
 """
 from __future__ import annotations
 
@@ -95,12 +115,76 @@ def unit_lower_inverse(a):
     return inv[..., 0, :, :]
 
 
-def gated_delta_rule(q, k, v, g, beta, chunk=64):
+def gated_delta_rule(q, k, v, g, beta, chunk=64, interpret=None):
     """The chunk-parallel form of :func:`gated_delta_rule_recurrent` (the
-    module's text has the equations).  q, k (B, S, H, d_k), v (B, S, H,
-    d_v) in the compute dtype, g and beta (B, S, H) float32; S a multiple
-    of ``chunk`` or shorter than it (then one chunk).  -> o (B, S, H, d_v)
-    in v's dtype."""
+    module's text has the equations).  q, k (B, S, H_k, d_k), v (B, S, H,
+    d_v) in the compute dtype, H a multiple of H_k (value head h reads key
+    head h // (H / H_k)); g and beta (B, S, H) float32; S a multiple of
+    ``chunk`` or shorter than it (then one chunk).  -> o (B, S, H, d_v) in
+    v's dtype.
+
+    The Pallas kernels (``kernels/delta_rule.py``) where the computation is
+    placed on a TPU and they take the shapes (``delta_blocks``), the XLA
+    form below anywhere else (``kernels.common.dispatch``, as
+    ``flash_attention`` decides).  An explicit ``interpret`` runs the
+    kernels either way: ``True`` through the Pallas interpreter (tests),
+    ``False`` through Mosaic.
+
+    Traced for a mesh of several devices (``current_sequence_parallel``, as
+    ``sharded_self_attention`` reads it) the choice is made per device under
+    ``shard_map``, the batch split over dp and the key heads over tp where
+    those divide — GSPMD cannot partition a Mosaic kernel.  Where the mesh
+    shards the sequence the state would cross devices: the XLA form, which
+    GSPMD partitions."""
+    from ..kernels import delta_rule
+    from ..kernels.common import dispatch
+    from ..parallel.ring_attention import (current_sequence_parallel,
+                                           mesh_axis_that_splits)
+    if v.shape[2] % q.shape[2]:
+        raise ValueError("%d value heads do not group over %d key heads"
+                         % (v.shape[2], q.shape[2]))
+
+    def reference(q, k, v, g, beta):
+        return gated_delta_rule_xla(q, k, v, g, beta, chunk)
+
+    blocks = delta_rule.delta_blocks(
+        q.shape[1], chunk, q.shape[-1], v.shape[-1],
+        v.shape[2] // q.shape[2], v.dtype.itemsize)
+    ctx = current_sequence_parallel()
+    if blocks is None or (ctx is not None
+                          and ctx.seq_axis in ctx.mesh.axis_names):
+        return reference(q, k, v, g, beta)
+
+    def kernel(q, k, v, g, beta, interpret=False):
+        return delta_rule.gated_delta_rule_kernel(q, k, v, g, beta, *blocks,
+                                                  interpret=interpret)
+
+    def rule(q, k, v, g, beta):
+        if interpret is not None:
+            return kernel(q, k, v, g, beta, interpret=bool(interpret))
+        return dispatch(kernel, reference, q, k, v, g, beta)
+
+    if ctx is None:
+        return rule(q, k, v, g, beta)
+    from jax import shard_map
+    from jax.sharding import PartitionSpec as P
+    # a device keeps whole groups: the value heads split as the key heads do
+    dp = mesh_axis_that_splits(ctx.mesh, ctx.batch_axis, q.shape[0])
+    tp = mesh_axis_that_splits(ctx.mesh, "tp", q.shape[2])
+    heads, scalars = P(dp, None, tp, None), P(dp, None, tp)
+    return shard_map(rule, mesh=ctx.mesh,
+                     in_specs=(heads,) * 3 + (scalars,) * 2, out_specs=heads,
+                     check_vma=False)(q, k, v, g, beta)
+
+
+def gated_delta_rule_xla(q, k, v, g, beta, chunk=64):
+    """:func:`gated_delta_rule` as plain ``jax.numpy`` and one ``lax.scan``,
+    differentiated by ``jax``: the kernels' reference, and what runs off a
+    TPU.  q and k of fewer heads than v are repeated here and nowhere
+    else."""
+    r = v.shape[2] // q.shape[2]
+    if r > 1:
+        q, k = (jnp.repeat(t, r, axis=2) for t in (q, k))
     B, S, H, d_k = q.shape
     d_v = v.shape[-1]
     C = min(int(chunk), S)
@@ -265,7 +349,7 @@ class GatedDeltaNet(OperatorProperty):
         q, k, v = jnp.split(mixed, [hk * dk, 2 * hk * dk], axis=-1)
         q = l2_normalise(q.reshape(B, S, hk, dk)) * dk ** -0.5
         k = l2_normalise(k.reshape(B, S, hk, dk))
-        q, k = (jnp.repeat(t.astype(x.dtype), r, axis=2) for t in (q, k))
+        q, k = q.astype(x.dtype), k.astype(x.dtype)
         b = ba[..., :r].reshape(B, S, hv).astype(jnp.float32)
         a = ba[..., r:].reshape(B, S, hv).astype(jnp.float32)
         g = -jnp.exp(a_log.astype(jnp.float32)) \

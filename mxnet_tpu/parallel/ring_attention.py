@@ -43,6 +43,7 @@ from jax.ad_checkpoint import checkpoint_name
 __all__ = ["attention_reference", "flash_attention", "ring_attention",
            "blockwise_combine", "sequence_parallel",
            "current_sequence_parallel", "attention_scope",
+           "mesh_axis_that_splits",
            "FLASH_RESIDUALS"]
 
 # What the flash forward hands its backward — q, k, v, the output and the
@@ -782,6 +783,14 @@ def attention_scope(mesh, seq_axis=None):
     return sequence_parallel(mesh, seq_axis="sp" if ring else None)
 
 
+def mesh_axis_that_splits(mesh, axis, dim):
+    """``axis`` where ``mesh`` has more than one device along it and they
+    divide ``dim``, else None (the dimension stays whole a device): the
+    entry of a ``shard_map`` spec for a per-device kernel call."""
+    size = mesh.shape.get(axis, 1) if axis else 1
+    return axis if size > 1 and dim % size == 0 else None
+
+
 def sharded_self_attention(q, k, v, causal=False):
     """Attention dispatch for (B, H, S, D): flash/reference on one
     device; under a :func:`sequence_parallel` mesh context, ring
@@ -808,14 +817,10 @@ def sharded_self_attention(q, k, v, causal=False):
         # wrap the call in a shard_map" (the four-chip host, PR 21): the
         # batch splits over dp, the heads over tp where those divide,
         # and every device attends over its own block
-        def split(axis, dim):
-            size = mesh.shape.get(axis, 1) if axis else 1
-            return axis if size > 1 and dim % size == 0 else None
-
         # heads split only where the key/value heads do: a device keeps
         # whole groups
-        spec = P(split(ctx.batch_axis, q.shape[0]),
-                 split("tp", k.shape[1]), None, None)
+        spec = P(mesh_axis_that_splits(mesh, ctx.batch_axis, q.shape[0]),
+                 mesh_axis_that_splits(mesh, "tp", k.shape[1]), None, None)
         check_vma = False       # pallas_call outputs declare no vma
 
         def att(q, k, v):

@@ -113,9 +113,13 @@ def test_every_shipped_kernel_compiles_under_mosaic():
     if p.returncode == 2:
         pytest.skip("local TPU PJRT topology unavailable")
     line = [l for l in p.stdout.splitlines() if l.startswith("{")][-1]
-    kernels = json.loads(line)["kernels"]
+    report = json.loads(line)
+    kernels = report["kernels"]
     refused = {k: v for k, v in kernels.items() if not v["ok"]}
     assert not refused and p.returncode == 0, (refused, p.stderr[-1500:])
+    # on a mesh of four the gated delta rule's kernels run per device
+    # (shard_map): the forward and the backward, as on one
+    assert report.get("delta_rule_dp2tp2_mosaic_calls", 2) == 2
     # the forward kernel and the backward kernel, one Mosaic call each
     for want in ("flash_fwd_bwd[float32]", "flash_fwd_bwd[bfloat16]",
                  "flash_fwd_bwd[bfloat16,latent]"):

@@ -10,7 +10,9 @@ chip time:
 1. every Pallas kernel the tree ships, at the widths chip_smoke.py runs
    them (its phases 2-3): the flash-attention forward and backward
    kernels (s1024 d64; s8192 at latent attention's 192/128, at grouped
-   heads of 128 and of 256), the
+   heads of 128 and of 256), the gated delta rule's forward and backward
+   kernels (s8192, 16 key heads on 32 value heads of 128; and, with four
+   devices, per device on a dp2 × tp2 mesh), the
    weight-only quantized matmul at
    GPT-2-small FFN shapes and at the LM head of 50,257, and the fused
    optimizer sweep at one bucket the size of ResNet-50's parameters;
@@ -91,6 +93,22 @@ def kernel_cases():
                                                  jnp.bfloat16)
     cases.append(("flash_fwd_bwd[bfloat16,gqa256]",
                   jax.grad(flash_loss, argnums=(0, 1, 2)), (q, kv, kv)))
+    # the gated delta rule at the timed shape: 8,192 tokens, 16 key heads on
+    # 32 value heads of 128 — the forward that writes the chunk states and
+    # the backward, chosen by ``dispatch`` because the call is lowered for a
+    # TPU
+    from mxnet_tpu.ops.linear_attention import gated_delta_rule
+    b, _s, h_k, h_v, d_k, d_v, _short = widths["delta_rule"]
+
+    def rule_loss(*args):
+        return jnp.sum(gated_delta_rule(*args).astype(jnp.float32))
+
+    qk, v = sds((b, 8192, h_k, d_k), jnp.bfloat16), sds((b, 8192, h_v, d_v),
+                                                        jnp.bfloat16)
+    scalar = sds((b, 8192, h_v), jnp.float32)
+    cases.append(("gated_delta_fwd_bwd[bfloat16,16on32]",
+                  jax.grad(rule_loss, argnums=(0, 1, 2, 3, 4)),
+                  (qk, qk, v, scalar, scalar)))
     # serve/lm (GPT-2 small): decode rows 8, prefill rows 256
     for m, k, n in widths["qmm"]:
         for dt in (jnp.float32, jnp.bfloat16):
@@ -138,6 +156,38 @@ def compile_kernels(device):
     return report
 
 
+def compile_delta_rule_on_mesh(devs):
+    """Mosaic calls in the gated delta rule's gradient compiled for a
+    dp2 × tp2 mesh of ``devs``, traced under ``attention_scope`` as
+    ``ShardedTrainer`` traces its step: GSPMD cannot partition a Mosaic
+    kernel, so the rule must carry both kernels per device under shard_map,
+    the batch split over dp and the key heads over tp."""
+    import numpy as np
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    import chip_smoke
+    from mxnet_tpu.ops.linear_attention import gated_delta_rule
+    from mxnet_tpu.parallel.ring_attention import attention_scope
+    mesh = Mesh(np.array(devs[:4]).reshape(2, 2), ("dp", "tp"))
+    _b, _s, h_k, h_v, d_k, d_v, _short = chip_smoke.FULL["kernels"][
+        "delta_rule"]
+    rows = NamedSharding(mesh, P("dp"))
+
+    def sds(*shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct((2, 8192) + shape, dtype, sharding=rows)
+
+    def rule_loss(*args):
+        return jnp.sum(gated_delta_rule(*args).astype(jnp.float32))
+
+    scalar = sds(h_v, dtype=jnp.float32)
+    with attention_scope(mesh):
+        text = jax.jit(jax.grad(rule_loss, argnums=(0, 1, 2, 3, 4))).lower(
+            sds(h_k, d_k), sds(h_k, d_k), sds(h_v, d_v), scalar,
+            scalar).compile().as_text()
+    return text.count(MOSAIC)
+
+
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--topology", default="v5e:2x2")
@@ -163,6 +213,10 @@ def main():
     # 1. every shipped kernel at full width
     out["kernels"] = compile_kernels(devs[0])
     ok = all(r["ok"] for r in out["kernels"].values())
+    if len(devs) >= 4:
+        out["delta_rule_dp2tp2_mosaic_calls"] = compile_delta_rule_on_mesh(
+            devs)
+        ok = ok and out["delta_rule_dp2tp2_mosaic_calls"] == 2
     if args.kernels_only:
         print(json.dumps(out))
         return 0 if ok else 1
