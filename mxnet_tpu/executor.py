@@ -26,6 +26,7 @@ from .base import MXNetError
 from .context import Context
 from .ndarray import NDArray, zeros
 from . import random as _random
+from .observability import device_scopes as _device_scopes
 from .observability import spans as _spans
 from .parallel.ring_attention import FLASH_RESIDUALS
 from .train_step import (apply_updates, compute_cast, loss_and_grads,
@@ -412,7 +413,11 @@ def _build_program(symbol, group2ctx):
         aux_names = ["%s_%s" % (node.name, a)
                      for a in op.list_auxiliary_states()]
         aux_in = [aux_values[a] for a in aux_names]
-        outs, aux_updates = op.forward(ins, aux_in, is_train, key)
+        # the node's name on every instruction it lowers to, in each pass
+        # over it (observability/device_scopes.py); metadata, no fusion
+        # moves
+        with jax.named_scope(node.name):
+            outs, aux_updates = op.forward(ins, aux_in, is_train, key)
         dev = node_device.get(id(node))
         if dev is not None:
             outs = [jax.device_put(o, dev) for o in outs]
@@ -634,6 +639,9 @@ class Executor:
         self._n_fused_step = 0
         self._n_monitored_compiled = 0
         self._fused_cache = None  # (optimizer fingerprint, jitted step)
+        # device_scopes records, made at a step's first dispatch
+        self._fused_record = None
+        self._fwd_bwd_record = None
 
     def _check_placement(self):
         """Refuse arrays that do not live on this executor's context (or,
@@ -810,9 +818,12 @@ class Executor:
                       for g in out_grads]
         wrt = {n: arg_values[n] for n in wrt_names}
         self._n_fwd_bwd += 1
+        step_args = (arg_values, aux_values, rng, ograds, wrt)
+        if self._fwd_bwd_record is None:
+            self._fwd_bwd_record = self._register_step(self._jit_fwd_bwd,
+                                                       step_args)
         with _spans.span("step_dispatch"):
-            outs, aux_out, grads = self._jit_fwd_bwd(
-                arg_values, aux_values, rng, ograds, wrt)
+            outs, aux_out, grads = self._jit_fwd_bwd(*step_args)
         for i, o in enumerate(outs):
             self._publish_output(i, o)
         for n, a in self.aux_dict.items():
@@ -911,6 +922,7 @@ class Executor:
             os.environ.get("MXNET_COMPUTE_DTYPE", ""))
         if self._fused_cache is None or self._fused_cache[0] != key:
             self._fused_cache = (key, self._build_fused_step(optimizer))
+            self._fused_record = None
         return self._fused_cache[1]
 
     def fused_step(self, optimizer, states, num_update, **kwargs):
@@ -932,14 +944,16 @@ class Executor:
         else:
             lr = optimizer.lr
         self._n_fused_step += 1
+        # host scalars ride with the call; ``jnp.float32(lr)`` would be a
+        # device program and a transfer of its own, each
+        step_args = (wrt, old_grads, arg_values, aux_values, rng, states,
+                     _np.float32(lr), _np.float32(optimizer.wd),
+                     _np.int32(num_update))
+        if self._fused_record is None:
+            self._fused_record = self._register_step(jit_step, step_args)
         with _spans.span("step_dispatch", step=num_update):
-            # host scalars ride with the call; ``jnp.float32(lr)`` would
-            # be a device program and a transfer of its own, each
-            outs, aux_out, grads, new_w, new_s = jit_step(
-                wrt, old_grads, arg_values, aux_values, rng, states,
-                _np.float32(lr), _np.float32(optimizer.wd),
-                _np.int32(num_update))
-        del wrt, old_grads, aux_values      # donated
+            outs, aux_out, grads, new_w, new_s = jit_step(*step_args)
+        del wrt, old_grads, aux_values, step_args       # donated
         for i, o in enumerate(outs):
             self._publish_output(i, o)
         for n, a in self.aux_dict.items():
@@ -948,6 +962,23 @@ class Executor:
             self.grad_dict[n]._bind_fresh(grads[n])
             self.arg_dict[n]._bind_fresh(new_w[n])
         return new_s
+
+    def _register_step(self, jitted, step_args):
+        """A step's first dispatch: its ``device_scopes`` record (the
+        jitted function, the arguments' shapes, the graph's nodes)."""
+        from .parallel.ring_attention import reopen_current_scope
+        return _device_scopes.register(
+            "jit_" + jitted.__name__, jitted, step_args,
+            _device_scopes.graph_nodes(self._symbol),
+            context=reopen_current_scope())     # a mesh group's
+
+    def device_scopes(self):
+        """The ``device_scopes.StepRecord`` of the train step this
+        executor dispatched (the fused step's, else ``forward_backward``'s),
+        or ``None`` before the first one: ``record.scopes()`` maps each
+        instruction of the compiled step to its graph node and pass
+        (docs/observability.md, "Device time by scope")."""
+        return self._fused_record or self._fwd_bwd_record
 
     def _fused_operands(self, wrt_names, donate=True):
         """``(weights, old gradients, other arguments, auxiliary states)``

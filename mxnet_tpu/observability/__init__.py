@@ -14,11 +14,16 @@ The fit loops / trainer / kvstore / resilience seams call
 annotation (``mx.<name>``) and a record in :mod:`.spans`' in-memory ring,
 about 2 us; with telemetry off neither call writes to the log, and
 ``record_step`` only notes the step in the flight recorder's ring.
+
+The device's side of a step is named by :mod:`.device_scopes`: the scopes
+the step is lowered under, and the map from a compiled step's
+instructions to them ("Device time by scope" in docs/observability.md).
 """
 from __future__ import annotations
 
 from . import (events, spans, counters, aggregate, phases, trace,
-               flight, slo, locktrace, retrace, metrics, sloengine)
+               flight, slo, locktrace, retrace, metrics, sloengine,
+               device_scopes)
 from .events import (enabled, emit, flush, refresh, run_id, last_fault,
                      EventLog)
 from .phases import PHASES, TRAIN_PHASES, SERVE_PHASES
@@ -32,6 +37,7 @@ from .aggregate import (publish_summary, collect_summaries,
 __all__ = [
     "events", "spans", "counters", "aggregate", "phases", "trace",
     "flight", "slo", "locktrace", "retrace", "metrics", "sloengine",
+    "device_scopes",
     "enabled", "emit", "flush", "refresh", "run_id", "last_fault",
     "EventLog",
     "PHASES", "TRAIN_PHASES", "SERVE_PHASES",

@@ -26,7 +26,9 @@ __all__ = ["TRAIN_PHASES", "SERVE_PHASES", "PHASES", "is_canonical",
            "DATA_WAIT", "H2D", "STEP", "ALLREDUCE", "KV_BARRIER",
            "CKPT_SAVE", "EVAL", "HOTSTATE_SNAPSHOT", "WARM_RESUME",
            "FIT_STEP", "STEP_DISPATCH", "UPDATE", "METRIC", "METRIC_SYNC",
-           "BATCH_END", "EPOCH_END", "QUEUE_WAIT", "PACK", "DEVICE", "UNPACK"]
+           "BATCH_END", "EPOCH_END", "QUEUE_WAIT", "PACK", "DEVICE", "UNPACK",
+           "DEVICE_PHASES", "GRAD_SYNC", "ATTENTION_SCOPES", "ROUTED_SCOPES",
+           "DEVICE_SUBSCOPES", "COMPILER_NAMED"]
 
 #: phases the training wiring emits (fit loops, ShardedTrainer, kvstore,
 #: and the warm-elasticity transition: host offload + warm assembly).
@@ -50,6 +52,31 @@ PHASES = TRAIN_PHASES + SERVE_PHASES
  HOTSTATE_SNAPSHOT, WARM_RESUME, FIT_STEP, STEP_DISPATCH, UPDATE, METRIC,
  METRIC_SYNC, BATCH_END, EPOCH_END) = TRAIN_PHASES
 (QUEUE_WAIT, PACK, DEVICE, UNPACK) = SERVE_PHASES
+
+#: what a device operation of a compiled train step is charged to
+#: (:mod:`.device_scopes`): ``forward``, ``recompute`` and ``backward``
+#: are read off jax's own name stack around a graph node's scope; the
+#: step opens ``update`` (the host phase's word: the optimizer's update,
+#: here inside the step) and ``grad_sync`` itself; ``other`` is the rest.
+GRAD_SYNC = "grad_sync"
+DEVICE_PHASES = ("forward", "recompute", "backward", UPDATE, GRAD_SYNC,
+                 "other")
+
+#: scopes inside one graph node, where a node is more than a tenth of a
+#: step and holds unlike work: the attention ops (``GatedDeltaNet``'s
+#: ``kernel`` holds the rule's own ``gated_delta_rule`` scope) and
+#: ``RoutedExperts`` (``dispatch``: the sort, the gathers, the scatters)
+ATTENTION_SCOPES = ("proj_in", "rotary_norm", "kernel", "proj_out")
+ROUTED_SCOPES = ("route", "dispatch", "experts", "shared")
+DEVICE_SUBSCOPES = ATTENTION_SCOPES + ROUTED_SCOPES
+
+#: instructions the compiler names itself, dropping the ``op_name`` they
+#: were lowered under, and the sub-scope they belong to: XLA:TPU makes
+#: of ``lax.ragged_dot`` a Mosaic call ``ragged-dot-none`` (and
+#: ``ragged-dot-metadata`` beside it) whose ``op_name`` is that name.
+#: :mod:`.device_scopes` puts such an instruction under the scope its
+#: computation's other instructions share (a routed layer's chunk loop)
+COMPILER_NAMED = {"ragged-dot": "experts"}
 
 _CANON = frozenset(PHASES)
 

@@ -21,8 +21,12 @@ from jax import lax
 
 from ..base import MXNetError
 from ..dparam import Field, ParamStruct
+from ..observability.phases import ATTENTION_SCOPES
 from .registry import (OperatorProperty, register_op, require_known,
                        contract_sharding, dedup_axes)
+
+# the device sub-scopes of an attention node (observability/device_scopes.py)
+PROJ_IN, ROTARY_NORM, KERNEL, PROJ_OUT = ATTENTION_SCOPES
 
 
 class _LayerNormParam(ParamStruct):
@@ -279,37 +283,46 @@ class CompressedConvAttention(OperatorProperty):
         p = self.param
         H, K, d = p.num_heads, p.num_kv_heads, p.head_dim
         g = H // K
-        q = (x @ wq.T).reshape(B, S, H, d)
-        k = (x @ wk.T).reshape(B, S, K, d)
-        v = x @ wv.T                                    # (B, S, K·d)
-        half = K * d // 2
-        v = jnp.concatenate([v[..., :half],
-                             shift_tokens(v[..., half:], 1)], axis=-1)
-        q32 = q.astype(jnp.float32).reshape(B, S, K, g, d)
-        k32 = k.astype(jnp.float32)
-        mean_q = 0.5 * (q32 + k32[:, :, :, None, :])
-        mean_k = 0.5 * (k32 + jnp.mean(q32, axis=3))
-        q = causal_conv_pair(q, qc0, qc1).astype(jnp.float32) \
-            + mean_q.reshape(B, S, H, d)
-        k = causal_conv_pair(k, kc0, kc1).astype(jnp.float32) + mean_k
+        with jax.named_scope(PROJ_IN):
+            q = (x @ wq.T).reshape(B, S, H, d)
+            k = (x @ wk.T).reshape(B, S, K, d)
+            v = x @ wv.T                                # (B, S, K·d)
 
         def unit_rms(t):
             return t * lax.rsqrt(jnp.mean(jnp.square(t), axis=-1,
                                           keepdims=True) + p.eps)
 
-        q = unit_rms(q)
-        k = unit_rms(k) * temp.astype(jnp.float32)[:, None]
         rot = int(round(p.partial_rotary_factor * d))
 
         def heads(t):       # (B, S, heads, d) -> (B, heads, S, d) rotated
             return rotary_half(t.transpose(0, 2, 1, 3), p.rope_theta,
                                rot).astype(x.dtype)
 
+        # the value shift, the convolutions with the q-k mean, the
+        # normalisation and the rotary: all that lies between the
+        # projections and the kernel
+        with jax.named_scope(ROTARY_NORM):
+            half = K * d // 2
+            v = jnp.concatenate([v[..., :half],
+                                 shift_tokens(v[..., half:], 1)], axis=-1)
+            q32 = q.astype(jnp.float32).reshape(B, S, K, g, d)
+            k32 = k.astype(jnp.float32)
+            mean_q = 0.5 * (q32 + k32[:, :, :, None, :])
+            mean_k = 0.5 * (k32 + jnp.mean(q32, axis=3))
+            q = causal_conv_pair(q, qc0, qc1).astype(jnp.float32) \
+                + mean_q.reshape(B, S, H, d)
+            k = causal_conv_pair(k, kc0, kc1).astype(jnp.float32) + mean_k
+            q = unit_rms(q)
+            k = unit_rms(k) * temp.astype(jnp.float32)[:, None]
+            q, k = heads(q), heads(k)
+            v = v.reshape(B, S, K, d).transpose(0, 2, 1, 3)
+
         from ..parallel.ring_attention import sharded_self_attention
-        o = sharded_self_attention(
-            heads(q), heads(k),
-            v.reshape(B, S, K, d).transpose(0, 2, 1, 3), causal=True)
-        return [o.transpose(0, 2, 1, 3).reshape(B, S, H * d) @ wo.T], None
+        with jax.named_scope(KERNEL):
+            o = sharded_self_attention(q, k, v, causal=True)
+        with jax.named_scope(PROJ_OUT):
+            return [o.transpose(0, 2, 1, 3).reshape(B, S, H * d) @ wo.T], \
+                None
 
 
 class _GatedAttentionParam(ParamStruct):
@@ -387,22 +400,26 @@ class GatedAttention(OperatorProperty):
         B, S, _E = x.shape
         p = self.param
         H, K, d = p.num_heads, p.num_kv_heads, p.head_dim
-        qg = (x @ wq.T).reshape(B, S, H, 2 * d)
-        q, gate = qg[..., :d], qg[..., d:]
-        k = (x @ wk.T).reshape(B, S, K, d)
-        v = (x @ wv.T).reshape(B, S, K, d)
+        with jax.named_scope(PROJ_IN):
+            qg = (x @ wq.T).reshape(B, S, H, 2 * d)
+            q, gate = qg[..., :d], qg[..., d:]
+            k = (x @ wk.T).reshape(B, S, K, d)
+            v = (x @ wv.T).reshape(B, S, K, d)
         rot = int(round(p.partial_rotary_factor * d))
 
         def heads(t, gamma):    # normalised, (B, heads, S, d), rotated
             return rotary_half(rms_norm(t, gamma, p.eps)
                                .transpose(0, 2, 1, 3), p.rope_theta, rot)
 
+        with jax.named_scope(ROTARY_NORM):
+            q, k, v = heads(q, gq), heads(k, gk), v.transpose(0, 2, 1, 3)
         from ..parallel.ring_attention import sharded_self_attention
-        o = sharded_self_attention(heads(q, gq), heads(k, gk),
-                                   v.transpose(0, 2, 1, 3), causal=True)
-        o = o.transpose(0, 2, 1, 3).astype(jnp.float32) \
-            * jax.nn.sigmoid(gate.astype(jnp.float32))
-        return [o.astype(x.dtype).reshape(B, S, H * d) @ wo.T], None
+        with jax.named_scope(KERNEL):
+            o = sharded_self_attention(q, k, v, causal=True)
+        with jax.named_scope(PROJ_OUT):
+            o = o.transpose(0, 2, 1, 3).astype(jnp.float32) \
+                * jax.nn.sigmoid(gate.astype(jnp.float32))
+            return [o.astype(x.dtype).reshape(B, S, H * d) @ wo.T], None
 
 
 class _MLAParam(ParamStruct):
@@ -489,19 +506,34 @@ class MultiHeadLatentAttention(OperatorProperty):
         def heads(t, width):    # (B, S, H*width) -> (B, H, S, width)
             return t.reshape(B, S, H, width).transpose(0, 2, 1, 3)
 
-        q = heads(rms_norm(x @ wqa.T, gq, p.eps) @ wqb.T, nope + rope)
-        q = jnp.concatenate(
-            [q[..., :nope], rotary_interleaved(q[..., nope:], p.rope_theta)],
-            axis=-1)
-        ckv = x @ wkva.T                                    # (B, S, rkv+rope)
-        k_rope = rotary_interleaved(ckv[..., rkv:], p.rope_theta)
-        kv = heads(rms_norm(ckv[..., :rkv], gkv, p.eps) @ wkvb.T, nope + dv)
-        k = jnp.concatenate(
-            [kv[..., :nope],
-             jnp.broadcast_to(k_rope[:, None], (B, H, S, rope))], axis=-1)
+        # a low-rank path holds its norm between two products: both
+        # products and the norm are the projection.  (The scopes follow
+        # the statements' order; moving a statement would renumber the
+        # compiled step.)
+        with jax.named_scope(PROJ_IN):
+            q = heads(rms_norm(x @ wqa.T, gq, p.eps) @ wqb.T, nope + rope)
+        with jax.named_scope(ROTARY_NORM):
+            q = jnp.concatenate(
+                [q[..., :nope],
+                 rotary_interleaved(q[..., nope:], p.rope_theta)], axis=-1)
+        with jax.named_scope(PROJ_IN):
+            ckv = x @ wkva.T                                # (B, S, rkv+rope)
+        with jax.named_scope(ROTARY_NORM):
+            k_rope = rotary_interleaved(ckv[..., rkv:], p.rope_theta)
+        with jax.named_scope(PROJ_IN):
+            kv = heads(rms_norm(ckv[..., :rkv], gkv, p.eps) @ wkvb.T,
+                       nope + dv)
+        with jax.named_scope(ROTARY_NORM):
+            k = jnp.concatenate(
+                [kv[..., :nope],
+                 jnp.broadcast_to(k_rope[:, None], (B, H, S, rope))],
+                axis=-1)
         from ..parallel.ring_attention import sharded_self_attention
-        o = sharded_self_attention(q, k, kv[..., nope:], causal=True)
-        return [o.transpose(0, 2, 1, 3).reshape(B, S, H * dv) @ wo.T], None
+        with jax.named_scope(KERNEL):
+            o = sharded_self_attention(q, k, kv[..., nope:], causal=True)
+        with jax.named_scope(PROJ_OUT):
+            return [o.transpose(0, 2, 1, 3).reshape(B, S, H * dv) @ wo.T], \
+                None
 
     def infer_sharding(self, in_specs, in_shapes, out_shapes, mesh_shape):
         """Head-parallel over whatever axis shards the rows of the two
@@ -592,27 +624,26 @@ class MultiHeadAttention(OperatorProperty):
         B, S, E = x.shape
         H = self.param.num_heads
         D = E // H
-        qkv = x @ wqkv.T + bqkv  # (B, S, 3E)
-        q, k, v = jnp.split(qkv, 3, axis=-1)
-
         def heads(t):  # (B, S, E) -> (B, H, S, D)
             return t.reshape(B, S, H, D).transpose(0, 2, 1, 3)
 
-        if self.param.use_flash:
-            from ..parallel.ring_attention import sharded_self_attention
-            o = sharded_self_attention(heads(q), heads(k), heads(v),
-                                       causal=self.param.causal)
-        else:
-            from ..parallel.ring_attention import attention_reference
-            o = attention_reference(heads(q), heads(k), heads(v),
-                                    causal=self.param.causal)
-        o = o.transpose(0, 2, 1, 3).reshape(B, S, E)
-        if is_train and self.param.dropout > 0.0 and rng is not None:
-            import jax
-            keep = 1.0 - self.param.dropout
-            mask = jax.random.bernoulli(rng, keep, o.shape)
-            o = jnp.where(mask, o / keep, 0.0).astype(o.dtype)
-        return [o @ wo.T + bo], None
+        with jax.named_scope(PROJ_IN):
+            qkv = x @ wqkv.T + bqkv  # (B, S, 3E)
+            q, k, v = (heads(t) for t in jnp.split(qkv, 3, axis=-1))
+        with jax.named_scope(KERNEL):
+            if self.param.use_flash:
+                from ..parallel.ring_attention import sharded_self_attention
+                o = sharded_self_attention(q, k, v, causal=self.param.causal)
+            else:
+                from ..parallel.ring_attention import attention_reference
+                o = attention_reference(q, k, v, causal=self.param.causal)
+        with jax.named_scope(PROJ_OUT):
+            o = o.transpose(0, 2, 1, 3).reshape(B, S, E)
+            if is_train and self.param.dropout > 0.0 and rng is not None:
+                keep = 1.0 - self.param.dropout
+                mask = jax.random.bernoulli(rng, keep, o.shape)
+                o = jnp.where(mask, o / keep, 0.0).astype(o.dtype)
+            return [o @ wo.T + bo], None
 
     def infer_sharding(self, in_specs, in_shapes, out_shapes, mesh_shape):
         data, qkv_w = in_specs[0], in_specs[1]

@@ -57,7 +57,8 @@ from jax import lax
 
 from ..base import MXNetError
 from ..dparam import Field, ParamStruct
-from .attention import rms_norm, shift_tokens
+from .attention import (KERNEL, PROJ_IN, PROJ_OUT, ROTARY_NORM, rms_norm,
+                        shift_tokens)
 from .registry import OperatorProperty, register_op, require_known
 
 _HIGHEST = lax.Precision.HIGHEST
@@ -340,23 +341,31 @@ class GatedDeltaNet(OperatorProperty):
         B, S, _E = x.shape
         hk, hv, dk, dv = self._dims()
         r = hv // hk
-        qkvz = (x @ w_qkvz.T).reshape(B, S, hk, 2 * dk + 2 * r * dv)
-        ba = (x @ w_ba.T).reshape(B, S, hk, 2 * r)
-        q, k, v, z = jnp.split(qkvz, [dk, 2 * dk, 2 * dk + r * dv], axis=-1)
-        mixed = jnp.concatenate([t.reshape(B, S, -1) for t in (q, k, v)],
-                                axis=-1)
-        mixed = jax.nn.silu(causal_depthwise_conv(mixed, w_conv))
-        q, k, v = jnp.split(mixed, [hk * dk, 2 * hk * dk], axis=-1)
-        q = l2_normalise(q.reshape(B, S, hk, dk)) * dk ** -0.5
-        k = l2_normalise(k.reshape(B, S, hk, dk))
-        q, k = q.astype(x.dtype), k.astype(x.dtype)
-        b = ba[..., :r].reshape(B, S, hv).astype(jnp.float32)
-        a = ba[..., r:].reshape(B, S, hv).astype(jnp.float32)
-        g = -jnp.exp(a_log.astype(jnp.float32)) \
-            * jax.nn.softplus(a + dt_bias.astype(jnp.float32))
-        o = gated_delta_rule(q, k, v.reshape(B, S, hv, dv), g,
-                             jax.nn.sigmoid(b), self.param.chunk)
-        o = rms_norm(o, gamma, self.param.eps)
-        o = (o.astype(jnp.float32) * jax.nn.silu(
-            z.reshape(B, S, hv, dv).astype(jnp.float32))).astype(x.dtype)
-        return [o.reshape(B, S, hv * dv) @ w_out.T], None
+        with jax.named_scope(PROJ_IN):
+            qkvz = (x @ w_qkvz.T).reshape(B, S, hk, 2 * dk + 2 * r * dv)
+            ba = (x @ w_ba.T).reshape(B, S, hk, 2 * r)
+        # this mixer's counterpart of rotary and q-k norm: the convolution,
+        # the L2 normalisation, the decay and β
+        with jax.named_scope(ROTARY_NORM):
+            q, k, v, z = jnp.split(qkvz, [dk, 2 * dk, 2 * dk + r * dv],
+                                   axis=-1)
+            mixed = jnp.concatenate([t.reshape(B, S, -1)
+                                     for t in (q, k, v)], axis=-1)
+            mixed = jax.nn.silu(causal_depthwise_conv(mixed, w_conv))
+            q, k, v = jnp.split(mixed, [hk * dk, 2 * hk * dk], axis=-1)
+            q = l2_normalise(q.reshape(B, S, hk, dk)) * dk ** -0.5
+            k = l2_normalise(k.reshape(B, S, hk, dk))
+            q, k = q.astype(x.dtype), k.astype(x.dtype)
+            b = ba[..., :r].reshape(B, S, hv).astype(jnp.float32)
+            a = ba[..., r:].reshape(B, S, hv).astype(jnp.float32)
+            g = -jnp.exp(a_log.astype(jnp.float32)) \
+                * jax.nn.softplus(a + dt_bias.astype(jnp.float32))
+            v = v.reshape(B, S, hv, dv)
+            beta = jax.nn.sigmoid(b)
+        with jax.named_scope(KERNEL):
+            o = gated_delta_rule(q, k, v, g, beta, self.param.chunk)
+        with jax.named_scope(PROJ_OUT):
+            o = rms_norm(o, gamma, self.param.eps)
+            o = (o.astype(jnp.float32) * jax.nn.silu(
+                z.reshape(B, S, hv, dv).astype(jnp.float32))).astype(x.dtype)
+            return [o.reshape(B, S, hv * dv) @ w_out.T], None

@@ -45,6 +45,7 @@ from jax import lax
 
 from ..base import MXNetError
 from ..dparam import Field, ParamStruct
+from ..observability.phases import ROUTED_SCOPES
 from .attention import rms_norm
 from .registry import (OperatorProperty, register_cost_rule, register_op,
                        register_sharding_rule, require_known)
@@ -235,6 +236,12 @@ def _moe_cost(op, in_shapes, out_shapes):
 # RoutedExperts: top-k routing on sigmoid or softmax scores over all the
 # experts, the experts held here computed by sorted, grouped matrix products
 # ----------------------------------------------------------------------
+# the device sub-scopes of a RoutedExperts node
+# (observability/device_scopes.py); DISPATCH is the sort, the gathers and
+# the scatters around the grouped products
+ROUTE, DISPATCH, EXPERTS, SHARED = ROUTED_SCOPES
+
+
 def gated_ffn(x, w_gate, w_up, w_down):
     """W_down(silu(W_gate x) ⊙ W_up x); weights (out_features, in_features)."""
     return (jax.nn.silu(x @ w_gate.T) * (x @ w_up.T)) @ w_down.T
@@ -350,23 +357,29 @@ def routed_experts(h, w, idx, w_gate, w_up, w_down, first, chunk_rows):
 
 def _routed_fwd(h, w, idx, w_gate, w_up, w_down, first, chunk_rows):
     n_tok, k = idx.shape
-    order, counts = _sorted_assignments(idx, first, w_gate.shape[0])
     rows = _chunk_rows(n_tok * k, chunk_rows)
-    tok = order // k
-    w_sorted = w.reshape(-1)[order]
+    with jax.named_scope(DISPATCH):
+        order, counts = _sorted_assignments(idx, first, w_gate.shape[0])
+        tok = order // k
+        w_sorted = w.reshape(-1)[order]
 
     def chunk(c, y):
-        tok_c, w_c, valid, sizes = _chunk_plan(c, rows, tok, w_sorted,
-                                               counts)
-        x = jnp.where(valid, h[tok_c], 0)
-        o = _grouped_ffn(x, w_gate, w_up, w_down, sizes)
-        o = jnp.where(valid, o, 0).astype(jnp.float32) * w_c[:, None]
-        return y.at[tok_c].add(o)
+        with jax.named_scope(DISPATCH):
+            tok_c, w_c, valid, sizes = _chunk_plan(c, rows, tok, w_sorted,
+                                                   counts)
+            x = jnp.where(valid, h[tok_c], 0)
+        with jax.named_scope(EXPERTS):
+            o = _grouped_ffn(x, w_gate, w_up, w_down, sizes)
+        with jax.named_scope(DISPATCH):
+            o = jnp.where(valid, o, 0).astype(jnp.float32) * w_c[:, None]
+            return y.at[tok_c].add(o)
 
-    n_chunks = (jnp.sum(counts) + rows - 1) // rows
-    y = lax.fori_loop(0, n_chunks, chunk,
-                      jnp.zeros(h.shape, jnp.float32))
-    return ((y.astype(h.dtype), counts),
+    with jax.named_scope(DISPATCH):
+        n_chunks = (jnp.sum(counts) + rows - 1) // rows
+        y = lax.fori_loop(0, n_chunks, chunk,
+                          jnp.zeros(h.shape, jnp.float32))
+        y = y.astype(h.dtype)
+    return ((y, counts),
             (h, w, idx, w_gate, w_up, w_down, order, counts))
 
 
@@ -377,39 +390,46 @@ def _routed_bwd(first, chunk_rows, res, cts):
     dy = cts[0]
     n_tok, k = idx.shape
     rows = _chunk_rows(n_tok * k, chunk_rows)
-    tok = order // k
-    w_sorted = w.reshape(-1)[order]
+    with jax.named_scope(DISPATCH):
+        tok = order // k
+        w_sorted = w.reshape(-1)[order]
 
     def chunk(c, carry):
         dh, dw_sorted, d_gate, d_up, d_down = carry
-        tok_c, w_c, valid, sizes = _chunk_plan(c, rows, tok, w_sorted,
-                                               counts)
-        x = jnp.where(valid, h[tok_c], 0)
-        dy_c = jnp.where(valid, dy[tok_c], 0)
-        o, vjp = jax.vjp(
-            lambda x, a, b, c_: _grouped_ffn(x, a, b, c_, sizes),
-            x, w_gate, w_up, w_down)
-        o = jnp.where(valid, o, 0)
-        dw_c = jnp.sum(o.astype(jnp.float32) * dy_c.astype(jnp.float32),
-                       axis=-1)
-        dx, dg, du, dd = vjp((dy_c.astype(jnp.float32)
-                              * w_c[:, None]).astype(o.dtype))
-        dh = dh.at[tok_c].add(jnp.where(valid, dx, 0).astype(jnp.float32))
-        dw_sorted = lax.dynamic_update_slice_in_dim(dw_sorted, dw_c,
-                                                    c * rows, axis=0)
-        return (dh, dw_sorted, d_gate + dg.astype(jnp.float32),
-                d_up + du.astype(jnp.float32),
-                d_down + dd.astype(jnp.float32))
+        with jax.named_scope(DISPATCH):
+            tok_c, w_c, valid, sizes = _chunk_plan(c, rows, tok, w_sorted,
+                                                   counts)
+            x = jnp.where(valid, h[tok_c], 0)
+            dy_c = jnp.where(valid, dy[tok_c], 0)
+        with jax.named_scope(EXPERTS):
+            o, vjp = jax.vjp(
+                lambda x, a, b, c_: _grouped_ffn(x, a, b, c_, sizes),
+                x, w_gate, w_up, w_down)
+            o = jnp.where(valid, o, 0)
+            dw_c = jnp.sum(o.astype(jnp.float32)
+                           * dy_c.astype(jnp.float32), axis=-1)
+            dx, dg, du, dd = vjp((dy_c.astype(jnp.float32)
+                                  * w_c[:, None]).astype(o.dtype))
+        with jax.named_scope(DISPATCH):
+            dh = dh.at[tok_c].add(
+                jnp.where(valid, dx, 0).astype(jnp.float32))
+            dw_sorted = lax.dynamic_update_slice_in_dim(dw_sorted, dw_c,
+                                                        c * rows, axis=0)
+        with jax.named_scope(EXPERTS):
+            return (dh, dw_sorted, d_gate + dg.astype(jnp.float32),
+                    d_up + du.astype(jnp.float32),
+                    d_down + dd.astype(jnp.float32))
 
-    n_chunks = (jnp.sum(counts) + rows - 1) // rows
-    zeros32 = functools.partial(jnp.zeros, dtype=jnp.float32)
-    dh, dw_sorted, d_gate, d_up, d_down = lax.fori_loop(
-        0, n_chunks, chunk,
-        (zeros32(h.shape), zeros32((n_tok * k,)), zeros32(w_gate.shape),
-         zeros32(w_up.shape), zeros32(w_down.shape)))
-    # back from sorted rows to (token, choice) slots: the inverse of a
-    # permutation is a gather too
-    dw = dw_sorted[jnp.argsort(order)].reshape(n_tok, k)
+    with jax.named_scope(DISPATCH):
+        n_chunks = (jnp.sum(counts) + rows - 1) // rows
+        zeros32 = functools.partial(jnp.zeros, dtype=jnp.float32)
+        dh, dw_sorted, d_gate, d_up, d_down = lax.fori_loop(
+            0, n_chunks, chunk,
+            (zeros32(h.shape), zeros32((n_tok * k,)), zeros32(w_gate.shape),
+             zeros32(w_up.shape), zeros32(w_down.shape)))
+        # back from sorted rows to (token, choice) slots: the inverse of a
+        # permutation is a gather too
+        dw = dw_sorted[jnp.argsort(order)].reshape(n_tok, k)
     return (dh.astype(h.dtype), dw.astype(w.dtype), None,
             d_gate.astype(w_gate.dtype), d_up.astype(w_up.dtype),
             d_down.astype(w_down.dtype))
@@ -540,23 +560,27 @@ class RoutedExperts(OperatorProperty):
         p = self.param
         x, router, w_gate, w_up, w_down = inputs[:5]
         h = x.reshape(-1, x.shape[-1])
-        if self._given():
-            scores = router.reshape(-1, p.num_experts).astype(jnp.float32)
-        elif p.score_func == "softmax":
-            scores = softmax_scores(h, router)
-        else:
-            scores = sigmoid_scores(h, router)
-        idx, w = route_topk(scores, aux[0], p.top_k,
-                            p.routed_scaling_factor, p.norm_topk_prob)
+        with jax.named_scope(ROUTE):
+            if self._given():
+                scores = router.reshape(-1, p.num_experts) \
+                    .astype(jnp.float32)
+            elif p.score_func == "softmax":
+                scores = softmax_scores(h, router)
+            else:
+                scores = sigmoid_scores(h, router)
+            idx, w = route_topk(scores, aux[0], p.top_k,
+                                p.routed_scaling_factor, p.norm_topk_prob)
         y, counts = routed_experts(h, w, idx, w_gate, w_up, w_down,
                                    p.first_expert, CHUNK_ROWS)
         if p.shared_hidden_size:
-            shared = gated_ffn(h, *inputs[5:8])
-            if p.shared_gate:
-                gate = jax.nn.sigmoid(jnp.dot(
-                    h, inputs[8].T, preferred_element_type=jnp.float32))
-                shared = (shared.astype(jnp.float32) * gate).astype(y.dtype)
-            y = y + shared
+            with jax.named_scope(SHARED):
+                shared = gated_ffn(h, *inputs[5:8])
+                if p.shared_gate:
+                    gate = jax.nn.sigmoid(jnp.dot(
+                        h, inputs[8].T, preferred_element_type=jnp.float32))
+                    shared = (shared.astype(jnp.float32)
+                              * gate).astype(y.dtype)
+                y = y + shared
         if not is_train:
             return [y.reshape(x.shape)], None
         _bias, total, per_expert, peak_sum, peak_max = aux

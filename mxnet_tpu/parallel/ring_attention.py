@@ -772,6 +772,18 @@ def current_sequence_parallel():
     return getattr(_SP_STATE, "ctx", None)
 
 
+def reopen_current_scope():
+    """``make()`` -> the context that is active now, to be entered again
+    later (a step lowered once more, after its owner left the scope), or
+    None where none is active."""
+    ctx = current_sequence_parallel()
+    if ctx is None:
+        return None
+    return functools.partial(sequence_parallel, ctx.mesh,
+                             seq_axis=ctx.seq_axis,
+                             batch_axis=ctx.batch_axis)
+
+
 def attention_scope(mesh, seq_axis=None):
     """The context to trace and run a step over ``mesh`` under (None or
     one device: nothing to tell): :func:`sequence_parallel` with the

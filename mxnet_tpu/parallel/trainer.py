@@ -17,6 +17,8 @@ updates — the analog of the reference's shared memory pool + kWriteInplace.
 """
 from __future__ import annotations
 
+import functools
+
 import numpy as _np
 
 import jax
@@ -26,21 +28,10 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from ..base import MXNetError
 from .sharding import param_pspec, batch_pspec
 from . import overlap as _overlap
+from ..observability import device_scopes as _device_scopes
+from ..observability.phases import GRAD_SYNC
 
 __all__ = ["ShardedTrainer", "ShardedPredictor"]
-
-
-def _abstractify(a):
-    """ShapeDtypeStruct (with sharding when present) for jit.lower().
-
-    Single-device shardings (the uncommitted rng key) are dropped:
-    baking them in would make lower() reject the mix with mesh-sharded
-    arguments that the real dispatch accepts.  Host scalars have none."""
-    from jax.sharding import SingleDeviceSharding
-    sh = getattr(a, "sharding", None)
-    if sh is not None and not isinstance(sh, SingleDeviceSharding):
-        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sh)
-    return jax.ShapeDtypeStruct(_np.shape(a), a.dtype)
 
 
 def _place_batch(batch, sharding_fn):
@@ -175,7 +166,8 @@ class ShardedTrainer(object):
             outs, aux_out, grads = loss_and_grads(trace, cast, params, batch,
                                                   aux, rng)
             if self._bucket_grads:
-                grads = _overlap.interleave_grad_buckets(grads)
+                with jax.named_scope(GRAD_SYNC):
+                    grads = _overlap.interleave_grad_buckets(grads)
             grads = preprocess_grads(optimizer, grads)
             new_params, new_opt_state = apply_updates(
                 optimizer, params, grads, opt_state, lr, wd, t,
@@ -243,6 +235,7 @@ class ShardedTrainer(object):
             train_step,
             donate_argnums=(0, 1, 2, 8) if self.sentinel else (0, 1, 2))
         self._abstract_args = None   # ShapeDtypeStructs of the step args
+        self._step_record = None     # device_scopes record of the step
         self._lowered = None         # cached jax.stages.Lowered
         self._compiled_step = None   # cached jax.stages.Compiled of it
         self._cache_entry = None     # overlap compile-cache slot
@@ -602,8 +595,9 @@ class ShardedTrainer(object):
             step_args = step_args + (self._sentinel_state,)
         if self._abstract_args is None:
             self._abstract_args = jax.tree_util.tree_map(
-                _abstractify, step_args)
+                _device_scopes.abstractify, step_args)
             self._adopt_cached_step()
+            self.device_scopes()    # on record from the first dispatch on
 
         from .. import observability as _obs
         # host dispatch wall only: XLA execution is async, so this
@@ -739,8 +733,7 @@ class ShardedTrainer(object):
         compile-cache entry so later binds with the same key skip
         lowering entirely."""
         if self._lowered is None and self._abstract_args is not None:
-            with self._sp_scope():
-                self._lowered = self._jit_step.lower(*self._abstract_args)
+            self._lowered = self.device_scopes().lower()
             if self._cache_entry is not None:
                 self._cache_entry["lowered"] = self._lowered
         return self._lowered
@@ -755,6 +748,23 @@ class ShardedTrainer(object):
                 return None
             self._compiled_step = lowered.compile()
         return self._compiled_step
+
+    def device_scopes(self):
+        """The step's ``device_scopes.StepRecord``, or ``None`` before the
+        first step: ``record.scopes()`` maps each instruction of the
+        compiled step to its graph node and pass.  The record holds the
+        jitted step, its abstract arguments and the mesh context the step
+        is traced under — no device buffer — and outlives this trainer
+        (docs/observability.md, "Device time by scope")."""
+        if self._step_record is None and self._abstract_args is not None:
+            from .ring_attention import attention_scope
+            self._step_record = _device_scopes.register(
+                "jit_" + self._jit_step.__name__, self._jit_step,
+                self._abstract_args,
+                _device_scopes.graph_nodes(self.symbol),
+                context=functools.partial(attention_scope, self.mesh,
+                                          self.seq_axis))
+        return self._step_record
 
     def compiled_step_cost_analysis(self):
         """XLA cost analysis of the whole COMPILED train step (dict with

@@ -14,6 +14,8 @@ import numpy as _np
 import jax
 import jax.numpy as jnp
 
+from .observability.phases import UPDATE
+
 __all__ = ["compute_cast", "no_cast", "cast_each", "zero_cotangent",
            "loss_and_grads", "preprocess_grads", "apply_updates"]
 
@@ -95,8 +97,10 @@ def loss_and_grads(trace, cast, wrt, other_args, aux, rng, out_grads=None):
 def preprocess_grads(optimizer, grads):
     """Each gradient as the optimizer's update takes it (rescaled,
     clipped): what a finiteness check looks at, and the ``grads`` of
-    :func:`apply_updates`."""
-    return {n: optimizer._preprocess_grad(g) for n, g in grads.items()}
+    :func:`apply_updates`.  Under the device scope ``update``, as
+    :func:`apply_updates` is."""
+    with jax.named_scope(UPDATE):
+        return {n: optimizer._preprocess_grad(g) for n, g in grads.items()}
 
 
 def apply_updates(optimizer, weights, grads, states, lr, wd, t, *,
@@ -109,23 +113,25 @@ def apply_updates(optimizer, weights, grads, states, lr, wd, t, *,
     ``wd_mult`` map a key to a static factor on ``lr`` / ``wd``; a factor
     of exactly 1.0 emits no multiply.  ``fused`` ('1' or 'kernel') runs
     ``kernels.fused_opt.fused_apply`` in place of the loop: bit-identical,
-    elementwise optimizers only, and it knows no multipliers."""
-    if fused:
-        from .kernels.fused_opt import fused_apply
-        new_w, new_s = fused_apply(optimizer, weights, grads, states, lr,
-                                   wd, t, mode=fused)
-        return new_w, {n: s for n, s in new_s.items() if s is not None}
+    elementwise optimizers only, and it knows no multipliers.  Either way
+    under the device scope ``update`` (observability/device_scopes.py)."""
+    with jax.named_scope(UPDATE):
+        if fused:
+            from .kernels.fused_opt import fused_apply
+            new_w, new_s = fused_apply(optimizer, weights, grads, states, lr,
+                                       wd, t, mode=fused)
+            return new_w, {n: s for n, s in new_s.items() if s is not None}
 
-    def scaled(x, mult, n):
-        m = 1.0 if mult is None else mult.get(n, 1.0)
-        return x if m == 1.0 else x * m
+        def scaled(x, mult, n):
+            m = 1.0 if mult is None else mult.get(n, 1.0)
+            return x if m == 1.0 else x * m
 
-    new_w, new_s = {}, {}
-    for n in weights:
-        w, s = optimizer.update_fn(weights[n], grads[n], states.get(n),
-                                   scaled(lr, lr_mult, n),
-                                   scaled(wd, wd_mult, n), t)
-        new_w[n] = w
-        if s is not None:
-            new_s[n] = s
-    return new_w, new_s
+        new_w, new_s = {}, {}
+        for n in weights:
+            w, s = optimizer.update_fn(weights[n], grads[n], states.get(n),
+                                       scaled(lr, lr_mult, n),
+                                       scaled(wd, wd_mult, n), t)
+            new_w[n] = w
+            if s is not None:
+                new_s[n] = s
+        return new_w, new_s

@@ -270,6 +270,26 @@ def main():
     out["transformer_mosaic_calls"] = compiled.as_text().count(MOSAIC)
     ok = ok and out["transformer_mosaic_calls"] >= cfg["num_layers"]
 
+    # ... and each call under its own layer's scope and pass: the kernels'
+    # calls are jitted, and XLA joins a call site's name with the callee's
+    # where it inlines (observability/device_scopes.py)
+    from mxnet_tpu.observability import device_scopes
+    nodes = device_scopes.graph_nodes(sym)
+
+    def kernel_scopes(text):
+        found = {}
+        for name, op_name in device_scopes.parse(text).items():
+            if name.startswith("flash_"):
+                phase, node, _sub = device_scopes.classify(op_name, nodes)
+                found.setdefault("%s %s" % (name.split(".")[0], phase),
+                                 set()).add(node)
+        return {k: len(v) for k, v in sorted(found.items())}
+
+    want_scopes = {"flash_backward backward": cfg["num_layers"],
+                   "flash_forward forward": cfg["num_layers"]}
+    out["transformer_kernel_scopes"] = kernel_scopes(compiled.as_text())
+    ok = ok and out["transformer_kernel_scopes"] == want_scopes
+
     if len(devs) >= 4:
         # data parallel over four chips: GSPMD cannot partition a Mosaic
         # kernel, so the step must carry it per device (under shard_map)
@@ -279,8 +299,10 @@ def main():
                             seq_axis=None).as_text()
         out["dp4_mosaic_calls"] = text.count(MOSAIC)
         out["dp4_all_reduces"] = text.count("all-reduce")
+        out["dp4_kernel_scopes"] = kernel_scopes(text)
         ok = ok and out["dp4_mosaic_calls"] >= cfg["num_layers"] \
-            and out["dp4_all_reduces"] > 0
+            and out["dp4_all_reduces"] > 0 \
+            and out["dp4_kernel_scopes"] == want_scopes
         mesh4 = Mesh(np.array(devs[:4]).reshape(2, 2), ("dp", "sp"))
         c4 = compile_step(mesh4, seq_axis=1)
         out["ring_collective_permutes"] = c4.as_text().count(

@@ -41,7 +41,7 @@ def audit(batch, layers, dtype):
         {"data": (batch, 3, 224, 224)},
         label_shapes={"softmax_label": (batch,)})
     import jax.numpy as jnp
-    from mxnet_tpu.parallel.trainer import _abstractify
+    from mxnet_tpu.observability.device_scopes import abstractify
     batch_abstract = {
         "data": jax.ShapeDtypeStruct((batch, 3, 224, 224), jnp.float32),
         "softmax_label": jax.ShapeDtypeStruct((batch,), jnp.float32),
@@ -54,7 +54,7 @@ def audit(batch, layers, dtype):
                  jnp.float32(1e-4), jnp.int32(1))
     trainer._abstract_args = jax.tree_util.tree_map(
         lambda a: a if isinstance(a, jax.ShapeDtypeStruct)
-        else _abstractify(a), step_args)
+        else abstractify(a), step_args)
     lowered = trainer._lower()
     # STRUCTURAL audit on the backend-neutral StableHLO: what the program
     # asks for.  (The compiled text below is per-backend: XLA:CPU upcasts
