@@ -59,11 +59,16 @@ def mirror_scope(stage_name, enabled=True):
     inputs and recomputes the rest in backward, but for the values their
     producer names in ``executor.KEPT``: what the flash attention kernel
     hands its backward (q, k, v, the output, the softmax statistics),
-    because recomputing the last two is a second call of the kernel and
-    they fit only the operands they were made from — so a block with
-    attention in it runs the forward kernel once, and a block with none
-    saves its inputs alone.  What is kept depends only on what the
-    traced segment holds; nothing here or in the environment selects it.
+    what the gated delta rule's forward sweep hands on (q, k, v, the
+    chunk scalars, the chunk states, the output) and what the routed
+    layer hands its backward (the chosen experts and their weights, the
+    sorted order, the counts; the routed sum where a backward reads it),
+    because recomputing them is a second run of the kernels and a kept
+    value fits only the operands and choices it was made from — so a
+    block runs each forward kernel and its grouped products once, and a
+    block with no such producer saves its inputs alone.  What is kept
+    depends only on what the traced segment holds; nothing here or in
+    the environment selects it.
     ``enabled=False`` returns a no-op context so model builders can
     expose a ``mirror_blocks`` flag without branching (models/resnet.py,
     models/transformer.py)."""

@@ -28,6 +28,8 @@ from .ndarray import NDArray, zeros
 from . import random as _random
 from .observability import device_scopes as _device_scopes
 from .observability import spans as _spans
+from .kernels.delta_rule import DELTA_RESIDUALS
+from .ops.moe import ROUTED_RESIDUALS
 from .parallel.ring_attention import FLASH_RESIDUALS
 from .train_step import (apply_updates, compute_cast, loss_and_grads,
                          no_cast, preprocess_grads)
@@ -38,7 +40,10 @@ _ZERO_KEY = None
 def _zero_key():
     global _ZERO_KEY
     if _ZERO_KEY is None:
-        _ZERO_KEY = jax.random.PRNGKey(0)
+        # the first caller may be inside a trace (``_saved_residuals``):
+        # the key kept for the process must be an array, not its tracer
+        with jax.ensure_compile_time_eval():
+            _ZERO_KEY = jax.random.PRNGKey(0)
     return _ZERO_KEY
 
 
@@ -168,7 +173,8 @@ class _Program:
     def mirror_kept(self, arg_values, aux_values, wrt_names):
         """``[(name, bytes)]`` of what the mirrored segments save by name
         (``KEPT``) instead of recomputing, for these shapes: the counter
-        that says the kernel's second forward call is gone.  Traced on
+        that says a kernel's second forward call, or the routed layer's
+        second sort, is gone.  Traced on
         demand, like ``trace_residual_bytes``; ``[]`` for a program with
         no mirrored segment, whose named values are saved like all its
         others."""
@@ -223,16 +229,26 @@ def _consumable(arrays, beside=None):
 # The ``checkpoint_name``s a mirrored segment saves instead of
 # recomputing.  The rule: a producer names what a kernel hands its own
 # backward when recomputing it costs a second call of the kernel, and it
-# names that set whole — the flash forward's operands q, k, v with its
-# output and softmax statistics.  The operands alone would be cheap to
+# names that set whole.  Three producers do: the flash forward (its
+# operands q, k, v with its output and softmax statistics), the gated
+# delta rule's forward sweep (q, k, v, the chunk scalars, the chunk states
+# and the output, which the gated norm after it reads) and the routed
+# layer (the chosen experts and their weights, the sorted order and the
+# counts, and the routed sum: its recomputation is the grouped products
+# again).  Operands and discrete choices alone would be cheap to
 # recompute, but in bfloat16 a recomputation is not the first computation
-# to the bit, and statistics kept from the first call no longer normalise
+# to the bit: statistics kept from the first call no longer normalise
 # scores made from recomputed operands (PERF.md section 6, PR 32: a 1 %
-# error in ZAYA1's gradient norms).  Held a block: 336 MB in JoyAI (q and
-# k 101 MB each), 42 MB in ZAYA1, against a block input of 33.5 MB.  A
-# segment that holds no such name saves what a policy-less checkpoint
-# saves: its inputs.
-KEPT = FLASH_RESIDUALS
+# error in ZAYA1's gradient norms), and a top-k taken again may order a
+# near-tie otherwise than the kept sort did.  A named value is saved only
+# where the segment's backward reads it: the routed sum where a learned
+# scale follows the layer (ZAYA1) and not where an add does.  Held a
+# block: the flash kernel's 336 MB in JoyAI (q and k 101 MB each) and
+# 42 MB in ZAYA1, a delta-rule layer's 337 MB in Qwen3-Next (the states
+# 134 MB), a routed layer's 34 MB where the sum is kept and under 2 MB
+# where not, against a block input of 33.5 MB.  A segment that holds no
+# such name saves what a policy-less checkpoint saves: its inputs.
+KEPT = FLASH_RESIDUALS + DELTA_RESIDUALS + ROUTED_RESIDUALS
 
 
 def mirror_checkpoint(fn):
@@ -362,8 +378,9 @@ def _build_program(symbol, group2ctx):
     ``jax.checkpoint``: their activations leave the residual set and are
     recomputed during the vjp — the TPU-native memory/FLOPs trade.  The
     checkpoint (``mirror_checkpoint``) saves the names in ``KEPT``, so a
-    block that holds the flash kernel recomputes everything but the
-    kernel's call and its operands.
+    block that holds the flash kernel, the delta rule's or a routed layer
+    recomputes everything but the kernels' calls, their operands and the
+    routing's choices.
     """
     topo = symbol._topo()
     heads = list(symbol._heads)
@@ -1035,8 +1052,9 @@ class Executor:
         the bound shapes — the activation-memory quantity mirroring
         (``force_mirroring``/MXNET_BACKWARD_DO_MIRROR ->
         ``jax.checkpoint``) exists to shrink.  It counts what a mirrored
-        segment keeps by name (``mirror_kept``: the flash kernel's
-        operands, output and statistics) beside the segments' inputs.
+        segment keeps by name (``mirror_kept``: what the flash kernel,
+        the delta rule's forward sweep and the routed layer hand their
+        backward) beside the segments' inputs.
         Backend-independent: read from the partial-eval trace, not the
         compiled executable (XLA:CPU does not attribute temp buffers)."""
         return trace_residual_bytes(self._program.trace,

@@ -31,9 +31,11 @@ them.
 
 The backward (``jax.custom_vjp``) is two sweeps: the forward kernel as the
 ``fwd`` rule runs it, where it also writes each chunk's starting state in
-the compute dtype (N × d_k × d_v a head; under a checkpoint that sweep is the
-recomputation and the states live from it to the backward kernel alone), and
-``gated_delta_backward``, which walks blocks, tiles and chunks in reverse
+the compute dtype (N × d_k × d_v a head) and names what it hands on
+(``DELTA_RESIDUALS``: a checkpoint that saves those names, as the
+executor's mirrored segments do, keeps the states from the first sweep to
+the backward kernel; a bare one runs the sweep again as its recomputation),
+and ``gated_delta_backward``, which walks blocks, tiles and chunks in reverse
 with dS in VMEM, recomputes a tile's locals, and returns all five gradients
 — dq and dk of a key head summed over its r value heads in float32 inside
 the kernel.  Cotangents are rounded to the compute dtype for the products
@@ -57,10 +59,21 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 
 __all__ = ["delta_blocks", "gated_delta_rule_kernel",
            "gated_delta_forward_kernel_spec",
-           "gated_delta_backward_kernel_spec"]
+           "gated_delta_backward_kernel_spec", "DELTA_RESIDUALS"]
+
+# What the forward sweep hands on — q, k, v, the chunk-major scalars, the
+# output and the chunk states — each under a ``checkpoint_name``, as the
+# flash kernel's are (``ring_attention.FLASH_RESIDUALS``, and why the
+# operands belong to the set): a ``jax.checkpoint`` whose policy saves
+# these names does not run the sweep again.  The backward kernel does not
+# read the output; the gated norm after the rule does, in its own backward,
+# and a checkpoint that lacks it runs the sweep again to have it.
+DELTA_RESIDUALS = ("delta_q", "delta_k", "delta_v", "delta_scalars",
+                   "delta_out", "delta_states")
 
 # tokens a program, largest first (the flash kernels' blocks)
 _BLOCKS = (512, 256, 128)
@@ -589,6 +602,10 @@ def gated_delta_rule_kernel(q, k, v, g, beta, block, chunk, interpret=False):
 
     def rule_fwd(q, k, v, gb):
         o, states = _forward_call(q, k, v, gb, block, chunk, True, interpret)
+        # a name is the identity: outside a checkpoint it lowers to nothing
+        q, k, v, gb, o, states = (
+            checkpoint_name(x, name) for x, name in zip(
+                (q, k, v, gb, o, states), DELTA_RESIDUALS))
         return o, (q, k, v, gb, states)
 
     def rule_bwd(res, do):

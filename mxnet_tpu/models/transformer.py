@@ -58,8 +58,9 @@ def get_symbol(vocab_size=32000, num_layers=4, num_heads=8, dim=256,
     ``mirror_blocks=True`` tags every op inside each decoder layer with
     ``force_mirroring`` + a per-layer ``mirror_stage`` (same mechanism
     as models.resnet): backward recomputes whole layers and keeps only
-    layer-boundary activations — the standard per-layer remat for
-    HBM-limited long-context training, here expressed as symbol attrs
+    layer-boundary activations and what a layer's kernels hand their
+    backward (``attribute.mirror_scope``) — the standard per-layer remat
+    for HBM-limited long-context training, here expressed as symbol attrs
     and lowered by the executor's mirror segments (executor.py
     ``_mirror_segments``)."""
     from ..attribute import mirror_scope

@@ -77,7 +77,9 @@ def get_symbol(vocab_size=32000, num_layers=4, dim=256, seq_len=512,
     """The LM symbol (module docstring).  ``mirror_blocks=True`` makes
     the backward pass recompute each layer from its two inputs (per-layer
     recomputation, as ``models.transformer`` has it; what the attention
-    kernel hands its backward is kept: ``attribute.mirror_scope``)."""
+    kernel and the routed layer hand their backward is kept, and the
+    routed sum, which the learned scale after the layer reads:
+    ``attribute.mirror_scope``)."""
     from ..attribute import mirror_scope
     attention = dict(num_heads=num_heads, num_kv_heads=num_kv_heads,
                      head_dim=head_dim, conv_taps0=cca_time0,

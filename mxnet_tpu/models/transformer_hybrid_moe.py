@@ -71,7 +71,8 @@ def get_symbol(vocab_size=32000, num_layers=4, dim=256, seq_len=512,
                num_experts_per_tok=4, rms_norm_eps=1e-6, mirror_blocks=False):
     """The LM symbol (module docstring).  ``mirror_blocks=True`` makes the
     backward pass recompute each block from its input (per-layer
-    recomputation; what the attention kernel hands its backward is kept:
+    recomputation; what the attention kernel, the delta rule's forward
+    sweep and the routed layer hand their backward is kept:
     ``attribute.mirror_scope``)."""
     from ..attribute import mirror_scope
     attention = dict(num_heads=num_heads, num_kv_heads=num_kv_heads,

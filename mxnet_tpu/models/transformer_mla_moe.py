@@ -84,7 +84,8 @@ def get_symbol(vocab_size=32000, num_layers=4, first_k_dense=1, dim=256,
     """The LM symbol (module docstring).  ``mirror_blocks=True`` makes
     the backward pass recompute each block from its input (per-layer
     recomputation, as ``models.transformer`` has it; what the attention
-    kernel hands its backward is kept: ``attribute.mirror_scope``)."""
+    kernel and the routed layer hand their backward is kept:
+    ``attribute.mirror_scope``)."""
     from ..attribute import mirror_scope
     if num_nextn_predict_layers not in (0, 1):
         raise ValueError("one prediction module at the most (depth 1)")

@@ -42,6 +42,7 @@ import numpy as _np
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 
 from ..base import MXNetError
 from ..dparam import Field, ParamStruct
@@ -241,6 +242,21 @@ def _moe_cost(op, in_shapes, out_shapes):
 # the scatters around the grouped products
 ROUTE, DISPATCH, EXPERTS, SHARED = ROUTED_SCOPES
 
+#: What the routed layer's backward is handed that is neither a weight nor
+#: the layer's input — the chosen experts and their weights, the sorted
+#: order and the counts — and the routed sum, each under a
+#: ``checkpoint_name`` where it is made: a ``jax.checkpoint`` whose policy
+#: saves these names (the executor's mirrored segments) makes no discrete
+#: choice a second time, and where a backward reads the routed sum (a
+#: learned scale after the layer) does not walk the chunks again to have
+#: it.  The set is whole for the flash kernel's reason
+#: (``ring_attention.FLASH_RESIDUALS``): a top-k taken again from scores
+#: that differ in the last bit may order a near-tie otherwise, and an order
+#: kept from the first call then sorts the wrong slots' weights.
+ROUTED_RESIDUALS = ("routed_idx", "routed_w", "routed_order",
+                    "routed_counts", "routed_out")
+_IDX, _W, _ORDER, _COUNTS, _OUT = ROUTED_RESIDUALS
+
 
 def gated_ffn(x, w_gate, w_up, w_down):
     """W_down(silu(W_gate x) ⊙ W_up x); weights (out_features, in_features)."""
@@ -255,11 +271,14 @@ def route_topk(scores, bias, top_k, scaling=1.0, normalize=True):
     ``scaling``.  The choice carries no gradient; the weights carry the
     scores'."""
     _, idx = lax.top_k(scores + bias.astype(jnp.float32), top_k)
+    # named before anything reads it: the weights' backward reads the
+    # choice too
+    idx = checkpoint_name(idx.astype(jnp.int32), _IDX)
     chosen = idx[..., None] == jnp.arange(scores.shape[-1])
     w = jnp.sum(jnp.where(chosen, scores[:, None, :], 0.0), axis=-1)
     if normalize:
         w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
-    return idx.astype(jnp.int32), w * scaling
+    return idx, checkpoint_name(w * scaling, _W)
 
 
 def sigmoid_scores(h, router_weight):
@@ -295,7 +314,7 @@ def _sorted_assignments(idx, first, n_local):
     order = jnp.argsort(eid, stable=True).astype(jnp.int32)
     counts = jnp.sum(eid[:, None] == jnp.arange(n_local)[None, :], axis=0,
                      dtype=jnp.int32)
-    return order, counts
+    return checkpoint_name(order, _ORDER), checkpoint_name(counts, _COUNTS)
 
 
 #: rows of the sorted assignment list that ``RoutedExperts`` computes at a
@@ -378,14 +397,17 @@ def _routed_fwd(h, w, idx, w_gate, w_up, w_down, first, chunk_rows):
         n_chunks = (jnp.sum(counts) + rows - 1) // rows
         y = lax.fori_loop(0, n_chunks, chunk,
                           jnp.zeros(h.shape, jnp.float32))
-        y = y.astype(h.dtype)
+        # the routed sum, before a shared expert is added to it
+        y = checkpoint_name(y.astype(h.dtype), _OUT)
     return ((y, counts),
             (h, w, idx, w_gate, w_up, w_down, order, counts))
 
 
 def _routed_bwd(first, chunk_rows, res, cts):
     """Walks the same chunks: each recomputes its rows' FFN and takes
-    its vjp, so nothing is kept from the forward but the sorted order."""
+    its vjp, so nothing is kept from the forward's loop; the choice, its
+    weights, the sorted order and the counts are the forward's own
+    (``ROUTED_RESIDUALS``)."""
     h, w, idx, w_gate, w_up, w_down, order, counts = res
     dy = cts[0]
     n_tok, k = idx.shape

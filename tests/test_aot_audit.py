@@ -120,6 +120,13 @@ def test_every_shipped_kernel_compiles_under_mosaic():
     # on a mesh of four the gated delta rule's kernels run per device
     # (shard_map): the forward and the backward, as on one
     assert report.get("delta_rule_dp2tp2_mosaic_calls", 2) == 2
+    # a mirrored hybrid step: each kernel once a layer, under its own pass —
+    # no forward kernel under ``recompute`` (executor.KEPT)
+    for mesh, found in report["hybrid_kernel_scopes"].items():
+        assert found == {"flash_backward backward": 2,
+                         "flash_forward forward": 2,
+                         "gated_delta_backward backward": 2,
+                         "gated_delta_forward forward": 2}, (mesh, found)
     # the forward kernel and the backward kernel, one Mosaic call each
     for want in ("flash_fwd_bwd[float32]", "flash_fwd_bwd[bfloat16]",
                  "flash_fwd_bwd[bfloat16,latent]"):

@@ -230,15 +230,20 @@ def test_chunk_scalars_are_the_running_sums():
 
 
 # -- the op's block: which kernels its gradient program holds -----------------
-def _block(chunk=16, batch=1, key_heads=1):
+def _delta_net(chunk=16, batch=1, key_heads=1):
+    """A ``GatedDeltaNet`` operator and seeded leaves for it."""
     op = create_operator(
         "GatedDeltaNet", num_key_heads=key_heads,
         num_value_heads=2 * key_heads, key_head_dim=128, value_head_dim=128,
         conv_taps=4, chunk=chunk, eps=1e-6)
     shapes = op.infer_shape([(batch, 128, 32)] + [None] * 7)[0]
     keys = jax.random.split(jax.random.PRNGKey(7), len(shapes))
-    leaves = [0.3 * jax.random.normal(key, s, dtype=F32)
-              for key, s in zip(keys, shapes)]
+    return op, [0.3 * jax.random.normal(key, s, dtype=F32)
+                for key, s in zip(keys, shapes)]
+
+
+def _block(chunk=16, batch=1, key_heads=1):
+    op, leaves = _delta_net(chunk, batch, key_heads)
 
     def block(*leaves):
         return jnp.sum(jnp.sin(op.forward(list(leaves), [], True,
@@ -249,17 +254,18 @@ def _block(chunk=16, batch=1, key_heads=1):
 @pytest.mark.parametrize("wrap,calls", [
     ("plain", {"gated_delta_forward": 1, "gated_delta_backward": 1}),
     ("checkpoint", {"gated_delta_forward": 2, "gated_delta_backward": 1}),
-    ("mirror", {"gated_delta_forward": 2, "gated_delta_backward": 1}),
+    ("mirror", {"gated_delta_forward": 1, "gated_delta_backward": 1}),
 ])
 def test_gradient_program_of_a_delta_block(monkeypatch, wrap, calls):
     """The forward kernel once where nothing recomputes (the ``fwd`` rule's
-    sweep, which also writes the chunk states), twice under a checkpoint — the executor's
-    mirrored one too: ``KEPT`` names nothing of the rule, the block
-    recomputes it — and the backward kernel once.  The mirrored block's
-    gradients are the plain block's."""
+    sweep, which also writes the chunk states) and once under the executor's
+    mirrored checkpoint, which keeps what the sweep hands on by name
+    (``DELTA_RESIDUALS``, whole: the output too, which the gated norm after
+    the rule reads); twice under a bare checkpoint; the backward kernel
+    once.  The mirrored block's gradients are the plain block's."""
     from mxnet_tpu import executor
     from mxnet_tpu.kernels import common
-    from test_mirror import _kernel_calls
+    from test_mirror import _kept_by_name, _kernel_calls
     monkeypatch.setattr(
         common, "dispatch",
         lambda kernel, _reference, *args: kernel(*args, interpret=True))
@@ -269,8 +275,16 @@ def test_gradient_program_of_a_delta_block(monkeypatch, wrap, calls):
     wrt = tuple(range(len(leaves)))
     found = _kernel_calls(jax.make_jaxpr(jax.grad(fn, wrt))(*leaves).jaxpr)
     assert found == calls, found
-    assert executor.KEPT == ("flash_q", "flash_k", "flash_v", "flash_out",
-                             "flash_lse")
+    assert set(dr.DELTA_RESIDUALS) <= set(executor.KEPT)
+    kept = _kept_by_name(fn, leaves)
+    if wrap == "checkpoint":
+        assert kept == []
+    else:
+        # one key head on two value heads of 128, 128 tokens in 8 chunks of
+        # 16, float32 (unwrapped, a named value is saved like any other)
+        assert sorted(kept) == sorted(zip(dr.DELTA_RESIDUALS, (
+            128 * 128 * 4, 128 * 128 * 4, 128 * 2 * 128 * 4,
+            2 * 2 * 128 * 4, 128 * 2 * 128 * 4, 2 * 8 * 128 * 128 * 4)))
     if wrap == "mirror":
         got = jax.grad(fn, wrt)(*leaves)
         want = jax.grad(block, wrt)(*leaves)
@@ -333,6 +347,89 @@ def test_block_on_a_mesh_lowers_for_a_tpu(axes, shape, seq_axis,
             lowering_platforms=("tpu",)).as_text()
     assert text.count(MOSAIC) == mosaic_calls
     assert ("shard_map" in str(jaxpr)) == bool(mosaic_calls)
+
+
+def _hybrid_block(batch=4, key_heads=2):
+    """A delta-rule mixer and a routed layer with their residual adds, as a
+    hybrid model's block has them: ``(block, leaves, expert leaves)``."""
+    gdn, leaves = _delta_net(batch=batch, key_heads=key_heads)
+    moe = create_operator("RoutedExperts", num_experts=4, hidden_size=16,
+                          top_k=2, score_func="softmax")
+    shapes, _, aux_shapes = moe.infer_shape([(batch * 128, 32)] + [None] * 4)
+    keys = jax.random.split(jax.random.PRNGKey(11), len(shapes))
+    routed = [0.3 * jax.random.normal(key, shape, dtype=F32)
+              for key, shape in zip(keys[1:], shapes[1:])]
+    aux = [jnp.zeros(shape, F32 if i == 0 else jnp.int32)
+           for i, shape in enumerate(aux_shapes)]
+    n = len(leaves)
+
+    def block(*args):
+        x = args[0]
+        x = x + gdn.forward(list(args[:n]), [], True, None)[0][0]
+        f = moe.forward([x.reshape(-1, 32)] + list(args[n:]), aux, True,
+                        None)[0][0]
+        return x + f.reshape(x.shape)
+    return block, leaves + routed, range(n + 1, n + len(routed))
+
+
+@pytest.mark.parametrize("axes,shape,seq_axis", [
+    (("dp",), (4,), None),
+    (("dp", "tp"), (2, 2), None),
+    (("dp", "ep"), (2, 2), None),
+    (("dp", "sp"), (2, 2), 1),
+])
+def test_mirrored_hybrid_block_on_a_mesh_keeps_the_names(axes, shape,
+                                                         seq_axis):
+    """The block under the executor's mirrored checkpoint, lowered for a
+    TPU over four devices as ``ShardedTrainer`` lowers its step: the rule's
+    names are found inside ``shard_map`` (each at its global size) and the
+    forward kernel is called once, not twice; the routed layer's names pass
+    through GSPMD, the experts sharded over ``ep`` where the mesh has it.
+    Where the mesh shards the sequence the rule is the XLA form, which
+    names nothing: it has no second kernel call to save."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    from mxnet_tpu.executor import mirror_checkpoint
+    from mxnet_tpu.parallel.ring_attention import attention_scope
+    from test_mirror import _kept_by_name
+    mesh = Mesh(np.array(jax.devices()[:4]).reshape(shape), axes)
+    block, leaves, experts = _hybrid_block()
+    kept_block = mirror_checkpoint(block)
+
+    def loss(*args):
+        return jnp.sum(jnp.sin(kept_block(*args)))
+
+    def sharding(i):
+        if i == 0:
+            return NamedSharding(mesh, P("dp"))
+        if i in experts and "ep" in axes:
+            return NamedSharding(mesh, P("ep"))
+        return NamedSharding(mesh, P())
+    args = [jax.ShapeDtypeStruct(t.shape, t.dtype, sharding=sharding(i))
+            for i, t in enumerate(leaves)]
+    wrt = tuple(range(len(leaves)))
+    with jax.enable_x64(False), attention_scope(mesh, seq_axis):
+        step = jax.value_and_grad(loss, wrt)
+        jaxpr = jax.make_jaxpr(step)(*leaves)
+        text = jax.jit(step).trace(*args).lower(
+            lowering_platforms=("tpu",)).as_text()
+        kept = _kept_by_name(loss, leaves)
+    slots = 4 * 128 * 2 * 4
+    routed = [("routed_counts", 4 * 4), ("routed_idx", slots),
+              ("routed_order", slots), ("routed_w", slots)]
+    if seq_axis is not None:
+        assert text.count(MOSAIC) == 0 and "shard_map" not in str(jaxpr)
+        assert sorted(kept) == routed
+        return
+    assert text.count(MOSAIC) == 2              # one forward, one backward
+    assert "shard_map" in str(jaxpr)
+    # two key heads on four value heads of 128.  A residual leaves
+    # ``shard_map`` laid over every mesh axis: over ``ep`` the rule is
+    # replicated, and both members' copies are counted
+    tokens = 4 * 128 * (2 if "ep" in axes else 1)
+    assert sorted(kept) == sorted(routed + list(zip(dr.DELTA_RESIDUALS, (
+        tokens * 2 * 128 * 4, tokens * 2 * 128 * 4, tokens * 4 * 128 * 4,
+        tokens * 4 * 2 * 4, tokens * 4 * 128 * 4,
+        tokens // 16 * 4 * 128 * 128 * 4))))
 
 
 def test_block_on_a_mesh_is_the_block_on_one_device():

@@ -487,8 +487,7 @@ def test_each_layers_kernel_is_charged_to_its_own_node_and_pass(mesh_shape,
                                                                 axes):
     """The inner-``jit`` hazard: both flash kernels and the delta rule's
     are jitted once per shape.  A cached lowering that kept the first call
-    site's scope would charge every layer's kernel, and its recomputed
-    call, to layer 0's forward."""
+    site's scope would charge every layer's kernel to layer 0's forward."""
     tr = _trainer(_hybrid_net(), mesh_shape, axes, seq=512)
     record = tr.device_scopes()
     with jax.enable_x64(False), record.context():
@@ -500,10 +499,11 @@ def test_each_layers_kernel_is_charged_to_its_own_node_and_pass(mesh_shape,
         assert sub == "kernel", name
         found.setdefault(kernel, []).append((node, phase))
     gdn, att = ("layer0_gdn", "layer2_gdn"), ("layer1_att", "layer3_att")
-    # the rule's forward runs again in a mirrored block's recomputation;
-    # the flash forward's output is kept (executor.KEPT) and does not
+    # what either forward kernel hands its backward is kept
+    # (executor.KEPT): no mirrored block's recomputation runs the rule's
+    # again
     assert sorted(found["gated_delta_forward"]) == sorted(
-        [(n, p) for n in gdn for p in ("forward", "recompute")])
+        [(n, "forward") for n in gdn])
     assert sorted(found["gated_delta_backward"]) == sorted(
         [(n, "backward") for n in gdn])
     assert sorted(found["flash_backward"]) == sorted(

@@ -321,25 +321,65 @@ def test_mirrored_step_lowers_to_the_same_text_in_every_process():
 
 
 # ----------------------------------------------------------------------
-# What a mirrored segment keeps: the flash kernel's operands, output and
-# softmax statistics, by the names their producer gives them
-# (executor.KEPT)
+# What a mirrored segment keeps, by the names their producer gives them
+# (executor.KEPT): the flash kernel's operands, output and softmax
+# statistics; what the delta rule's forward sweep hands on; the routed
+# layer's choices, sorted order and, where a backward reads it, its sum
 # ----------------------------------------------------------------------
-def _kernel_calls(jaxpr, found=None):
-    """{kernel name: pallas_call equations} of ``jaxpr``, however deep
-    (checkpoints, conds, jits and custom_vjps hold jaxprs of their own)."""
-    found = {} if found is None else found
+def _equations(jaxpr):
+    """Every equation of ``jaxpr``, however deep (checkpoints, conds, jits
+    and custom_vjps hold jaxprs of their own)."""
     for eqn in jaxpr.eqns:
-        if eqn.primitive.name == "pallas_call":
-            name = eqn.params["name"]
-            found[name] = found.get(name, 0) + 1
+        yield eqn
         for value in eqn.params.values():
             for sub in (value if isinstance(value, (tuple, list))
                         else (value,)):
                 sub = getattr(sub, "jaxpr", sub)
                 if hasattr(sub, "eqns"):
-                    _kernel_calls(sub, found)
+                    yield from _equations(sub)
+
+
+def _kernel_calls(jaxpr):
+    """{kernel name: pallas_call equations} of ``jaxpr``."""
+    found = {}
+    for eqn in _equations(jaxpr):
+        if eqn.primitive.name == "pallas_call":
+            name = eqn.params["name"]
+            found[name] = found.get(name, 0) + 1
     return found
+
+
+def _count(jaxpr, primitive):
+    """Equations of ``primitive`` in ``jaxpr``."""
+    return sum(eqn.primitive.name == primitive for eqn in _equations(jaxpr))
+
+
+def _kept_by_name(fn, leaves):
+    """``trace_mirror_kept`` of ``fn(*leaves)``, every leaf differentiated."""
+    from mxnet_tpu.executor import trace_mirror_kept
+    names = ["leaf%d" % i for i in range(len(leaves))]
+    return trace_mirror_kept(
+        lambda a, aux, _rng, _train: ([fn(*[a[n] for n in names])], aux),
+        dict(zip(names, leaves)), {}, tuple(names))
+
+
+def _seeded_state(net, shapes, ids=True):
+    """``(args, aux)`` of ``net`` bound to ``shapes`` (data and labels):
+    small seeded weights, token ids below 64 where ``ids``, auxiliary
+    state 0 in its own types."""
+    import jax.numpy as jnp
+    arg_shapes, _, aux_shapes = net.infer_shape(**shapes)
+    _, _, aux_types = net.infer_type()
+    rs = np.random.RandomState(0)
+    args = {n: jnp.asarray((rs.rand(*s).astype(np.float32) - 0.5) * 0.2)
+            for n, s in zip(net.list_arguments(), arg_shapes)}
+    if ids:
+        for n in shapes:
+            args[n] = jnp.asarray(rs.randint(0, 64, shapes[n])
+                                  .astype(np.float32))
+    aux = {n: jnp.zeros(s, t) for n, s, t in zip(
+        net.list_auxiliary_states(), aux_shapes, aux_types)}
+    return args, aux
 
 
 def _qkv(shape_q, shape_kv, seed=0):
@@ -361,10 +401,31 @@ def _attention_block(q, k, v, w):
 
 def test_kept_names_are_the_kernels():
     from mxnet_tpu import executor
+    from mxnet_tpu.kernels import delta_rule
+    from mxnet_tpu.ops import moe
     from mxnet_tpu.parallel import ring_attention
-    assert executor.KEPT == ring_attention.FLASH_RESIDUALS
-    assert executor.KEPT == ("flash_q", "flash_k", "flash_v", "flash_out",
-                             "flash_lse")
+    assert executor.KEPT == (ring_attention.FLASH_RESIDUALS
+                             + delta_rule.DELTA_RESIDUALS
+                             + moe.ROUTED_RESIDUALS)
+    assert executor.KEPT == (
+        "flash_q", "flash_k", "flash_v", "flash_out", "flash_lse",
+        "delta_q", "delta_k", "delta_v", "delta_scalars", "delta_out",
+        "delta_states",
+        "routed_idx", "routed_w", "routed_order", "routed_counts",
+        "routed_out")
+    assert len(set(executor.KEPT)) == len(executor.KEPT)
+
+
+def test_counting_what_is_kept_first_leaks_no_tracer(monkeypatch):
+    """``trace_mirror_kept`` traces, and may be the process's first user of
+    the executor's zero key: the key it leaves behind is an array."""
+    import jax
+    import jax.numpy as jnp
+    from mxnet_tpu import executor
+    monkeypatch.setattr(executor, "_ZERO_KEY", None)
+    assert _kept_by_name(lambda x: jnp.sum(jnp.sin(x)), [jnp.ones(4)]) == []
+    assert not isinstance(executor._zero_key(), jax.core.Tracer)
+    jax.jit(lambda x: x + executor._zero_key()[0])(jnp.ones(2))
 
 
 @pytest.mark.parametrize("heads,kv_heads", [(2, 2), (4, 2)])
@@ -447,38 +508,136 @@ def test_a_name_outside_a_checkpoint_lowers_to_nothing(monkeypatch):
     assert text() == named
 
 
+@pytest.mark.parametrize("which", ["gpt2", "resnet", "hybrid"])
+def test_a_step_with_no_mirrored_segment_lowers_to_the_parents_text(
+        which, monkeypatch):
+    """``gpt2m_train_s1024``'s and ``resnet50_fit_b256``'s shapes of step
+    (no mirrored segment; no named producer at all in ResNet), and an
+    unmirrored hybrid that holds all three producers: the gradient
+    program's text with the names in the source is the text with every
+    ``checkpoint_name`` taken out."""
+    import re
+    import jax
+    import jax.numpy as jnp
+    from mxnet_tpu.executor import _build_program, _zero_key
+    from mxnet_tpu.kernels import common, delta_rule
+    from mxnet_tpu.models import resnet
+    from mxnet_tpu.ops import moe
+    from mxnet_tpu.parallel import ring_attention
+    monkeypatch.setattr(
+        common, "dispatch",
+        lambda kernel, _reference, *args: kernel(*args, interpret=True))
+    if which == "gpt2":
+        net = mx.models.transformer.get_symbol(
+            vocab_size=64, num_layers=2, num_heads=2, dim=32, seq_len=128)
+        shapes = dict(data=(1, 128), softmax_label=(1, 128))
+    elif which == "resnet":
+        net = resnet.get_symbol(num_classes=10, num_layers=18,
+                                image_shape=(3, 32, 32))
+        shapes = dict(data=(2, 3, 32, 32), softmax_label=(2,))
+    else:
+        net = _routed_block_model("hybrid", False)[0]
+        shapes = dict(data=(1, 128), softmax_label=(1, 128))
+    args, aux = _seeded_state(net, shapes, ids=which != "resnet")
+    w = {n: args.pop(n) for n in list(args) if n not in shapes}
+
+    def text(names):
+        prog = _build_program(net, {})
+        assert not prog.mirrored
+
+        def loss(w, batch, aux):
+            outs, _aux = prog.trace(dict(batch, **w), aux, _zero_key(), True)
+            return sum(jnp.sum(o * o) for o in outs)
+        grad = jax.grad(loss)
+        assert bool(_count(jax.make_jaxpr(grad)(w, args, aux).jaxpr,
+                           "name")) == names
+        return re.sub(r"@(\w+?)_\d+\b", r"@\1",
+                      jax.jit(grad).lower(w, args, aux).as_text())
+    named = text(which != "resnet")
+    for module in (ring_attention, delta_rule, moe):
+        monkeypatch.setattr(module, "checkpoint_name", lambda x, _name: x)
+    assert text(False) == named
+
+
+def _named(names, sizes, times=1):
+    return list(zip(names, sizes)) * times
+
+
 def _routed_block_model(which, mirror):
-    from mxnet_tpu.models import transformer_cca_moe, transformer_mla_moe
-    if which == "mla":
+    """A small model of each routed family, and what its mirrored blocks
+    keep: (net, labels, flash calls, delta-rule layers, [(name, bytes)])."""
+    from mxnet_tpu.kernels.delta_rule import DELTA_RESIDUALS
+    from mxnet_tpu.models import (transformer_cca_moe, transformer_hybrid_moe,
+                                  transformer_mla_moe)
+    from mxnet_tpu.ops.moe import ROUTED_RESIDUALS
+    from mxnet_tpu.parallel.ring_attention import FLASH_RESIDUALS
+    tokens = 128
+    if which == "mla":          # JoyAI-shaped
         net = transformer_mla_moe.get_symbol(
-            vocab_size=64, num_layers=2, dim=32, seq_len=128, num_heads=2,
+            vocab_size=64, num_layers=2, dim=32, seq_len=tokens, num_heads=2,
             q_lora_rank=16, kv_lora_rank=8, qk_nope_head_dim=8,
             qk_rope_head_dim=8, v_head_dim=8, intermediate_size=64,
             moe_intermediate_size=16, n_routed_experts=4,
             num_experts_per_tok=2, mirror_blocks=mirror)
-        labels = dict(softmax_label=(1, 128), mtp_label=(1, 128))
+        labels = dict(softmax_label=(1, tokens), mtp_label=(1, tokens))
         # two layers and the prediction module's: three calls of the
         # kernel; q and k (1, 2, 128, 16), v and the output (1, 2, 128, 8),
-        # lse (1, 2, 128)
-        return net, labels, 3, (2 * 128 * 16 * 4, 2 * 128 * 16 * 4,
-                                2 * 128 * 8 * 4, 2 * 128 * 8 * 4,
-                                2 * 128 * 4)
-    net = transformer_cca_moe.get_symbol(
-        vocab_size=64, num_layers=2, dim=32, seq_len=128, num_heads=4,
+        # lse (1, 2, 128).  Two routed layers of two choices a token over
+        # four experts: the choice, its weights and the sorted order
+        # (128 × 2) × 4 bytes, the counts 4 × 4; the sum meets an add and
+        # is not kept
+        kept = _named(FLASH_RESIDUALS,
+                      (2 * tokens * 16 * 4, 2 * tokens * 16 * 4,
+                       2 * tokens * 8 * 4, 2 * tokens * 8 * 4,
+                       2 * tokens * 4), 3) \
+            + _named(ROUTED_RESIDUALS[:4], (tokens * 2 * 4,) * 3 + (16,), 2)
+        return net, labels, 3, 0, kept
+    if which == "hybrid":       # Qwen3-Next-shaped
+        net = transformer_hybrid_moe.get_symbol(
+            vocab_size=64, num_layers=2, dim=32, seq_len=tokens,
+            full_attention_interval=2, num_heads=2, num_kv_heads=1,
+            head_dim=16, linear_num_key_heads=1, linear_num_value_heads=2,
+            linear_key_head_dim=128, linear_value_head_dim=128,
+            delta_chunk=16, moe_intermediate_size=16,
+            shared_expert_intermediate_size=16, num_experts=4,
+            num_experts_per_tok=2, mirror_blocks=mirror)
+        # one delta-rule layer, one key head on two value heads of 128,
+        # eight chunks of 16: q and k 128 × 128, v and o 128 × 2 × 128, the
+        # scalars 2 × 2 × 128, the chunk states 2 × 8 × 128 × 128, all
+        # float32 here; one attention layer, two query heads on one
+        # key/value head of 16; two routed layers whose sum meets an add
+        kept = _named(DELTA_RESIDUALS,
+                      (tokens * 128 * 4, tokens * 128 * 4,
+                       tokens * 2 * 128 * 4, 2 * 2 * tokens * 4,
+                       tokens * 2 * 128 * 4, 2 * 8 * 128 * 128 * 4)) \
+            + _named(FLASH_RESIDUALS,
+                     (2 * tokens * 16 * 4, tokens * 16 * 4, tokens * 16 * 4,
+                      2 * tokens * 16 * 4, 2 * tokens * 4)) \
+            + _named(ROUTED_RESIDUALS[:4], (tokens * 2 * 4,) * 3 + (16,), 2)
+        return net, dict(softmax_label=(1, tokens)), 1, 1, kept
+    net = transformer_cca_moe.get_symbol(    # ZAYA1-shaped
+        vocab_size=64, num_layers=2, dim=32, seq_len=tokens, num_heads=4,
         num_kv_heads=2, head_dim=8, moe_intermediate_size=16,
         num_experts=4, router_hidden_size=8, mirror_blocks=mirror)
-    # four query heads on two key/value heads of 8
-    return (net, dict(softmax_label=(1, 128)), 2,
-            (4 * 128 * 8 * 4, 2 * 128 * 8 * 4, 2 * 128 * 8 * 4,
-             4 * 128 * 8 * 4, 4 * 128 * 4))
+    # four query heads on two key/value heads of 8; one choice a token, and
+    # the routed sum (128 × 32) is kept: a learned scale reads it
+    kept = _named(FLASH_RESIDUALS,
+                  (4 * tokens * 8 * 4, 2 * tokens * 8 * 4, 2 * tokens * 8 * 4,
+                   4 * tokens * 8 * 4, 4 * tokens * 4), 2) \
+        + _named(ROUTED_RESIDUALS,
+                 (tokens * 4,) * 3 + (16, tokens * 32 * 4), 2)
+    return net, dict(softmax_label=(1, tokens)), 2, 0, kept
 
 
-@pytest.mark.parametrize("which", ["mla", "cca"])
+@pytest.mark.parametrize("which", ["mla", "cca", "hybrid"])
 def test_build_program_keeps_the_kernels_output_in_a_mirrored_block(
         which, monkeypatch):
     """Through ``_build_program``, the dispatch forced to the interpreted
-    kernel: a small latent-attention block and a small convolution-mixed
-    one, ``mirror_blocks=True``."""
+    kernels: a small latent-attention model (JoyAI's shape), a small
+    convolution-mixed one (ZAYA1's) and a small hybrid of the delta rule
+    and attention (Qwen3-Next's), ``mirror_blocks=True``.  Each kernel
+    once a layer, the grouped products as often as unmirrored, and
+    ``mirror_kept`` lists exactly what the blocks hold by name."""
     import jax
     import jax.numpy as jnp
     from mxnet_tpu.executor import _build_program, _zero_key
@@ -488,41 +647,108 @@ def test_build_program_keeps_the_kernels_output_in_a_mirrored_block(
         lambda kernel, _reference, *args: kernel(*args, interpret=True))
 
     def build(mirror):
-        net, labels, layers, sizes = _routed_block_model(which, mirror)
+        net, labels, flash, delta, kept = _routed_block_model(which, mirror)
         prog = _build_program(net, {})
-        shapes, _, aux_shapes = net.infer_shape(data=(1, 128), **labels)
-        _, _, aux_types = net.infer_type()
-        rs = np.random.RandomState(0)
-        args = {n: jnp.asarray((rs.rand(*s).astype(np.float32) - 0.5) * 0.2)
-                for n, s in zip(net.list_arguments(), shapes)}
-        for n in ("data",) + tuple(labels):
-            args[n] = jnp.asarray(rs.randint(0, 64, (1, 128))
-                                  .astype(np.float32))
-        aux = {n: jnp.zeros(s, t) for n, s, t in zip(
-            net.list_auxiliary_states(), aux_shapes, aux_types)}
+        args, aux = _seeded_state(net, dict(labels, data=(1, 128)))
         wrt = tuple(n for n in args if n != "data" and n not in labels)
 
         def loss(w):
             outs, _aux = prog.trace(dict(args, **w), aux, _zero_key(), True)
             return sum(jnp.sum(o * o) for o in outs)
-        return prog, args, aux, wrt, loss, layers, sizes
+        return prog, args, aux, wrt, loss, flash, delta, kept
 
-    prog, args, aux, wrt, loss, layers, sizes = build(True)
+    prog, args, aux, wrt, loss, flash, delta, kept = build(True)
     w = {n: args[n] for n in wrt}
     assert prog.mirrored
-    assert _kernel_calls(jax.make_jaxpr(jax.grad(loss))(w).jaxpr) \
-        == {"flash_forward": layers, "flash_backward": layers}
-    from mxnet_tpu.executor import KEPT
-    assert sorted(prog.mirror_kept(args, aux, wrt)) \
-        == sorted(list(zip(KEPT, sizes)) * layers)
+    program = jax.make_jaxpr(jax.grad(loss))(w).jaxpr
+    calls = {"flash_forward": flash, "flash_backward": flash}
+    if delta:
+        calls.update(gated_delta_forward=delta, gated_delta_backward=delta)
+    assert _kernel_calls(program) == calls
+    assert sorted(prog.mirror_kept(args, aux, wrt)) == sorted(kept)
 
-    plain, p_args, p_aux, _wrt, p_loss, _layers, _sizes = build(False)
+    plain, p_args, p_aux, _wrt, p_loss = build(False)[:5]
     assert not plain.mirrored and plain.mirror_kept(p_args, p_aux, wrt) == []
+    # no grouped product, sort or top-k is made a second time
+    unmirrored = jax.make_jaxpr(jax.grad(p_loss))(w).jaxpr
+    for primitive in ("ragged_dot_general", "sort", "top_k"):
+        assert _count(program, primitive) == _count(unmirrored, primitive) \
+            > 0, primitive
     want = jax.grad(p_loss)(w)
     got = jax.grad(loss)(w)
     for n in wrt:
         np.testing.assert_allclose(np.asarray(got[n]), np.asarray(want[n]),
                                    rtol=2e-4, atol=1e-6, err_msg=n)
+
+
+# -- the routed layer alone: what follows it decides whether its sum is kept
+def _routed_block(ends):
+    """``(block, leaves)``: something before a ``RoutedExperts`` layer with
+    a shared expert, the layer, and a learned scale or a plain add after it
+    (ZAYA1's block end, and JoyAI's and Qwen3-Next's)."""
+    import jax
+    import jax.numpy as jnp
+    from mxnet_tpu.ops.registry import create_operator
+    op = create_operator("RoutedExperts", num_experts=8, hidden_size=16,
+                         top_k=2, shared_hidden_size=16,
+                         score_func="softmax")
+    shapes, _, aux_shapes = op.infer_shape([(64, 32)] + [None] * 7)
+    keys = jax.random.split(jax.random.PRNGKey(3), len(shapes) + 1)
+    leaves = [0.3 * jax.random.normal(key, shape, dtype=jnp.float32)
+              for key, shape in zip(keys, shapes + [(32,)])]
+    aux = [jnp.zeros(shape, jnp.float32 if i == 0 else jnp.int32)
+           for i, shape in enumerate(aux_shapes)]
+
+    def block(*leaves):
+        x = jnp.tanh(leaves[0])
+        f = op.forward([x] + list(leaves[1:-1]), aux, True, None)[0][0]
+        return x + (f * leaves[-1] if ends == "scale" else f + leaves[-1])
+    return block, leaves
+
+
+@pytest.mark.parametrize("ends", ["scale", "add"])
+def test_mirrored_routed_block_walks_its_chunks_as_often_as_unwrapped(ends):
+    """A routed block under ``mirror_checkpoint`` runs ``ragged_dot``, the
+    sort and the top-k as often as the unwrapped block; a bare
+    ``jax.checkpoint`` makes the sort and the top-k again and, where a
+    learned scale reads the routed sum, the grouped products of one more
+    forward walk.  The sum is kept only there; the gradients are the
+    unwrapped block's."""
+    import jax
+    import jax.numpy as jnp
+    from mxnet_tpu.executor import mirror_checkpoint
+    block, leaves = _routed_block(ends)
+    wrt = tuple(range(len(leaves)))
+
+    def loss_of(wrap):
+        return lambda *a: jnp.sum(jnp.sin(wrap(block)(*a)))
+
+    def counts(wrap):
+        jaxpr = jax.make_jaxpr(jax.grad(loss_of(wrap), wrt))(*leaves).jaxpr
+        return {p: _count(jaxpr, p)
+                for p in ("ragged_dot_general", "sort", "top_k")}
+    plain = counts(lambda fn: fn)
+    # forward 3 products, backward 3 again and 2 each for their vjp
+    assert plain == {"ragged_dot_general": 12, "sort": 2, "top_k": 1}
+    assert counts(mirror_checkpoint) == plain
+    bare = counts(jax.checkpoint)
+    assert bare["sort"] == 3 and bare["top_k"] == 2
+    assert bare["ragged_dot_general"] == (15 if ends == "scale" else 12)
+
+    def kept(wrap):
+        return sorted(_kept_by_name(loss_of(wrap), leaves))
+    slots = 64 * 2 * 4
+    want = [("routed_counts", 8 * 4), ("routed_idx", slots),
+            ("routed_order", slots), ("routed_w", slots)]
+    if ends == "scale":
+        want.append(("routed_out", 64 * 32 * 4))
+    assert kept(mirror_checkpoint) == sorted(want)
+    assert kept(jax.checkpoint) == []
+
+    got = jax.grad(loss_of(mirror_checkpoint), wrt)(*leaves)
+    for a, b in zip(got, jax.grad(loss_of(lambda fn: fn), wrt)(*leaves)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-6,
+                                   atol=1e-7)
 
 
 @pytest.mark.parametrize("which", ["mlp", "mlp_env", "resnet"])
@@ -551,7 +777,6 @@ def test_a_segment_that_names_nothing_saves_what_it_saved(which,
     assert ex.mirror_kept() == []
     with_policy = ex.backward_residual_bytes()
     monkeypatch.setattr(executor, "KEPT", ())
-    executor._PROGRAM_REGISTRY.clear()
     assert bound().backward_residual_bytes() == with_policy
 
 

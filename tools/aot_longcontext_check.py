@@ -25,6 +25,14 @@ chip time:
    the ppermute ring (collective-permute ops), proving the sequence-
    parallel schedule survives XLA:TPU lowering.
 
+With the kernels (so under ``--kernels-only`` too) goes one small step that
+holds them all: a hybrid of the delta rule and attention over routed
+experts with ``mirror_blocks`` (four layers, heads of 128, 512 tokens), on
+one device and, with four, on dp2 × tp2 — each layer's kernel must read
+under its own node and pass (``hybrid_kernel_scopes``): every forward
+kernel under ``forward`` alone, since a mirrored block keeps what a kernel
+hands its backward (``executor.KEPT``) and its recomputation calls none.
+
 Prints one JSON line; exit 2 = topology unavailable (callers SKIP), 1 =
 a kernel was refused or a step lost its Mosaic call / ring.
 Run serially: the local libtpu serves ONE process at a time.
@@ -188,6 +196,80 @@ def compile_delta_rule_on_mesh(devs):
     return text.count(MOSAIC)
 
 
+def compile_step(sym, opt, mesh, batch, seq_len, seq_axis=None):
+    """``sym``'s fused train step compiled for ``mesh`` through
+    ``ShardedTrainer._lower`` (which engages the attention scope), from
+    abstract state: nothing is placed on a device."""
+    import jax
+    import jax.numpy as jnp
+    from mxnet_tpu.parallel.trainer import ShardedTrainer
+    tr = ShardedTrainer(sym, opt, mesh, compute_dtype="bfloat16",
+                        seq_axis=seq_axis)
+    shp = (batch, seq_len)
+    params, o, a = tr.abstract_state(
+        {"data": shp}, label_shapes={"softmax_label": shp})
+    repl = tr._replicated()
+    key = jax.eval_shape(lambda: jax.random.PRNGKey(0))
+    b = {"data": jax.ShapeDtypeStruct(shp, jnp.int32,
+                                      sharding=tr.batch_sharding(shp)),
+         "softmax_label": jax.ShapeDtypeStruct(
+             shp, jnp.float32, sharding=tr.batch_sharding(shp))}
+    tr._abstract_args = (
+        params, o, a, b,
+        jax.ShapeDtypeStruct(key.shape, key.dtype, sharding=repl),
+        jax.ShapeDtypeStruct((), jnp.float32, sharding=repl),
+        jax.ShapeDtypeStruct((), jnp.float32, sharding=repl),
+        jax.ShapeDtypeStruct((), jnp.int32, sharding=repl))
+    return tr._lower().compile()
+
+
+def kernel_scopes(text, sym):
+    """``{"<kernel> <pass>": nodes}`` of a compiled step's text: under how
+    many graph nodes each of our kernels reads in each pass.  The kernels'
+    calls are jitted, and XLA joins a call site's name with the callee's
+    where it inlines (observability/device_scopes.py)."""
+    from mxnet_tpu.observability import device_scopes
+    nodes = device_scopes.graph_nodes(sym)
+    found = {}
+    for name, op_name in device_scopes.parse(text).items():
+        if name.startswith(("flash_", "gated_delta_")):
+            phase, node, _sub = device_scopes.classify(op_name, nodes)
+            found.setdefault("%s %s" % (name.split(".")[0], phase),
+                             set()).add(node)
+    return {k: len(v) for k, v in sorted(found.items())}
+
+
+#: what the small mirrored hybrid step must read on every mesh: two
+#: delta-rule layers and two attention layers, each kernel once a layer,
+#: the forward ones under ``forward`` alone
+HYBRID_SCOPES = {"flash_backward backward": 2, "flash_forward forward": 2,
+                 "gated_delta_backward backward": 2,
+                 "gated_delta_forward forward": 2}
+
+
+def hybrid_kernel_scopes(devs):
+    """``{mesh: kernel_scopes}`` of the small mirrored hybrid step
+    (``HYBRID_SCOPES`` is what each must read)."""
+    import numpy as np
+    from jax.sharding import Mesh
+    from mxnet_tpu import optimizer as opt_mod
+    from mxnet_tpu.models import transformer_hybrid_moe
+    sym = transformer_hybrid_moe.get_symbol(
+        vocab_size=64, num_layers=4, dim=256, seq_len=512,
+        full_attention_interval=2, num_heads=4, num_kv_heads=2,
+        head_dim=128, linear_num_key_heads=2, linear_num_value_heads=4,
+        linear_key_head_dim=128, linear_value_head_dim=128, num_experts=8,
+        n_local_experts=8, num_experts_per_tok=2, mirror_blocks=True)
+    opt = opt_mod.create("sgd", learning_rate=0.1, momentum=0.9)
+    meshes = {"one_device": Mesh(np.array(devs[:1]), ("dp",))}
+    if len(devs) >= 4:
+        meshes["dp2tp2"] = Mesh(np.array(devs[:4]).reshape(2, 2),
+                                ("dp", "tp"))
+    return {name: kernel_scopes(
+        compile_step(sym, opt, mesh, 4, 512).as_text(), sym)
+        for name, mesh in meshes.items()}
+
+
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--topology", default="v5e:2x2")
@@ -217,6 +299,9 @@ def main():
         out["delta_rule_dp2tp2_mosaic_calls"] = compile_delta_rule_on_mesh(
             devs)
         ok = ok and out["delta_rule_dp2tp2_mosaic_calls"] == 2
+    out["hybrid_kernel_scopes"] = hybrid_kernel_scopes(devs)
+    ok = ok and all(found == HYBRID_SCOPES
+                    for found in out["hybrid_kernel_scopes"].values())
     if args.kernels_only:
         print(json.dumps(out))
         return 0 if ok else 1
@@ -224,7 +309,6 @@ def main():
     # 2 + 3. transformer fused step, single-chip and dp x sp ring
     from mxnet_tpu.models import transformer
     from mxnet_tpu import optimizer as opt_mod
-    from mxnet_tpu.parallel.trainer import ShardedTrainer
 
     if args.full:
         cfg = dict(vocab_size=8192, num_layers=8, num_heads=8, dim=512,
@@ -237,29 +321,12 @@ def main():
     sym = transformer.get_symbol(**cfg)
     opt = opt_mod.create("sgd", learning_rate=0.1, momentum=0.9,
                          rescale_grad=1.0 / (batch * cfg["seq_len"]))
-    key = jax.eval_shape(lambda: jax.random.PRNGKey(0))
 
-    def compile_step(mesh, seq_axis):
-        tr = ShardedTrainer(sym, opt, mesh, compute_dtype="bfloat16",
-                            seq_axis=seq_axis)
-        shp = (batch, cfg["seq_len"])
-        params, o, a = tr.abstract_state(
-            {"data": shp}, label_shapes={"softmax_label": shp})
-        repl = tr._replicated()
-        b = {"data": jax.ShapeDtypeStruct(shp, jnp.int32,
-                                          sharding=tr.batch_sharding(shp)),
-             "softmax_label": jax.ShapeDtypeStruct(
-                 shp, jnp.float32, sharding=tr.batch_sharding(shp))}
-        tr._abstract_args = (
-            params, o, a, b,
-            jax.ShapeDtypeStruct(key.shape, key.dtype, sharding=repl),
-            jax.ShapeDtypeStruct((), jnp.float32, sharding=repl),
-            jax.ShapeDtypeStruct((), jnp.float32, sharding=repl),
-            jax.ShapeDtypeStruct((), jnp.int32, sharding=repl))
-        return tr._lower().compile()    # _lower engages _sp_scope
+    def compile_for(mesh, seq_axis):
+        return compile_step(sym, opt, mesh, batch, cfg["seq_len"], seq_axis)
 
     mesh1 = Mesh(np.array(devs[:1]), ("dp",))
-    compiled = compile_step(mesh1, seq_axis=None)
+    compiled = compile_for(mesh1, seq_axis=None)
     ca = compiled.cost_analysis() or {}
     out["transformer_tf_per_step"] = round(
         float(ca.get("flops") or 0) / 1e12, 3)
@@ -270,24 +337,10 @@ def main():
     out["transformer_mosaic_calls"] = compiled.as_text().count(MOSAIC)
     ok = ok and out["transformer_mosaic_calls"] >= cfg["num_layers"]
 
-    # ... and each call under its own layer's scope and pass: the kernels'
-    # calls are jitted, and XLA joins a call site's name with the callee's
-    # where it inlines (observability/device_scopes.py)
-    from mxnet_tpu.observability import device_scopes
-    nodes = device_scopes.graph_nodes(sym)
-
-    def kernel_scopes(text):
-        found = {}
-        for name, op_name in device_scopes.parse(text).items():
-            if name.startswith("flash_"):
-                phase, node, _sub = device_scopes.classify(op_name, nodes)
-                found.setdefault("%s %s" % (name.split(".")[0], phase),
-                                 set()).add(node)
-        return {k: len(v) for k, v in sorted(found.items())}
-
+    # ... and each call under its own layer's scope and pass
     want_scopes = {"flash_backward backward": cfg["num_layers"],
                    "flash_forward forward": cfg["num_layers"]}
-    out["transformer_kernel_scopes"] = kernel_scopes(compiled.as_text())
+    out["transformer_kernel_scopes"] = kernel_scopes(compiled.as_text(), sym)
     ok = ok and out["transformer_kernel_scopes"] == want_scopes
 
     if len(devs) >= 4:
@@ -295,16 +348,16 @@ def main():
         # kernel, so the step must carry it per device (under shard_map)
         # next to the gradient all-reduce — what refused to lower on the
         # four-chip host in PR 21
-        text = compile_step(Mesh(np.array(devs[:4]), ("dp",)),
-                            seq_axis=None).as_text()
+        text = compile_for(Mesh(np.array(devs[:4]), ("dp",)),
+                           seq_axis=None).as_text()
         out["dp4_mosaic_calls"] = text.count(MOSAIC)
         out["dp4_all_reduces"] = text.count("all-reduce")
-        out["dp4_kernel_scopes"] = kernel_scopes(text)
+        out["dp4_kernel_scopes"] = kernel_scopes(text, sym)
         ok = ok and out["dp4_mosaic_calls"] >= cfg["num_layers"] \
             and out["dp4_all_reduces"] > 0 \
             and out["dp4_kernel_scopes"] == want_scopes
         mesh4 = Mesh(np.array(devs[:4]).reshape(2, 2), ("dp", "sp"))
-        c4 = compile_step(mesh4, seq_axis=1)
+        c4 = compile_for(mesh4, seq_axis=1)
         out["ring_collective_permutes"] = c4.as_text().count(
             "collective-permute")
         ok = ok and out["ring_collective_permutes"] > 0
