@@ -55,6 +55,7 @@ FULL = {
     "kernels": dict(flash=(8, 8, 1024, 64), flash_latent=(1, 32, 8192, 192, 128),
                     flash_grouped=(1, 8, 2, 8192, 128),
                     flash_gqa256=(1, 16, 2, 8192, 256),
+                    flash_window=(1, 64, 8, 8192, 128, 512),
                     delta_rule=(1, 4096, 16, 32, 128, 128, 1024),
                     qmm=((8, 768, 3072), (256, 768, 3072), (8, 3072, 768),
                          (8, 768, 50257), (256, 768, 50257))),
@@ -71,6 +72,7 @@ TINY = {
     "kernels": dict(flash=(1, 2, 256, 8), flash_latent=(1, 2, 256, 24, 16),
                     flash_grouped=(1, 4, 2, 256, 16),
                     flash_gqa256=(1, 8, 1, 256, 32),
+                    flash_window=(1, 8, 1, 256, 16, 48),
                     delta_rule=(1, 128, 1, 2, 128, 128, 128),
                     qmm=((8, 256, 384), (300, 600, 1000))),
 }
@@ -492,7 +494,8 @@ def phase_kernels(env, cfg, lm_params):
     # 192 wide, v 128, 8,192 keys — and grouped queries' — 8 heads of 128
     # on 2 key/value heads, and 16 heads of 256 on 2 (the forward asks for
     # VMEM, the backward splits each group of eight over four programs),
-    # 8,192 keys — in bfloat16.  The reference goes
+    # 8,192 keys, and 64 heads of 128 on 8 under a sliding window of 512
+    # (the windowed kernels) — in bfloat16.  The reference goes
     # by query blocks, each against the keys up to its end, so that 8,192
     # keys of 32 heads fit beside the kernel's own buffers; it repeats
     # k and v over a group, which the kernels do not.
@@ -500,12 +503,18 @@ def phase_kernels(env, cfg, lm_params):
     lb, lh, ls, l_qk, l_v = cfg["flash_latent"]
     gb, gh, gkv, gs, gd = cfg["flash_grouped"]
     wb, wh, wkv, ws, wd = cfg["flash_gqa256"]
-    cases = [((b, h, h, s, d, d), jnp.float32, 2e-2, ""),
-             ((b, h, h, s, d, d), jnp.bfloat16, 4e-2, ""),
-             ((lb, lh, lh, ls, l_qk, l_v), jnp.bfloat16, 4e-2, ",latent"),
-             ((gb, gh, gkv, gs, gd, gd), jnp.bfloat16, 4e-2, ",grouped"),
-             ((wb, wh, wkv, ws, wd, wd), jnp.bfloat16, 4e-2, ",gqa256")]
-    for (b, h, h_kv, s, d_qk, d_v), dt, tol, tag in cases:
+    sb, sh, skv, ss, sd, window = cfg["flash_window"]
+    cases = [((b, h, h, s, d, d), jnp.float32, 2e-2, "", None),
+             ((b, h, h, s, d, d), jnp.bfloat16, 4e-2, "", None),
+             ((lb, lh, lh, ls, l_qk, l_v), jnp.bfloat16, 4e-2, ",latent",
+              None),
+             ((gb, gh, gkv, gs, gd, gd), jnp.bfloat16, 4e-2, ",grouped",
+              None),
+             ((wb, wh, wkv, ws, wd, wd), jnp.bfloat16, 4e-2, ",gqa256",
+              None),
+             ((sb, sh, skv, ss, sd, sd), jnp.bfloat16, 4e-2,
+              ",window%d" % window, window)]
+    for (b, h, h_kv, s, d_qk, d_v), dt, tol, tag, window in cases:
         q = put(jnp.asarray(rng.randn(b, h, s, d_qk), dt))
         k = put(jnp.asarray(rng.randn(b, h_kv, s, d_qk), dt))
         v = put(jnp.asarray(rng.randn(b, h_kv, s, d_v), dt))
@@ -514,15 +523,16 @@ def phase_kernels(env, cfg, lm_params):
             o = fn(q, k, v)
             return jnp.sum(jnp.sin(o.astype(jnp.float32))), o
 
-        def kernel(q, k, v):
+        def kernel(q, k, v, window=window):
             return flash_attention(q, k, v, causal=True,
-                                   interpret=interpret)
+                                   interpret=interpret, window=window)
 
-        def reference(q, k, v, step=min(1024, s)):
+        def reference(q, k, v, step=min(1024, s), window=window):
             q, k, v = (t.astype(jnp.float32) for t in (q, k, v))
             return jnp.concatenate([
                 jax.checkpoint(functools.partial(
-                    attention_reference, causal=True, q_offset=i))(
+                    attention_reference, causal=True, q_offset=i,
+                    window=window))(
                         q[..., i:i + step, :], k[..., :i + step, :],
                         v[..., :i + step, :])
                 for i in range(0, s, step)], axis=-2)
@@ -538,6 +548,12 @@ def phase_kernels(env, cfg, lm_params):
         require(max(errs) <= tol, "%s: out/dq/dk/dv errors %s exceed %g"
                 % (name, errs, tol))
         out[name] = round(max(errs), 5)
+        if window is not None and not interpret:
+            # placed on the chip, the windowed call must be the kernels
+            text = jax.jit(grad(kernel)).lower(q, k, v).compile().as_text()
+            require(text.count(MOSAIC) >= 2 and "flash_window" in text,
+                    "%s: the gradient program holds %d Mosaic calls, not "
+                    "the windowed kernels" % (name, text.count(MOSAIC)))
 
         # a mirrored block around the kernel, as the executor lowers one:
         # its gradient is the unmirrored block's, with the tanh recomputed

@@ -2,8 +2,8 @@
 MultiHeadLatentAttention (low-rank query and key/value paths, one rotary
 key shared by all heads), CompressedConvAttention (attention inside a
 compressed latent mixed by two causal convolutions, grouped query heads),
-GatedAttention (grouped query heads, RMSNorm on q and k, a partial rotary,
-a sigmoid gate on the output).
+GatedAttention (grouped query heads, RMSNorm on q and k, a partial rotary
+— YaRN-scaled or not —, a sigmoid gate on the output, a sliding window).
 
 TPU-native extensions beyond the reference op set (the reference predates
 transformers; SURVEY §5 notes its only long-sequence tools are bucketing
@@ -12,6 +12,8 @@ and pipeline LSTM).  These ops complete the symbolic surface needed by
 in ``parallel/ring_attention.py``.
 """
 from __future__ import annotations
+
+import math
 
 import numpy as _np
 
@@ -131,21 +133,53 @@ def rotary_interleaved(x, theta):
     return (x32 * cos + turned * sin).astype(x.dtype)
 
 
-def rotary_half(x, theta, rotary_dim=None):
+def yarn_inv_freq(rotary_dim, theta, factor, original_max_position,
+                  beta_fast=32.0, beta_slow=1.0):
+    """YaRN's inverse frequencies (arXiv:2309.00071; as the transformers
+    library's ``_compute_yarn_parameters`` builds them, with its default
+    truncation) for the ``rotary_dim / 2`` pairs of a rotary at ``theta``:
+    pair i turns at theta^(−2i/r) where it makes more than ``beta_fast``
+    turns over ``original_max_position`` positions, at that over
+    ``factor`` where it makes fewer than ``beta_slow``, and between the
+    two along a linear ramp over the pairs.  float64 numpy."""
+    r = int(rotary_dim)
+
+    def pair_of(turns):     # the (fractional) pair that makes ``turns``
+        return r * math.log(original_max_position / (turns * 2 * math.pi)) \
+            / (2 * math.log(theta))
+
+    low = max(math.floor(pair_of(beta_fast)), 0)
+    high = min(math.ceil(pair_of(beta_slow)), r - 1)
+    if low == high:
+        high += 0.001
+    extrapolation = 1.0 / theta ** (_np.arange(0, r, 2) / float(r))
+    ramp = _np.clip((_np.arange(r // 2) - low) / (high - low), 0.0, 1.0)
+    return extrapolation / factor * ramp + extrapolation * (1.0 - ramp)
+
+
+def rotary_half(x, theta, rotary_dim=None, inv_freq=None, scale=None):
     """Rotary position embedding of x (..., S, D) in the half-split
     layout, on the first ``rotary_dim`` channels (all of them by
     default): channel i < rotary_dim/2 of position p pairs with channel
     i + rotary_dim/2 and the pair is turned by the angle
-    p · theta^(−2i/rotary_dim); the channels from ``rotary_dim`` on pass
-    through.  float32 inside, x's dtype out."""
+    p · theta^(−2i/rotary_dim) — or p · ``inv_freq[i]`` where the
+    frequencies are given (:func:`yarn_inv_freq`) — and cos and sin are
+    multiplied by ``scale`` where one is given (YaRN's attention factor:
+    the rotated channels' scores grow by its square); the channels from
+    ``rotary_dim`` on pass through.  float32 inside, x's dtype out."""
     s, d = x.shape[-2], x.shape[-1]
     r = d if rotary_dim is None else int(rotary_dim)
     half = r // 2
     x32 = x.astype(jnp.float32)
-    inv_freq = 1.0 / (float(theta) ** (
-        jnp.arange(0, r, 2, dtype=jnp.float32) / r))
+    if inv_freq is None:
+        inv_freq = 1.0 / (float(theta) ** (
+            jnp.arange(0, r, 2, dtype=jnp.float32) / r))
+    else:
+        inv_freq = jnp.asarray(inv_freq, jnp.float32)
     ang = jnp.arange(s, dtype=jnp.float32)[:, None] * inv_freq
     cos, sin = jnp.cos(ang), jnp.sin(ang)
+    if scale is not None:
+        cos, sin = cos * scale, sin * scale
     a, b = x32[..., :half], x32[..., half:r]
     return jnp.concatenate([a * cos - b * sin, b * cos + a * sin,
                             x32[..., r:]], axis=-1).astype(x.dtype)
@@ -334,31 +368,52 @@ class _GatedAttentionParam(ParamStruct):
     partial_rotary_factor = Field(float, default=1.0,
                                   doc="share of a head that is rotated")
     eps = Field(float, default=1e-6, doc="of the q and k RMSNorms")
+    qk_norm = Field(bool, default=True,
+                    doc="RMSNorm on q and k, a gain vector each")
+    window = Field(int, default=0, lower=0,
+                   doc="sliding window: a query sees itself and the "
+                       "window - 1 keys before it (0: every key before it)")
+    rope_type = Field(str, default="default", enum=("default", "yarn"))
+    rope_factor = Field(float, default=1.0, doc="YaRN's scaling factor")
+    rope_original_max_position = Field(
+        int, default=0, lower=0, doc="YaRN's original context length")
+    rope_beta_fast = Field(float, default=32.0)
+    rope_beta_slow = Field(float, default=1.0)
+    rope_attention_factor = Field(
+        float, default=1.0, doc="YaRN's factor on cos and sin")
 
 
 @register_op("GatedAttention")
 class GatedAttention(OperatorProperty):
     """Softmax attention with grouped query heads, per-head RMSNorm on q
     and k, a partial rotary and a sigmoid output gate (the full-attention
-    layer of the ``qwen3_next`` family), data (B, S, E) -> (B, S, E).
+    layer of the ``qwen3_next`` family; with a sliding window, YaRN and
+    no q/k norm the two layer kinds of ``laguna``), data (B, S, E) ->
+    (B, S, E).
 
     H = ``num_heads`` query heads on H_kv = ``num_kv_heads`` key/value
     heads of d = ``head_dim``.  [q_h ‖ gate_h] = u W_qᵀ a head (E → H·2d);
     k, v = u W_kᵀ, u W_vᵀ (E → H_kv·d);  q and k through an RMSNorm over
     d with a gain vector each (``q_norm_gamma``, ``k_norm_gamma``: one for
-    all heads);  rotary on the first ``partial_rotary_factor``·d channels,
-    half-split pairing (:func:`rotary_half`);  causal
-    softmax(q kᵀ / √d) v, query head h on key/value head h // (H / H_kv)
-    (the flash path of ``parallel/ring_attention.py``, k and v of H_kv
-    heads, repeated nowhere);  y = (o ⊙ sigmoid(gate)) W_oᵀ (H·d → E): the
-    gate is elementwise, outside the kernel.  No biases; weights are
+    all heads; none where ``qk_norm`` is off);  rotary on the first
+    ``partial_rotary_factor``·d channels, half-split pairing
+    (:func:`rotary_half`) — with ``rope_type="yarn"`` at YaRN's
+    frequencies (:func:`yarn_inv_freq`) and cos and sin times
+    ``rope_attention_factor``;  causal softmax(q kᵀ / √d) v, query head
+    h on key/value head h // (H / H_kv), and with ``window`` W query i
+    sees keys i − W + 1 … i only (the flash path of
+    ``parallel/ring_attention.py``, k and v of H_kv heads, repeated
+    nowhere);  y = (o ⊙ sigmoid(gate)) W_oᵀ (H·d → E): the gate is
+    elementwise, outside the kernel.  No biases; weights are
     (out_features, in_features)."""
     param_cls = _GatedAttentionParam
     mxu = True
 
     def list_arguments(self):
-        return ["data", "q_weight", "k_weight", "v_weight", "q_norm_gamma",
-                "k_norm_gamma", "out_weight"]
+        norms = ["q_norm_gamma", "k_norm_gamma"] if self.param.qk_norm \
+            else []
+        return ["data", "q_weight", "k_weight", "v_weight"] + norms \
+            + ["out_weight"]
 
     def infer_shape(self, in_shapes):
         data = in_shapes[0]
@@ -373,16 +428,21 @@ class GatedAttention(OperatorProperty):
                              "over %d key/value heads" % (H, K))
         if int(round(p.partial_rotary_factor * d)) % 2:
             raise MXNetError("the rotated part of a head must be even")
-        return ([data, (2 * H * d, E), (K * d, E), (K * d, E), (d,), (d,),
-                 (E, H * d)], [data], [])
+        if p.rope_type == "yarn" and not p.rope_original_max_position:
+            raise MXNetError("GatedAttention: YaRN needs "
+                             "rope_original_max_position")
+        norms = [(d,), (d,)] if p.qk_norm else []
+        return ([data, (2 * H * d, E), (K * d, E), (K * d, E)] + norms
+                + [(E, H * d)], [data], [])
 
     def cost_mxu_dims(self, in_shapes, out_shapes):
         B, S, E = in_shapes[0]
         p = self.param
         H, K, d = p.num_heads, p.num_kv_heads, p.head_dim
         T = B * S
+        keys = min(S, p.window) if p.window else S
         return [(T, E, 2 * H * d), (T, E, K * d), (T, E, K * d),
-                (T, H * d, E), (S, d, S), (S, S, d)]
+                (T, H * d, E), (S, d, keys), (S, keys, d)]
 
     def cost_flops(self, in_shapes, out_shapes):
         B = in_shapes[0][0]
@@ -395,10 +455,23 @@ class GatedAttention(OperatorProperty):
     def cost_reduce_len(self, in_shapes, out_shapes):
         return int(in_shapes[0][1])     # softmax over the key axis
 
-    def forward(self, inputs, aux, is_train, rng):
-        x, wq, wk, wv, gq, gk, wo = inputs
-        B, S, _E = x.shape
+    def _rotary(self):
+        """(inv_freq, scale) for :func:`rotary_half`: None, None but for
+        YaRN."""
         p = self.param
+        if p.rope_type != "yarn":
+            return None, None
+        rot = int(round(p.partial_rotary_factor * p.head_dim))
+        return (yarn_inv_freq(rot, p.rope_theta, p.rope_factor,
+                              p.rope_original_max_position,
+                              p.rope_beta_fast, p.rope_beta_slow),
+                p.rope_attention_factor)
+
+    def forward(self, inputs, aux, is_train, rng):
+        p = self.param
+        x, wq, wk, wv = inputs[:4]
+        wo = inputs[-1]
+        B, S, _E = x.shape
         H, K, d = p.num_heads, p.num_kv_heads, p.head_dim
         with jax.named_scope(PROJ_IN):
             qg = (x @ wq.T).reshape(B, S, H, 2 * d)
@@ -406,16 +479,21 @@ class GatedAttention(OperatorProperty):
             k = (x @ wk.T).reshape(B, S, K, d)
             v = (x @ wv.T).reshape(B, S, K, d)
         rot = int(round(p.partial_rotary_factor * d))
+        inv_freq, scale = self._rotary()
 
         def heads(t, gamma):    # normalised, (B, heads, S, d), rotated
-            return rotary_half(rms_norm(t, gamma, p.eps)
-                               .transpose(0, 2, 1, 3), p.rope_theta, rot)
+            if p.qk_norm:
+                t = rms_norm(t, gamma, p.eps)
+            return rotary_half(t.transpose(0, 2, 1, 3), p.rope_theta, rot,
+                               inv_freq, scale)
 
+        gq, gk = inputs[4:6] if p.qk_norm else (None, None)
         with jax.named_scope(ROTARY_NORM):
             q, k, v = heads(q, gq), heads(k, gk), v.transpose(0, 2, 1, 3)
         from ..parallel.ring_attention import sharded_self_attention
         with jax.named_scope(KERNEL):
-            o = sharded_self_attention(q, k, v, causal=True)
+            o = sharded_self_attention(q, k, v, causal=True,
+                                       window=p.window or None)
         with jax.named_scope(PROJ_OUT):
             o = o.transpose(0, 2, 1, 3).astype(jnp.float32) \
                 * jax.nn.sigmoid(gate.astype(jnp.float32))

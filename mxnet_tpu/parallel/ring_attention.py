@@ -17,7 +17,10 @@ for long sequences:
   softmax state, the saved logsumexp and the gradients' accumulators in
   float32.  Under ``causal`` a query block meets the key blocks up to
   the diagonal and no further, in both directions: masked blocks are
-  skipped, not computed.
+  skipped, not computed.  Under a sliding ``window`` W as well (query i
+  sees keys i − W + 1 … i) a query block meets only the key blocks of
+  its band, and the calls are named ``flash_window_forward`` /
+  ``flash_window_backward``.
 - ``ring_attention``: blockwise attention over a ``Mesh`` axis ("sp"):
   each device holds a sequence chunk of q/k/v; k/v chunks rotate around
   the ring via ``lax.ppermute`` while the online-softmax state (o, m, l)
@@ -62,15 +65,24 @@ _NEG_INF = -1e30
 _LSE_ROWS = 8
 
 
+def _visible(qpos, kpos, window):
+    """Which keys a query sees under the causal mask, and with a sliding
+    ``window`` W only the W up to and including itself."""
+    if window is None:
+        return qpos >= kpos
+    return (qpos >= kpos) & (qpos - kpos < window)
+
+
 def attention_reference(q, k, v, causal=False, scale=None,
-                        q_offset=0, kv_offset=0):
+                        q_offset=0, kv_offset=0, window=None):
     """Plain softmax attention; q (..., Sq, D), k/v (..., Sk, D).  Where
     q is (B, H, Sq, D) and k/v have fewer heads (grouped queries: H a
     multiple of theirs), query head h attends key/value head
     h // (H / H_kv): k and v are repeated, here and nowhere else.
 
     ``q_offset``/``kv_offset`` are the global positions of element 0 (used
-    for causal masking of sequence chunks).
+    for causal masking of sequence chunks); ``window`` (with ``causal``)
+    lets query i see keys i − window + 1 … i only.
     """
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[-1])
@@ -81,7 +93,7 @@ def attention_reference(q, k, v, causal=False, scale=None,
     if causal:
         qpos = jnp.arange(q.shape[-2])[:, None] + q_offset
         kpos = jnp.arange(k.shape[-2])[None, :] + kv_offset
-        s = jnp.where(qpos >= kpos, s, _NEG_INF)
+        s = jnp.where(_visible(qpos, kpos, window), s, _NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
     return jnp.einsum("...qk,...kd->...qd", p, v.astype(p.dtype)) \
         .astype(q.dtype)
@@ -98,13 +110,14 @@ def _head_group(q, k):
     return q.shape[1] // k.shape[1]
 
 
-def _block_step(q, k, v, scale, causal, q_offset, kv_offset, m, l, o):
+def _block_step(q, k, v, scale, causal, q_offset, kv_offset, m, l, o,
+                window=None):
     """One online-softmax accumulation step (see module docstring)."""
     s = jnp.einsum("...qd,...kd->...qk", q, k).astype(jnp.float32) * scale
     if causal:
         qpos = jnp.arange(q.shape[-2])[:, None] + q_offset
         kpos = jnp.arange(k.shape[-2])[None, :] + kv_offset
-        s = jnp.where(qpos >= kpos, s, _NEG_INF)
+        s = jnp.where(_visible(qpos, kpos, window), s, _NEG_INF)
     m_new = jnp.maximum(m, jnp.max(s, axis=-1))
     p = jnp.exp(s - m_new[..., None])
     c = jnp.exp(m - m_new)
@@ -154,6 +167,28 @@ def _causal_k_blocks(q_block, block_q, block_k, n_k_blocks):
     return unmasked, visited
 
 
+def _window_k_blocks(q_block, block_q, block_k, n_k_blocks, window):
+    """:func:`_causal_k_blocks` under a sliding ``window`` W, where row r
+    sees keys r − W + 1 … r: ``(first, lo, hi, visited)`` — key blocks
+    ``[first, lo)`` are crossed by the band's lower edge (masked),
+    ``[lo, hi)`` lie wholly inside the band of every row, ``[hi,
+    visited)`` are crossed by the diagonal (masked; with a band narrower
+    than a block, by both edges), and no other block holds a visible key.
+    Python ints in, ints out; the kernel hands it ``pl.program_id``."""
+    first_row = q_block * block_q
+    last_row = first_row + block_q - 1
+    traced = not isinstance(q_block, int)
+    clamp_hi = jnp.minimum if traced else min
+    clamp_lo = jnp.maximum if traced else max
+    unmasked, visited = _causal_k_blocks(q_block, block_q, block_k,
+                                         n_k_blocks)
+    first = clamp_lo(first_row - window + 1, 0) // block_k
+    inside = clamp_lo(last_row - window + block_k, 0) // block_k
+    lo = clamp_hi(clamp_lo(inside, first), visited)
+    hi = clamp_lo(clamp_hi(unmasked, visited), lo)
+    return first, lo, hi, visited
+
+
 def _scale_folds_into(scale, dtype):
     """Whether ``q * scale`` in ``dtype`` loses nothing the scores would
     keep: float32 rounds it where the product rounds anyway; a narrower
@@ -170,12 +205,28 @@ def _mask_past_diagonal(s, key_lead):
     return jnp.where(qpos - kpos >= key_lead, s, _NEG_INF)
 
 
+def _mask(s, key_lead, window):
+    """:func:`_mask_past_diagonal`, and under a sliding ``window`` also
+    every key ``window`` or more positions before its query."""
+    if window is None:
+        return _mask_past_diagonal(s, key_lead)
+    kpos = lax.broadcasted_iota(jnp.int32, s.shape, 0)
+    qpos = lax.broadcasted_iota(jnp.int32, s.shape, 1)
+    lead = qpos - kpos - key_lead       # query's position less the key's
+    return jnp.where((lead >= 0) & (lead < window), s, _NEG_INF)
+
+
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_k, causal,
-                  scale, seq_k):
+                  scale, seq_k, window=None):
     """Grid: (batch*heads, q_blocks).  One q block against the key
     blocks it can see: all of them, or under ``causal`` those up to the
     diagonal (``_causal_k_blocks``), of which only the ones the diagonal
-    crosses are masked.
+    crosses are masked; under a ``window`` those of its band
+    (``_window_k_blocks``), of which only the ones either edge crosses
+    are masked.  A row that sees no key of a masked block takes
+    exp(0) there while its maximum is still the initial −1e30; the
+    first block holding a key it sees multiplies that by
+    exp(−1e30 − m) = 0, and every row sees itself.
 
     Both products take q, k, v as they come (bfloat16 operands are not
     widened) and sum in float32; p is rounded to v's dtype for p·v; the
@@ -209,7 +260,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_k, causal,
         if not fold:
             s = s * scale
         if masked:
-            s = _mask_past_diagonal(s, start - q_offset)
+            s = _mask(s, start - q_offset, window)
         m_new = jnp.maximum(m, jnp.max(s, axis=0, keepdims=True))
         p = jnp.exp(s - m_new)
         c = jnp.exp(m - m_new)
@@ -222,7 +273,14 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_k, causal,
     carry = (jnp.full((1, block_q), _NEG_INF, jnp.float32),
              jnp.zeros((1, block_q), jnp.float32),
              jnp.zeros((d_v, block_q), jnp.float32))
-    if causal:
+    if window is not None:
+        first, lo, hi, visited = _window_k_blocks(
+            pl.program_id(1), block_q, block_k, n_k_blocks, window)
+        for start, stop, masked in ((first, lo, True), (lo, hi, False),
+                                    (hi, visited, True)):
+            carry = lax.fori_loop(start, stop,
+                                  functools.partial(step, masked), carry)
+    elif causal:
         unmasked, visited = _causal_k_blocks(pl.program_id(1), block_q,
                                              block_k, n_k_blocks)
         carry = lax.fori_loop(0, unmasked, functools.partial(step, False),
@@ -296,10 +354,17 @@ def _flash_block_layout(bh, sq, sk, d, block_q, d_v=None, group=1):
     return in_blocks, out_blocks
 
 
+def _kernel_name(direction, window):
+    """``flash_forward`` / ``flash_backward``, and ``flash_window_*`` for
+    a call under a sliding window: the trace tells the two apart."""
+    return "flash_%s%s" % ("window_" if window is not None else "",
+                           direction)
+
+
 @functools.partial(jax.jit, static_argnames=(
-    "causal", "scale", "block_q", "block_k", "interpret"))
+    "causal", "scale", "block_q", "block_k", "interpret", "window"))
 def _flash_forward_kernel_call(q, k, v, causal, scale, block_q, block_k,
-                               interpret):
+                               interpret, window=None):
     """(o, lse) of the forward kernel.  Jitted so that a model's layers,
     which call it with the same shapes, trace and lower the kernel once
     and not once a layer (24 layers of GPT-2-medium: 4–5 s of set-up).
@@ -321,7 +386,8 @@ def _flash_forward_kernel_call(q, k, v, causal, scale, block_q, block_k,
     (qb, kb, vb), (ob, lseb) = _flash_block_layout(B * H, Sq, sk, D,
                                                    block_q, d_v, group)
     kernel = functools.partial(_flash_kernel, block_k=block_k,
-                               causal=causal, scale=scale, seq_k=sk)
+                               causal=causal, scale=scale, seq_k=sk,
+                               window=window)
     if group == 1:
         kv_head = lambda b, i: (b, 0, 0)                # noqa: E731
     else:
@@ -354,7 +420,7 @@ def _flash_forward_kernel_call(q, k, v, causal, scale, block_q, block_k,
             jax.ShapeDtypeStruct(ob[1], q.dtype),
             jax.ShapeDtypeStruct(lseb[1], jnp.float32),
         ],
-        name="flash_forward",
+        name=_kernel_name("forward", window),
         interpret=interpret,
         **params,
     )(q3, k3, v3)
@@ -375,14 +441,36 @@ def _causal_q_blocks(k_block, block_q, block_k, n_q_blocks):
     return visited, unmasked
 
 
+def _window_q_blocks(k_block, block_q, block_k, n_q_blocks, window):
+    """The mirror of :func:`_window_k_blocks` for key block ``k_block``,
+    whose key c is seen by rows c … c + W − 1: ``(visited, lo, hi,
+    end)`` — query blocks ``[visited, lo)`` are crossed by the diagonal,
+    ``[lo, hi)`` see every key of the block, ``[hi, end)`` are crossed
+    by the band's lower edge (both masked), and the blocks after the one
+    that holds the block's last key + W − 1 never see it."""
+    first_key = k_block * block_k
+    traced = not isinstance(k_block, int)
+    clamp_hi = jnp.minimum if traced else min
+    clamp_lo = jnp.maximum if traced else max
+    visited, unmasked = _causal_q_blocks(k_block, block_q, block_k,
+                                         n_q_blocks)
+    end = clamp_hi((first_key + block_k + window - 2) // block_q + 1,
+                   n_q_blocks)
+    lo = clamp_hi(unmasked, end)
+    hi = clamp_lo(clamp_hi((first_key + window) // block_q, end), lo)
+    return visited, lo, hi, end
+
+
 def _flash_backward_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                            dq_ref, dk_ref, dv_ref, dq_acc, *, block_q,
-                           causal, scale, group):
+                           causal, scale, group, window=None):
     """Grid: (batch*key/value heads, k_blocks), the key blocks in order.
     One key block against the query blocks that can see it: all of them,
     or under ``causal`` those from the diagonal down
     (``_causal_q_blocks``), of which only the ones the diagonal crosses
-    are masked.  With p = exp(s·scale − lse) recomputed from the saved
+    are masked; under a ``window`` those of its band
+    (``_window_q_blocks``), masked where either edge crosses them.  With
+    p = exp(s·scale − lse) recomputed from the saved
     logsumexp and delta = rowsum(do ⊙ o):
 
         dv_j = pᵀ·do      ds = p ⊙ (do·vᵀ − delta)
@@ -430,7 +518,7 @@ def _flash_backward_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         if not fold:
             s = s * scale
         if masked:
-            s = _mask_past_diagonal(s, k_offset - i * block_q)
+            s = _mask(s, k_offset - i * block_q, window)
         p = jnp.exp(s - lse_ref[row])
         dv = dv + jnp.dot(p.astype(do.dtype), do,
                           preferred_element_type=jnp.float32)
@@ -446,7 +534,15 @@ def _flash_backward_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
              jnp.zeros(v_ref.shape, jnp.float32))
     for head in range(group):
         first = head * n_q_blocks
-        if causal:
+        if window is not None:
+            visited, lo, hi, end = _window_q_blocks(j, block_q, block_k,
+                                                    n_q_blocks, window)
+            for start, stop, masked in ((visited, lo, True),
+                                        (lo, hi, False), (hi, end, True)):
+                carry = lax.fori_loop(
+                    start, stop, functools.partial(step, masked, first),
+                    carry)
+        elif causal:
             visited, unmasked = _causal_q_blocks(j, block_q, block_k,
                                                  n_q_blocks)
             carry = lax.fori_loop(
@@ -535,9 +631,9 @@ def _flash_backward_split(bh, sq, sk, d, block_q, block_k, d_v, group,
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "causal", "scale", "block_q", "block_k", "interpret"))
+    "causal", "scale", "block_q", "block_k", "interpret", "window"))
 def _flash_backward_kernel_call(q, k, v, o, lse, do, causal, scale,
-                                block_q, block_k, interpret):
+                                block_q, block_k, interpret, window=None):
     """(dq, dk, dv) of the backward kernel from the forward's residuals.
     Jitted for the reason the forward call is: a model's layers lower it
     once."""
@@ -561,7 +657,7 @@ def _flash_backward_kernel_call(q, k, v, o, lse, do, causal, scale,
                 delta.reshape(programs, rows // block_q, 1, block_q))
     kernel = functools.partial(_flash_backward_kernel, block_q=block_q,
                                causal=causal, scale=scale,
-                               group=group // split)
+                               group=group // split, window=window)
     whole = lambda b, j: (b, 0, 0)          # noqa: E731
     by_key = lambda b, j: (b, j, 0)         # noqa: E731
     rows = lambda b, j: (b, 0, 0, 0)        # noqa: E731
@@ -583,7 +679,7 @@ def _flash_backward_kernel_call(q, k, v, o, lse, do, causal, scale,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
             vmem_limit_bytes=int(vmem)),
-        name="flash_backward",
+        name=_kernel_name("backward", window),
         interpret=interpret,
     )(*operands)
     if split > 1:       # the programs' float32 partial sums, rounded once
@@ -593,7 +689,7 @@ def _flash_backward_kernel_call(q, k, v, o, lse, do, causal, scale,
 
 
 def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
-                    block_k=None, interpret=None):
+                    block_k=None, interpret=None, window=None):
     """Fused attention; q (B, H, S, D), k (B, H_kv, S, D), v (B, H_kv, S,
     D_v) with D_v = D or not (the output is as wide as v) and H_kv = H or
     a divisor of it (grouped queries: head h attends key/value head
@@ -628,6 +724,15 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
     under ``causal`` a key block meets only the query blocks from the
     diagonal down.  It takes every shape the forward kernel takes.
 
+    ``window`` W (with ``causal``): query i sees keys i − W + 1 … i, the
+    sliding-window causal mask (W keys with itself).  Each query block
+    then reads only the key blocks of its band, from the first that meets
+    the band to the diagonal, and each key block meets only the query
+    blocks up to the one that holds its last key + W − 1; the blocks that
+    either edge crosses are masked, the rest are not.  Both calls are
+    named ``flash_window_forward`` / ``flash_window_backward``, and the
+    forward's residuals carry the same ``FLASH_RESIDUALS`` names.
+
     ``block_q``/``block_k`` default to the largest of 512/256/128 that
     divides the sequence (``_flash_blocks``), for both kernels.
     Sequence lengths must be multiples of the block sizes for the kernel
@@ -636,9 +741,13 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
     """
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[-1])
+    if window is not None and (not causal or window < 1):
+        raise ValueError("a sliding window of %r needs causal attention and "
+                         "one key or more" % (window,))
 
     def reference(q, k, v):
-        return attention_reference(q, k, v, causal=causal, scale=scale)
+        return attention_reference(q, k, v, causal=causal, scale=scale,
+                                   window=window)
 
     blocks = _flash_blocks(q.shape[-2], k.shape[-2], block_q, block_k)
     if blocks is None:                 # hard kernel constraint
@@ -649,12 +758,14 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
         @jax.custom_vjp
         def _fa(q, k, v):
             out, _ = _flash_forward_kernel_call(
-                q, k, v, causal, scale, block_q, block_k, interpret)
+                q, k, v, causal, scale, block_q, block_k, interpret,
+                window=window)
             return out
 
         def _fa_fwd(q, k, v):
             out, lse = _flash_forward_kernel_call(
-                q, k, v, causal, scale, block_q, block_k, interpret)
+                q, k, v, causal, scale, block_q, block_k, interpret,
+                window=window)
             # a name is the identity: outside a checkpoint it lowers to
             # nothing
             res = tuple(checkpoint_name(x, name) for x, name in zip(
@@ -665,7 +776,7 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
             q, k, v, out, lse = res
             return _flash_backward_kernel_call(
                 q, k, v, out, lse, ct, causal, scale, block_q, block_k,
-                interpret)
+                interpret, window=window)
 
         _fa.defvjp(_fa_fwd, _fa_bwd)
         return _fa(q, k, v)
@@ -679,8 +790,10 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
 # ----------------------------------------------------------------------
 # Ring attention over a mesh axis
 # ----------------------------------------------------------------------
-def ring_attention(q, k, v, axis_name="sp", causal=False, scale=None):
-    """Sequence-parallel attention inside shard_map.
+def ring_attention(q, k, v, axis_name="sp", causal=False, scale=None,
+                   window=None):
+    """Sequence-parallel attention inside shard_map (``window``: the
+    sliding-window causal mask of :func:`attention_reference`).
 
     Every device holds the (B, H, S/n, D) chunk of q, k, v for its slice
     of the sequence (chunks in ring order = sequence order).  k/v rotate
@@ -713,7 +826,7 @@ def ring_attention(q, k, v, axis_name="sp", causal=False, scale=None):
         kv_offset = src * chunk
         if causal:
             m, l, o = _block_step(q, k, v, scale, True, q_offset,
-                                  kv_offset, m, l, o)
+                                  kv_offset, m, l, o, window)
         else:
             m, l, o = _block_step(q, k, v, scale, False, 0, 0, m, l, o)
         k = lax.ppermute(k, axis_name, perm)
@@ -803,13 +916,14 @@ def mesh_axis_that_splits(mesh, axis, dim):
     return axis if size > 1 and dim % size == 0 else None
 
 
-def sharded_self_attention(q, k, v, causal=False):
+def sharded_self_attention(q, k, v, causal=False, window=None):
     """Attention dispatch for (B, H, S, D): flash/reference on one
     device; under a :func:`sequence_parallel` mesh context, ring
-    attention over the sequence axis, or per-device flash."""
+    attention over the sequence axis, or per-device flash.  ``window``
+    (with ``causal``): the sliding-window mask, on every path."""
     ctx = current_sequence_parallel()
     if ctx is None:
-        return flash_attention(q, k, v, causal=causal)
+        return flash_attention(q, k, v, causal=causal, window=window)
     from jax import shard_map
     from jax.sharding import PartitionSpec as P
     mesh = ctx.mesh
@@ -823,7 +937,7 @@ def sharded_self_attention(q, k, v, causal=False):
 
         def att(q, k, v):
             return ring_attention(q, k, v, axis_name=ctx.seq_axis,
-                                  causal=causal)
+                                  causal=causal, window=window)
     else:
         # "Mosaic kernels cannot be automatically partitioned. Please
         # wrap the call in a shard_map" (the four-chip host, PR 21): the
@@ -836,7 +950,7 @@ def sharded_self_attention(q, k, v, causal=False):
         check_vma = False       # pallas_call outputs declare no vma
 
         def att(q, k, v):
-            return flash_attention(q, k, v, causal=causal)
+            return flash_attention(q, k, v, causal=causal, window=window)
 
     return shard_map(att, mesh=mesh, in_specs=(spec,) * 3, out_specs=spec,
                      check_vma=check_vma)(q, k, v)

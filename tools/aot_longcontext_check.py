@@ -101,6 +101,18 @@ def kernel_cases():
                                                  jnp.bfloat16)
     cases.append(("flash_fwd_bwd[bfloat16,gqa256]",
                   jax.grad(flash_loss, argnums=(0, 1, 2)), (q, kv, kv)))
+    # 64 heads of 128 on 8 under a sliding window of 512: the windowed
+    # kernels, the backward's group of eight over two programs
+    b, h, h_kv, s, d, window = widths["flash_window"]
+    q, kv = sds((b, h, s, d), jnp.bfloat16), sds((b, h_kv, s, d),
+                                                 jnp.bfloat16)
+
+    def window_loss(q, k, v):
+        out = flash_attention(q, k, v, causal=True, window=window)
+        return jnp.sum(out.astype(jnp.float32))
+
+    cases.append(("flash_fwd_bwd[bfloat16,window%d]" % window,
+                  jax.grad(window_loss, argnums=(0, 1, 2)), (q, kv, kv)))
     # the gated delta rule at the timed shape: 8,192 tokens, 16 key heads on
     # 32 value heads of 128 — the forward that writes the chunk states and
     # the backward, chosen by ``dispatch`` because the call is lowered for a
