@@ -19,10 +19,11 @@ reads its own kind from per-layer lists:
   weights normalised over the chosen and times ``routed_scaling_factor``,
   plus an ungated shared expert; the layer computes the part of the result
   that the ``n_local_experts`` it holds give (``first_expert`` onward; all
-  of them by default).
+  of them by default), walking the assignments on them in chunks of twice
+  what a balanced router sends them from a sequence.
 
-q and k are not normalised; the attention output passes a sigmoid gate a
-channel (``GatedAttention``).  A final RMSNorm and an untied head give the
+q and k are not normalised; each head's attention output passes one sigmoid
+gate, a column of the query projection (``GatedAttention``'s headwise gate).  A final RMSNorm and an untied head give the
 next-token distribution.
 
 Inputs: ``data`` (B, S) token ids, ``softmax_label`` (B, S) the next tokens.
@@ -51,7 +52,7 @@ def attention_args(kind, heads, num_kv_heads, head_dim, sliding_window,
                    rope):
     """``GatedAttention``'s keyword arguments for one layer."""
     args = dict(num_heads=heads, num_kv_heads=num_kv_heads,
-                head_dim=head_dim, qk_norm=False,
+                head_dim=head_dim, qk_norm=False, gate="headwise",
                 window=sliding_window if kind == "sliding_attention" else 0)
     for key, field in _ROPE_FIELDS:
         if key in rope:
@@ -76,6 +77,15 @@ def decoder_block(x, name, seq_len, dim, attention, eps, dense_width=0,
         f = sym.RoutedExperts(data=h, name="%s_moe" % name, **routed)
     return x + sym.Reshape(data=f, shape=(-1, seq_len, dim),
                            name="%s_ffn_out" % name)
+
+
+def chunk_rows(seq_len, top_k, held, num_experts):
+    """Rows of a routed layer's sorted assignments computed at a time:
+    twice what a balanced router sends the ``held`` experts from one
+    sequence, so that a step walks one chunk.  At 32 of 256 experts, 8 a
+    token, the shared default (``ops.moe.CHUNK_ROWS``) is what a balanced
+    router sends, and a step walked one chunk or two as the tokens fell."""
+    return max(1, 2 * seq_len * top_k * held // num_experts)
 
 
 def routed_layer_names(mlp_layer_types):
@@ -113,7 +123,10 @@ def get_symbol(vocab_size=32000, seq_len=512, dim=256,
                   top_k=num_experts_per_tok, score_func="sigmoid",
                   norm_topk_prob=True,
                   shared_hidden_size=shared_expert_intermediate_size,
-                  routed_scaling_factor=routed_scaling_factor)
+                  routed_scaling_factor=routed_scaling_factor,
+                  chunk_rows=chunk_rows(seq_len, num_experts_per_tok,
+                                        n_local_experts or num_experts,
+                                        num_experts))
     rope_parameters = rope_parameters or {}
 
     data = sym.Variable("data")

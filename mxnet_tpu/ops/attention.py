@@ -370,6 +370,9 @@ class _GatedAttentionParam(ParamStruct):
     eps = Field(float, default=1e-6, doc="of the q and k RMSNorms")
     qk_norm = Field(bool, default=True,
                     doc="RMSNorm on q and k, a gain vector each")
+    gate = Field(str, default="elementwise",
+                 enum=("elementwise", "headwise"),
+                 doc="a gate a channel of a head, or one a head")
     window = Field(int, default=0, lower=0,
                    doc="sliding window: a query sees itself and the "
                        "window - 1 keys before it (0: every key before it)")
@@ -387,12 +390,13 @@ class _GatedAttentionParam(ParamStruct):
 class GatedAttention(OperatorProperty):
     """Softmax attention with grouped query heads, per-head RMSNorm on q
     and k, a partial rotary and a sigmoid output gate (the full-attention
-    layer of the ``qwen3_next`` family; with a sliding window, YaRN and
-    no q/k norm the two layer kinds of ``laguna``), data (B, S, E) ->
-    (B, S, E).
+    layer of the ``qwen3_next`` family; with a sliding window, YaRN, a
+    gate a head and no q/k norm the two layer kinds of ``laguna``), data
+    (B, S, E) -> (B, S, E).
 
     H = ``num_heads`` query heads on H_kv = ``num_kv_heads`` key/value
-    heads of d = ``head_dim``.  [q_h ‖ gate_h] = u W_qᵀ a head (E → H·2d);
+    heads of d = ``head_dim``.  [q_h ‖ gate_h] = u W_qᵀ a head (E → H·2d;
+    with ``gate="headwise"`` gate_h is one channel, E → H·(d + 1));
     k, v = u W_kᵀ, u W_vᵀ (E → H_kv·d);  q and k through an RMSNorm over
     d with a gain vector each (``q_norm_gamma``, ``k_norm_gamma``: one for
     all heads; none where ``qk_norm`` is off);  rotary on the first
@@ -403,9 +407,9 @@ class GatedAttention(OperatorProperty):
     h on key/value head h // (H / H_kv), and with ``window`` W query i
     sees keys i − W + 1 … i only (the flash path of
     ``parallel/ring_attention.py``, k and v of H_kv heads, repeated
-    nowhere);  y = (o ⊙ sigmoid(gate)) W_oᵀ (H·d → E): the gate is
-    elementwise, outside the kernel.  No biases; weights are
-    (out_features, in_features)."""
+    nowhere);  y = (o ⊙ sigmoid(gate)) W_oᵀ (H·d → E): the gate, a
+    channel's or a head's, is applied outside the kernel.  No biases;
+    weights are (out_features, in_features)."""
     param_cls = _GatedAttentionParam
     mxu = True
 
@@ -432,8 +436,8 @@ class GatedAttention(OperatorProperty):
             raise MXNetError("GatedAttention: YaRN needs "
                              "rope_original_max_position")
         norms = [(d,), (d,)] if p.qk_norm else []
-        return ([data, (2 * H * d, E), (K * d, E), (K * d, E)] + norms
-                + [(E, H * d)], [data], [])
+        return ([data, (H * self._q_width(), E), (K * d, E), (K * d, E)]
+                + norms + [(E, H * d)], [data], [])
 
     def cost_mxu_dims(self, in_shapes, out_shapes):
         B, S, E = in_shapes[0]
@@ -441,7 +445,7 @@ class GatedAttention(OperatorProperty):
         H, K, d = p.num_heads, p.num_kv_heads, p.head_dim
         T = B * S
         keys = min(S, p.window) if p.window else S
-        return [(T, E, 2 * H * d), (T, E, K * d), (T, E, K * d),
+        return [(T, E, H * self._q_width()), (T, E, K * d), (T, E, K * d),
                 (T, H * d, E), (S, d, keys), (S, keys, d)]
 
     def cost_flops(self, in_shapes, out_shapes):
@@ -454,6 +458,11 @@ class GatedAttention(OperatorProperty):
 
     def cost_reduce_len(self, in_shapes, out_shapes):
         return int(in_shapes[0][1])     # softmax over the key axis
+
+    def _q_width(self):
+        """A head's columns of ``q_weight``: its query and its gate."""
+        d = self.param.head_dim
+        return 2 * d if self.param.gate == "elementwise" else d + 1
 
     def _rotary(self):
         """(inv_freq, scale) for :func:`rotary_half`: None, None but for
@@ -474,7 +483,7 @@ class GatedAttention(OperatorProperty):
         B, S, _E = x.shape
         H, K, d = p.num_heads, p.num_kv_heads, p.head_dim
         with jax.named_scope(PROJ_IN):
-            qg = (x @ wq.T).reshape(B, S, H, 2 * d)
+            qg = (x @ wq.T).reshape(B, S, H, self._q_width())
             q, gate = qg[..., :d], qg[..., d:]
             k = (x @ wk.T).reshape(B, S, K, d)
             v = (x @ wv.T).reshape(B, S, K, d)

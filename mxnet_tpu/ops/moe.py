@@ -491,6 +491,10 @@ class _RoutedExpertsParam(ParamStruct):
         bool, default=True,
         doc="divide the chosen scores by their sum (False: a chosen "
             "score is the weight as it stands)")
+    chunk_rows = Field(
+        int, default=0, lower=0,
+        doc="rows of the sorted assignment list computed at a time "
+            "(0: CHUNK_ROWS)")
 
 
 #: the counters a RoutedExperts node keeps as auxiliary state, summed on
@@ -593,7 +597,8 @@ class RoutedExperts(OperatorProperty):
             idx, w = route_topk(scores, aux[0], p.top_k,
                                 p.routed_scaling_factor, p.norm_topk_prob)
         y, counts = routed_experts(h, w, idx, w_gate, w_up, w_down,
-                                   p.first_expert, CHUNK_ROWS)
+                                   p.first_expert,
+                                   p.chunk_rows or CHUNK_ROWS)
         if p.shared_hidden_size:
             with jax.named_scope(SHARED):
                 shared = gated_ffn(h, *inputs[5:8])

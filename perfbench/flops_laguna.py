@@ -33,14 +33,14 @@ def band_pairs(seq, window=None):
 
 def attention_macs(cfg, heads, seq, window=None):
     """Multiply-adds a token of one layer's gated attention: q with its
-    gate (twice the heads' width), k, v and out, and q·kᵀ and p·v over the
+    gate (a column a head), k, v and out, and q·kᵀ and p·v over the
     pairs it meets (a causal layer counted as half the square, as
     ``flops_zaya.attention_score_macs`` counts it)."""
     e, kv, d = (int(cfg["hidden_size"]), int(cfg["num_key_value_heads"]),
                 int(cfg["head_dim"]))
     keys = seq / 2.0 if window is None else band_pairs(seq, window) \
         / float(seq)
-    return e * 2 * heads * d + 2 * e * kv * d + heads * d * e \
+    return e * heads * (d + 1) + 2 * e * kv * d + heads * d * e \
         + keys * heads * 2 * d
 
 

@@ -15,14 +15,14 @@ configuration's per-layer lists (``layer_types``,
 
 - x = x + Attn(RMSNorm₁(x)); x = x + FFN(RMSNorm₂(x)).
 - Attn (H query heads of the layer on H_kv key/value heads of d): [q_h ‖
-  gate_h] = u W_q a head; k, v = u W_k, u W_v; no norm on q or k; rotary,
+  g_h] = u W_q a head, g_h one number; k, v = u W_k, u W_v; no norm on q or k; rotary,
   half-split, on the first ``partial_rotary_factor``·d channels of a head
   as ``rope_parameters[kind]`` says — YaRN's inverse frequencies
   (:func:`yarn_inv_freq`) with cos and sin times ``attention_factor`` on
   full layers, plain rotary on window layers; softmax(q kᵀ / √d) v, query
   head h on key/value head h // (H / H_kv), causal, and on window layers
-  query i sees keys i − W + 1 … i (W = ``sliding_window``); y = (o ⊙
-  sigmoid(gate)) W_o.
+  query i sees keys i − W + 1 … i (W = ``sliding_window``); y = [o_h ·
+  sigmoid(g_h)]_h W_o.
 - FFN: ``dense`` — (silu(u W_g) ⊙ u W_u) W_d of ``intermediate_size``;
   ``sparse`` — s = sigmoid(u W_r) over all ``deployment.router_width``
   experts; the ``num_experts_per_tok`` largest chosen; w = s at the chosen
@@ -81,7 +81,7 @@ def _layer_shapes(d, heads, mlp):
     e, w, kv = d["e"], d["d"], d["kv"]
     shapes = {
         "norm1_gamma": (e,), "norm2_gamma": (e,),
-        "att_q_weight": (2 * heads * w, e), "att_k_weight": (kv * w, e),
+        "att_q_weight": (heads * (w + 1), e), "att_k_weight": (kv * w, e),
         "att_v_weight": (kv * w, e), "att_out_weight": (e, heads * w)}
     if mlp == "dense":
         shapes.update({"ffn_gate_weight": (d["inner"], e),
@@ -236,7 +236,7 @@ def gated_attention(u, p, cfg, kind, heads, lowprec=None, without=()):
     d = _dims(cfg)
     hk, w = d["kv"], d["d"]
     b, s, _ = u.shape
-    qg = _linear(u, p["att_q_weight"], lowprec).reshape(b, s, heads, 2 * w)
+    qg = _linear(u, p["att_q_weight"], lowprec).reshape(b, s, heads, w + 1)
     q, gate = qg[..., :w], qg[..., w:]
     k = _linear(u, p["att_k_weight"], lowprec).reshape(b, s, hk, w)
     v = _linear(u, p["att_v_weight"], lowprec).reshape(b, s, hk, w)
@@ -377,7 +377,7 @@ def make_train_step(cfg, lr, momentum, wd, lowprec=None, without=()):
     """One SGD-momentum step as the config states it
     (``m = momentum*m - lr*(g + wd*w); w = w + m``):
     ``step(w, m, ids, labels) -> (rows, readings, new w, new m)``.
-    ``w`` and ``m`` are donated (767 M float32 parameters: the old and the
+    ``w`` and ``m`` are donated (692 M float32 parameters: the old and the
     new state do not fit one chip side by side), and the gradient leaves
     the step as ``readings``: ``grad`` {leaf: ‖g‖} and ``sketch``
     (:func:`expert_sketch`).  ``without``: names of :data:`FAULTS`."""

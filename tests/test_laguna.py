@@ -524,6 +524,46 @@ def test_four_shares_add_up_to_the_uncut_layer():
     _close(jnp.sum(weights, axis=-1), jnp.full((96,), 2.5), 1e-6)
 
 
+@pytest.mark.parametrize("chunk_rows", [32, 128, 384])
+def test_routed_chunks_sized_from_the_shapes(chunk_rows):
+    """The cell's routed layers walk chunks of twice what a balanced router
+    sends the experts held (8,192 tokens, 8 of 256, 32 held: 16,384 rows,
+    where the shared default is 8,192 and a step walked one chunk or two),
+    and the layer's result, gradients and counters do not depend on how
+    its assignments are chunked."""
+    from mxnet_tpu.models import transformer_swa_moe as model
+    assert model.chunk_rows(8192, 8, 32, 256) == 16384
+    net = model.get_symbol(seq_len=8192, num_experts=256, n_local_experts=32,
+                           num_experts_per_tok=8)
+    assert net.attr_dict()["layer1_moe"]["chunk_rows"] == "16384"
+    key = jax.random.PRNGKey(56)
+    p = _layer(key, 1)
+    h = jax.random.normal(jax.random.fold_in(key, 1), (96, 32), dtype=F32)
+    stacks = jax.random.split(jax.random.PRNGKey(57), 3)
+    leaves = [p["moe_router_weight"]] + [
+        0.3 * jax.random.normal(k, shape, dtype=F32) for k, shape in zip(
+            stacks, ((16, 16, 32), (16, 16, 32), (16, 32, 16)))]
+
+    def run(rows):
+        op = create_operator(
+            "RoutedExperts", num_experts=16, hidden_size=16, top_k=4,
+            routed_scaling_factor=2.5, chunk_rows=rows)
+
+        def loss(h, *w):
+            outs, aux = op.forward([h] + list(w), _zero_aux(op), True, None)
+            return jnp.sum(outs[0] ** 2), (outs[0], aux[1])
+        grads, (out, counted) = _highest(jax.grad(
+            loss, argnums=tuple(range(5)), has_aux=True))(h, *leaves)
+        return out, counted, grads
+
+    out, counted, grads = run(chunk_rows)
+    want_out, want_counted, want_grads = run(0)     # 8,192: one chunk
+    assert int(counted[0]) == int(want_counted[0]) == 96 * 4
+    _close(out, want_out, 1e-6)
+    for got, want in zip(grads, want_grads):
+        _close(got, want, 1e-5)
+
+
 # -- the model ----------------------------------------------------------------
 def test_layer_pattern_heads_and_routed_layers():
     from mxnet_tpu.models import transformer_swa_moe as model
@@ -534,7 +574,7 @@ def test_layer_pattern_heads_and_routed_layers():
     shapes = dict(zip(names, net.infer_shape(data=(1, SEQ),
                                              softmax_label=(1, SEQ))[0]))
     for i, heads in enumerate(CFG["num_attention_heads_per_layer"]):
-        assert shapes["layer%d_att_q_weight" % i] == (2 * heads * 16, 32)
+        assert shapes["layer%d_att_q_weight" % i] == (heads * 17, 32)
         assert ("layer%d_ffn_gate_weight" % i in names) == (i == 0)
         assert ("layer%d_moe_router_weight" % i in names) == (i > 0)
         assert "layer%d_att_q_norm_gamma" % i not in names
