@@ -13,6 +13,7 @@ in ``parallel/ring_attention.py``.
 """
 from __future__ import annotations
 
+import functools
 import math
 
 import numpy as _np
@@ -157,6 +158,63 @@ def yarn_inv_freq(rotary_dim, theta, factor, original_max_position,
     return extrapolation / factor * ramp + extrapolation * (1.0 - ramp)
 
 
+@functools.lru_cache(maxsize=None)
+def _half_swap(d, r):
+    """The d × d signed permutation of the half-split rotary on the first
+    ``r`` channels: t @ P is (−t[r/2:r], t[:r/2], 0 …) — each channel's
+    partner, the first half's negated — and zero from channel r on.  Every
+    column holds one ±1 or nothing, so a product with it is exact."""
+    half = r // 2
+    p = _np.zeros((d, d), _np.float32)
+    i = _np.arange(half)
+    p[i + half, i] = -1.0
+    p[i, i + half] = 1.0
+    return p
+
+
+def _swap(t, perm):
+    """t @ ``perm`` in float32, exact where every column of ``perm`` holds
+    one ±1 or nothing: a 16-bit t in one pass of the matrix unit and out
+    in its own dtype, a wider one as float32 at ``HIGHEST``."""
+    precision = None
+    if jnp.dtype(t.dtype).itemsize > 2:
+        t, precision = t.astype(jnp.float32), lax.Precision.HIGHEST
+    return jnp.matmul(t, jnp.asarray(perm, t.dtype), precision=precision,
+                      preferred_element_type=t.dtype).astype(jnp.float32)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _turn(r, x, cos, sin):
+    """x · cos + (x @ P) · sin with P = ``_half_swap(d, r)``, x's dtype."""
+    perm = _half_swap(x.shape[-1], r)
+    return (x.astype(jnp.float32) * cos + _swap(x, perm) * sin).astype(
+        x.dtype)
+
+
+def _turn_fwd(r, x, cos, sin):
+    return _turn(r, x, cos, sin), (cos, sin)
+
+
+def _turn_bwd(r, res, g):
+    """g · cos + (g @ Pᵀ) · sin: the partner is taken before the product
+    with sin, which both channels of a pair share, so the products read g
+    in its own dtype (autodiff would give (g · sin) @ Pᵀ, a float32
+    operand rounded on the matrix unit's default pass).  g's own term is
+    a product too, with the identity: the matrix unit writes both terms
+    in whatever layout the gradient's consumer asks for (a headwise
+    gate's 129-wide q gradient wants the sequence minor), where an
+    elementwise pass would have the compiler relayout g in float32
+    first."""
+    cos, sin = res
+    d = g.shape[-1]
+    dx = _swap(g, _np.eye(d, dtype=_np.float32)) * cos \
+        + _swap(g, _half_swap(d, r).T) * sin
+    return dx.astype(g.dtype), None, None
+
+
+_turn.defvjp(_turn_fwd, _turn_bwd)
+
+
 def rotary_half(x, theta, rotary_dim=None, inv_freq=None, scale=None):
     """Rotary position embedding of x (..., S, D) in the half-split
     layout, on the first ``rotary_dim`` channels (all of them by
@@ -166,11 +224,18 @@ def rotary_half(x, theta, rotary_dim=None, inv_freq=None, scale=None):
     frequencies are given (:func:`yarn_inv_freq`) — and cos and sin are
     multiplied by ``scale`` where one is given (YaRN's attention factor:
     the rotated channels' scores grow by its square); the channels from
-    ``rotary_dim`` on pass through.  float32 inside, x's dtype out."""
+    ``rotary_dim`` on pass through.  float32 inside, x's dtype out.
+
+    Computed as x · cos + (x @ P) · sin, P the signed half-swap
+    (``_half_swap``), cos and sin (S, D) tables that hold a pair's value
+    on both its channels and 1 and 0 on pass-through ones: no slice of a
+    head, the transpose before the call and the cast after it fuse into
+    the product.  Term by term these are a · cos − b · sin and
+    b · cos + a · sin, so value and gradient are those of the halves'
+    formula to the bit, operation by operation (but for the sign of a
+    pass-through zero)."""
     s, d = x.shape[-2], x.shape[-1]
     r = d if rotary_dim is None else int(rotary_dim)
-    half = r // 2
-    x32 = x.astype(jnp.float32)
     if inv_freq is None:
         inv_freq = 1.0 / (float(theta) ** (
             jnp.arange(0, r, 2, dtype=jnp.float32) / r))
@@ -180,9 +245,12 @@ def rotary_half(x, theta, rotary_dim=None, inv_freq=None, scale=None):
     cos, sin = jnp.cos(ang), jnp.sin(ang)
     if scale is not None:
         cos, sin = cos * scale, sin * scale
-    a, b = x32[..., :half], x32[..., half:r]
-    return jnp.concatenate([a * cos - b * sin, b * cos + a * sin,
-                            x32[..., r:]], axis=-1).astype(x.dtype)
+
+    def table(t, rest):     # (S, r/2) -> (S, D): a pair's value twice
+        return jnp.pad(jnp.tile(t, (1, 2)), ((0, 0), (0, d - r)),
+                       constant_values=rest)
+
+    return _turn(r, x, table(cos, 1.0), table(sin, 0.0))
 
 
 def shift_tokens(z, n, axis=1):

@@ -4,6 +4,7 @@ block loops' bookkeeping, values and gradients in interpret mode at windows
 aligned to a block and not, under a block and past the sequence, at groups
 of 6 and 8 query heads), the causal kernels' Mosaic modules unchanged for the
 four language-model cells' shapes, YaRN's inverse frequencies, the
+half-split rotary's product form against the halves' formula bit for bit, the
 ``GatedAttention`` op with a window, YaRN and no q/k norm, a mirrored
 windowed block that runs its forward kernel once, the same block lowered for
 a TPU on a mesh of four, the sigmoid routed layer's shares of the experts,
@@ -325,6 +326,110 @@ def test_yarn_rotary_scales_only_the_rotated_channels():
     # the default arguments are the rotary the other models run
     assert np.array_equal(np.asarray(att.rotary_half(x, 1e4, 8)),
                           np.asarray(att.rotary_half(x, 1e4, 8, None, None)))
+
+
+# -- the rotary as a product with a signed permutation ------------------------
+def _rotary_by_slices(x, theta, rotary_dim, inv_freq=None, scale=None):
+    """The half-split rotary as slices and a concatenation: the formula
+    ``rotary_half`` computes as a product, kept here as its reference."""
+    s, d = x.shape[-2], x.shape[-1]
+    half = rotary_dim // 2
+    x32 = x.astype(jnp.float32)
+    if inv_freq is None:
+        inv_freq = 1.0 / (float(theta) ** (
+            jnp.arange(0, rotary_dim, 2, dtype=jnp.float32) / rotary_dim))
+    else:
+        inv_freq = jnp.asarray(inv_freq, jnp.float32)
+    ang = jnp.arange(s, dtype=jnp.float32)[:, None] * inv_freq
+    cos, sin = jnp.cos(ang), jnp.sin(ang)
+    if scale is not None:
+        cos, sin = cos * scale, sin * scale
+    a, b = x32[..., :half], x32[..., half:rotary_dim]
+    return jnp.concatenate([a * cos - b * sin, b * cos + a * sin,
+                            x32[..., rotary_dim:]], axis=-1).astype(x.dtype)
+
+
+def _bits(t):
+    t = np.asarray(t)
+    return t.view({2: np.uint16, 4: np.uint32}[t.dtype.itemsize])
+
+
+@pytest.mark.parametrize("yarn", [False, True])
+@pytest.mark.parametrize("d,rotary_dim", [(128, 128), (128, 64), (256, 256),
+                                          (256, 128)])
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, F32])
+def test_rotary_product_is_the_slices_formula_to_the_bit(dtype, d,
+                                                         rotary_dim, yarn):
+    """Value and ``jax.vjp`` gradient equal the slices' formula bit for bit
+    (operation by operation: under ``jit`` a compiler may contract a
+    product and a sum into one fused multiply-add, and which product it
+    takes follows the order of the sum), at a length that is no power of
+    two; the channels past the rotated ones come out as they went in."""
+    inv, scale = ((att.yarn_inv_freq(rotary_dim, 500000.0, 64.0, 4096,
+                                     32.0, 1.0), 1.4158883083359672)
+                  if yarn else (None, None))
+    key = jax.random.PRNGKey(d + rotary_dim + yarn)
+    x = (3 * jax.random.normal(key, (2, 3, 72, d), F32)).astype(dtype)
+    g = jax.random.normal(jax.random.fold_in(key, 1), x.shape,
+                          F32).astype(dtype)
+    y, back = jax.vjp(lambda t: att.rotary_half(t, 500000.0, rotary_dim,
+                                                inv, scale), x)
+    want, want_back = jax.vjp(lambda t: _rotary_by_slices(
+        t, 500000.0, rotary_dim, inv, scale), x)
+    assert y.dtype == x.dtype and back(g)[0].dtype == x.dtype
+    np.testing.assert_array_equal(_bits(y), _bits(want))
+    np.testing.assert_array_equal(_bits(back(g)[0]), _bits(want_back(g)[0]))
+    np.testing.assert_array_equal(_bits(y[..., rotary_dim:]),
+                                  _bits(x[..., rotary_dim:]))
+    np.testing.assert_array_equal(_bits(back(g)[0][..., rotary_dim:]),
+                                  _bits(g[..., rotary_dim:]))
+
+
+def _scoped(jaxpr, scope, prefix=""):
+    """``(equation, name stack)`` of every equation of ``jaxpr``, however
+    deep, whose name stack holds ``scope``; an inner jaxpr's stack is read
+    after its equation's."""
+    for eqn in jaxpr.eqns:
+        stack = prefix + "/" + str(eqn.source_info.name_stack)
+        if scope in stack:
+            yield eqn, stack
+        for value in eqn.params.values():
+            for sub in (value if isinstance(value, (tuple, list))
+                        else (value,)):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _scoped(sub, scope, stack)
+
+
+@pytest.mark.parametrize("op_type", ["GatedAttention",
+                                     "CompressedConvAttention"])
+def test_rotary_runs_as_products_without_slices(op_type):
+    """In a small attention layer's gradient the rotary scope holds two
+    (d, d) products forward (q and k by the half-swap) and four backward
+    (q and k by its transpose and by the identity), and no slice or
+    concatenation narrower than a head."""
+    d = 16
+    op = create_operator(op_type, num_heads=4, num_kv_heads=2, head_dim=d,
+                         rope_theta=100.0, partial_rotary_factor=0.5)
+    shapes = op.infer_shape([(1, 8, 32)] + [None] * 16)[0]
+    leaves = [0.1 * jax.random.normal(jax.random.PRNGKey(i), s, F32)
+              for i, s in enumerate(shapes)]
+
+    def loss(*leaves):
+        return jnp.sum(op.forward(list(leaves), [], True, None)[0][0])
+
+    jaxpr = jax.make_jaxpr(jax.grad(loss, tuple(range(len(leaves)))))(
+        *leaves).jaxpr
+    passes, narrow = [], []
+    for eqn, stack in _scoped(jaxpr, att.ROTARY_NORM):
+        shapes = [v.aval.shape for v in eqn.invars + eqn.outvars
+                  if hasattr(v.aval, "shape")]
+        if eqn.primitive.name == "dot_general" and (d, d) in shapes:
+            passes.append("transpose" in stack)
+        if eqn.primitive.name in ("slice", "concatenate"):
+            narrow += [s for s in shapes if s[-1] % d]
+    assert sorted(passes) == [False] * 2 + [True] * 4
+    assert narrow == []
 
 
 # -- the op -------------------------------------------------------------------
