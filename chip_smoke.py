@@ -57,6 +57,7 @@ FULL = {
                     flash_gqa256=(1, 16, 2, 8192, 256),
                     flash_window=(1, 64, 8, 8192, 128, 512),
                     delta_rule=(1, 4096, 16, 32, 128, 128, 1024),
+                    kda_rule=(1, 4096, 32, 128, 1024),
                     qmm=((8, 768, 3072), (256, 768, 3072), (8, 3072, 768),
                          (8, 768, 50257), (256, 768, 50257))),
 }
@@ -74,6 +75,7 @@ TINY = {
                     flash_gqa256=(1, 8, 1, 256, 32),
                     flash_window=(1, 8, 1, 256, 16, 48),
                     delta_rule=(1, 128, 1, 2, 128, 128, 128),
+                    kda_rule=(1, 128, 1, 128, 128),
                     qmm=((8, 256, 384), (300, 600, 1000))),
 }
 
@@ -622,6 +624,52 @@ def phase_kernels(env, cfg, lm_params):
                     "backward kernels" % text.count(MOSAIC))
         for form, fn in forms:
             name = "gated_delta_rule[%s,%s]" % (jnp.dtype(dt).name, form)
+            err = _rel_err(jax.jit(fn)(*args), want)
+            require(err <= tol, "%s: output error %g against the "
+                    "recurrence exceeds %g" % (name, err, tol))
+            got_g = jax.jit(jax.grad(functools.partial(rule_loss, fn),
+                                     wrt))(*short)
+            errs = [_rel_err(a, b) for a, b in zip(got_g, want_g)]
+            require(max(errs) <= 2 * tol, "%s: dq/dk/dv/dg/dbeta errors %s "
+                    "against the recurrence exceed %g"
+                    % (name, errs, 2 * tol))
+            out[name] = round(max([err] + errs), 5)
+            del got_g
+        del q, k, v, g, beta, args, short, want, want_g
+
+    # Kimi delta attention: the rule with a decay a key channel, at Ling's
+    # head geometry (32 heads of 128), the same way — its two kernels and
+    # its XLA form against the recurrence — with the decay drawn across its
+    # bounded range and at the bound, −5, on every channel of the second
+    # chunk, where a sub-block's key factor reaches e^75
+    kb, ks, kh, kd, k_short = cfg["kda_rule"]
+    kda = functools.partial(la.kda_rule, interpret=True) if interpret \
+        else la.kda_rule
+    for dt, tol in ((jnp.float32, 2e-2), (jnp.bfloat16, 4e-2)):
+        q = la.l2_normalise(rng.randn(kb, ks, kh, kd)) * kd ** -0.5
+        k = la.l2_normalise(rng.randn(kb, ks, kh, kd) + 0.5)
+        q, k = put(q.astype(dt)), put(k.astype(dt))
+        v = put(jnp.asarray(rng.randn(kb, ks, kh, kd), dt))
+        g = -5.0 * jax.nn.sigmoid(jnp.asarray(
+            2 * rng.randn(kb, ks, kh, kd), jnp.float32))
+        g = put(g.at[:, 64:128].set(-5.0))
+        beta = put(jax.nn.sigmoid(jnp.asarray(rng.randn(kb, ks, kh),
+                                              jnp.float32)))
+        args = (q, k, v, g, beta)
+        short = tuple(t[:, :k_short] for t in args)
+        wrt = (0, 1, 2, 3, 4)
+        want = highest(la.kda_rule_recurrent, *args)
+        want_g = highest(jax.grad(functools.partial(
+            rule_loss, la.kda_rule_recurrent), wrt), *short)
+        if not interpret:
+            text = jax.jit(jax.grad(functools.partial(rule_loss, kda),
+                                    wrt)).lower(*short).compile().as_text()
+            require(text.count(MOSAIC) >= 2,
+                    "kda_rule: placed on the chip, its gradient program "
+                    "holds %d Mosaic calls, not the forward and backward "
+                    "kernels" % text.count(MOSAIC))
+        for form, fn in (("kernel", kda), ("xla", la.kda_rule_xla)):
+            name = "kda_rule[%s,%s]" % (jnp.dtype(dt).name, form)
             err = _rel_err(jax.jit(fn)(*args), want)
             require(err <= tol, "%s: output error %g against the "
                     "recurrence exceeds %g" % (name, err, tol))

@@ -59,8 +59,8 @@ def mirror_scope(stage_name, enabled=True):
     inputs and recomputes the rest in backward, but for the values their
     producer names in ``executor.KEPT``: what the flash attention kernel
     hands its backward (q, k, v, the output, the softmax statistics),
-    what the gated delta rule's forward sweep hands on (q, k, v, the
-    chunk scalars, the chunk states, the output) and what the routed
+    what the gated delta rule's forward sweep and KDA's hand on (q, k,
+    v, the chunk scalars, the chunk states, the output) and what the routed
     layer hands its backward (the chosen experts and their weights, the
     sorted order, the counts; the routed sum where a backward reads it),
     because recomputing them is a second run of the kernels and a kept

@@ -28,7 +28,7 @@ from .ndarray import NDArray, zeros
 from . import random as _random
 from .observability import device_scopes as _device_scopes
 from .observability import spans as _spans
-from .kernels.delta_rule import DELTA_RESIDUALS
+from .kernels.delta_rule import DELTA_RESIDUALS, KDA_RESIDUALS
 from .ops.moe import ROUTED_RESIDUALS
 from .parallel.ring_attention import FLASH_RESIDUALS
 from .train_step import (apply_updates, compute_cast, loss_and_grads,
@@ -229,10 +229,12 @@ def _consumable(arrays, beside=None):
 # The ``checkpoint_name``s a mirrored segment saves instead of
 # recomputing.  The rule: a producer names what a kernel hands its own
 # backward when recomputing it costs a second call of the kernel, and it
-# names that set whole.  Three producers do: the flash forward (its
+# names that set whole.  Four producers do: the flash forward (its
 # operands q, k, v with its output and softmax statistics), the gated
 # delta rule's forward sweep (q, k, v, the chunk scalars, the chunk states
-# and the output, which the gated norm after it reads) and the routed
+# and the output, which the gated norm after it reads), KDA's forward
+# sweep (the same, its running sums of the decay a channel and β apart)
+# and the routed
 # layer (the chosen experts and their weights, the sorted order and the
 # counts, and the routed sum: its recomputation is the grouped products
 # again).  Operands and discrete choices alone would be cheap to
@@ -245,10 +247,11 @@ def _consumable(arrays, beside=None):
 # scale follows the layer (ZAYA1) and not where an add does.  Held a
 # block: the flash kernel's 336 MB in JoyAI (q and k 101 MB each) and
 # 42 MB in ZAYA1, a delta-rule layer's 337 MB in Qwen3-Next (the states
-# 134 MB), a routed layer's 34 MB where the sum is kept and under 2 MB
-# where not, against a block input of 33.5 MB.  A segment that holds no
+# 134 MB), a KDA layer's 538 MB in Ling (the decays' running sums 134 MB
+# and the states 134 MB), a routed layer's 34 MB where the sum is kept and
+# under 2 MB where not, against a block input of 33.5 MB.  A segment that holds no
 # such name saves what a policy-less checkpoint saves: its inputs.
-KEPT = FLASH_RESIDUALS + DELTA_RESIDUALS + ROUTED_RESIDUALS
+KEPT = FLASH_RESIDUALS + DELTA_RESIDUALS + KDA_RESIDUALS + ROUTED_RESIDUALS
 
 
 def mirror_checkpoint(fn):

@@ -63,7 +63,8 @@ from jax.ad_checkpoint import checkpoint_name
 
 __all__ = ["delta_blocks", "gated_delta_rule_kernel",
            "gated_delta_forward_kernel_spec",
-           "gated_delta_backward_kernel_spec", "DELTA_RESIDUALS"]
+           "gated_delta_backward_kernel_spec", "DELTA_RESIDUALS",
+           "kda_blocks", "kda_rule_kernel", "KDA_RESIDUALS"]
 
 # What the forward sweep hands on — q, k, v, the chunk-major scalars, the
 # output and the chunk states — each under a ``checkpoint_name``, as the
@@ -619,6 +620,405 @@ def gated_delta_rule_kernel(q, k, v, g, beta, block, chunk, interpret=False):
     # XLA form's: what a compiled step makes for the rule carries its name
     with jax.named_scope("gated_delta_rule"):
         return rule(q, k, v, _chunk_scalars(g, beta, chunk))
+
+
+# ----------------------------------------------------------------------
+# Kimi delta attention: a decay a key channel
+# ----------------------------------------------------------------------
+# KDA (arXiv:2510.26692) decays the state by one number a key channel,
+# S ← (I − β k kᵀ) Diag(α) S + β k vᵀ, α = e^g, g (d_k) a token.  Per chunk,
+# with Γ the running sum of g inside it, a channel's:
+#
+#     A = tril(β ⊙ Σ_c k_ic k_jc e^{Γ_ic − Γ_jc}, −1),  T = (I + A)⁻¹
+#     W = T (β ⊙ K ⊙ e^Γ),  U = T (β ⊙ V),  V′ = U − W S
+#     O = (Q ⊙ e^Γ) S + tril(Σ_c q_ic k_jc e^{Γ_ic − Γ_jc}) V′
+#     S′ = Diag(e^{Γ_C}) S + (K ⊙ e^{Γ_C − Γ})ᵀ V′
+#
+# The decay no longer factors out of Q Kᵀ as one (R, R) matrix: a pair
+# product carries e^{Γ_i − Γ_j} a channel.  It is formed as the product of
+# (Q ⊙ e^{Γ − Γ_r}) and (K ⊙ e^{Γ_r − Γ})ᵀ, with r the first row of the
+# query row's sub-block of ``_DIAG`` rows, a product a sub-block: off the
+# diagonal (keys before the sub-block) both factors are at most 1; on it
+# the key factor reaches e^{−Σ g} over at most 15 tokens.  With the gate
+# bounded below by −5 (``kda_safe_gate``: g = −5 σ(·)) that is at most
+# e^{75}, inside float32's e^{88.7}, and the product stays e^{Γ_i − Γ_j}
+# ≤ 1 where it is kept; keys past the sub-block have their argument
+# masked (not the result), so no exponent ever passes 75.  An unbounded
+# gate could overflow there: the op bounds it, the kernels assume it.  The
+# reference row cancels out of every product, so its gradient, a sum of
+# cancelling terms, is left out.
+#
+# Both kernels walk a head's blocks, tiles of R = pack · C tokens and
+# chunks as the scalar ones do, with the same blocks, inverse and VMEM
+# rule.  Besides q, k, v they read Γ (B, S, H·d_k) float32 — the running
+# sum taken outside, which jax differentiates — and β as rows (B, H, N, C).
+KDA_RESIDUALS = ("kda_q", "kda_k", "kda_v", "kda_gates", "kda_beta",
+                 "kda_out", "kda_states")
+
+# bytes an element of each block of the two calls' layouts
+_KDA_FORWARD_SIZES = (None, None, None, 4, 4, None, None)
+_KDA_BACKWARD_SIZES = (None,) * 4 + (4, 4, None) + (None,) * 3 + (4, 4)
+
+
+def kda_blocks(seq, chunk, d_k, d_v, itemsize=2):
+    """``(block, C)`` of :func:`delta_blocks` where the KDA kernels' own
+    working set (:func:`_kda_vmem_need` of the backward) fits too, else
+    None."""
+    from ..parallel import ring_attention as ra
+    found = delta_blocks(seq, chunk, d_k, d_v, 1, itemsize)
+    if found is None:
+        return None
+    block, C = found
+    layout = _kda_layout(1, block, 1, d_k, d_v, block, C, backward=True)
+    if _kda_vmem_need(layout, _KDA_BACKWARD_SIZES, itemsize, block, C, d_k,
+                      d_v) > ra._VMEM_BUDGET:
+        return None
+    return found
+
+
+def _kda_vmem_need(layout, sizes, itemsize, block, C, d_k, d_v):
+    """:func:`_vmem_need`, and the sub-blocks' key factors of a tile, float32
+    and in the compute dtype."""
+    R = _pack(block // C, C) * C
+    return _vmem_need(layout, sizes, itemsize, block, C, d_k, d_v, 1) \
+        + (R // min(_DIAG, C)) * R * d_k * (4 + itemsize)
+
+
+def _kda_layout(B, S, H, d_k, d_v, block, C, backward, emit_states=False):
+    """(block, array, index map) rows of a KDA call.  Forward: q, k, v, Γ,
+    β, then o (and the chunk states).  Backward, the blocks in reverse: q,
+    k, v, do, Γ, β, states, then dq, dk, dv, dΓ, dβ."""
+    N, nc, last = S // C, block // C, S // block - 1
+
+    def at(i):
+        return last - i if backward else i
+
+    by_key = ((None, block, d_k), (B, S, H * d_k),
+              lambda p, i: (p // H, at(i), p % H))
+    by_value = ((None, block, d_v), (B, S, H * d_v),
+                lambda p, i: (p // H, at(i), p % H))
+    rows = ((None, None, nc, C), (B, H, N, C),
+            lambda p, i: (p // H, p % H, at(i), 0))
+    states = ((None, None, nc, d_k, d_v), (B, H, N, d_k, d_v),
+              lambda p, i: (p // H, p % H, at(i), 0, 0))
+    if backward:
+        return ([by_key, by_key, by_value, by_value, by_key, rows, states],
+                [by_key, by_key, by_value, by_key, rows])
+    return ([by_key, by_key, by_value, by_key, rows],
+            [by_value] + ([states] if emit_states else []))
+
+
+def _kda_rows(b_ref, first, pack):
+    """(1, R) row of β for the ``pack`` chunks from ``first``."""
+    import jax.experimental.pallas as pl
+    rows = b_ref[pl.ds(first, pack), :]
+    return jnp.concatenate([rows[i:i + 1, :] for i in range(pack)], axis=1)
+
+
+def _kda_locals(q, k, v, G, b_row, C):
+    """Everything of a tile of R tokens that does not read the state (the
+    text above), the tile's chunks side by side: q, k (R, d_k), v (R,
+    d_v) in the compute dtype, Γ (R, d_k) and the β row (1, R) float32.
+    Each sub-block b of n rows has its reference row's factors ``fac[b]``
+    (R, d_k), e^{Γ_r − Γ} on the keys of its chunk up to its own last row
+    and 0 elsewhere, and its rows of [β K; Q] ⊙ e^{Γ − Γ_r} (``kd``,
+    ``qd``) meet K ⊙ fac[b] (``kc[b]``) in one product."""
+    dt = v.dtype
+    R, d_k = q.shape
+    n = min(_DIAG, C)
+    row, col = _indices(R)
+    eye = row == col
+    same = (row // C) == (col // C)
+    beta = _to_col(b_row, eye)
+    q32, k32 = q.astype(_F32), k.astype(_F32)
+    key_row = lax.broadcasted_iota(jnp.int32, (R, d_k), 0)
+    refs = [G[b * n:b * n + 1, :] for b in range(R // n)]
+    e_in = jnp.exp(G - jnp.concatenate(
+        [jnp.broadcast_to(r, (n, d_k)) for r in refs], axis=0))
+    qd32, kd32 = q32 * e_in, k32 * beta * e_in
+    qd, kd = qd32.astype(dt), kd32.astype(dt)
+    fac, kc, a_rows, p_rows = [], [], [], []
+    for b, r in enumerate(refs):
+        seen = ((key_row // C) == (b * n) // C) & (key_row < (b + 1) * n)
+        f = jnp.exp(jnp.where(seen, r - G, _NEG_INF))
+        fac.append(f)
+        kc.append((k32 * f).astype(dt))
+        both = _dot(jnp.concatenate([kd[b * n:(b + 1) * n],
+                                     qd[b * n:(b + 1) * n]], axis=0),
+                    kc[b], (1, 1))                              # (2n, R)
+        a_rows.append(both[:n])
+        p_rows.append(both[n:])
+    strict = (row > col) & same
+    lower = (row >= col) & same
+    a = jnp.where(strict, jnp.concatenate(a_rows, axis=0), 0.0)
+    p32 = jnp.where(lower, jnp.concatenate(p_rows, axis=0), 0.0)
+    t = _unit_lower_inverse(a, C).astype(dt)
+    e_g = jnp.exp(G)
+    kbg32 = k32 * beta * e_g
+    kbg = kbg32.astype(dt)
+    vb = (v.astype(_F32) * beta).astype(dt)
+    wu = _dot(t, jnp.concatenate([kbg, vb], axis=1))            # (R, d_k+d_v)
+    # Γ at each chunk's last token: a row a chunk, down the chunk's rows,
+    # and as a column (d_k, 1) for the state's rows
+    lasts = [jnp.sum(jnp.where(key_row == i * C + C - 1, G, 0.0), axis=0,
+                     keepdims=True) for i in range(R // C)]
+    f_last = jnp.exp(jnp.concatenate(
+        [jnp.broadcast_to(x, (C, d_k)) for x in lasts], axis=0) - G)
+    eye_k = _indices(d_k)
+    eye_k = eye_k[0] == eye_k[1]
+    kt32 = k32 * f_last
+    return dict(
+        eye=eye, eye_k=eye_k, strict=strict, lower=lower, beta=beta,
+        e_in=e_in, qd=qd, kd=kd, qd32=qd32, kd32=kd32, fac=fac, kc=kc,
+        t=t, e_g=e_g, kbg=kbg, kbg32=kbg32, vb=vb, f_last=f_last,
+        e_last=[jnp.exp(_to_col(x, eye_k)) for x in lasts],
+        w=wu[:, :d_k].astype(dt), u=wu[:, d_k:], p32=p32,
+        qg32=q32 * e_g, qg=(q32 * e_g).astype(dt), kt32=kt32,
+        kt=kt32.astype(dt))
+
+
+def _kda_forward_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, o_ref, *rest,
+                        chunk):
+    """Grid: (batch · heads, blocks), a head's blocks in order; ``rest``:
+    the chunk states' block where the backward asked for them, then the
+    float32 state.  The scalar forward's walk with :func:`_kda_locals`."""
+    import jax.experimental.pallas as pl
+
+    states_ref = rest[0] if len(rest) == 2 else None
+    state = rest[-1]
+    C = chunk
+    n_chunks = q_ref.shape[0] // C
+    pack = _pack(n_chunks, C)
+    R = pack * C
+
+    @pl.when(pl.program_id(1) == 0)
+    def _():
+        state[...] = jnp.zeros_like(state)
+
+    def one_tile(tile, carry):
+        rows = pl.ds(pl.multiple_of(tile * R, R), R)
+        v = v_ref[rows, :]
+        dt = v.dtype
+        x = _kda_locals(q_ref[rows, :], k_ref[rows, :], v, g_ref[rows, :],
+                        _kda_rows(b_ref, tile * pack, pack), C)
+        p = x["p32"].astype(dt)
+        outs = []
+        for i in range(pack):
+            at = slice(i * C, (i + 1) * C)
+            s = state[...].astype(dt)
+            if states_ref is not None:
+                states_ref[tile * pack + i] = s
+            ws = _dot(jnp.concatenate([x["w"][at], x["qg"][at]], axis=0), s)
+            v_new = (x["u"][at] - ws[:C]).astype(dt)
+            outs.append(ws[C:] + _dot(p[at, at], v_new))
+            state[...] = state[...] * x["e_last"][i] \
+                + _dot(x["kt"][at], v_new, (0, 0))
+        o_ref[rows, :] = jnp.concatenate(outs, axis=0).astype(o_ref.dtype)
+        return carry
+
+    lax.fori_loop(0, n_chunks // pack, one_tile, 0)
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "block", "chunk", "emit_states", "interpret"))
+def _kda_forward_call(q, k, v, G, b, block, chunk, emit_states, interpret):
+    """o (B, S, H, d_v), and with ``emit_states`` each chunk's starting
+    state (B, H, N, d_k, d_v) in v's dtype."""
+    import jax.experimental.pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    B, S, H, d_k = q.shape
+    d_v = v.shape[-1]
+    ins, outs = _kda_layout(B, S, H, d_k, d_v, block, chunk, False,
+                            emit_states)
+    need = _kda_vmem_need((ins, outs), _KDA_FORWARD_SIZES, v.dtype.itemsize,
+                          block, chunk, d_k, d_v)
+    res = pl.pallas_call(
+        functools.partial(_kda_forward_kernel, chunk=chunk),
+        grid=(B * H, S // block),
+        in_specs=[pl.BlockSpec(blk, index) for blk, _arr, index in ins],
+        out_specs=[pl.BlockSpec(blk, index) for blk, _arr, index in outs],
+        out_shape=[jax.ShapeDtypeStruct(arr, v.dtype)
+                   for _blk, arr, _index in outs],
+        scratch_shapes=[pltpu.VMEM((d_k, d_v), _F32)],
+        compiler_params=_compiler_params(need),
+        name="kda_forward",
+        interpret=interpret,
+    )(q.reshape(B, S, H * d_k), k.reshape(B, S, H * d_k),
+      v.reshape(B, S, H * d_v), G, b)
+    o = res[0].reshape(B, S, H, d_v)
+    return (o, res[1]) if emit_states else o
+
+
+def _kda_backward_kernel(q_ref, k_ref, v_ref, do_ref, g_ref, b_ref,
+                         states_ref, dq_ref, dk_ref, dv_ref, dg_ref, db_ref,
+                         d_state, *, chunk):
+    """Grid: (batch · heads, blocks), a head's blocks in reverse.  The
+    scalar backward's chunk steps (its text), then the pair products and
+    the factors per channel: a product P = X Yᵀ gives dX = dP Y and
+    dY = dPᵀ X, and a factor e^{±Γ} sends ± its term to Γ."""
+    import jax.experimental.pallas as pl
+
+    C = chunk
+    d_k = q_ref.shape[1]
+    n_chunks = q_ref.shape[0] // C
+    pack = _pack(n_chunks, C)
+    R = pack * C
+    n = min(_DIAG, C)
+    lane = lax.broadcasted_iota(jnp.int32, (1, R), 1)
+    key_row = lax.broadcasted_iota(jnp.int32, (R, d_k), 0)
+
+    @pl.when(pl.program_id(1) == 0)
+    def _():
+        d_state[...] = jnp.zeros_like(d_state)
+
+    def one_tile(step, carry):
+        tile = n_chunks // pack - 1 - step
+        rows = pl.ds(pl.multiple_of(tile * R, R), R)
+        q, k, v, do = (r[rows, :] for r in (q_ref, k_ref, v_ref, do_ref))
+        dt = q.dtype
+        k32 = k.astype(_F32)
+        x = _kda_locals(q, k, v, g_ref[rows, :],
+                        _kda_rows(b_ref, tile * pack, pack), C)
+        p = x["p32"].astype(dt)
+        # the chunks against the state, the tile's last first
+        pieces, to_last = [None] * pack, jnp.zeros((R, d_k), _F32)
+        for i in reversed(range(pack)):
+            at = slice(i * C, (i + 1) * C)
+            s = states_ref[tile * pack + i]
+            ds_next = d_state[...]
+            ds_dt = ds_next.astype(dt)
+            v_new = (x["u"][at] - _dot(x["w"][at], s)).astype(dt)
+            dv_new = (_dot(p[at, at], do[at], (0, 0))
+                      + _dot(x["kt"][at], ds_dt)).astype(dt)     # = dU
+            d_state[...] = ds_next * x["e_last"][i] + _dot(
+                jnp.concatenate([x["qg"][at], x["w"][at]], axis=0),
+                jnp.concatenate([do[at], -dv_new], axis=0), (0, 0))
+            from_s = _dot(jnp.concatenate([do[at], dv_new], axis=0), s,
+                          (1, 1))                       # d(Q e^Γ); −dW
+            dkt = _dot(v_new, ds_dt, (1, 1))
+            pieces[i] = (v_new, dv_new, (-from_s[C:]).astype(dt),
+                         from_s[:C], dkt)
+            # Γ_C's own: through Diag(e^{Γ_C}) and through K e^{Γ_C − Γ}
+            d_last = _to_row(x["e_last"][i] * jnp.sum(
+                ds_next * s.astype(_F32), axis=1, keepdims=True),
+                x["eye_k"]) + jnp.sum(dkt * x["kt32"][at], axis=0,
+                                      keepdims=True)
+            to_last = to_last + jnp.where(key_row == i * C + C - 1, d_last,
+                                          0.0)
+        v_new, dv_new, dw, dqg, dkt = (
+            jnp.concatenate(t, axis=0) for t in zip(*pieces))
+
+        # the tile's chunks side by side again
+        dp = jnp.where(x["lower"], _dot(do, v_new, (1, 1)), 0.0)
+        dwu = jnp.concatenate([dw, dv_new], axis=1)
+        d_t = _dot(dwu, jnp.concatenate([x["kbg"], x["vb"]], axis=1),
+                   (1, 1)).astype(dt)
+        from_t = _dot(x["t"], dwu, (0, 0))          # d(β K e^Γ) | d(β V)
+        dkbg, dvb = from_t[:, :d_k], from_t[:, d_k:]
+        da = -_dot(_dot(x["t"], d_t, (0, 0)).astype(dt), x["t"], (1, 1))
+        da = jnp.where(x["strict"], da, 0.0).astype(dt)
+        dp = dp.astype(dt)
+
+        # the pair products, a sub-block at a time
+        d_rows, d_cols = [], jnp.zeros((R, d_k), _F32)
+        for b in range(R // n):
+            mine = slice(b * n, (b + 1) * n)
+            left = jnp.concatenate([da[mine], dp[mine]], axis=0)  # (2n, R)
+            d_rows.append(_dot(left, x["kc"][b]))
+            d_cols = d_cols + x["fac"][b] * _dot(
+                left, jnp.concatenate([x["kd"][mine], x["qd"][mine]],
+                                      axis=0), (0, 0))
+        dkd = jnp.concatenate([r[:n] for r in d_rows], axis=0)
+        dqd = jnp.concatenate([r[n:] for r in d_rows], axis=0)
+
+        dq = dqd * x["e_in"] + dqg * x["e_g"]
+        dk = x["beta"] * (dkd * x["e_in"] + dkbg * x["e_g"]) + d_cols \
+            + dkt * x["f_last"]
+        dg = dqd * x["qd32"] + dkd * x["kd32"] - d_cols * k32 \
+            + dqg * x["qg32"] + dkbg * x["kbg32"] - dkt * x["kt32"] \
+            + to_last
+        d_beta = _to_row(
+            jnp.sum(k32 * (dkd * x["e_in"] + dkbg * x["e_g"]), axis=1,
+                    keepdims=True)
+            + jnp.sum(dvb * v.astype(_F32), axis=1, keepdims=True),
+            x["eye"])
+        dq_ref[rows, :] = dq.astype(dq_ref.dtype)
+        dk_ref[rows, :] = dk.astype(dk_ref.dtype)
+        dv_ref[rows, :] = (dvb * x["beta"]).astype(dv_ref.dtype)
+        dg_ref[rows, :] = dg
+        for i in range(pack):
+            db_ref[pl.ds(tile * pack + i, 1), :] = \
+                d_beta[:, i * C:(i + 1) * C]
+        return carry
+
+    lax.fori_loop(0, n_chunks // pack, one_tile, 0)
+
+
+@functools.partial(jax.jit, static_argnames=("block", "chunk", "interpret"))
+def _kda_backward_call(q, k, v, G, b, states, do, block, chunk, interpret):
+    """(dq, dk, dv, dΓ, dβ) from the operands, the chunk states of the
+    forward sweep and the output's cotangent."""
+    import jax.experimental.pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    B, S, H, d_k = q.shape
+    d_v = v.shape[-1]
+    ins, outs = _kda_layout(B, S, H, d_k, d_v, block, chunk, True)
+    need = _kda_vmem_need((ins, outs), _KDA_BACKWARD_SIZES,
+                          v.dtype.itemsize, block, chunk, d_k, d_v)
+    dq, dk, dv, dG, db = pl.pallas_call(
+        functools.partial(_kda_backward_kernel, chunk=chunk),
+        grid=(B * H, S // block),
+        in_specs=[pl.BlockSpec(blk, index) for blk, _arr, index in ins],
+        out_specs=[pl.BlockSpec(blk, index) for blk, _arr, index in outs],
+        out_shape=[jax.ShapeDtypeStruct(arr, dtype) for (_blk, arr, _index),
+                   dtype in zip(outs, (q.dtype, k.dtype, v.dtype, _F32,
+                                       _F32))],
+        scratch_shapes=[pltpu.VMEM((d_k, d_v), _F32)],
+        compiler_params=_compiler_params(need),
+        name="kda_backward",
+        interpret=interpret,
+    )(q.reshape(B, S, H * d_k), k.reshape(B, S, H * d_k),
+      v.reshape(B, S, H * d_v), do.reshape(B, S, H * d_v), G, b, states)
+    return (dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape),
+            dG, db)
+
+
+def kda_rule_kernel(q, k, v, g, beta, block, chunk, interpret=False):
+    """The KDA rule through the kernels.  q, k (B, S, H, d_k), v (B, S,
+    H, d_v) in the compute dtype; g (B, S, H, d_k) float32, each ≥ −5 (the
+    text above); beta (B, S, H); ``(block, chunk)`` from
+    :func:`kda_blocks`.  -> o (B, S, H, d_v)."""
+
+    @jax.custom_vjp
+    def rule(q, k, v, G, b):
+        return _kda_forward_call(q, k, v, G, b, block, chunk, False,
+                                 interpret)
+
+    def rule_fwd(q, k, v, G, b):
+        o, states = _kda_forward_call(q, k, v, G, b, block, chunk, True,
+                                      interpret)
+        q, k, v, G, b, o, states = (
+            checkpoint_name(x, name) for x, name in zip(
+                (q, k, v, G, b, o, states), KDA_RESIDUALS))
+        return o, (q, k, v, G, b, states)
+
+    def rule_bwd(res, do):
+        q, k, v, G, b, states = res
+        return _kda_backward_call(q, k, v, G, b, states, do.astype(v.dtype),
+                                  block, chunk, interpret)
+
+    rule.defvjp(rule_fwd, rule_bwd)
+    # the running sum and β's chunk rows are plain jnp, outside the
+    # custom_vjp: jax differentiates them
+    with jax.named_scope("kda_rule"):
+        B, S, H, d_k = g.shape
+        G = jnp.cumsum(g.astype(_F32).reshape(B, S // chunk, chunk, H, d_k),
+                       axis=2).reshape(B, S, H * d_k)
+        b = jnp.moveaxis(beta.astype(_F32), 1, 2).reshape(B, H, S // chunk,
+                                                          chunk)
+        return rule(q, k, v, G, b)
 
 
 # ----------------------------------------------------------------------

@@ -579,13 +579,23 @@ class GatedAttention(OperatorProperty):
 
 class _MLAParam(ParamStruct):
     num_heads = Field(int, required=True, lower=1)
-    q_lora_rank = Field(int, required=True, lower=1)
+    q_lora_rank = Field(int, required=True, lower=0,
+                        doc="0: a direct query projection, no inner norm")
     kv_lora_rank = Field(int, required=True, lower=1)
     qk_nope_head_dim = Field(int, required=True, lower=1)
     qk_rope_head_dim = Field(int, required=True, lower=2)
     v_head_dim = Field(int, required=True, lower=1)
     rope_theta = Field(float, default=10000.0)
-    eps = Field(float, default=1e-6, doc="of the two inner RMSNorms")
+    rope_interleave = Field(bool, default=True,
+                            doc="rotary pairs interleaved (False: "
+                                "half-split, rotary_half)")
+    q_norm = Field(bool, default=False,
+                   doc="an RMSNorm a head over q's whole width, one gain "
+                       "vector for all heads")
+    gate = Field(str, default="none", enum=("none", "headwise"),
+                 doc="headwise: each head's output times sigmoid(u w_hᵀ), "
+                     "gate_weight (heads, E)")
+    eps = Field(float, default=1e-6, doc="of the inner RMSNorms")
 
 
 @register_op("MultiHeadLatentAttention")
@@ -593,12 +603,16 @@ class MultiHeadLatentAttention(OperatorProperty):
     """Latent attention (DeepSeek-V2/V3's MLA), data (B, S, E) -> (B, S, E).
 
     The query goes through a rank-``q_lora_rank`` bottleneck with an
-    RMSNorm inside it; keys and values come from one rank-``kv_lora_rank``
+    RMSNorm inside it (``q_lora_rank`` 0: one direct projection,
+    ``q_weight``); keys and values come from one rank-``kv_lora_rank``
     latent (RMSNorm inside) plus ONE rotary key of ``qk_rope_head_dim``
     that every head shares.  Per head, q = [q_nope ; rot(q_rope)],
     k = [k_nope ; rot(k_rope)] (``qk_nope_head_dim + qk_rope_head_dim``
-    wide), v is ``v_head_dim`` wide; causal softmax(q kᵀ / sqrt(width of
-    q)) v;
+    wide), v is ``v_head_dim`` wide; with ``q_norm`` q is RMS-normed over
+    its whole width before the rotary; the rotary pairs interleaved
+    channels, or with ``rope_interleave`` off the two halves
+    (:func:`rotary_half`); causal softmax(q kᵀ / sqrt(width of q)) v;
+    with ``gate="headwise"`` head h's output times sigmoid(u w_hᵀ);
     heads concatenated -> ``out_weight``.  No biases.  Weights are
     (out_features, in_features).  The attention itself is the flash
     path of ``parallel/ring_attention.py``, which takes q/k of one width
@@ -608,9 +622,14 @@ class MultiHeadLatentAttention(OperatorProperty):
     mxu = True
 
     def list_arguments(self):
-        return ["data", "q_a_weight", "q_a_norm_gamma", "q_b_weight",
-                "kv_a_weight", "kv_a_norm_gamma", "kv_b_weight",
-                "out_weight"]
+        p = self.param
+        query = ["q_a_weight", "q_a_norm_gamma", "q_b_weight"] \
+            if p.q_lora_rank else ["q_weight"]
+        if p.q_norm:
+            query.append("q_norm_gamma")
+        gate = ["gate_weight"] if p.gate == "headwise" else []
+        return ["data"] + query + ["kv_a_weight", "kv_a_norm_gamma",
+                                   "kv_b_weight"] + gate + ["out_weight"]
 
     def _dims(self):
         p = self.param
@@ -629,55 +648,76 @@ class MultiHeadLatentAttention(OperatorProperty):
         H, rq, rkv, nope, rope, dv = self._dims()
         if rope % 2:
             raise MXNetError("qk_rope_head_dim must be even (rotary pairs)")
-        return ([data, (rq, E), (rq,), (H * (nope + rope), rq),
-                 (rkv + rope, E), (rkv,), (H * (nope + dv), rkv),
-                 (E, H * dv)], [data], [])
+        p = self.param
+        query = [(rq, E), (rq,), (H * (nope + rope), rq)] if rq \
+            else [(H * (nope + rope), E)]
+        if p.q_norm:
+            query.append((nope + rope,))
+        gate = [(H, E)] if p.gate == "headwise" else []
+        return ([data] + query + [(rkv + rope, E), (rkv,),
+                                  (H * (nope + dv), rkv)]
+                + gate + [(E, H * dv)], [data], [])
 
     def cost_mxu_dims(self, in_shapes, out_shapes):
         B, S, E = in_shapes[0]
         H, rq, rkv, nope, rope, dv = self._dims()
         T = B * S
-        return [(T, E, rq), (T, rq, H * (nope + rope)), (T, E, rkv + rope),
-                (T, rkv, H * (nope + dv)), (T, H * dv, E),
-                (S, nope + rope, S), (S, S, dv)]
+        query = [(T, E, rq), (T, rq, H * (nope + rope))] if rq \
+            else [(T, E, H * (nope + rope))]
+        gate = [(T, E, H)] if self.param.gate == "headwise" else []
+        return query + [(T, E, rkv + rope), (T, rkv, H * (nope + dv))] \
+            + gate + [(T, H * dv, E), (S, nope + rope, S), (S, S, dv)]
 
     def cost_flops(self, in_shapes, out_shapes):
         B, S, _E = in_shapes[0]
         H = self.param.num_heads
         dims = self.cost_mxu_dims(in_shapes, out_shapes)
-        proj = sum(2 * m * k * n for m, k, n in dims[:5])
-        attn = sum(2 * B * H * m * k * n for m, k, n in dims[5:])
+        proj = sum(2 * m * k * n for m, k, n in dims[:-2])
+        attn = sum(2 * B * H * m * k * n for m, k, n in dims[-2:])
         return float(proj + attn)
 
     def cost_reduce_len(self, in_shapes, out_shapes):
         return int(in_shapes[0][1])     # softmax over the key axis
 
     def forward(self, inputs, aux, is_train, rng):
-        x, wqa, gq, wqb, wkva, gkv, wkvb, wo = inputs
-        B, S, _E = x.shape
-        H, _rq, rkv, nope, rope, dv = self._dims()
         p = self.param
+        args = dict(zip(self.list_arguments(), inputs))
+        x = args["data"]
+        B, S, _E = x.shape
+        H, rq, rkv, nope, rope, dv = self._dims()
 
         def heads(t, width):    # (B, S, H*width) -> (B, H, S, width)
             return t.reshape(B, S, H, width).transpose(0, 2, 1, 3)
+
+        def rotate(t):          # (..., S, rope)
+            if p.rope_interleave:
+                return rotary_interleaved(t, p.rope_theta)
+            return rotary_half(t, p.rope_theta)
 
         # a low-rank path holds its norm between two products: both
         # products and the norm are the projection.  (The scopes follow
         # the statements' order; moving a statement would renumber the
         # compiled step.)
         with jax.named_scope(PROJ_IN):
-            q = heads(rms_norm(x @ wqa.T, gq, p.eps) @ wqb.T, nope + rope)
+            if rq:
+                q = rms_norm(x @ args["q_a_weight"].T,
+                             args["q_a_norm_gamma"], p.eps) \
+                    @ args["q_b_weight"].T
+            else:
+                q = x @ args["q_weight"].T
+            q = heads(q, nope + rope)
         with jax.named_scope(ROTARY_NORM):
-            q = jnp.concatenate(
-                [q[..., :nope],
-                 rotary_interleaved(q[..., nope:], p.rope_theta)], axis=-1)
+            if p.q_norm:
+                q = rms_norm(q, args["q_norm_gamma"], p.eps)
+            q = jnp.concatenate([q[..., :nope], rotate(q[..., nope:])],
+                                axis=-1)
         with jax.named_scope(PROJ_IN):
-            ckv = x @ wkva.T                                # (B, S, rkv+rope)
+            ckv = x @ args["kv_a_weight"].T                 # (B, S, rkv+rope)
         with jax.named_scope(ROTARY_NORM):
-            k_rope = rotary_interleaved(ckv[..., rkv:], p.rope_theta)
+            k_rope = rotate(ckv[..., rkv:])
         with jax.named_scope(PROJ_IN):
-            kv = heads(rms_norm(ckv[..., :rkv], gkv, p.eps) @ wkvb.T,
-                       nope + dv)
+            kv = heads(rms_norm(ckv[..., :rkv], args["kv_a_norm_gamma"],
+                                p.eps) @ args["kv_b_weight"].T, nope + dv)
         with jax.named_scope(ROTARY_NORM):
             k = jnp.concatenate(
                 [kv[..., :nope],
@@ -687,21 +727,28 @@ class MultiHeadLatentAttention(OperatorProperty):
         with jax.named_scope(KERNEL):
             o = sharded_self_attention(q, k, kv[..., nope:], causal=True)
         with jax.named_scope(PROJ_OUT):
-            return [o.transpose(0, 2, 1, 3).reshape(B, S, H * dv) @ wo.T], \
-                None
+            o = o.transpose(0, 2, 1, 3)                     # (B, S, H, dv)
+            if p.gate == "headwise":
+                gate = jax.nn.sigmoid(jnp.dot(
+                    x, args["gate_weight"].T,
+                    preferred_element_type=jnp.float32))
+                o = (o.astype(jnp.float32) * gate[..., None]).astype(x.dtype)
+            return [o.reshape(B, S, H * dv) @ args["out_weight"].T], None
 
     def infer_sharding(self, in_specs, in_shapes, out_shapes, mesh_shape):
         """Head-parallel over whatever axis shards the rows of the two
         up-projections (``q_b``, ``kv_b``: heads), closed by a
         row-parallel ``out_weight``; the two low-rank down-projections
         and their norms stay replicated (they are shared by all heads)."""
-        data = in_specs[0]
-        head = tuple(in_specs[3][0] if in_specs[3] else ())
-        out_c = tuple(in_specs[7][1] if len(in_specs[7]) > 1 else ())
+        at = {n: i for i, n in enumerate(self.list_arguments())}
+        q_up = at["q_b_weight" if self.param.q_lora_rank else "q_weight"]
+        data, out_spec = in_specs[0], in_specs[at["out_weight"]]
+        head = tuple(in_specs[q_up][0] if in_specs[q_up] else ())
+        out_c = tuple(out_spec[1] if len(out_spec) > 1 else ())
         batch = tuple(data[0] if data else ())
         seq = tuple(data[1] if len(data) > 1 else ())
         required = [None] * len(in_specs)
-        required[6] = (head, ())        # kv_b splits as q_b does
+        required[at["kv_b_weight"]] = (head, ())    # splits as q's does
         out = {"out": [(batch, seq, ())], "in": required}
         if head and head == out_c:
             out["reduce"] = {head: "head-parallel latent attention closed "
@@ -710,7 +757,8 @@ class MultiHeadLatentAttention(OperatorProperty):
         elif head or out_c:
             axes = head or out_c
             out["notes"] = [{
-                "kind": "attn_unreduced", "arg": 3 if head else 7,
+                "kind": "attn_unreduced",
+                "arg": q_up if head else at["out_weight"],
                 "axes": axes,
                 "message": "latent attention is head-parallel over %s but "
                            "the out projection does not close it with a "

@@ -48,6 +48,13 @@ under ``shard_map``, the batch split over dp and the key heads over tp,
 because GSPMD does not partition a Mosaic kernel; where the mesh shards the
 sequence itself the state would cross devices, and the XLA form runs,
 partitioned by GSPMD.
+
+``KimiDeltaAttention`` (Kimi delta attention, arXiv:2510.26692) decays the
+state by one number a key channel, S ← Diag(e^{g_t}) S, g_t (d_k) bounded
+below: :func:`kda_rule_recurrent` is its definition, :func:`kda_rule` its
+chunk-parallel form, placed the same way (``kda_forward`` /
+``kda_backward``, or :func:`kda_rule_xla`); ``kernels/delta_rule.py`` has
+its equations and why the bound keeps every exponent finite.
 """
 from __future__ import annotations
 
@@ -138,9 +145,6 @@ def gated_delta_rule(q, k, v, g, beta, chunk=64, interpret=None):
     shards the sequence the state would cross devices: the XLA form, which
     GSPMD partitions."""
     from ..kernels import delta_rule
-    from ..kernels.common import dispatch
-    from ..parallel.ring_attention import (current_sequence_parallel,
-                                           mesh_axis_that_splits)
     if v.shape[2] % q.shape[2]:
         raise ValueError("%d value heads do not group over %d key heads"
                          % (v.shape[2], q.shape[2]))
@@ -151,31 +155,45 @@ def gated_delta_rule(q, k, v, g, beta, chunk=64, interpret=None):
     blocks = delta_rule.delta_blocks(
         q.shape[1], chunk, q.shape[-1], v.shape[-1],
         v.shape[2] // q.shape[2], v.dtype.itemsize)
+    return _placed(delta_rule.gated_delta_rule_kernel, reference, blocks,
+                   interpret, q, k, v, g, beta)
+
+
+def _placed(kernel, reference, blocks, interpret, q, k, v, g, beta):
+    """A rule's kernels (``kernel(q, k, v, g, beta, *blocks, interpret)``)
+    or its XLA form (``reference``), chosen as :func:`gated_delta_rule`'s
+    text says: the XLA form where ``blocks`` is None or the mesh shards
+    the sequence; else per device under ``shard_map`` on a mesh, the batch
+    split over dp and the key heads over tp where those divide — a device
+    keeps whole groups: the value heads split as the key heads do, and g
+    as they do, whether a number a head or a vector."""
+    from ..kernels.common import dispatch
+    from ..parallel.ring_attention import (current_sequence_parallel,
+                                           mesh_axis_that_splits)
     ctx = current_sequence_parallel()
     if blocks is None or (ctx is not None
                           and ctx.seq_axis in ctx.mesh.axis_names):
         return reference(q, k, v, g, beta)
 
-    def kernel(q, k, v, g, beta, interpret=False):
-        return delta_rule.gated_delta_rule_kernel(q, k, v, g, beta, *blocks,
-                                                  interpret=interpret)
+    def placed(q, k, v, g, beta, interpret=False):
+        return kernel(q, k, v, g, beta, *blocks, interpret=interpret)
 
     def rule(q, k, v, g, beta):
         if interpret is not None:
-            return kernel(q, k, v, g, beta, interpret=bool(interpret))
-        return dispatch(kernel, reference, q, k, v, g, beta)
+            return placed(q, k, v, g, beta, interpret=bool(interpret))
+        return dispatch(placed, reference, q, k, v, g, beta)
 
     if ctx is None:
         return rule(q, k, v, g, beta)
     from jax import shard_map
     from jax.sharding import PartitionSpec as P
-    # a device keeps whole groups: the value heads split as the key heads do
     dp = mesh_axis_that_splits(ctx.mesh, ctx.batch_axis, q.shape[0])
     tp = mesh_axis_that_splits(ctx.mesh, "tp", q.shape[2])
     heads, scalars = P(dp, None, tp, None), P(dp, None, tp)
     return shard_map(rule, mesh=ctx.mesh,
-                     in_specs=(heads,) * 3 + (scalars,) * 2, out_specs=heads,
-                     check_vma=False)(q, k, v, g, beta)
+                     in_specs=(heads,) * 3 + (heads if g.ndim == 4
+                                              else scalars, scalars),
+                     out_specs=heads, check_vma=False)(q, k, v, g, beta)
 
 
 def gated_delta_rule_xla(q, k, v, g, beta, chunk=64):
@@ -237,6 +255,119 @@ def gated_delta_rule_xla(q, k, v, g, beta, chunk=64):
         _, o = lax.scan(one_chunk, state, tuple(
             jnp.moveaxis(t, 2, 0)
             for t in (w, u, qk, q_in, k_out, jnp.exp(last))))
+        # (N, B, H, C, d_v) -> (B, S, H, d_v)
+        return jnp.moveaxis(o, (0, 2), (1, 3)).reshape(B, S, H, d_v)
+
+
+def kda_rule_recurrent(q, k, v, g, beta):
+    """Kimi delta attention's recurrence itself: the gated delta rule with
+    a decay a key channel.  q, k (B, S, H, d_k), v (B, S, H, d_v), g (B,
+    S, H, d_k), beta (B, S, H).  Per head, from S = 0:
+
+        S ← Diag(e^{g_t}) S;  δ_t = β_t (v_t − Sᵀ k_t);  S ← S + k_t δ_tᵀ;
+        o_t = Sᵀ q_t
+
+    -> o (B, S, H, d_v), all in float32."""
+    q, k, v, g, beta = (t.astype(jnp.float32) for t in (q, k, v, g, beta))
+    B, _S, H, d_k = q.shape
+
+    def token(state, x):
+        q_t, k_t, v_t, g_t, b_t = x
+        state = state * jnp.exp(g_t)[..., :, None]
+        mem = jnp.einsum("bhkv,bhk->bhv", state, k_t, precision=_HIGHEST)
+        delta = (v_t - mem) * b_t[..., None]
+        state = state + k_t[..., :, None] * delta[..., None, :]
+        return state, jnp.einsum("bhkv,bhk->bhv", state, q_t,
+                                 precision=_HIGHEST)
+
+    state = jnp.zeros((B, H, d_k, v.shape[-1]), jnp.float32)
+    _, o = lax.scan(token, state, tuple(
+        jnp.moveaxis(t, 1, 0) for t in (q, k, v, g, beta)))
+    return jnp.moveaxis(o, 0, 1)
+
+
+def kda_rule(q, k, v, g, beta, chunk=64, interpret=None):
+    """The chunk-parallel form of :func:`kda_rule_recurrent`.  q, k (B, S,
+    H, d_k), v (B, S, H, d_v) in the compute dtype; g (B, S, H, d_k)
+    float32, bounded below (``kernels/delta_rule.py`` says by what and
+    why); beta (B, S, H) float32.  -> o (B, S, H, d_v) in v's dtype.
+
+    Placed as :func:`gated_delta_rule` places the scalar rule: the Pallas
+    kernels ``kda_forward`` / ``kda_backward`` where the step is placed on
+    a TPU and they take the shapes (``kernels.delta_rule.kda_blocks``),
+    per device under ``shard_map`` on a mesh; :func:`kda_rule_xla`
+    anywhere else and where the mesh shards the sequence."""
+    from ..kernels import delta_rule
+
+    def reference(q, k, v, g, beta):
+        return kda_rule_xla(q, k, v, g, beta, chunk)
+
+    blocks = delta_rule.kda_blocks(q.shape[1], chunk, q.shape[-1],
+                                   v.shape[-1], v.dtype.itemsize)
+    return _placed(delta_rule.kda_rule_kernel, reference, blocks, interpret,
+                   q, k, v, g, beta)
+
+
+def kda_rule_xla(q, k, v, g, beta, chunk=64):
+    """:func:`kda_rule` as plain ``jax.numpy`` and one ``lax.scan`` over
+    the chunks, differentiated by ``jax``: the kernels' reference, and
+    what runs off a TPU.  A chunk's pair sums carry e^{Γ_i − Γ_j} a channel
+    as a (C, C, d_k) array, its argument masked above the diagonal, and
+    are taken in float32; the rest rounds where :func:`gated_delta_rule_xla`
+    does."""
+    B, S, H, d_k = q.shape
+    d_v = v.shape[-1]
+    C = min(int(chunk), S)
+    if S % C:
+        raise ValueError("%d tokens are no whole number of chunks of %d"
+                         % (S, C))
+    N, dt, f32 = S // C, v.dtype, jnp.float32
+
+    def chunks(t):      # (B, S, H, ...) -> (N, B, H, C, ...)
+        t = t.reshape((B, N, C, H) + t.shape[3:])
+        return jnp.moveaxis(jnp.moveaxis(t, 3, 2), 1, 0)
+
+    def dot(a, b, spec):
+        return jnp.einsum(spec, a, b, preferred_element_type=f32)
+
+    row = jnp.arange(C)
+    lower = row[:, None] >= row[None, :]
+
+    def one_chunk(state, x):
+        q_c, k_c, v_c, G, b = x
+        q32, k32 = q_c.astype(f32), k_c.astype(f32)
+        decay = jnp.exp(jnp.where(lower[..., None],
+                                  G[..., :, None, :] - G[..., None, :, :],
+                                  -1e30))                # (B, H, C, C, d_k)
+
+        def pairs(x32):
+            return jnp.einsum("bhic,bhjc,bhijc->bhij", x32, k32, decay,
+                              precision=_HIGHEST)
+
+        a = jnp.where(row[:, None] > row[None, :],
+                      pairs(k32 * b[..., None]), 0.0)
+        t_inv = unit_lower_inverse(a).astype(dt)
+        e_g = jnp.exp(G)
+        w = dot(t_inv, (k32 * b[..., None] * e_g).astype(dt),
+                "bhij,bhjd->bhid").astype(dt)
+        u = dot(t_inv, (v_c.astype(f32) * b[..., None]).astype(dt),
+                "bhij,bhjd->bhid")
+        qk = jnp.where(lower, pairs(q32), 0.0).astype(dt)
+        last = G[..., -1:, :]                            # (B, H, 1, d_k)
+        k_out = (k32 * jnp.exp(last - G)).astype(dt)
+        s = state.astype(dt)
+        v_new = (u - dot(w, s, "bhik,bhkv->bhiv")).astype(dt)
+        o = dot((q32 * e_g).astype(dt), s, "bhik,bhkv->bhiv") \
+            + dot(qk, v_new, "bhij,bhjv->bhiv")
+        state = state * jnp.exp(last)[..., 0, :, None] \
+            + dot(k_out, v_new, "bhik,bhiv->bhkv")
+        return state, o.astype(dt)
+
+    with jax.named_scope("kda_rule"):
+        G = jnp.cumsum(chunks(g.astype(f32)), axis=3)  # (N, B, H, C, d_k)
+        state = jnp.zeros((B, H, d_k, d_v), f32)
+        _, o = lax.scan(one_chunk, state, (
+            chunks(q), chunks(k), chunks(v), G, chunks(beta.astype(f32))))
         # (N, B, H, C, d_v) -> (B, S, H, d_v)
         return jnp.moveaxis(o, (0, 2), (1, 3)).reshape(B, S, H, d_v)
 
@@ -369,3 +500,105 @@ class GatedDeltaNet(OperatorProperty):
             o = (o.astype(jnp.float32) * jax.nn.silu(
                 z.reshape(B, S, hv, dv).astype(jnp.float32))).astype(x.dtype)
             return [o.reshape(B, S, hv * dv) @ w_out.T], None
+
+
+class _KimiDeltaAttentionParam(ParamStruct):
+    num_heads = Field(int, required=True, lower=1,
+                      doc="heads of q, k and v alike")
+    head_dim = Field(int, required=True, lower=1,
+                     doc="width of a head of q, k and v")
+    conv_taps = Field(int, default=4, lower=1,
+                      doc="taps of the causal depthwise convolutions")
+    gate_lower_bound = Field(
+        float, default=-5.0, lower=-5.0, upper=-1e-3,
+        doc="g = bound · sigmoid(·): the least log-decay a channel takes "
+            "a token; at least −5, which keeps the kernels' exponents "
+            "finite (kernels/delta_rule.py)")
+    chunk = Field(int, default=64, lower=1,
+                  doc="tokens a chunk of the chunk-parallel rule (a power "
+                      "of two; the program's choice, not a width)")
+    eps = Field(float, default=1e-6, doc="of the output norm")
+
+
+@register_op("KimiDeltaAttention")
+class KimiDeltaAttention(OperatorProperty):
+    """Kimi delta attention token mixer (arXiv:2510.26692), data (B, S, E)
+    -> (B, S, E): the gated delta rule with a decay a key channel.
+
+    H = ``num_heads`` heads of d = ``head_dim`` for q, k and v alike.
+
+    1. q, k, v = u W_qᵀ, u W_kᵀ, u W_vᵀ (E → H·d each), each through its
+       own causal depthwise convolution of ``conv_taps`` taps, no bias,
+       then SiLU.
+    2. q and k L2-normalised a head (:func:`l2_normalise`), q times d^−½.
+    3. The decay a channel, bounded (the safe gate):
+       g = ``gate_lower_bound`` · sigmoid(exp(A_log) ⊙ (u W_fᵀ + dt_bias)),
+       W_f full rank (E → H·d), ``A_log`` a head, ``dt_bias`` a channel,
+       in float32;  β = sigmoid(u W_bᵀ) a head;  o = :func:`kda_rule`.
+    4. y = (RMSNorm(o) ⊙ sigmoid(u W_gᵀ)) W_outᵀ: the norm over a head's d
+       channels with one gain vector (``norm_gamma``, (d,)) for all heads,
+       the gate a channel (W_g full rank, E → H·d).
+
+    No biases; weights are (out_features, in_features)."""
+    param_cls = _KimiDeltaAttentionParam
+    mxu = True
+
+    def list_arguments(self):
+        return ["data", "q_weight", "k_weight", "v_weight", "q_conv_weight",
+                "k_conv_weight", "v_conv_weight", "f_weight", "A_log",
+                "dt_bias", "b_weight", "g_weight", "norm_gamma",
+                "out_weight"]
+
+    def infer_shape(self, in_shapes):
+        data = in_shapes[0]
+        if data is None:
+            require_known("KimiDeltaAttention", in_shapes[:1], ["data"])
+        if len(data) != 3:
+            raise MXNetError("KimiDeltaAttention: data must be (B, S, E)")
+        E, H, d = data[2], self.param.num_heads, self.param.head_dim
+        conv = (self.param.conv_taps, H * d)
+        return ([data, (H * d, E), (H * d, E), (H * d, E), conv, conv, conv,
+                 (H * d, E), (H,), (H * d,), (H, E), (H * d, E), (d,),
+                 (E, H * d)], [data], [])
+
+    def cost_mxu_dims(self, in_shapes, out_shapes):
+        B, S, E = in_shapes[0]
+        H, d = self.param.num_heads, self.param.head_dim
+        T = B * S
+        return [(T, E, 5 * H * d), (T, E, H), (T, H * d, E),
+                (T * H, d, d), (T * H, d, d), (T * H, d, d)]
+
+    def cost_flops(self, in_shapes, out_shapes):
+        # the projections (q, k, v, the decay, the output gate; β; out) and
+        # the recurrence's own operations a token and head
+        return float(sum(2 * m * k * n for m, k, n in
+                         self.cost_mxu_dims(in_shapes, out_shapes)))
+
+    def forward(self, inputs, aux, is_train, rng):
+        (x, wq, wk, wv, cq, ck, cv, wf, a_log, dt_bias, wb, wg, gamma,
+         w_out) = inputs
+        B, S, _E = x.shape
+        H, d, f32 = self.param.num_heads, self.param.head_dim, jnp.float32
+        with jax.named_scope(PROJ_IN):
+            q, k, v = x @ wq.T, x @ wk.T, x @ wv.T
+            f = jnp.dot(x, wf.T, preferred_element_type=f32)
+            b = jnp.dot(x, wb.T, preferred_element_type=f32)
+            gate = x @ wg.T
+        with jax.named_scope(ROTARY_NORM):
+            q, k, v = (jax.nn.silu(causal_depthwise_conv(t, w))
+                       for t, w in ((q, cq), (k, ck), (v, cv)))
+            q = l2_normalise(q.reshape(B, S, H, d)) * d ** -0.5
+            k = l2_normalise(k.reshape(B, S, H, d))
+            q, k = q.astype(x.dtype), k.astype(x.dtype)
+            v = v.reshape(B, S, H, d)
+            g = self.param.gate_lower_bound * jax.nn.sigmoid(
+                jnp.exp(a_log.astype(f32))[:, None]
+                * (f.reshape(B, S, H, d) + dt_bias.astype(f32).reshape(H, d)))
+            beta = jax.nn.sigmoid(b)
+        with jax.named_scope(KERNEL):
+            o = kda_rule(q, k, v, g, beta, self.param.chunk)
+        with jax.named_scope(PROJ_OUT):
+            o = rms_norm(o, gamma, self.param.eps)
+            o = (o.astype(f32) * jax.nn.sigmoid(
+                gate.reshape(B, S, H, d).astype(f32))).astype(x.dtype)
+            return [o.reshape(B, S, H * d) @ w_out.T], None

@@ -263,14 +263,30 @@ def gated_ffn(x, w_gate, w_up, w_down):
     return (jax.nn.silu(x @ w_gate.T) * (x @ w_up.T)) @ w_down.T
 
 
-def route_topk(scores, bias, top_k, scaling=1.0, normalize=True):
+def route_topk(scores, bias, top_k, scaling=1.0, normalize=True, n_group=1,
+               topk_group=1):
     """``(idx (T, k) int32, w (T, k) float32)`` from float32 ``scores``
     (T, N): the ``top_k`` largest of scores + bias are chosen (the bias
     steers the choice only); the weights are the scores at the chosen
     experts — divided by their sum where ``normalize`` — times
     ``scaling``.  The choice carries no gradient; the weights carry the
-    scores'."""
-    _, idx = lax.top_k(scores + bias.astype(jnp.float32), top_k)
+    scores'.
+
+    With ``n_group`` > 1 the choice is group-limited (DeepSeek-V3's):
+    the N experts fall into ``n_group`` groups of N / n_group in order, a
+    group scores the sum of its two largest scores + bias, and only the
+    ``topk_group`` best groups' experts may be chosen — the others are
+    masked to −∞ before the top-k."""
+    choice = scores + bias.astype(jnp.float32)
+    if n_group > 1:
+        T, N = choice.shape
+        grouped = choice.reshape(T, n_group, N // n_group)
+        _, keep = lax.top_k(jnp.sum(lax.top_k(grouped, 2)[0], axis=-1),
+                            topk_group)
+        kept = jnp.any(keep[..., None] == jnp.arange(n_group), axis=1)
+        choice = jnp.where(kept[:, :, None], grouped, -jnp.inf) \
+            .reshape(T, N)
+    _, idx = lax.top_k(choice, top_k)
     # named before anything reads it: the weights' backward reads the
     # choice too
     idx = checkpoint_name(idx.astype(jnp.int32), _IDX)
@@ -495,6 +511,11 @@ class _RoutedExpertsParam(ParamStruct):
         int, default=0, lower=0,
         doc="rows of the sorted assignment list computed at a time "
             "(0: CHUNK_ROWS)")
+    n_group = Field(int, default=1, lower=1,
+                    doc="groups the experts fall into, in order (1: no "
+                        "group limit; route_topk)")
+    topk_group = Field(int, default=1, lower=1,
+                       doc="groups a token may choose its experts from")
 
 
 #: the counters a RoutedExperts node keeps as auxiliary state, summed on
@@ -514,8 +535,10 @@ class RoutedExperts(OperatorProperty):
     ``scores`` input as a router outside the op made it (a softmax over
     an MLP's logits, say); the ``top_k`` largest of s + ``router_bias``
     are chosen (the bias is auxiliary state: it steers the choice, takes
-    no gradient and is left as it was); w = s at the chosen experts,
-    normalised over them (``norm_topk_prob``) and scaled.  y = Σᵢ wᵢ Eᵢ(h) over the chosen
+    no gradient and is left as it was), from the ``topk_group`` best of
+    ``n_group`` groups where those are set (:func:`route_topk`); w = s at
+    the chosen experts, normalised over them (``norm_topk_prob``) and
+    scaled.  y = Σᵢ wᵢ Eᵢ(h) over the chosen
     experts *held here* (``first_expert .. first_expert +
     num_local_experts − 1``) — every such assignment, whatever the
     imbalance; what the other experts would add is another device's part
@@ -564,6 +587,12 @@ class RoutedExperts(OperatorProperty):
         if p.first_expert + L > N:
             raise MXNetError("RoutedExperts: experts %d..%d held of %d"
                              % (p.first_expert, p.first_expert + L - 1, N))
+        if N % p.n_group or p.topk_group > p.n_group \
+                or (p.n_group > 1 and N // p.n_group < 2) \
+                or p.top_k > p.topk_group * (N // p.n_group):
+            raise MXNetError("RoutedExperts: %d experts in %d groups, %d "
+                             "groups kept, top %d" % (N, p.n_group,
+                                                      p.topk_group, p.top_k))
         router = tuple(data[:-1]) + (N,) if self._given() else (N, E)
         shapes = [data, router, (L, H, E), (L, H, E), (L, E, H)]
         if p.shared_hidden_size:
@@ -595,7 +624,8 @@ class RoutedExperts(OperatorProperty):
             else:
                 scores = sigmoid_scores(h, router)
             idx, w = route_topk(scores, aux[0], p.top_k,
-                                p.routed_scaling_factor, p.norm_topk_prob)
+                                p.routed_scaling_factor, p.norm_topk_prob,
+                                p.n_group, p.topk_group)
         y, counts = routed_experts(h, w, idx, w_gate, w_up, w_down,
                                    p.first_expert,
                                    p.chunk_rows or CHUNK_ROWS)

@@ -406,11 +406,14 @@ def test_kept_names_are_the_kernels():
     from mxnet_tpu.parallel import ring_attention
     assert executor.KEPT == (ring_attention.FLASH_RESIDUALS
                              + delta_rule.DELTA_RESIDUALS
+                             + delta_rule.KDA_RESIDUALS
                              + moe.ROUTED_RESIDUALS)
     assert executor.KEPT == (
         "flash_q", "flash_k", "flash_v", "flash_out", "flash_lse",
         "delta_q", "delta_k", "delta_v", "delta_scalars", "delta_out",
         "delta_states",
+        "kda_q", "kda_k", "kda_v", "kda_gates", "kda_beta", "kda_out",
+        "kda_states",
         "routed_idx", "routed_w", "routed_order", "routed_counts",
         "routed_out")
     assert len(set(executor.KEPT)) == len(executor.KEPT)

@@ -320,6 +320,49 @@ GRAD_SPECS = {
          "gd_A_log": _f64(4) * 0.5, "gd_dt_bias": _f64(4) * 0.5,
          "gd_norm_gamma": _pos64(2), "gd_out_weight": _f64(6, 8) * 0.6},
         {"rtol": 5e-2, "atol": 5e-2}),
+    # eight tokens in chunks of four, a decay a key channel
+    "KimiDeltaAttention": lambda: (
+        sym.KimiDeltaAttention(
+            V("a"), num_heads=2, head_dim=2, conv_taps=3, chunk=4,
+            name="kd"),
+        {"a": _f64(1, 8, 6), "kd_q_weight": _f64(4, 6) * 0.6,
+         "kd_k_weight": _f64(4, 6) * 0.6, "kd_v_weight": _f64(4, 6) * 0.6,
+         "kd_q_conv_weight": _f64(3, 4) * 0.5 + 0.5,
+         "kd_k_conv_weight": _f64(3, 4) * 0.5 + 0.5,
+         "kd_v_conv_weight": _f64(3, 4) * 0.5 + 0.5,
+         "kd_f_weight": _f64(4, 6) * 0.6, "kd_A_log": _f64(2) * 0.5,
+         "kd_dt_bias": _f64(4) * 0.5, "kd_b_weight": _f64(2, 6) * 0.6,
+         "kd_g_weight": _f64(4, 6) * 0.6, "kd_norm_gamma": _pos64(2),
+         "kd_out_weight": _f64(6, 4) * 0.6},
+        {"rtol": 5e-2, "atol": 5e-2}),
+    # a direct query, a norm on q, half-split rotary, a gate a head
+    "MultiHeadLatentAttention[direct]": lambda: (
+        sym.MultiHeadLatentAttention(
+            V("a"), num_heads=2, q_lora_rank=0, kv_lora_rank=4,
+            qk_nope_head_dim=2, qk_rope_head_dim=2, v_head_dim=2,
+            rope_theta=100.0, rope_interleave=False, q_norm=True,
+            gate="headwise", name="ld"),
+        {"a": _f64(1, 4, 6), "ld_q_weight": _f64(8, 6) * 0.6,
+         "ld_q_norm_gamma": _pos64(4), "ld_kv_a_weight": _f64(6, 6) * 0.6,
+         "ld_kv_a_norm_gamma": _pos64(4), "ld_kv_b_weight": _f64(8, 4) * 0.6,
+         "ld_gate_weight": _f64(2, 6) * 0.6,
+         "ld_out_weight": _f64(6, 4) * 0.6},
+        {"rtol": 5e-2, "atol": 5e-2}),
+    # two groups of two, one kept: experts 0 and 1 score over a half and
+    # lead their group, so neither group nor expert flips inside epsilon
+    "RoutedExperts[groups]": lambda: (
+        sym.RoutedExperts(V("a"), num_experts=4, hidden_size=4, top_k=2,
+                          n_group=2, topk_group=1, shared_hidden_size=4,
+                          routed_scaling_factor=2.5, name="rg"),
+        {"a": (_distinct64(6, 4) + 0.2) * 2.0,
+         "rg_router_weight": np.diag([3.0, 3.0, -3.0, -3.0]),
+         "rg_expert_gate_weight": _f64(4, 4, 4) * 0.4,
+         "rg_expert_up_weight": _f64(4, 4, 4) * 0.4,
+         "rg_expert_down_weight": _f64(4, 4, 4) * 0.4,
+         "rg_shared_gate_weight": _f64(4, 4) * 0.4,
+         "rg_shared_up_weight": _f64(4, 4) * 0.4,
+         "rg_shared_down_weight": _f64(4, 4) * 0.4},
+        {"rtol": 5e-2, "atol": 5e-3}),
     # the scores sum to one a token: they are weighed by a fixed "w" so
     # that their gradient shows in the sum of the outputs
     "MLPRouter": lambda: (

@@ -12,7 +12,8 @@ chip time:
    kernels (s1024 d64; s8192 at latent attention's 192/128, at grouped
    heads of 128 and of 256), the gated delta rule's forward and backward
    kernels (s8192, 16 key heads on 32 value heads of 128; and, with four
-   devices, per device on a dp2 × tp2 mesh), the
+   devices, per device on a dp2 × tp2 mesh), Kimi delta attention's
+   (s8192, 32 heads of 128, a decay a key channel; on the mesh too), the
    weight-only quantized matmul at
    GPT-2-small FFN shapes and at the LM head of 50,257, and the fused
    optimizer sweep at one bucket the size of ResNet-50's parameters;
@@ -129,6 +130,19 @@ def kernel_cases():
     cases.append(("gated_delta_fwd_bwd[bfloat16,16on32]",
                   jax.grad(rule_loss, argnums=(0, 1, 2, 3, 4)),
                   (qk, qk, v, scalar, scalar)))
+    # Kimi delta attention at Ling's timed shape: 8,192 tokens, 32 heads of
+    # 128, a decay a key channel
+    from mxnet_tpu.ops.linear_attention import kda_rule
+    b, _s, h, d, _short = widths["kda_rule"]
+
+    def kda_loss(*args):
+        return jnp.sum(kda_rule(*args).astype(jnp.float32))
+
+    qkv, g = sds((b, 8192, h, d), jnp.bfloat16), sds((b, 8192, h, d),
+                                                     jnp.float32)
+    cases.append(("kda_fwd_bwd[bfloat16,32x128]",
+                  jax.grad(kda_loss, argnums=(0, 1, 2, 3, 4)),
+                  (qkv, qkv, qkv, g, sds((b, 8192, h), jnp.float32))))
     # serve/lm (GPT-2 small): decode rows 8, prefill rows 256
     for m, k, n in widths["qmm"]:
         for dt in (jnp.float32, jnp.bfloat16):
@@ -176,34 +190,41 @@ def compile_kernels(device):
     return report
 
 
-def compile_delta_rule_on_mesh(devs):
-    """Mosaic calls in the gated delta rule's gradient compiled for a
-    dp2 × tp2 mesh of ``devs``, traced under ``attention_scope`` as
-    ``ShardedTrainer`` traces its step: GSPMD cannot partition a Mosaic
-    kernel, so the rule must carry both kernels per device under shard_map,
-    the batch split over dp and the key heads over tp."""
+def compile_delta_rule_on_mesh(devs, kda=False):
+    """Mosaic calls in the gated delta rule's gradient — or, with ``kda``,
+    Kimi delta attention's — compiled for a dp2 × tp2 mesh of ``devs``,
+    traced under ``attention_scope`` as ``ShardedTrainer`` traces its step:
+    GSPMD cannot partition a Mosaic kernel, so the rule must carry both
+    kernels per device under shard_map, the batch split over dp and the key
+    heads over tp."""
     import numpy as np
     import jax
     import jax.numpy as jnp
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
     import chip_smoke
-    from mxnet_tpu.ops.linear_attention import gated_delta_rule
+    from mxnet_tpu.ops.linear_attention import gated_delta_rule, kda_rule
     from mxnet_tpu.parallel.ring_attention import attention_scope
     mesh = Mesh(np.array(devs[:4]).reshape(2, 2), ("dp", "tp"))
-    _b, _s, h_k, h_v, d_k, d_v, _short = chip_smoke.FULL["kernels"][
-        "delta_rule"]
+    widths = chip_smoke.FULL["kernels"]
+    if kda:
+        _b, _s, h_v, d_v, _short = widths["kda_rule"]
+        h_k, d_k = h_v, d_v
+    else:
+        _b, _s, h_k, h_v, d_k, d_v, _short = widths["delta_rule"]
     rows = NamedSharding(mesh, P("dp"))
 
     def sds(*shape, dtype=jnp.bfloat16):
         return jax.ShapeDtypeStruct((2, 8192) + shape, dtype, sharding=rows)
 
     def rule_loss(*args):
-        return jnp.sum(gated_delta_rule(*args).astype(jnp.float32))
+        rule = kda_rule if kda else gated_delta_rule
+        return jnp.sum(rule(*args).astype(jnp.float32))
 
     scalar = sds(h_v, dtype=jnp.float32)
+    g = sds(h_k, d_k, dtype=jnp.float32) if kda else scalar
     with attention_scope(mesh):
         text = jax.jit(jax.grad(rule_loss, argnums=(0, 1, 2, 3, 4))).lower(
-            sds(h_k, d_k), sds(h_k, d_k), sds(h_v, d_v), scalar,
+            sds(h_k, d_k), sds(h_k, d_k), sds(h_v, d_v), g,
             scalar).compile().as_text()
     return text.count(MOSAIC)
 
@@ -244,7 +265,7 @@ def kernel_scopes(text, sym):
     nodes = device_scopes.graph_nodes(sym)
     found = {}
     for name, op_name in device_scopes.parse(text).items():
-        if name.startswith(("flash_", "gated_delta_")):
+        if name.startswith(("flash_", "gated_delta_", "kda_")):
             phase, node, _sub = device_scopes.classify(op_name, nodes)
             found.setdefault("%s %s" % (name.split(".")[0], phase),
                              set()).add(node)
@@ -310,7 +331,10 @@ def main():
     if len(devs) >= 4:
         out["delta_rule_dp2tp2_mosaic_calls"] = compile_delta_rule_on_mesh(
             devs)
-        ok = ok and out["delta_rule_dp2tp2_mosaic_calls"] == 2
+        out["kda_rule_dp2tp2_mosaic_calls"] = compile_delta_rule_on_mesh(
+            devs, kda=True)
+        ok = ok and out["delta_rule_dp2tp2_mosaic_calls"] == 2 \
+            and out["kda_rule_dp2tp2_mosaic_calls"] == 2
     out["hybrid_kernel_scopes"] = hybrid_kernel_scopes(devs)
     ok = ok and all(found == HYBRID_SCOPES
                     for found in out["hybrid_kernel_scopes"].values())
